@@ -1,31 +1,2 @@
-import jax as _jax
-
-# jax < 0.4.38 ships shard_map only under jax.experimental.shard_map, with
-# the older kwarg vocabulary (check_rep / auto) instead of the stable
-# spelling's (check_vma / axis_names). The distributed/static stack calls
-# the stable `jax.shard_map`; adapt it once here (core is the first
-# paddle_tpu package imported) so both jax generations work.
-if not hasattr(_jax, "shard_map"):
-    try:
-        from jax.experimental.shard_map import shard_map as _exp_shard_map
-
-        def _shard_map_compat(f, mesh=None, in_specs=None, out_specs=None,
-                              axis_names=None, check_vma=None, **kw):
-            if check_vma is not None and "check_rep" not in kw:
-                kw["check_rep"] = bool(check_vma)
-            if axis_names and mesh is not None and "auto" not in kw:
-                # stable API: axis_names = axes handled MANUALLY (empty /
-                # omitted = all manual, which is the old API's default —
-                # so only a NON-empty set translates); old API: auto =
-                # axes NOT handled manually
-                kw["auto"] = frozenset(mesh.axis_names) \
-                    - frozenset(axis_names)
-            return _exp_shard_map(f, mesh=mesh, in_specs=in_specs,
-                                  out_specs=out_specs, **kw)
-
-        _jax.shard_map = _shard_map_compat
-    except ImportError:
-        pass  # truly ancient jax: the distributed stack will fail loudly
-
 from . import dtype, place, random, flags, autograd, tensor  # noqa: F401
 from .tensor import Tensor, Parameter  # noqa: F401

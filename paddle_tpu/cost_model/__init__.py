@@ -10,29 +10,43 @@ import json
 import os
 import time
 
-__all__ = ["CostModel", "device_peak_flops"]
+__all__ = ["CostModel", "device_peak_flops", "PEAK_BF16_FLOPS"]
 
 
-def device_peak_flops():
-    """bf16 peak FLOP/s of the local accelerator — the MFU denominator
-    shared by bench.py and profiler.Profiler.summary(). CPU gets a
-    nominal 1e12 so degraded runs still produce a (tagged) number."""
-    import jax
+# bf16 peak FLOP/s per chip, keyed by the `device_kind` string JAX reports.
+# Peaks: Google Cloud TPU documentation, system-architecture pages (v4: 275
+# TFLOP/s, v5e: 197, v5p: 459, v6e: 918, each per chip = per JAX device).
+# Kind spellings: the installed jax's own table
+# (jax/_src/pallas/mosaic/tpu_info.py); "TPU v5 lite" is what the v5e reports.
+# A kind that is not listed is an error, never a default: a utilization
+# against a guessed peak is not a measurement.
+PEAK_BF16_FLOPS = {
+    "TPU v4": 275e12,
+    "TPU v5 lite": 197e12,
+    "TPU v5e": 197e12,
+    "TPU v5": 459e12,
+    "TPU v5p": 459e12,
+    "TPU v6 lite": 918e12,
+    "TPU v6e": 918e12,
+}
 
-    dev = jax.devices()[0]
-    kind = getattr(dev, "device_kind", "").lower()
-    # TPU v5 lite (v5e): 197 TFLOP/s bf16; v5p: 459; v4: 275; v3: 123
-    if "v5 lite" in kind or "v5e" in kind:
-        return 197e12
-    if "v5p" in kind or "v5" in kind:
-        return 459e12
-    if "v4" in kind:
-        return 275e12
-    if "v3" in kind:
-        return 123e12
-    if dev.platform == "cpu":
-        return 1e12
-    return 197e12  # default to v5e
+
+def device_peak_flops(device=None):
+    """bf16 peak FLOP/s of `device` (default: the first JAX device) — the
+    MFU denominator. Raises ``LookupError`` for any device kind missing
+    from ``PEAK_BF16_FLOPS``, the host CPU included."""
+    if device is None:
+        import jax
+
+        device = jax.devices()[0]
+    kind = getattr(device, "device_kind", "")
+    try:
+        return PEAK_BF16_FLOPS[kind]
+    except KeyError:
+        raise LookupError(
+            f"no bf16 peak on record for device kind {kind!r} (platform "
+            f"{device.platform!r}); known kinds: {sorted(PEAK_BF16_FLOPS)}"
+        ) from None
 
 
 class CostModel:

@@ -207,9 +207,7 @@ def _shard_map_call(group, fn, *arrays, in_specs, out_specs):
     # concrete arrays committed to a single device (the default for
     # to_tensor outputs) are incompatible with a multi-device shard_map —
     # spread them over the group mesh first; tracers (executor replay under
-    # jit) already compose and must not be device_put. per_arg_specs
-    # carries the PartitionSpec-is-a-tuple guard (jax <= 0.4.37 subclasses
-    # tuple, so a bare isinstance check would unpack a single spec).
+    # jit) already compose and must not be device_put.
     specs = per_arg_specs(in_specs, len(arrays))
     placed = []
     for a, spec in zip(arrays, specs):

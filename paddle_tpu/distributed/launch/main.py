@@ -34,7 +34,8 @@ def _parse():
     p.add_argument("--nnodes", type=int, default=1, help="number of hosts")
     p.add_argument("--rank", type=int, default=0, help="this host's rank")
     p.add_argument("--nproc_per_node", type=int, default=1,
-                   help="processes per host (1 for TPU single-controller)")
+                   help="processes per host; must be 1 on a TPU host (one "
+                        "process drives all local chips)")
     p.add_argument("--log_dir", default="log")
     p.add_argument("--devices", default=None,
                    help="visible device ids (TPU_VISIBLE_DEVICES)")
@@ -65,6 +66,46 @@ def _parse():
     p.add_argument("training_script")
     p.add_argument("training_script_args", nargs=argparse.REMAINDER)
     return p.parse_args()
+
+
+def _local_tpu_chips():
+    """TPU chips on this host, counted on the PCI bus (Google's vendor id,
+    the scan jax itself uses to notice an unused TPU) — no JAX backend is
+    initialized, so the launcher never claims a chip its trainer needs."""
+    import glob
+
+    n = 0
+    for path in glob.glob("/sys/bus/pci/devices/*/vendor"):
+        try:
+            with open(path) as f:
+                n += f.read().strip() == "0x1ae0"
+        except OSError:
+            pass
+    return n
+
+
+def _children_on_cpu():
+    """True when the environment pins the trainers' JAX to the CPU."""
+    plats = os.environ.get("JAX_PLATFORMS", "")
+    names = {p.strip().lower() for p in plats.split(",") if p.strip()}
+    return bool(names) and names <= {"cpu"}
+
+
+def _check_one_process_per_chip(nproc_per_node):
+    """A TPU chip belongs to one process, and one process drives every
+    local chip (jax.devices() lists them; fleet.init(use_spmd) shards over
+    them). N trainers with identical device visibility would leave N-1 of
+    them failing or hanging on the claim, so refuse up front."""
+    if nproc_per_node <= 1 or _children_on_cpu():
+        return
+    chips = _local_tpu_chips()
+    if chips:
+        sys.exit(
+            f"[launch] --nproc_per_node={nproc_per_node} on a host with "
+            f"{chips} TPU chip(s): a chip belongs to one process, and one "
+            "process drives all local chips. Use --nproc_per_node 1 (the "
+            "trainer sees every chip in jax.devices()), or set "
+            "JAX_PLATFORMS=cpu for a CPU world.")
 
 
 def _rc_describe(rc):
@@ -494,6 +535,7 @@ def _rendezvous(args):
 
 def launch():
     args = _parse()
+    _check_one_process_per_chip(args.nproc_per_node)
     if args.elastic and args.nnodes > 1:
         # Pod-level elastic resize reasons about the LOCAL proc table as
         # the world (rank remapping, shrink targets, generation
@@ -536,6 +578,8 @@ def launch():
             "PADDLE_LOCAL_RANK": str(local_rank),
             "FLAGS_selected_tpus": args.devices or "",
         })
+        if args.devices:
+            env["TPU_VISIBLE_DEVICES"] = args.devices
         cmd = [sys.executable, "-u", args.training_script,
                *args.training_script_args]
         pod.spawn(cmd, env, os.path.join(args.log_dir,

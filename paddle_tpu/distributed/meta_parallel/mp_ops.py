@@ -44,30 +44,17 @@ __all__ = ["axis_in_scope", "mp_axis_size", "mp_rank",
 MP_AXIS = "mp"
 
 
-def _axis_size(name):
-    """jax.lax.axis_size with a jax<=0.4.37 fallback (the symbol landed
-    later; on old jax, jax.core.axis_frame(name) IS the size int, raising
-    when the axis is unbound). Without this the axis_in_scope probe below
-    reported False inside every shard_map region and the manual-mp
-    collectives silently degraded to identity."""
-    fn = getattr(jax.lax, "axis_size", None)
-    if fn is not None:
-        return fn(name)
-    v = jax.core.axis_frame(name)
-    return getattr(v, "size", v)
-
-
 def axis_in_scope(name: str = MP_AXIS) -> bool:
     """True when `name` is a manual (shard_map) axis in the current trace."""
     try:
-        _axis_size(name)
+        jax.lax.axis_size(name)
         return True
     except Exception:
         return False
 
 
 def mp_axis_size(axis: str = MP_AXIS) -> int:
-    return _axis_size(axis)
+    return jax.lax.axis_size(axis)
 
 
 def mp_rank(axis: str = MP_AXIS):
@@ -222,7 +209,7 @@ def _c_split(x, group=None, axis: str = MP_AXIS):
     """Keep this rank's chunk of the last dim (mp_ops.py:145)."""
     _tally("_c_split", x)
     if axis_in_scope(axis):
-        n = _axis_size(axis)
+        n = jax.lax.axis_size(axis)
         rank = jax.lax.axis_index(axis)
         chunk = x.shape[-1] // n
         return jax.lax.dynamic_slice_in_dim(x, rank * chunk, chunk, -1)

@@ -42,10 +42,12 @@ tick's carry); `recompute=True` wraps the per-block body in
 `jax.checkpoint` for the usual trade.
 
 jaxlib note: no `shard_map` and no `with_sharding_constraint` on the
-loop carry — manual-'pp'-plus-auto-axes regions fail to lower on jaxlib
-<= 0.4.36, and a constraint on the scanned activation buffer miscompiles
-its gradient there (bisected; the executable-boundary in_shardings the
-capture engine pins are sufficient to drive propagation).
+loop carry — on the jaxlib this step was first written against,
+manual-'pp'-plus-auto-axes regions failed to lower and a constraint on
+the scanned activation buffer miscompiled its gradient (bisected; the
+executable-boundary in_shardings the capture engine pins are sufficient
+to drive propagation). Not re-examined on the installed jaxlib
+(ROADMAP D9).
 """
 from __future__ import annotations
 
@@ -499,11 +501,12 @@ class PipelineSpmdStep:
             if ticks <= self.unroll_ticks:
                 # unrolled form (the ISSUE's sanctioned alternative):
                 # static microbatch indices and ingest/loss masks. Also
-                # the jaxlib-0.4.36 workaround — differentiating the
-                # tick scan under jax_enable_x64 hits an
-                # s64/s32 partitioned-dynamic-update-slice verifier bug
-                # at M=1 (bisected; the unrolled form never builds the
-                # jvp while loop)
+                # a workaround from the jaxlib this was written against
+                # — differentiating the tick scan under jax_enable_x64
+                # hit an s64/s32 partitioned-dynamic-update-slice
+                # verifier bug at M=1 (bisected; the unrolled form never
+                # builds the jvp while loop). Kept until a cell can
+                # judge its removal (ROADMAP D9/D4)
                 act, acc = act0, jnp.float32(0.0)
                 for t in range(ticks):
                     if t < M:

@@ -72,7 +72,7 @@ from ..profiler import registry as _registry
 
 __all__ = ["enable", "disable", "enabled", "current_mesh", "spmd_guard",
            "mesh_from_hcg", "serving_mesh", "param_pspec",
-           "per_arg_specs", "is_single_spec", "shard_model",
+           "per_arg_specs", "shard_model",
            "shard_batch", "describe_plans", "remesh_for_world"]
 
 # shared scope with core/lazy.py (step_compiles / python_collectives /
@@ -86,22 +86,13 @@ _counters = _registry.scoped_counters("spmd", {
 
 # ---------------------------- shared spec helpers ----------------------------
 
-def is_single_spec(obj):
-    """True when `obj` is ONE PartitionSpec rather than a tuple of specs.
-
-    PartitionSpec itself subclasses tuple on jax <= 0.4.37, so a bare
-    `isinstance(obj, tuple)` check unpacks a single spec into its axis
-    entries — the guard every in_specs consumer needs (shared by
-    collective._shard_map_call and the spec-derivation code here)."""
-    return isinstance(obj, PartitionSpec) or not isinstance(obj, tuple)
-
-
 def per_arg_specs(specs, n):
-    """Broadcast `specs` to exactly one spec per argument, honoring the
-    PartitionSpec-is-a-tuple guard above."""
-    if is_single_spec(specs):
+    """Broadcast `specs` to exactly one spec per argument: a single
+    PartitionSpec (or None) serves every argument, a tuple is taken as
+    given."""
+    if not isinstance(specs, tuple):
         return (specs,) * n
-    return tuple(specs)
+    return specs
 
 
 def param_pspec(spec, mesh, shape=None):

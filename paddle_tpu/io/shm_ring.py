@@ -18,15 +18,15 @@ def _lib():
     # NO package imports here: this module is loaded standalone inside
     # JAX-free DataLoader worker children (see _worker.py) — pulling in
     # paddle_tpu.sysconfig would import the whole package and JAX with it.
-    # Build-on-demand mirrors sysconfig.ensure_native_built incl. the
-    # flock guard against concurrent cold-start builds.
+    # Build-on-demand mirrors sysconfig.ensure_native_built (make decides
+    # what is stale), incl. the flock guard against concurrent builds.
     here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     lib_dir = os.path.join(here, "lib")
     so = os.path.join(lib_dir, "libshmring.so")
-    if not os.path.exists(so):
+    src = os.path.join(os.path.dirname(here), "csrc")
+    if os.path.exists(os.path.join(src, "Makefile")):
         import subprocess
 
-        src = os.path.join(os.path.dirname(here), "csrc")
         os.makedirs(lib_dir, exist_ok=True)
         with open(os.path.join(lib_dir, ".build.lock"), "w") as lock:
             try:
@@ -35,9 +35,9 @@ def _lib():
                 fcntl.flock(lock, fcntl.LOCK_EX)
             except ImportError:
                 pass
-            if not os.path.exists(so):
-                subprocess.run(["make", "-C", src], check=True,
-                               capture_output=True)
+            subprocess.run(
+                ["make", "-C", src, "../paddle_tpu/lib/libshmring.so"],
+                check=True, capture_output=True)
     lib = ctypes.CDLL(so)
     lib.ptshm_create.restype = ctypes.c_void_p
     lib.ptshm_create.argtypes = [ctypes.c_char_p, ctypes.c_uint64,

@@ -225,7 +225,7 @@ class TrainStep:
             for (oi, an, pi, t), arr in zip(self._acc_refs, acc_arrays):
                 t._data = arr
             for o, s in zip(opts, steps):
-                o._opt_step = s + 1
+                o._opt_step = s  # fn's own opt.step() advances it
             prandom.set_rng_state(key)
             try:
                 out = fn(*[Tensor(a) for a in arg_arrays])

@@ -14,21 +14,28 @@ Every public entry point falls back to a pure-XLA implementation when the
 platform is not TPU or shapes don't tile (CPU tests, odd seq lens), so
 numerics are always available — the same role the reference's CPU reference
 kernels play for its CUDA ops.
+
+Kernel bodies are x64-proof where they are written: the package turns
+``jax_enable_x64`` on, under which a bare python float or int becomes an
+f64/i64 constant that Mosaic has no type for. Every in-kernel constant is
+therefore a typed 32-bit scalar (``_f32`` / ``jnp.int32``), and every MXU dot
+names its precision (``_dot_precision``) instead of inheriting the global
+``jax_default_matmul_precision``.
 """
 from __future__ import annotations
 
 import functools
+import math
 import os
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import PartitionSpec as P
 
-try:  # pltpu only importable when libtpu present; guard for CPU CI
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
-
+from ..core import lazy as _lazy
 from ..profiler import explainer as _explain
 from ..profiler import registry as _registry
 
@@ -69,16 +76,35 @@ def _env_flag(name: str) -> bool:
 def _on_tpu() -> bool:
     if _env_flag("PADDLE_TPU_DISABLE_PALLAS"):  # perf A/B escape hatch
         return False
-    try:
-        return jax.default_backend() not in ("cpu",) and pltpu is not None
-    except Exception:  # pragma: no cover
-        return False
+    return jax.default_backend() == "tpu"
 
 
 def _i0():
     """int32 zero for BlockSpec index maps: under jax_enable_x64 a bare
     python 0 lowers as an i64 constant, which Mosaic rejects."""
     return jnp.int32(0)
+
+
+# typed in-kernel float constants (numpy scalars: strongly typed f32 in a
+# trace, and building them touches no JAX backend at import)
+_f32 = np.float32
+_NEG_INF = _f32(-np.inf)
+_TINY = _f32(1e-30)
+
+
+def _dot_precision(dtype):
+    """MXU precision for a kernel's dots, chosen from the operand dtype the
+    caller handed in. bf16 inputs are exact in one bf16 MXU pass (DEFAULT)
+    — the same arithmetic XLA gives the model's own bf16 matmuls; f32
+    inputs keep the package's full-f32 contract (HIGHEST, the multi-pass
+    decomposition) so f32 parity bounds hold on the chip as in the
+    interpreter. Pinned per dot because the kernels upcast their operands
+    to f32 in VMEM: left to the global ``jax_default_matmul_precision``
+    ("highest") a bf16 model would pay the multi-pass rate for bits its
+    inputs never had."""
+    if jnp.dtype(dtype) == jnp.dtype(jnp.bfloat16):
+        return jax.lax.Precision.DEFAULT
+    return jax.lax.Precision.HIGHEST
 
 
 # =========================== flash attention =================================
@@ -91,12 +117,14 @@ def _i0():
 # (`phi/kernels/gpu/flash_attn_kernel.cu`, `flash_attn_grad_kernel.cu`).
 
 def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, causal,
-                      block_q, block_k, seq_len):
+                      block_q, block_k, seq_len, precision):
     head_dim = q_ref.shape[-1]
-    q = q_ref[:].astype(jnp.float32) * scale
+    dot = functools.partial(jnp.dot, preferred_element_type=jnp.float32,
+                            precision=precision)
+    q = q_ref[:].astype(jnp.float32) * _f32(scale)
     q_blk = pl.program_id(1)
 
-    m0 = jnp.full((block_q, 1), -jnp.inf, jnp.float32)
+    m0 = jnp.full((block_q, 1), _NEG_INF, jnp.float32)
     l0 = jnp.zeros((block_q, 1), jnp.float32)
     acc0 = jnp.zeros((block_q, head_dim), jnp.float32)
 
@@ -114,28 +142,32 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, causal,
         m, l, acc = carry
         k = k_ref[pl.ds(i * bk, block_k), :].astype(jnp.float32)
         v = v_ref[pl.ds(i * bk, block_k), :].astype(jnp.float32)
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32)
+        s = dot(q, k.T)
         if causal:
             qpos = q_blk * bq + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 0)
             kpos = i * bk + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(qpos >= kpos, s, -jnp.inf)
+            s = jnp.where(qpos >= kpos, s, _NEG_INF)
         m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
         p = jnp.exp(s - m_new)
         corr = jnp.exp(m - m_new)
         l_new = l * corr + p.sum(axis=-1, keepdims=True)
-        acc_new = acc * corr + jnp.dot(p, v, preferred_element_type=jnp.float32)
+        acc_new = acc * corr + dot(p, v)
         return m_new, l_new, acc_new
 
-    m, l, acc = jax.lax.fori_loop(0, hi, body, (m0, l0, acc0))
-    l = jnp.maximum(l, 1e-30)
+    m, l, acc = jax.lax.fori_loop(jnp.int32(0), hi, body, (m0, l0, acc0))
+    l = jnp.maximum(l, _TINY)
     o_ref[:] = (acc / l).astype(o_ref.dtype)
     lse_ref[:] = m + jnp.log(l)
 
 
 def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                         dq_ref, *, scale, causal, block_q, block_k, seq_len):
+                         dq_ref, *, scale, causal, block_q, block_k, seq_len,
+                         precision):
+    dot = functools.partial(jnp.dot, preferred_element_type=jnp.float32,
+                            precision=precision)
+    scale = _f32(scale)
     q = q_ref[:].astype(jnp.float32)
     do = do_ref[:].astype(jnp.float32)
     lse = lse_ref[:]
@@ -152,26 +184,29 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     def body(i, dq):
         k = k_ref[pl.ds(i * bk, block_k), :].astype(jnp.float32)
         v = v_ref[pl.ds(i * bk, block_k), :].astype(jnp.float32)
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
+        s = dot(q, k.T) * scale
         if causal:
             qpos = q_blk * bq + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 0)
             kpos = i * bk + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(qpos >= kpos, s, -jnp.inf)
+            s = jnp.where(qpos >= kpos, s, _NEG_INF)
         p = jnp.exp(s - lse)
-        dp = jnp.dot(do, v.T, preferred_element_type=jnp.float32)
+        dp = dot(do, v.T)
         ds = p * (dp - delta)
-        return dq + jnp.dot(ds, k, preferred_element_type=jnp.float32)
+        return dq + dot(ds, k)
 
     dq0 = jnp.zeros_like(q)
-    dq = jax.lax.fori_loop(0, hi, body, dq0)
+    dq = jax.lax.fori_loop(jnp.int32(0), hi, body, dq0)
     dq_ref[:] = (dq * scale).astype(dq_ref.dtype)
 
 
 def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                           dk_ref, dv_ref, *, scale, causal, block_q, block_k,
-                          seq_len):
+                          seq_len, precision):
+    dot = functools.partial(jnp.dot, preferred_element_type=jnp.float32,
+                            precision=precision)
+    scale = _f32(scale)
     k = k_ref[:].astype(jnp.float32)
     v = v_ref[:].astype(jnp.float32)
     k_blk = pl.program_id(1)
@@ -186,18 +221,18 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         do = do_ref[pl.ds(i * bq, block_q), :].astype(jnp.float32)
         lse = lse_ref[pl.ds(i * bq, block_q), :]
         delta = delta_ref[pl.ds(i * bq, block_q), :]
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
+        s = dot(q, k.T) * scale
         if causal:
             qpos = i * bq + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 0)
             kpos = k_blk * bk + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(qpos >= kpos, s, -jnp.inf)
+            s = jnp.where(qpos >= kpos, s, _NEG_INF)
         p = jnp.exp(s - lse)
-        dv_new = dv + jnp.dot(p.T, do, preferred_element_type=jnp.float32)
-        dp = jnp.dot(do, v.T, preferred_element_type=jnp.float32)
+        dv_new = dv + dot(p.T, do)
+        dp = dot(do, v.T)
         ds = p * (dp - delta)
-        dk_new = dk + jnp.dot(ds.T, q, preferred_element_type=jnp.float32)
+        dk_new = dk + dot(ds.T, q)
         return dk_new, dv_new
 
     dk0 = jnp.zeros_like(k)
@@ -213,7 +248,8 @@ def _flash_fwd_call(q, k, v, causal, scale, block_q, block_k):
     grid = (BN, T // block_q)
     out, lse = pl.pallas_call(
         functools.partial(_flash_fwd_kernel, scale=scale, causal=causal,
-                          block_q=block_q, block_k=block_k, seq_len=T),
+                          block_q=block_q, block_k=block_k, seq_len=T,
+                          precision=_dot_precision(q.dtype)),
         grid=grid,
         in_specs=[
             pl.BlockSpec((None, block_q, H), lambda b, i: (b, i, _i0())),
@@ -249,7 +285,8 @@ def _flash_flat_bwd(causal, scale, block_q, block_k, res, do):
     delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
                     axis=-1, keepdims=True)
     common = dict(scale=scale, causal=causal, block_q=block_q,
-                  block_k=block_k, seq_len=T)
+                  block_k=block_k, seq_len=T,
+                  precision=_dot_precision(q.dtype))
     full = lambda b, i: (b, _i0(), _i0())  # noqa: E731
     row = lambda b, i: (b, i, _i0())  # noqa: E731
     dq = pl.pallas_call(
@@ -351,80 +388,123 @@ def _stock_flash():
         return None
 
 
+def _flash_block_env(name, default, T):
+    """``PADDLE_TPU_FLASH_BLOCK_Q/K`` override, honored only when it is a
+    positive divisor of the sequence length (a partial block would
+    silently drop tail rows)."""
+    import warnings
+
+    raw = os.environ.get(name)
+    if raw is None:
+        return default
+    try:
+        val = int(raw)
+    except ValueError:
+        warnings.warn(f"{name}={raw!r} is not an int; using {default}")
+        return default
+    if val <= 0 or T % val:
+        warnings.warn(f"{name}={val} does not divide seq_len {T}; using "
+                      f"{default}")
+        return default
+    return val
+
+
+def _flash_shape_refusal(q, k, mask):
+    """Why the Pallas flash kernel cannot take this call (None = it can)."""
+    T, H = q.shape[1], q.shape[3]
+    if mask is not None:
+        return "explicit attn_mask (flash kernel is mask-free)"
+    if k.shape[1] != T:
+        return f"cross-length kv (T={T}, S={k.shape[1]})"
+    if T % 128:
+        return f"seq_len {T} not a multiple of 128"
+    if H not in (64, 96, 128, 256):
+        return f"head_dim {H} not in (64, 96, 128, 256)"
+    if q.dtype not in (jnp.float32, jnp.bfloat16):
+        return f"dtype {q.dtype} not in (float32, bfloat16)"
+    return None
+
+
+def _flash_mesh_spec(mesh, batch, heads):
+    """How the flash call splits over the installed SPMD mesh. A Mosaic
+    custom call has no partitioning rule — GSPMD refuses it outright
+    ("Mosaic kernels cannot be automatically partitioned") — so under a
+    mesh the kernel runs per shard through ``jax.shard_map``: batch over
+    the data axes ('dp', and 'ep' whose ranks are data-parallel for the
+    dense trunk), heads over 'mp', each shard the unmodified kernel on
+    its local [B/dp, T, N/mp, H] slice (attention never mixes batch rows
+    or heads, so no collective is needed). Returns ``(spec, None)``,
+    ``(None, None)`` when no split is needed, or ``(None, why)`` when
+    this mesh has no per-shard plan and the call must take the XLA path
+    (which GSPMD partitions by itself)."""
+    from ..distributed.meta_parallel.mp_ops import axis_in_scope
+
+    axes = {n: int(s) for n, s in zip(mesh.axis_names, mesh.devices.shape)
+            if int(s) > 1}
+    # an axis already manual in this trace: the caller runs per shard
+    if not axes or any(axis_in_scope(a) for a in axes):
+        return None, None
+    unplanned = sorted(set(axes) - {"dp", "ep", "mp"})
+    if unplanned:
+        return None, (f"mesh axes {unplanned} have no per-shard flash "
+                      "plan")
+    data = tuple(a for a in ("dp", "ep") if a in axes)
+    n_data = math.prod(axes[a] for a in data)
+    if batch % n_data:
+        return None, (f"batch {batch} does not divide over mesh axes "
+                      f"{data} = {n_data}")
+    mp = axes.get("mp", 1)
+    if heads % mp:
+        return None, f"{heads} heads do not divide over mesh axis mp={mp}"
+    return P(data or None, None, "mp" if mp > 1 else None, None), None
+
+
 def flash_attention(q, k, v, mask=None, causal=False, scale=None):
-    """[B, T, N, H] attention; Pallas on TPU when tileable, XLA otherwise."""
+    """[B, T, N, H] attention; Pallas on TPU when tileable, XLA otherwise.
+    Under an installed SPMD mesh the kernel runs per shard (see
+    :func:`_flash_mesh_spec`)."""
     B, T, N, H = q.shape
-    use_pallas = (
-        _on_tpu()
-        and mask is None
-        and k.shape[1] == T
-        and T % 128 == 0
-        and H in (64, 96, 128, 256)
-        and q.dtype in (jnp.float32, jnp.bfloat16)
-    )
-    if use_pallas:
+    on_tpu = _on_tpu()
+    why = _flash_shape_refusal(q, k, mask) if on_tpu else "not on tpu"
+    spec = None
+    mesh = _lazy.spmd_mesh() if why is None else None
+    if mesh is not None:
+        spec, why = _flash_mesh_spec(mesh, B, N)
+    if why is None:
         fa = _stock_flash()
         if fa is not None:
             _flash_counters["flash.stock"] += 1
             sm_scale = float(scale) if scale is not None else H ** -0.5
-            # library kernel layout is [B, N, T, H]
-            qt = q.transpose(0, 2, 1, 3)
-            kt = k.transpose(0, 2, 1, 3)
-            vt = v.transpose(0, 2, 1, 3)
-            out = fa.flash_attention(qt, kt, vt, causal=causal,
-                                     sm_scale=sm_scale)
-            out = out.transpose(0, 2, 1, 3)
+
+            def kernel(q, k, v):  # library kernel layout is [B, N, T, H]
+                out = fa.flash_attention(
+                    q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
+                    v.transpose(0, 2, 1, 3), causal=causal,
+                    sm_scale=sm_scale)
+                return out.transpose(0, 2, 1, 3)
         else:
-            import warnings
-
             _flash_counters["flash.pallas"] += 1
-
             blk = 256 if T % 256 == 0 else 128
-
-            def _blk_env(name, default):
-                raw = os.environ.get(name)
-                if raw is None:
-                    return default
-                try:
-                    val = int(raw)
-                except ValueError:
-                    warnings.warn(f"{name}={raw!r} is not an int; using "
-                                  f"{default}")
-                    return default
-                if val <= 0 or T % val:
-                    # the kernel grid requires block | seq_len; a partial
-                    # block would silently drop tail rows
-                    warnings.warn(f"{name}={val} does not divide seq_len "
-                                  f"{T}; using {default}")
-                    return default
-                return val
-
-            bq = _blk_env("PADDLE_TPU_FLASH_BLOCK_Q", blk)
-            bk = _blk_env("PADDLE_TPU_FLASH_BLOCK_K", blk)
-            out = _flash_attention_tpu(q, k, v, causal=causal, scale=scale,
-                                       block_q=bq, block_k=bk)
+            kernel = functools.partial(
+                _flash_attention_tpu, causal=causal, scale=scale,
+                block_q=_flash_block_env("PADDLE_TPU_FLASH_BLOCK_Q", blk, T),
+                block_k=_flash_block_env("PADDLE_TPU_FLASH_BLOCK_K", blk, T))
+        if spec is not None:
+            kernel = jax.shard_map(kernel, mesh=mesh, in_specs=(spec,) * 3,
+                                   out_specs=spec, check_vma=False)
+        out = kernel(q, k, v)
     else:
         # record the fallback REASON when the platform was eligible but a
-        # shape/dtype constraint forced the XLA path (satellite: the flash
+        # shape/dtype/mesh constraint forced the XLA path (the flash
         # selection rides the same counters/explainer as the paged family)
         _flash_counters["flash.xla"] += 1
-        if _on_tpu():
-            if mask is not None:
-                why = "explicit attn_mask (flash kernel is mask-free)"
-            elif k.shape[1] != T:
-                why = f"cross-length kv (T={T}, S={k.shape[1]})"
-            elif T % 128:
-                why = f"seq_len {T} not a multiple of 128"
-            elif H not in (64, 96, 128, 256):
-                why = f"head_dim {H} not in (64, 96, 128, 256)"
-            else:
-                why = f"dtype {q.dtype} not in (float32, bfloat16)"
+        if on_tpu:
             _note_kernel_fallback("flash_attention", why,
                                   shape=str(tuple(q.shape)))
         out = _attention_xla(q, k, v, mask=mask, causal=causal, scale=scale)
     # tag for remat policies: attention is the most expensive op to
-    # rematerialize (profiled ~57% of gpt2-medium step time), so the
-    # "attn"/"dots_attn" recompute policies pin this output in HBM by name
+    # rematerialize, so the "attn"/"dots_attn" recompute policies pin this
+    # output in HBM by name
     from jax.ad_checkpoint import checkpoint_name
 
     return checkpoint_name(out, "attn_out")
@@ -473,7 +553,8 @@ PAGED_PARITY_TOL = {"float32": (3e-5, 3e-5), "bfloat16": (0.05, 0.05)}
 
 
 def _paged_attn_kernel(bt_ref, sl_ref, qo_ref, q_ref, k_ref, v_ref, o_ref,
-                       m_scr, l_scr, acc_scr, *, scale, block_size):
+                       m_scr, l_scr, acc_scr, *, scale, block_size,
+                       precision):
     """Grid (B, M): program (b, j) folds logical block j of slot b into
     the slot's online-softmax state. Scratch (m/l/acc) persists across
     the M dimension; the output block is written once, at the last j."""
@@ -481,10 +562,13 @@ def _paged_attn_kernel(bt_ref, sl_ref, qo_ref, q_ref, k_ref, v_ref, o_ref,
     j = pl.program_id(1)
     T, H = q_ref.shape[0], q_ref.shape[1]
     bs = jnp.int32(block_size)
+    scale = _f32(scale)
+    dot = functools.partial(jnp.dot, preferred_element_type=jnp.float32,
+                            precision=precision)
 
     @pl.when(j == 0)
     def _init():
-        m_scr[...] = jnp.full_like(m_scr, -jnp.inf)
+        m_scr[...] = jnp.full_like(m_scr, _NEG_INF)
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
@@ -504,19 +588,18 @@ def _paged_attn_kernel(bt_ref, sl_ref, qo_ref, q_ref, k_ref, v_ref, o_ref,
             qh = q_ref[:, h, :].astype(jnp.float32) * scale
             kh = k_ref[:, h, :].astype(jnp.float32)
             vh = v_ref[:, h, :].astype(jnp.float32)
-            s = jnp.dot(qh, kh.T, preferred_element_type=jnp.float32)
-            s = jnp.where(mask, s, -jnp.inf)
+            s = dot(qh, kh.T)
+            s = jnp.where(mask, s, _NEG_INF)
             m_new = jnp.maximum(m_scr[h], s.max(axis=-1, keepdims=True))
             p = jnp.exp(s - m_new)
             corr = jnp.exp(m_scr[h] - m_new)
             l_scr[h] = l_scr[h] * corr + p.sum(axis=-1, keepdims=True)
-            acc_scr[h] = acc_scr[h] * corr + jnp.dot(
-                p, vh, preferred_element_type=jnp.float32)
+            acc_scr[h] = acc_scr[h] * corr + dot(p, vh)
             m_scr[h] = m_new
 
     @pl.when(j == pl.num_programs(1) - 1)
     def _finalize():
-        l = jnp.maximum(l_scr[...], 1e-30)
+        l = jnp.maximum(l_scr[...], _TINY)
         o_ref[...] = (acc_scr[...] / l).transpose(1, 0, 2).astype(
             o_ref.dtype)
 
@@ -556,7 +639,8 @@ def _paged_attention_fused(q, k_pool, v_pool, block_tables, seq_lens,
         ],
     )
     return pl.pallas_call(
-        functools.partial(_paged_attn_kernel, scale=scale, block_size=bs),
+        functools.partial(_paged_attn_kernel, scale=scale, block_size=bs,
+                          precision=_dot_precision(q.dtype)),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, T, H, Dh), q.dtype),
         interpret=interpret,
@@ -580,8 +664,6 @@ def _paged_attention_sharded(q, k_pool, v_pool, block_tables, seq_lens,
     independently (per-head scratch rows, no cross-head reduction), so
     the sharded result is bitwise the single-chip result. check_vma is
     off because pallas_call carries no replication rule."""
-    from jax.sharding import PartitionSpec as P
-
     mp = _mesh_mp_degree(mesh)
     H = int(q.shape[2])
     if H % mp:  # select_paged_kernel prevents this; defensive
@@ -672,19 +754,34 @@ def paged_attention(q, k_pool, v_pool, block_tables, seq_lens, q_offsets,
     return out
 
 
-def paged_tileable(head_dim, block_size, dtype):
-    """Can the COMPILED kernel tile these shapes on a real TPU? (The
-    interpreter route has no tiling constraints.) Returns (ok, reason)."""
+# largest pool block [block_size, H, Dh] the kernel's pipeline fits: K and V,
+# double-buffered, share the 16 MiB of VMEM Mosaic scopes to one kernel with
+# the fp32 scratch. Compiled for the v5e, 2 MiB blocks fit and 4 MiB blocks
+# end in RESOURCE_EXHAUSTED (tests/test_tpu_lowering.py).
+_PAGED_MAX_BLOCK_BYTES = 2 << 20
+
+
+def paged_tileable(head_dim, block_size, dtype, num_heads=None):
+    """Will Mosaic compile the kernel for this pool geometry? (The
+    interpreter route has no such constraint.) Returns (ok, reason).
+
+    The pool block spans the whole (H, Dh) minor dims of the pool, so no
+    head_dim or block_size fails to tile: compiled for the v5e, every
+    head_dim in 32..256 x block_size in 4..32 x heads in 1..16 is accepted
+    in fp32 and bf16. What is refused is a dtype the body has no arithmetic
+    for and a block too large for VMEM (judged when ``num_heads`` — the
+    heads one shard holds — is given)."""
     dt = jnp.dtype(dtype)
     if dt not in (jnp.dtype(jnp.float32), jnp.dtype(jnp.bfloat16)):
         return False, f"pool dtype {dt.name} not in (float32, bfloat16)"
-    if head_dim % 64:
-        return False, (f"head_dim {head_dim} not a multiple of 64 "
-                       "(VPU lane alignment)")
-    sub = 8 if dt == jnp.dtype(jnp.float32) else 16
-    if block_size % sub:
-        return False, (f"block_size {block_size} not a multiple of the "
-                       f"{dt.name} sublane tile {sub}")
+    if num_heads is not None:
+        block = block_size * num_heads * head_dim * dt.itemsize
+        if block > _PAGED_MAX_BLOCK_BYTES:
+            return False, (
+                f"one KV block [{block_size}, {num_heads}, {head_dim}] "
+                f"{dt.name} is {block / 2 ** 20:.1f} MiB; K and V blocks, "
+                "double-buffered, would not fit the kernel's VMEM (limit "
+                f"{_PAGED_MAX_BLOCK_BYTES >> 20} MiB per block)")
     return True, "tileable"
 
 
@@ -707,8 +804,7 @@ def select_paged_kernel(requested=None, *, head_dim, block_size, dtype,
     A ``mesh`` whose 'mp' axis has > 1 devices resolves PER SHARD: the
     kernel is head-parallel, so when ``num_heads`` divides mp each
     shard runs the unmodified body over its local num_heads/mp heads
-    (tileability depends only on head_dim/block_size/dtype, which head
-    sharding does not change). Indivisible or unknown head counts
+    (a shard's KV block holds only its local heads). Indivisible or unknown head counts
     demote to the GSPMD gather path with a loud fallback naming both
     numbers. Returns ``(kind, reason)`` and bumps
     ``serving.kernel.<kind>`` — call once at engine build, never per
@@ -724,14 +820,12 @@ def select_paged_kernel(requested=None, *, head_dim, block_size, dtype,
             "\"interpret\" is a RESOLVED kind, not a request — ask for "
             "pallas and off-chip engines run the interpreter)")
     on_tpu = _on_tpu()
-    ok, why = paged_tileable(head_dim, block_size, dtype)
     mp = _mesh_mp_degree(mesh)
+    local_heads = num_heads // mp if num_heads and not num_heads % mp \
+        else None
+    ok, why = paged_tileable(head_dim, block_size, dtype, local_heads)
     if req == "xla":
         kind, reason = "xla", "requested"
-    elif pltpu is None:  # pragma: no cover — jaxlib without pallas-tpu
-        kind, reason = "xla", "jax.experimental.pallas.tpu unavailable"
-        if req == "pallas":
-            _note_kernel_fallback(family, reason)
     elif mp > 1 and (num_heads is None or num_heads % mp):
         if num_heads is None:
             reason = (f"mesh-sharded decode (mp={mp}) needs num_heads "

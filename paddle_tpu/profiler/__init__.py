@@ -39,7 +39,7 @@ from . import explainer, registry, timeline
 __all__ = ["Profiler", "ProfilerTarget", "ProfilerState", "ProfilerResult",
            "RecordEvent", "make_scheduler", "export_chrome_tracing",
            "load_profiler_result", "stats", "explain", "reset_stats",
-           "set_step_metrics"]
+           "set_step_metrics", "CompileWatch"]
 
 
 class ProfilerTarget(enum.Enum):
@@ -285,7 +285,10 @@ class Profiler:
         if flops:
             from ..cost_model import device_peak_flops
 
-            line += f" MFU={flops / avg_s / device_peak_flops():.2%}"
+            try:
+                line += f" MFU={flops / avg_s / device_peak_flops():.2%}"
+            except LookupError:
+                pass  # no peak on record for this device (CPU): no MFU
         return line
 
 
@@ -297,6 +300,51 @@ def set_step_metrics(flops_per_step=None, tokens_per_step=None):
         registry.gauge_set("step.flops", float(flops_per_step))
     if tokens_per_step is not None:
         registry.gauge_set("step.tokens", float(tokens_per_step))
+
+
+class CompileWatch:
+    """Counts what JAX itself compiles while the block runs, from JAX's own
+    monitoring events — the ground truth under the registry's per-layer
+    signature radars (``serving.decode_compiles``, ``spmd.step_compiles``),
+    which count first-seen signatures rather than executables.
+
+        with profiler.CompileWatch() as warm:
+            step(batch)            # traces + compiles
+        with profiler.CompileWatch() as steady:
+            step(batch)
+        assert steady.compiles == 0
+
+    ``compiles``: executables XLA built or loaded from the persistent
+    cache; ``cache_hits``: how many of them the persistent cache served;
+    ``seconds``: trace + lowering + backend-compile time (set-up time)."""
+
+    _TIMED = ("/jax/core/compile/jaxpr_trace_duration",
+              "/jax/core/compile/jaxpr_to_mlir_module_duration",
+              "/jax/core/compile/backend_compile_duration")
+
+    def __init__(self):
+        self.compiles = 0
+        self.cache_hits = 0
+        self.seconds = 0.0
+
+    def _on_duration(self, event, seconds, **_):
+        if event in self._TIMED:
+            self.seconds += seconds
+            self.compiles += event == self._TIMED[2]
+
+    def _on_event(self, event, **_):
+        self.cache_hits += event == "/jax/compilation_cache/cache_hits"
+
+    def __enter__(self):
+        jax.monitoring.register_event_duration_secs_listener(
+            self._on_duration)
+        jax.monitoring.register_event_listener(self._on_event)
+        return self
+
+    def __exit__(self, *exc):
+        jax.monitoring.unregister_event_duration_listener(self._on_duration)
+        jax.monitoring.unregister_event_listener(self._on_event)
+        return False
 
 
 def stats(scope=None):

@@ -12,24 +12,6 @@ import gc
 import os
 
 
-def jaxlib_version():
-    """Installed jaxlib version as an int tuple, for version-gated skips.
-
-    Four tests are red ONLY on jaxlib <= 0.4.36 (they passed on the
-    newer jaxlib the repo was grown on): the pipeline/dryrun trio needs
-    SPMD 'auto' mode whose PartitionId lowering is unimplemented there,
-    and the multihost launcher needs cross-host device_put. Gate with
-    `skipif(jaxlib_version() < (0, 4, 37), ...)` so tier-1 is green on
-    this jaxlib and the tests come back automatically on an upgrade."""
-    import jaxlib
-
-    parts = []
-    for tok in jaxlib.__version__.split(".")[:3]:
-        digits = "".join(c for c in tok if c.isdigit())
-        parts.append(int(digits or 0))
-    return tuple(parts)
-
-
 def load_factor():
     """Multiplier for subprocess timeouts. PADDLE_TPU_TEST_LOAD_FACTOR
     overrides; default 3x on boxes with <=2 usable cores, 1x otherwise."""

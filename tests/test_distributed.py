@@ -6,17 +6,6 @@ import pytest
 import paddle_tpu as paddle
 from paddle_tpu.distributed import fleet
 from paddle_tpu.distributed.fleet.topology import CommunicateTopology
-from proc_utils import jaxlib_version
-
-# The pipeline engine runs dp/sharding/mp in GSPMD "auto" mode inside a
-# shard_map region; jaxlib <= 0.4.36 has no PartitionId lowering for
-# auto-mode sub-meshes, so these cases cannot pass on the installed
-# jaxlib (they did on the newer one this repo was grown with).
-_needs_spmd_auto = pytest.mark.skipif(
-    jaxlib_version() < (0, 4, 37),
-    reason="SPMD 'auto' mode PartitionId lowering is unimplemented in "
-           "jaxlib <= 0.4.36 (pipeline shard_map with GSPMD-auto inner "
-           "axes); passes on jaxlib >= 0.4.37")
 
 
 class TestTopology:
@@ -90,7 +79,6 @@ class TestHybridEngine:
         assert np.isfinite(losses).all()
         assert losses[-1] < losses[0]
 
-    @_needs_spmd_auto
     def test_pipeline(self):
         losses = self._run(dp=1, mp=2, pp=2, sharding=2)
         assert np.isfinite(losses).all()
@@ -114,7 +102,6 @@ class TestHybridEngine:
                        n_layer=4)
         np.testing.assert_allclose(l1, lp, rtol=1e-3, atol=1e-4)
 
-    @_needs_spmd_auto
     def test_1f1b_pp4(self):
         losses = self._run(dp=1, mp=2, pp=4, sharding=1)
         assert np.isfinite(losses).all()
@@ -129,9 +116,7 @@ class TestCollectives:
         from paddle_tpu.distributed import collective
 
         # establish an 8-rank world explicitly: the world group mirrors
-        # the LAST fleet.init topology, and the preceding pp engine tests
-        # that used to leave an 8-device mesh behind are skipped on
-        # jaxlib <= 0.4.36
+        # the LAST fleet.init topology, whatever test ran before
         strategy = fleet.DistributedStrategy()
         strategy.hybrid_configs = {"dp_degree": 8, "mp_degree": 1,
                                    "pp_degree": 1, "sharding_degree": 1}
@@ -157,7 +142,6 @@ class TestCollectives:
         assert g.get_group_rank(7) == -1
 
 
-@_needs_spmd_auto
 def test_dryrun_multichip_entry():
     import importlib.util
     import os

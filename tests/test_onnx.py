@@ -328,8 +328,8 @@ class TestOnnxExport:
             0, 128, (2, 16)).astype(np.int64)
         model = self._roundtrip(m, [toks], rtol=2e-4, atol=2e-4)
         ops = {n["op"] for n in model["nodes"]}
-        # qkv splitting lowers to a `split` primitive on older jax and
-        # to per-head `slice`s on 0.4.37+ — accept either spelling
+        # qkv splitting lowers to a `split` primitive or to per-head
+        # `slice`s depending on the jax version — accept either spelling
         assert {"Gather", "MatMul"} <= ops
         assert "Split" in ops or "Slice" in ops
 

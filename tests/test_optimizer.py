@@ -262,3 +262,36 @@ class TestLBFGS:
         run(o3, p3, 3)
         np.testing.assert_allclose(np.asarray(p3.numpy()),
                                    np.asarray(p1.numpy()), rtol=1e-6)
+
+
+def test_trainstep_follows_the_eager_trajectory():
+    """jit.TrainStep must take the SAME optimizer steps as the eager loop
+    it compiles: it once advanced the step counter twice per call (its
+    own +1 on top of opt.step()'s), so Adam's bias correction ran at
+    t = 2, 4, 6... and the loss fell along a different curve — found on
+    the chip by comparing the smoke's TrainStep and lazy legs."""
+    def build():
+        paddle.seed(3)
+        net = nn.Sequential(nn.Linear(8, 16), nn.Tanh(), nn.Linear(16, 4))
+        opt = paddle.optimizer.AdamW(learning_rate=1e-2,
+                                     parameters=net.parameters())
+        return net, opt
+
+    rng = np.random.default_rng(0)
+    x = paddle.to_tensor(rng.normal(size=(16, 8)).astype(np.float32))
+    y = paddle.to_tensor(rng.normal(size=(16, 4)).astype(np.float32))
+
+    def body(net, opt):
+        loss = ((net(x) - y) ** 2).mean()
+        loss.backward()
+        opt.step()
+        opt.clear_grad()
+        return loss
+
+    net, opt = build()
+    eager = [float(body(net, opt)) for _ in range(5)]
+    net2, opt2 = build()
+    train = paddle.jit.TrainStep(lambda: body(net2, opt2), net2, opt2)
+    compiled = [float(train()) for _ in range(5)]
+    np.testing.assert_allclose(compiled, eager, rtol=1e-5)
+    assert int(opt2._opt_step) == opt._opt_step == 5

@@ -379,9 +379,9 @@ class TestSwapInvalidatesPrefixCache:
 
 class TestMeshShardedDecode:
     """mp=2 decode over the forced-host-device CPU mesh must be
-    token-bitwise vs the single-chip engine. Runs on jaxlib 0.4.36+ (the
-    plain-GSPMD jit it uses is the same machinery test_spmd exercises);
-    guarded on device count like the other multi-chip suites."""
+    token-bitwise vs the single-chip engine (the plain-GSPMD jit it uses
+    is the same machinery test_spmd exercises); guarded on device count
+    like the other multi-chip suites."""
 
     @pytest.mark.skipif(
         __import__("jax").device_count() < 2,

@@ -184,7 +184,10 @@ class TestChromeTraceRoundTrip:
             prof.step()
         prof.stop()
         s = prof.summary()
-        assert "steps=" in s and "tokens/s=" in s and "MFU=" in s
+        assert "steps=" in s and "tokens/s=" in s
+        # the CPU has no peak on record, so no utilization is printed
+        # against a made-up one
+        assert "MFU=" not in s
 
 
 class TestExplainer:

@@ -111,17 +111,11 @@ def _shared_leg():
 
 
 class TestSpecDerivation:
-    """The shared mesh/axis-rules layer (satellite: PartitionSpec-is-a-
-    tuple guard deduped into spmd.is_single_spec/per_arg_specs)."""
+    """The shared mesh/axis-rules layer."""
 
-    def test_single_spec_guard(self):
-        # PartitionSpec subclasses tuple on jax <= 0.4.37: a bare
-        # isinstance(tuple) check unpacks one spec into its axis entries
-        assert spmd.is_single_spec(P("mp", None))
-        assert spmd.is_single_spec(P())
-        assert spmd.is_single_spec(None)
-        assert not spmd.is_single_spec((P("mp"), P()))
+    def test_per_arg_specs_broadcasts_one_spec(self):
         assert spmd.per_arg_specs(P("mp"), 3) == (P("mp"),) * 3
+        assert spmd.per_arg_specs(None, 2) == (None, None)
         assert spmd.per_arg_specs((P("mp"), P()), 2) == (P("mp"), P())
 
     def test_param_pspec_rules(self):

@@ -327,9 +327,8 @@ class TestParity:
     mesh."""
 
     def test_pp2_matches_engine_1f1b_oracle(self):
-        # engine oracle at pp=2 with degree-1 auto axes (the only pp
-        # engine config that lowers on jaxlib <= 0.4.36 — see
-        # test_distributed._needs_spmd_auto); same seed/init/data
+        # engine oracle at pp=2 with degree-1 auto axes; same
+        # seed/init/data
         _init_fleet(dp=1, mp=1, pp=2)
         model, opt, crit = _gpt2_tiny()
         model = fleet.distributed_model(model)
@@ -427,8 +426,7 @@ class TestExplicitMicrobatches:
     def test_accumulate_steps_below_pp_is_honored(self):
         # the lockstep schedule is correct for M < pp (bubblier, never
         # resized behind the user's back); M=1 also pins the unrolled
-        # form — the scan form trips a jaxlib-0.4.36 x64 partitioner
-        # bug there (see _pipeline_loss)
+        # form (see _pipeline_loss for why M=1 unrolls)
         _init_fleet(dp=1, mp=1, pp=2)
         model, opt, crit = _gpt2_tiny()
         step = pp_spmd.PipelineSpmdStep(model, opt, criterion=crit,

@@ -1,0 +1,258 @@
+"""Do the Pallas kernels lower and compile for the TPU under the package's
+own defaults (x64 on, matmul precision "highest")? — the half of "does it
+compile" that needs no chip.
+
+Two strengths, both CPU-only:
+
+* cross-lowering: ``jit(f).trace(avals).lower(lowering_platforms=("tpu",))``
+  from the CPU backend. With no TPU backend to ask, Pallas assumes the
+  OLDEST libtpu it supports, so this is the strictest reading of the kernel
+  bodies: one python-float constant (an f64 under x64) fails it.
+* ahead-of-time compilation: the installed libtpu describes a v5e 2x2 host
+  without owning one (``jax.experimental.topologies``), and XLA + Mosaic
+  compile against it — the same compiler the chip runs. Run in a child
+  process so that libtpu never loads into the test process.
+
+What neither can say is that the numbers are right on the chip; that is
+``chip_smoke.py``'s kernels leg.
+"""
+import json
+import os
+import subprocess
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import NamedSharding, PartitionSpec as P
+
+import paddle_tpu  # noqa: F401  (installs the package defaults under test)
+from paddle_tpu.core import lazy
+from paddle_tpu.distributed import spmd
+from paddle_tpu.ops import pallas_ops as po
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+FLASH_SHAPE = (8, 1024, 16, 64)  # gpt2-medium's attention at bs8 seq1024
+
+
+def _lowered_text(fn, *avals):
+    return jax.jit(fn).trace(*avals).lower(
+        lowering_platforms=("tpu",)).as_text()
+
+
+def _paged_avals(T, head_dim, dtype, sharding=None, repl=None):
+    B, H, bs, M, nb = 8, 16, 16, 64, 513
+    sds = jax.ShapeDtypeStruct
+    return (sds((B, T, H, head_dim), dtype, sharding=sharding),
+            sds((nb, bs, H, head_dim), dtype, sharding=sharding),
+            sds((nb, bs, H, head_dim), dtype, sharding=sharding),
+            sds((B, M), jnp.int32, sharding=repl),
+            sds((B,), jnp.int32, sharding=repl),
+            sds((B,), jnp.int32, sharding=repl))
+
+
+def test_package_defaults_are_what_is_under_test():
+    assert jax.config.jax_enable_x64
+    assert jax.config.jax_default_matmul_precision == "highest"
+
+
+class TestCrossLowering:
+    def test_flash_forward(self):
+        a = jax.ShapeDtypeStruct(FLASH_SHAPE, jnp.bfloat16)
+        text = _lowered_text(
+            lambda q, k, v: po._flash_attention_tpu(q, k, v, causal=True),
+            a, a, a)
+        assert text.count("tpu_custom_call") == 1
+
+    def test_flash_grad(self):
+        a = jax.ShapeDtypeStruct(FLASH_SHAPE, jnp.bfloat16)
+
+        def loss(q, k, v):
+            return po._flash_attention_tpu(q, k, v, causal=True).astype(
+                jnp.float32).sum()
+
+        text = _lowered_text(jax.grad(loss, argnums=(0, 1, 2)), a, a, a)
+        assert text.count("tpu_custom_call") == 3  # fwd, dq, dkv
+
+    @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+    @pytest.mark.parametrize("head_dim", [64, 128])
+    @pytest.mark.parametrize("T", [1, 5])  # decode, spec verify (K+1)
+    def test_paged(self, T, head_dim, dtype):
+        text = _lowered_text(
+            lambda *a: po.paged_attention(*a, kernel="pallas"),
+            *_paged_avals(T, head_dim, dtype))
+        assert "tpu_custom_call" in text
+
+    @pytest.mark.parametrize("T", [1, 5])
+    def test_paged_per_shard_on_the_virtual_mesh(self, T):
+        mesh = spmd.serving_mesh(2)
+        head = NamedSharding(mesh, P(None, None, "mp", None))
+        text = _lowered_text(
+            lambda *a: po.paged_attention(*a, kernel="pallas", mesh=mesh),
+            *_paged_avals(T, 64, jnp.bfloat16, head,
+                          NamedSharding(mesh, P())))
+        assert "tpu_custom_call" in text
+
+    def test_flash_per_shard_on_the_virtual_mesh(self, monkeypatch):
+        # the SPMD train step's route: flash_attention itself plans the
+        # split from the installed mesh (GSPMD cannot partition a Mosaic
+        # custom call and refuses to try)
+        monkeypatch.setattr(po, "_on_tpu", lambda: True)
+        mesh = jax.sharding.Mesh(
+            np.array(jax.devices()[:4]).reshape(2, 2), ("dp", "mp"))
+        sh = NamedSharding(mesh, P("dp", None, "mp", None))
+        a = jax.ShapeDtypeStruct(FLASH_SHAPE, jnp.bfloat16, sharding=sh)
+        c0 = dict(po._flash_counters)
+        with spmd.spmd_guard(mesh):
+            text = _lowered_text(
+                lambda q, k, v: po.flash_attention(q, k, v, causal=True),
+                a, a, a)
+        assert "tpu_custom_call" in text
+        assert po._flash_counters["flash.pallas"] == c0["flash.pallas"] + 1
+        assert po._flash_counters["flash.fallbacks"] == c0["flash.fallbacks"]
+        # each shard's kernel sees its own slice: batch/dp, heads/mp
+        assert "4x1024x8x64" in text.replace(" ", "")
+
+
+class TestFlashMeshPlan:
+    """flash_attention's resolution under a mesh (platform patched in)."""
+
+    def _mesh(self, shape, names):
+        n = int(np.prod(shape))
+        return jax.sharding.Mesh(
+            np.array(jax.devices()[:n]).reshape(shape), names)
+
+    def test_plans(self):
+        plan = po._flash_mesh_spec
+        assert plan(self._mesh((2, 2), ("dp", "mp")), 8, 16) == (
+            P(("dp",), None, "mp", None), None)
+        assert plan(self._mesh((2, 2), ("dp", "ep")), 8, 16) == (
+            P(("dp", "ep"), None, None, None), None)
+        assert plan(self._mesh((1, 1), ("dp", "mp")), 8, 16) == (None, None)
+        _, why = plan(self._mesh((2, 2), ("dp", "mp")), 8, 3)
+        assert "3 heads" in why and "mp=2" in why
+        _, why = plan(self._mesh((2, 2), ("dp", "mp")), 3, 16)
+        assert "batch 3" in why
+        _, why = plan(self._mesh((2, 2, 2), ("dp", "pp", "mp")), 8, 16)
+        assert "pp" in why
+
+    def test_unplanned_mesh_takes_xla_loudly(self, monkeypatch):
+        from paddle_tpu.profiler import explainer
+
+        monkeypatch.setattr(po, "_on_tpu", lambda: True)
+        mesh = self._mesh((2, 2), ("dp", "mp"))
+        q = jnp.zeros((2, 128, 3, 64), jnp.bfloat16)  # 3 heads over mp=2
+        c0 = dict(po._flash_counters)
+        with spmd.spmd_guard(mesh):
+            out = po.flash_attention(q, q, q, causal=True)
+        assert out.shape == q.shape
+        assert po._flash_counters["flash.xla"] == c0["flash.xla"] + 1
+        assert po._flash_counters["flash.fallbacks"] \
+            == c0["flash.fallbacks"] + 1
+        ev = explainer.events(kind="kernel_fallback")[-1]
+        assert "3 heads" in ev["why"]
+        lazy.drop_plans("test boundary")
+
+
+_AOT_CHILD = r"""
+import json, sys
+import numpy as np, jax, jax.numpy as jnp
+from jax.experimental import topologies
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+try:
+    topo = topologies.get_topology_desc(topology_name="v5e:2x2",
+                                        platform="tpu")
+except Exception as e:
+    print(json.dumps({"unavailable": f"{type(e).__name__}: {e}"[:300]}))
+    sys.exit(0)
+import paddle_tpu
+from paddle_tpu.core import lazy
+from paddle_tpu.ops import pallas_ops as po
+assert jax.config.jax_enable_x64
+po._on_tpu = lambda: True  # the platform these programs are compiled for
+devs = topo.devices
+one = NamedSharding(Mesh(np.array(devs[:1]), ("x",)), P())
+sds = jax.ShapeDtypeStruct
+out = {"device_kind": devs[0].device_kind}
+
+def compile_(name, fn, *avals):
+    try:
+        text = jax.jit(fn).trace(*avals).lower(
+            lowering_platforms=("tpu",)).compile().as_text()
+        out[name] = {"custom_calls": text.count('"tpu_custom_call"'),
+                     "collectives": sum(text.count(c + "(") for c in (
+                         "all-gather", "all-reduce", "all-to-all",
+                         "collective-permute"))}
+    except Exception as e:
+        out[name] = f"{type(e).__name__}: {e}"[:600]
+
+def loss(q, k, v):
+    return po.flash_attention(q, k, v, causal=True).astype(jnp.float32).sum()
+
+a = sds((8, 1024, 16, 64), jnp.bfloat16, sharding=one)
+compile_("flash_grad", jax.grad(loss, argnums=(0, 1, 2)), a, a, a)
+
+def paged(T, dh, dt, head, repl, mesh=None, H=16, bs=16):
+    B, M, nb = 8, 64, 513
+    return (lambda *x: po.paged_attention(*x, kernel="pallas", mesh=mesh),
+            sds((B, T, H, dh), dt, sharding=head),
+            sds((nb, bs, H, dh), dt, sharding=head),
+            sds((nb, bs, H, dh), dt, sharding=head),
+            sds((B, M), jnp.int32, sharding=repl),
+            sds((B,), jnp.int32, sharding=repl),
+            sds((B,), jnp.int32, sharding=repl))
+
+for T in (1, 5):
+    for dh in (64, 128):
+        for dt in (jnp.bfloat16, jnp.float32):
+            compile_(f"paged_T{T}_dh{dh}_{jnp.dtype(dt).name}",
+                     *paged(T, dh, dt, one, one))
+# geometry: nothing about head_dim / block_size / heads fails to tile ...
+compile_("paged_odd", *paged(3, 80, jnp.float32, one, one, H=12, bs=8))
+# ... but K and V blocks, double-buffered, must fit VMEM: 2 MiB do, 4 MiB
+# do not (paged_tileable's _PAGED_MAX_BLOCK_BYTES)
+compile_("paged_block_2MiB", *paged(1, 256, jnp.float32, one, one, H=64,
+                                    bs=32))
+compile_("paged_block_4MiB", *paged(1, 256, jnp.float32, one, one, H=64,
+                                    bs=64))
+mp = Mesh(np.array(devs), ("mp",))
+compile_("paged_mp4", *paged(1, 64, jnp.bfloat16,
+                             NamedSharding(mp, P(None, None, "mp", None)),
+                             NamedSharding(mp, P()), mesh=mp))
+mesh = Mesh(np.array(devs).reshape(2, 2), ("dp", "mp"))
+lazy.set_spmd_mesh(mesh)
+b = sds((8, 1024, 16, 64), jnp.bfloat16,
+        sharding=NamedSharding(mesh, P("dp", None, "mp", None)))
+compile_("flash_grad_dp2_mp2", jax.grad(loss, argnums=(0, 1, 2)), b, b, b)
+print(json.dumps(out))
+"""
+
+
+def test_aot_compile_for_v5e():
+    """XLA + Mosaic compile every kernel family for a described v5e —
+    single chip, the 'mp' serving mesh and the (dp2, mp2) train mesh —
+    with no collective around the per-shard custom calls."""
+    env = dict(os.environ, PYTHONPATH=REPO + os.pathsep
+               + os.environ.get("PYTHONPATH", ""), JAX_PLATFORMS="cpu")
+    r = subprocess.run([sys.executable, "-c", _AOT_CHILD], env=env,
+                       capture_output=True, text=True, timeout=600)
+    assert r.returncode == 0, r.stderr[-2000:]
+    res = json.loads(r.stdout.strip().splitlines()[-1])
+    if "unavailable" in res:
+        pytest.skip("libtpu cannot describe a v5e here: "
+                    + res["unavailable"])
+    assert res.pop("device_kind") == "TPU v5 lite"
+    too_big = res.pop("paged_block_4MiB")
+    assert isinstance(too_big, str) and "vmem" in too_big, too_big
+    assert not po.paged_tileable(256, 64, jnp.float32, 64)[0]
+    assert po.paged_tileable(256, 32, jnp.float32, 64)[0]
+    bad = {k: v for k, v in res.items() if not isinstance(v, dict)}
+    assert not bad, bad
+    assert res["flash_grad"] == {"custom_calls": 3, "collectives": 0}
+    assert res["flash_grad_dp2_mp2"] == {"custom_calls": 3,
+                                         "collectives": 0}
+    assert res["paged_mp4"] == {"custom_calls": 1, "collectives": 0}
+    assert all(v["custom_calls"] == 1 for k, v in res.items()
+               if k.startswith("paged_"))
