@@ -40,11 +40,14 @@ NEAR_TIE of each other (random weights give near-uniform logits, and bf16
 reduction order legitimately decides such a tie); every such position is
 reported with its gap.
 
-On success the last line of stdout is one JSON object:
-  {"ok": true, "device": {"platform": "tpu", "kind": ..., "count": N},
-   "versions": {...}, "legs": {leg: {"status": "ok", "wall_s": ...,
-   "compile_s": ..., ...}}}
-With fewer than four chips the four-chip legs read "not run (N chips)".
+On success stdout ends in two lines, one JSON object each. First the
+account of the run (also written to chiprun_out/ when that exists):
+  {"ok": true, "device": {...}, "versions": {...}, "legs": {leg:
+   {"status": "ok", "wall_s": ..., "compile_s": ..., ...}}}
+where, with fewer than four chips, the four-chip legs read "not run (N
+chips)". Then, as the LAST line, the verdict with exactly these keys, the
+device as JAX reports it:
+  {"ok": true, "device": {"platform": "tpu", "kind": "...", "count": N}}
 Anything else — no TPU, a leg that fails or raises, an assertion that does
 not hold — ends in a non-zero exit code and no result on stdout; the
 account of the failure goes to stderr.
@@ -170,7 +173,11 @@ def parent(args):
         name = "chip_smoke_partial.json" if args.legs else "chip_smoke.json"
         with open(os.path.join("chiprun_out", name), "w") as f:
             f.write(out + "\n")
-    print(out, flush=True)
+    print(out)
+    # the verdict: these keys and no others, last on stdout
+    print(json.dumps({"ok": True, "device": {
+        "platform": device["platform"], "kind": device["kind"],
+        "count": device["count"]}}), flush=True)
     return 0
 
 

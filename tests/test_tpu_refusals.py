@@ -57,6 +57,33 @@ class TestEntryPointsRefuseOffChip:
         assert r.returncode != 0 and r.stdout.strip() == ""
         assert "PADDLE_TPU_X64" in r.stderr
 
+    def test_chip_smoke_last_line_is_the_verdict(self, monkeypatch, capsys,
+                                                 tmp_path):
+        # the parent is stdlib-only, so its reporting runs here with the
+        # legs stubbed: the account of the run comes first, and the LAST
+        # stdout line carries exactly the keys the chip check reads
+        import importlib.util
+        import json
+
+        spec = importlib.util.spec_from_file_location(
+            "chip_smoke", os.path.join(REPO, "chip_smoke.py"))
+        smoke = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(smoke)
+        device = {"platform": "tpu", "kind": "TPU v5 lite", "count": 1}
+        monkeypatch.setattr(
+            smoke, "run_child", lambda leg, refs, tiny, timeout: {
+                "status": "ok", "wall_s": 1.0, "compile_s": 0.5,
+                "device": dict(device), "versions": {"jax": "0.9.0"}})
+        for k in [k for k in os.environ if k.startswith("PADDLE_TPU_")]:
+            monkeypatch.delenv(k)
+        monkeypatch.chdir(tmp_path)  # no chiprun_out/ here to write into
+        assert smoke.main([]) == 0
+        lines = capsys.readouterr().out.strip().splitlines()
+        assert json.loads(lines[-1]) == {"ok": True, "device": device}
+        account = json.loads(lines[-2])
+        assert account["legs"]["serve-1"]["status"] == "ok"
+        assert account["legs"]["train-4"] == "not run (1 chips)"
+
     def test_bench(self):
         r = _run(["bench.py"])
         assert r.returncode != 0
