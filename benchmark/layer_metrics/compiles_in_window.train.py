@@ -1,0 +1,9 @@
+"""Executables JAX built or loaded inside the window (profiler.CompileWatch,
+JAX's own compile events). Should be 0."""
+META = {"name": "compiles_in_window.train", "layer": "jit cache",
+        "unit": "count", "better": "lower", "source": "program_counter",
+        "moves": "train_tokens_per_s", "drivers": ["train_fixed_shape"]}
+
+
+def read(run):
+    return run["compiles_in_window"]
