@@ -1,0 +1,28 @@
+"""Flash-attention backward's share of its roofline: the least time one
+layer's backward can take (kernel_counts.flash_bwd_flops / flash_bwd_bytes)
+over the mean device time of one `flash_bwd_dkv` plus one `flash_bwd_dq`
+event inside complete `train_step` events (scope_reduce.py)."""
+import kernel_counts as kc
+import model as bench_model
+import scope_reduce
+
+META = {"name": "kernel.flash_bwd_roofline.train", "layer": "kernels",
+        "unit": "%", "better": "higher", "source": "device_trace",
+        "moves": "train_tokens_per_s", "drivers": ["train_fixed_shape"]}
+
+
+def read(run):
+    got = scope_reduce.per_event(run, "kernels", "flash_bwd_dkv",
+                                 "flash_bwd_dq")
+    if got is None:
+        return None
+    seconds, n = got
+    per_layer = seconds / (n / 2)  # one event of each a layer
+    sizes = bench_model.sizes(run["cfg"])
+    B = int(run["traffic"]["batch"]) // int(run["wl"]["chips"])
+    least, bound = kc.least_seconds(
+        kc.flash_bwd_flops(sizes, B), kc.flash_bwd_bytes(sizes, B),
+        run["peaks"]["devices"][run["device_kind"]])
+    run["say"](f"flash_bwd: least {1e3 * least:.4f} ms a layer (bound: "
+               f"{bound}), measured {1e3 * per_layer:.4f} ms (dkv + dq)")
+    return 100.0 * least / per_layer

@@ -31,6 +31,7 @@ import jax.numpy as jnp
 from ..core import random as prandom
 from ..core.tensor import Tensor
 from ..nn.layer.layers import Layer
+from ..profiler import span as _span
 
 __all__ = ["to_static", "TrainStep", "save", "load", "not_to_static",
            "ignore_module", "enable_to_static"]
@@ -214,7 +215,9 @@ class TrainStep:
         fn = self._fn
         opts = self._opts
 
-        def pure(state_arrays, acc_arrays, steps, key, arg_arrays):
+        # named from profiler.spans.EXECUTABLES: the device trace's
+        # module event reads `jit_train_step`
+        def train_step(state_arrays, acc_arrays, steps, key, arg_arrays):
             tensors = [self._state[n] for n in names]
             saved_p = [t._data for t in tensors]
             saved_a = [r[3]._data for r in self._acc_refs]
@@ -252,7 +255,7 @@ class TrainStep:
         # memory win is irrelevant
         donate = (0, 1) if self._donate and \
             jax.devices()[0].platform != "cpu" else ()
-        self._compiled = jax.jit(pure, donate_argnums=donate)
+        self._compiled = jax.jit(train_step, donate_argnums=donate)
         # planner-sharded params span a mesh: scalars (step counters, rng
         # key) and single-device batches must be lifted onto it, or jit
         # rejects the mixed committed placements
@@ -275,6 +278,10 @@ class TrainStep:
         return jax.device_put(arr, self._lift_sh)
 
     def __call__(self, *args):
+        with _span("train.step"):
+            return self._call(args)
+
+    def _call(self, args):
         if self._compiled is None:
             self._build()
         arg_arrays = tuple(

@@ -26,6 +26,7 @@ import numpy as np
 from .. import nn, ops
 from ..nn import functional as F
 from ..nn.initializer import Normal
+from ..profiler.spans import scope as _scope
 
 # one-time nudge off the growing-concat KV-cache path (below): it changes
 # the [B, t] cache shapes every generated token, so XLA recompiles the
@@ -174,22 +175,25 @@ class GPTAttention(nn.Layer):
             S = M * bs
             rows = cache_offset.unsqueeze(1) + ops.arange(0, T,
                                                           dtype="int32")
-            blk = ops.clip(rows // bs, max=M - 1)
-            phys = ops.take_along_axis(block_tables, blk, axis=1)
-            writable = rows < seq_lens.unsqueeze(-1)
-            flat_rows = ops.where(writable, phys * bs + rows % bs,
-                                  ops.zeros_like(rows))
-            k_flat = k_pool.reshape([Nb * bs, self.n_head, self.head_dim])
-            v_flat = v_pool.reshape([Nb * bs, self.n_head, self.head_dim])
-            widx = ops.broadcast_to(
-                flat_rows.reshape([B * T]).unsqueeze(-1).unsqueeze(-1),
-                [B * T, self.n_head, self.head_dim])
-            k_flat = ops.put_along_axis(
-                k_flat, widx,
-                k.reshape([B * T, self.n_head, self.head_dim]), axis=0)
-            v_flat = ops.put_along_axis(
-                v_flat, widx,
-                v.reshape([B * T, self.n_head, self.head_dim]), axis=0)
+            with _scope("kv_write"):
+                blk = ops.clip(rows // bs, max=M - 1)
+                phys = ops.take_along_axis(block_tables, blk, axis=1)
+                writable = rows < seq_lens.unsqueeze(-1)
+                flat_rows = ops.where(writable, phys * bs + rows % bs,
+                                      ops.zeros_like(rows))
+                k_flat = k_pool.reshape(
+                    [Nb * bs, self.n_head, self.head_dim])
+                v_flat = v_pool.reshape(
+                    [Nb * bs, self.n_head, self.head_dim])
+                widx = ops.broadcast_to(
+                    flat_rows.reshape([B * T]).unsqueeze(-1).unsqueeze(-1),
+                    [B * T, self.n_head, self.head_dim])
+                k_flat = ops.put_along_axis(
+                    k_flat, widx,
+                    k.reshape([B * T, self.n_head, self.head_dim]), axis=0)
+                v_flat = ops.put_along_axis(
+                    v_flat, widx,
+                    v.reshape([B * T, self.n_head, self.head_dim]), axis=0)
             if paged_kernel in ("pallas", "interpret"):
                 # Fused read path (ISSUE 14): the Pallas kernel walks the
                 # block table inside the kernel, so the gathered
@@ -198,8 +202,9 @@ class GPTAttention(nn.Layer):
                 # redirect intact); only the O(M*bs) gather is fused.
                 # `paged_kernel` is a static per-engine choice
                 # (pallas_ops.select_paged_kernel) — never data.
-                new_k = k_flat.reshape(k_pool.shape)
-                new_v = v_flat.reshape(v_pool.shape)
+                with _scope("kv_write"):
+                    new_k = k_flat.reshape(k_pool.shape)
+                    new_v = v_flat.reshape(v_pool.shape)
                 out = F.paged_attention(q, new_k, new_v, block_tables,
                                         seq_lens, cache_offset,
                                         kernel=paged_kernel,
@@ -310,20 +315,24 @@ class GPTBlock(nn.Layer):
         self._recompute_policy = getattr(cfg, "recompute_policy", None)
 
     def _forward(self, x):
-        x = x + self.dropout(self.attn(self.ln1(x)))
-        return x + self.mlp(self.ln2(x))
+        with _scope("attn"):
+            x = x + self.dropout(self.attn(self.ln1(x)))
+        with _scope("mlp"):
+            return x + self.mlp(self.ln2(x))
 
     def forward(self, x, cache=None, cache_offset=None, seq_lens=None,
                 block_tables=None, paged_kernel=None, paged_mesh=None):
         if cache is not None:
-            a, new_cache = self.attn(self.ln1(x), cache=cache,
-                                     cache_offset=cache_offset,
-                                     seq_lens=seq_lens,
-                                     block_tables=block_tables,
-                                     paged_kernel=paged_kernel,
-                                     paged_mesh=paged_mesh)
-            x = x + self.dropout(a)
-            return x + self.mlp(self.ln2(x)), new_cache
+            with _scope("attn"):
+                a, new_cache = self.attn(self.ln1(x), cache=cache,
+                                         cache_offset=cache_offset,
+                                         seq_lens=seq_lens,
+                                         block_tables=block_tables,
+                                         paged_kernel=paged_kernel,
+                                         paged_mesh=paged_mesh)
+                x = x + self.dropout(a)
+            with _scope("mlp"):
+                return x + self.mlp(self.ln2(x)), new_cache
         if self._recompute and self.training:
             from ..distributed.fleet.utils import recompute
 
@@ -413,7 +422,8 @@ class GPTForPretraining(nn.Layer):
         shared by forward() and the pipeline head, so a head change
         (untying, scaling) cannot diverge the two paths."""
         w = self.gpt.embeddings.word_embeddings.weight
-        return ops.matmul(x, w, transpose_y=True)
+        with _scope("lm_head"):
+            return ops.matmul(x, w, transpose_y=True)
 
     def forward(self, input_ids, position_ids=None):
         return self._lm_logits(self.gpt(input_ids, position_ids))
@@ -479,12 +489,13 @@ class GPTPretrainingCriterion(nn.Layer):
         super().__init__()
 
     def forward(self, logits, labels, loss_mask=None):
-        loss = F.cross_entropy(logits.reshape([-1, logits.shape[-1]]),
-                               labels.reshape([-1]), reduction="none")
-        if loss_mask is not None:
-            m = loss_mask.reshape([-1])
-            return (loss * m).sum() / ops.clip(m.sum(), min=1.0)
-        return loss.mean()
+        with _scope("lm_head"):
+            loss = F.cross_entropy(logits.reshape([-1, logits.shape[-1]]),
+                                   labels.reshape([-1]), reduction="none")
+            if loss_mask is not None:
+                m = loss_mask.reshape([-1])
+                return (loss * m).sum() / ops.clip(m.sum(), min=1.0)
+            return loss.mean()
 
 
 def gpt_tiny(**kw):

@@ -38,6 +38,7 @@ from jax.sharding import PartitionSpec as P
 from ..core import lazy as _lazy
 from ..profiler import explainer as _explain
 from ..profiler import registry as _registry
+from ..profiler.spans import device_name as _kernel_name
 
 # Kernel-selection telemetry (ISSUE 14): every resolution of a hot-path
 # kernel family bumps exactly one counter, so an operator can read which
@@ -264,6 +265,7 @@ def _flash_fwd_call(q, k, v, causal, scale, block_q, block_k):
             jax.ShapeDtypeStruct((BN, T, H), q.dtype),
             jax.ShapeDtypeStruct((BN, T, 1), jnp.float32),
         ],
+        name=_kernel_name("flash_fwd"),
     )(q, k, v)
     return out, lse
 
@@ -302,6 +304,7 @@ def _flash_flat_bwd(causal, scale, block_q, block_k, res, do):
         ],
         out_specs=pl.BlockSpec((None, block_q, H), row),
         out_shape=jax.ShapeDtypeStruct((BN, T, H), q.dtype),
+        name=_kernel_name("flash_bwd_dq"),
     )(q, k, v, do, lse, delta)
     dk, dv = pl.pallas_call(
         functools.partial(_flash_bwd_dkv_kernel, **common),
@@ -322,6 +325,7 @@ def _flash_flat_bwd(causal, scale, block_q, block_k, res, do):
             jax.ShapeDtypeStruct((BN, T, H), k.dtype),
             jax.ShapeDtypeStruct((BN, T, H), v.dtype),
         ],
+        name=_kernel_name("flash_bwd_dkv"),
     )(q, k, v, do, lse, delta)
     return dq, dk, dv
 
@@ -644,6 +648,7 @@ def _paged_attention_fused(q, k_pool, v_pool, block_tables, seq_lens,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, T, H, Dh), q.dtype),
         interpret=interpret,
+        name=_kernel_name("paged_attention"),
     )(block_tables.astype(jnp.int32), seq_lens.astype(jnp.int32),
       q_offsets.astype(jnp.int32), q, k_pool, v_pool)
 

@@ -142,8 +142,9 @@ class Optimizer:
         from ..core.selected_rows import SelectedRows
         from ..core.tensor import Tensor
         from ..profiler import RecordEvent
+        from ..profiler.spans import scope
 
-        with RecordEvent("optimizer-step"):
+        with RecordEvent("optimizer-step"), scope("optimizer"):
             self._step_impl(SelectedRows, Tensor)
 
     def _fastpath_tick(self):

@@ -15,13 +15,17 @@ TPU re-design, three pillars (ISSUE 3):
    capture fallback, segment recompile, capture promotion, and eager
    jit-cache miss records a structured cause event into a ring buffer —
    `explain()` reads it back; `FLAGS_log_compiles` logs live.
-3. **Host span timeline** (`timeline.py`): `RecordEvent` buffers host
-   spans while a Profiler window records, and `export_chrome_tracing`
-   writes valid chrome-trace JSON with no libtpu. The device tracer is
-   still libtpu's, surfaced through `jax.profiler` (XPlane) into the
-   same directory when available; `RecordEvent` maps each begin to a
-   `jax.profiler.TraceAnnotation` so host events nest into the device
-   timeline too.
+3. **Host spans** (`spans.py`): one `span(name)` for the program's own
+   call sites, with one table of the names it may carry (`spans.SPANS`;
+   the names of the kernels, executables and scopes on the device trace
+   sit beside it). Every span is a `jax.profiler.TraceAnnotation` (so it
+   lies under the device ops in a `jax.profiler` trace), moves its
+   `<name>_ns` / `<name>_n` counters, and goes to the `tracing` ring and
+   the `timeline` when those are on. `RecordEvent` is the Paddle-named
+   wrapper over the same code for a user's own event names.
+   `timeline.py` buffers the spans of a Profiler window and
+   `export_chrome_tracing` writes them as chrome-trace JSON with no
+   libtpu.
 
 `Profiler` keeps the reference's state machine
 (CLOSED/READY/RECORD/RECORD_AND_RETURN).
@@ -35,9 +39,10 @@ import time
 import jax
 
 from . import explainer, registry, timeline
+from .spans import HostSpan, span
 
 __all__ = ["Profiler", "ProfilerTarget", "ProfilerState", "ProfilerResult",
-           "RecordEvent", "make_scheduler", "export_chrome_tracing",
+           "RecordEvent", "span", "make_scheduler", "export_chrome_tracing",
            "load_profiler_result", "stats", "explain", "reset_stats",
            "set_step_metrics", "CompileWatch"]
 
@@ -97,15 +102,14 @@ def export_chrome_tracing(dir_name, worker_name=None):
 
 
 class RecordEvent:
-    """Host-side event annotation (reference event_tracing.h RecordEvent).
+    """Host-side event annotation (reference event_tracing.h RecordEvent):
+    Paddle's name for a span, open to any event name. A thin wrapper over
+    `spans.HostSpan` — the same `TraceAnnotation`, ring and timeline sinks
+    as the program's own `span`, but no name check and no counter.
 
     begin/end form a STACK: re-entrant begin() calls each open a span
-    and end() closes the innermost one (the old single-slot `_ctx`
-    leaked the first TraceAnnotation on a double begin); end() without a
-    matching begin is a no-op. Each begin enters a
-    `jax.profiler.TraceAnnotation` (device/xprof nesting when a device
-    trace is active) and, while a Profiler window records, stamps a
-    host span into the pure-host timeline."""
+    and end() closes the innermost one; end() without a matching begin
+    is a no-op."""
 
     __slots__ = ("name", "_stack")
 
@@ -122,22 +126,11 @@ class RecordEvent:
         return False
 
     def begin(self):
-        try:
-            ctx = jax.profiler.TraceAnnotation(self.name)
-            ctx.__enter__()
-        except Exception:
-            ctx = None
-        self._stack.append(
-            (ctx, time.perf_counter() if timeline.active() else None))
+        self._stack.append(HostSpan(self.name).__enter__())
 
     def end(self):
-        if not self._stack:
-            return
-        ctx, t0 = self._stack.pop()
-        if ctx is not None:
-            ctx.__exit__(None, None, None)
-        if t0 is not None:
-            timeline.add_span(self.name, t0, time.perf_counter())
+        if self._stack:
+            self._stack.pop().__exit__(None, None, None)
 
 
 class Profiler:

@@ -1,0 +1,202 @@
+"""paddle_tpu.profiler.spans — the one span API inside the program, and the
+one table of the names it (and the device trace) may carry.
+
+A host span (`span`) is, on the thread that did the work:
+
+* a `jax.profiler.TraceAnnotation(name)` — always; a `TraceMe` is a flag
+  check while no profiler trace runs — so under `jax.profiler.start_trace`
+  it lies on the host plane on the same clock as the device ops (host and
+  device `start_ns` share an origin);
+* two integer counters in the span's registry scope, `<name>_ns` and
+  `<name>_n` (`serving.decode_step` -> scope `serving`, `decode_step_ns`
+  and `decode_step_n`), which `registry.counters()` returns, so a window
+  delta of the counters holds durations from inside the program; a span
+  whose table entry says so also feeds the `registry.timing` reservoir of
+  its name, which `stats_dump` and the pod's `stats` reply read;
+* one entry of the ring of `profiler/tracing.py` while `tracing.enabled()`
+  (with its `trace_id`), and one of `profiler/timeline.py` while a
+  `Profiler` window records.
+
+A span that is in both the ring and the profiler's trace (same name, same
+order) gives the offset between `time.monotonic` and the trace's clock.
+
+Rule for call sites: step and phase frequency only — never per op, per slot
+or per token; at most six spans per steady decode iteration and two per
+training step. The program's own call sites use `span` and only names from
+`SPANS`; `RecordEvent` (Paddle's name, a user's own event names) runs the
+same code, takes any name and feeds no counter. `tracing.span` /
+`tracing.add_span` stay for what only they can do: spans closed after the
+fact from two timestamps and spans shipped between processes.
+
+The device side of the table (`KERNELS`, `EXECUTABLES`, `SCOPES`) holds the
+names the compiled programs carry: metadata only, the programs do not
+change.
+"""
+from __future__ import annotations
+
+import time
+
+import jax
+
+from . import registry, timeline, tracing
+
+# Host spans: name -> what it covers. PERF.md section 3 mirrors this table.
+SPANS = {
+    "serving.loop_idle": "server.py `_loop`, around `_work.wait`: the "
+                         "server thread had no work",
+    "serving.sched_step": "scheduler.py `step`, whole body: one scheduler "
+                          "iteration",
+    "serving.admit": "scheduler.py `_admit`: one admission, the prefill "
+                     "inside it",
+    "serving.prefill": "engine.py `_prefill_call`: dispatch + wait of one "
+                       "prefill executable (whole prompts, prefix-hit "
+                       "remainders and chunks)",
+    "serving.decode_step": "engine.py `decode_step`, fast and rebuild "
+                           "path: dispatch + wait of one decode (or "
+                           "speculative round) executable",
+    "serving.decode_sync": "inside serving.decode_step, around "
+                           "`np.asarray(toks_d)`: the host waiting for "
+                           "the device; the parent's self time is dispatch",
+    "serving.emit": "scheduler.py, the `_append_token` loop after a "
+                    "decode: per-request bookkeeping of one iteration",
+    "train.step": "jit `TrainStep.__call__`: lifting the arguments, the "
+                  "executable call, writing state back",
+}
+
+# Spans that also feed the `registry.timing` reservoir of the same name.
+_TIMED = frozenset({"serving.prefill", "serving.decode_step"})
+
+# Counters kept at the same boundaries as the spans (scope.name -> what).
+COUNTERS = {
+    "serving.sched_steps": "one a scheduler `step()`",
+    "serving.queue_wait_ns": "submit to admission start, summed over "
+                             "admitted requests",
+    "serving.admitted": "requests that left the queue for a slot",
+    "serving.kv_tokens_read": "sum of the active slots' lengths at each "
+                              "decode step: the KV rows that step's "
+                              "attention had to read (plain decode; a "
+                              "speculative round does not count them)",
+}
+
+# Mosaic kernels (`pl.pallas_call(name=...)`): the custom call's HLO
+# instruction is named from it, whoever calls the kernel.
+KERNELS = {
+    "flash_fwd": "flash attention forward -> (out, lse)",
+    "flash_bwd_dq": "flash attention backward -> dQ",
+    "flash_bwd_dkv": "flash attention backward -> (dK, dV)",
+    "paged_attention": "paged decode/verify attention over the block pool",
+}
+
+# Jitted steps: the function's name, so the `XLA Modules` event and the
+# host's `PjitFunction(...)` read `jit_<name>` / `<name>`.
+EXECUTABLES = {
+    "train_step": "jit.TrainStep: forward, backward, optimizer update",
+    "serving_prefill": "GenerationEngine: one prompt window at a bucket",
+    "serving_decode": "GenerationEngine: one token for every slot",
+}
+
+# `jax.named_scope` regions inside those executables: in every op's
+# `op_name` in the HLO, so xprof shows them when the trace holds the HLO
+# proto; an op event of a trace without it carries no `op_name`.
+SCOPES = {
+    "kv_write": "models/gpt.py: the new K/V rows scattered into the pool",
+    "attn": "models/gpt.py: LayerNorm, QKV, attention, output projection",
+    "mlp": "models/gpt.py: LayerNorm, FFN",
+    "lm_head": "models/gpt.py, serving/engine.py: the tied LM head's "
+               "logits, and the cross-entropy in training",
+    "sampling": "serving/sampling.py: top-k/top-p filter and Gumbel argmax",
+    "optimizer": "optimizer.step(): clip, decay and the update rule",
+}
+
+
+def device_name(name):
+    """`name`, checked against the device side of the table."""
+    if name not in KERNELS and name not in EXECUTABLES \
+            and name not in SCOPES:
+        raise ValueError(f"{name!r} is not a device name of "
+                         "paddle_tpu.profiler.spans' table")
+    return name
+
+
+def scope(name):
+    """`jax.named_scope` with a name from `SCOPES`."""
+    if name not in SCOPES:
+        raise ValueError(f"{name!r} is not in paddle_tpu.profiler.spans."
+                         "SCOPES")
+    return jax.named_scope(name)
+
+
+def named(fn, name):
+    """A plain function called `name` (one of `EXECUTABLES`) around `fn`,
+    for `jax.jit` to name the executable after."""
+    device_name(name)
+
+    def call(*args):
+        return fn(*args)
+
+    call.__name__ = call.__qualname__ = name
+    return call
+
+
+class HostSpan:
+    """What every span does; `span` adds the name check and the counters,
+    `RecordEvent` the begin/end stack."""
+
+    __slots__ = ("name", "trace_id", "_ann", "_t0", "_r0")
+
+    def __init__(self, name, trace_id=None):
+        self.name = name
+        self.trace_id = trace_id
+
+    def __enter__(self):
+        self._ann = jax.profiler.TraceAnnotation(self.name)
+        self._ann.__enter__()
+        self._r0 = tracing.clock() if tracing.enabled() else 0.0
+        self._t0 = time.perf_counter_ns()
+        return self
+
+    def __exit__(self, *exc):
+        ns = time.perf_counter_ns() - self._t0
+        self._ann.__exit__(None, None, None)
+        if self._r0:
+            tracing.add_span(self.trace_id, self.name, self._r0,
+                             tracing.clock())
+        if timeline.active():
+            t0 = self._t0 / 1e9
+            timeline.add_span(self.name, t0, t0 + ns / 1e9)
+        self._count(ns)
+        return False
+
+    def _count(self, ns):
+        pass
+
+
+class span(HostSpan):
+    """``with span("serving.decode_step"):`` — see the module docstring.
+    Refuses a name that is not in `SPANS`."""
+
+    __slots__ = ("_keys",)
+    _table: dict = {}
+
+    def __init__(self, name, trace_id=None):
+        keys = self._table.get(name)
+        if keys is None:
+            if name not in SPANS:
+                raise ValueError(f"{name!r} is not in paddle_tpu.profiler."
+                                 "spans.SPANS")
+            sc, _, short = name.partition(".")
+            keys = self._table[name] = (
+                registry.scoped_counters(sc, {short + "_ns": 0,
+                                              short + "_n": 0}),
+                short + "_ns", short + "_n",
+                (short, sc) if name in _TIMED else None)
+        self._keys = keys
+        self.name = name
+        self.trace_id = trace_id
+
+    def _count(self, ns):
+        d, k_ns, k_n, timed = self._keys
+        d[k_ns] += ns
+        d[k_n] += 1
+        if timed:
+            registry.timing(timed[0], ns / 1e9, scope=timed[1])

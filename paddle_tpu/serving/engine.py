@@ -40,7 +40,8 @@ cache: any signature first-seen bumps ``serving.prefill_compiles`` /
 ``serving.decode_compiles`` and lands a ``serving_prefill_compile`` /
 ``serving_decode_compile`` event in the profiler explainer ring — a decode
 retrace storm is loud (``profiler.explain()``) instead of a silent 100x
-slowdown. Host spans (``serving_prefill`` / ``serving_decode_step``) and
+slowdown. Host spans (``serving.prefill`` / ``serving.decode_step`` /
+``serving.decode_sync``, names from ``profiler.spans.SPANS``) and
 ``serving.*`` counters/timings ride the same observability stack as the
 training runtime.
 
@@ -65,9 +66,10 @@ from ..core import autograd as _ag
 from ..core import lazy as _lazy
 from ..core import random as _random
 from ..core.tensor import Tensor
-from ..profiler import RecordEvent
+from ..profiler import span as _span
 from ..profiler import explainer as _explain
 from ..profiler import registry as _registry
+from ..profiler import spans as _spans
 from ..profiler import tracing as _tracing
 from ..testing import faults as _faults
 from . import sampling as _sampling
@@ -80,7 +82,8 @@ _counters = _registry.scoped_counters("serving", {
     "prefix_hits": 0, "prefix_misses": 0, "prefix_hit_tokens": 0,
     "prefix_inserted_blocks": 0, "prefix_evicted_blocks": 0,
     "kv_blocks_hwm": 0, "handoff_exports": 0, "handoff_imports": 0,
-    "handoff_stale": 0, "chunked_prefills": 0, "prefill_chunks": 0})
+    "handoff_stale": 0, "chunked_prefills": 0, "prefill_chunks": 0,
+    "kv_tokens_read": 0})
 
 # Decode replay fast path (ISSUE 9, same machinery as lazy.ReplayStep):
 # in the steady window a decode iteration is one fingerprint check (the
@@ -318,10 +321,10 @@ class GenerationEngine:
         # co-resident in one process (hybrid_engine._compile has the
         # same gate for the same reason).
         self._donate = (1, 2) if jax.devices()[0].platform != "cpu" else ()
-        self._prefill_jit = jax.jit(self._prefill_pure,
-                                    donate_argnums=self._donate)
-        self._decode_jit = jax.jit(self._decode_pure,
-                                   donate_argnums=self._donate)
+        self._prefill_jit = jax.jit(
+            _spans.named(self._prefill_pure, "serving_prefill"),
+            donate_argnums=self._donate)
+        self._decode_jit = self._jit_decode()
         self._seen_sigs: set = set()
 
         # decode fast path state: cached weight-array tuple (invalidated
@@ -434,6 +437,10 @@ class GenerationEngine:
             _counters["kv_blocks_hwm"] = used
 
     # ----------------------------------------------------- pure step fns --
+    def _jit_decode(self):
+        return jax.jit(_spans.named(self._decode_pure, "serving_decode"),
+                       donate_argnums=self._donate)
+
     def _state_arrays(self):
         # cached between weight swaps: walking hundreds of Tensor
         # attribute loads per decode step was a measurable slice of the
@@ -501,7 +508,8 @@ class GenerationEngine:
                              (1, 1, hidden.shape[2])).astype(jnp.int32),
             axis=1)[:, 0]
         w = state_arrays[self._emb_idx]
-        logits = last.astype(jnp.float32) @ w.T.astype(jnp.float32)
+        with _spans.scope("lm_head"):
+            logits = last.astype(jnp.float32) @ w.T.astype(jnp.float32)
         gum = _sampling.gumbel_rows(key[None], jnp.zeros((1,), jnp.int32),
                                     logits.shape[-1])
         tok = _sampling.sample_tokens(logits, temp, top_k, top_p, gum)
@@ -526,8 +534,9 @@ class GenerationEngine:
             positions[:, 0], cur_lens + 1, block_tables,
             kernel=self._paged_kernel)
         w = state_arrays[self._emb_idx]
-        logits = (hidden[:, 0].astype(jnp.float32)
-                  @ w.T.astype(jnp.float32))
+        with _spans.scope("lm_head"):
+            logits = (hidden[:, 0].astype(jnp.float32)
+                      @ w.T.astype(jnp.float32))
         gum = _sampling.gumbel_rows(keys, gen_idx, logits.shape[-1])
         toks = _sampling.sample_tokens(logits, temps, top_ks, top_ps, gum)
         adv = active.astype(cur_lens.dtype)
@@ -677,8 +686,7 @@ class GenerationEngine:
         prefix cache is flushed too: a fault mid-step may have left
         cached prefix blocks in an unknown state, and recomputing a
         prefix is cheap next to serving a corrupt one."""
-        self._decode_jit = jax.jit(self._decode_pure,
-                                   donate_argnums=self._donate)
+        self._decode_jit = self._jit_decode()
         self._seen_sigs = {s for s in self._seen_sigs
                            if s[0] != "decode"}
         self._fast = None  # fresh executable: audited rebuild first
@@ -769,8 +777,7 @@ class GenerationEngine:
         self._note_signature(
             "prefill", args,
             f"bucket_len={L}, max_batch={self.max_batch_size}")
-        with RecordEvent("serving_prefill"), \
-                _registry.time_block("prefill", scope="serving"):
+        with _span("serving.prefill"):
             tok, nk, nv = self._prefill_jit(*args)
             tok = int(np.asarray(tok)[0])
         self._k, self._v = list(nk), list(nv)
@@ -1123,18 +1130,23 @@ class GenerationEngine:
         if fast is None:
             return self._decode_rebuild(active, n_active)
         args = (self._state_arrays(), tuple(self._k), tuple(self._v)) + fast
-        # the timing record stays per-step (one observation, no span
-        # stack) so timings.serving.decode_step keeps covering EVERY
-        # iteration, not just the rebuild ones
-        with _registry.time_block("decode_step", scope="serving"):
-            toks_d, nk, nv, nlast, nlens, ngen = self._decode_jit(*args)
-            toks = np.asarray(toks_d)
+        toks, nk, nv, nlast, nlens, ngen = self._decode_call(args)
         self._k, self._v = list(nk), list(nv)
         self._fast = (nlast, nlens, fast[2], ngen) + fast[4:]
         self._finish_decode(active, n_active, toks)
         self._decode_since_audit += 1
         _fp_counters["decode_fast_steps"] += 1
         return toks
+
+    def _decode_call(self, args):
+        """The one timed site of a decode iteration, fast path and rebuild
+        path alike: dispatch of the executable, then the wait for its
+        tokens (the span's self time is the dispatch)."""
+        with _span("serving.decode_step"):
+            toks_d, *rest = self._decode_jit(*args)
+            with _span("serving.decode_sync"):
+                toks = np.asarray(toks_d)
+        return (toks, *rest)
 
     def _decode_rebuild(self, active, n_active):
         """Off-steady decode: rebuild the device-side slot state from the
@@ -1152,10 +1164,7 @@ class GenerationEngine:
             f"max_batch={self.max_batch_size}, "
             f"max_seq_len={self.max_seq_len}")
         _fp_counters["decode_rebuilds"] += 1
-        with RecordEvent("serving_decode_step"), \
-                _registry.time_block("decode_step", scope="serving"):
-            toks_d, nk, nv, nlast, nlens, ngen = self._decode_jit(*args)
-            toks = np.asarray(toks_d)
+        toks, nk, nv, nlast, nlens, ngen = self._decode_call(args)
         self._k, self._v = list(nk), list(nv)
         self._fast = (nlast, nlens, tail[2], ngen) + tail[4:]
         self._decode_since_audit = 0
@@ -1165,10 +1174,13 @@ class GenerationEngine:
     def _finish_decode(self, active, n_active, toks):
         # host mirrors advance in lockstep with the device copies (numpy
         # stores over B elements; the audit cross-checks the two)
+        c = _counters
+        # the KV rows this step's attention read, whatever kernel read
+        # them: each active slot's length with its new row
         self._cur_lens[active] += 1
+        c["kv_tokens_read"] += int(self._cur_lens[active].sum())
         self._gen_idx[active] += 1
         self._last_tokens[active] = toks[active]
-        c = _counters
         c["decode_steps"] += 1
         c["active_slot_steps"] += n_active
         c["tokens_generated"] += n_active

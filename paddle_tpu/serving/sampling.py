@@ -26,6 +26,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from ..profiler.spans import scope as _scope
+
 
 def request_key(base_key, seed):
     """Raw ``uint32`` key data for one request: the engine's base key (drawn
@@ -46,7 +48,8 @@ def gumbel_rows(key_data, token_idx, vocab):
         k = jax.random.fold_in(jax.random.wrap_key_data(kd), idx)
         return jax.random.gumbel(k, (vocab,), jnp.float32)
 
-    return jax.vmap(row)(key_data, token_idx)
+    with _scope("sampling"):
+        return jax.vmap(row)(key_data, token_idx)
 
 
 def filter_top_k(logits, top_k):
@@ -83,10 +86,12 @@ def sample_tokens(logits, temperature, top_k, top_p, gumbel):
     All inputs are arrays (``logits [B, V]``, knobs ``[B]``, ``gumbel
     [B, V]``) so the call is shape-stable regardless of the per-request
     configs in the batch."""
-    logits = logits.astype(jnp.float32)
-    greedy = jnp.argmax(logits, axis=-1)
-    safe_t = jnp.where(temperature > 0, temperature, 1.0)
-    scaled = logits / safe_t[:, None]
-    filtered = filter_top_p(filter_top_k(scaled, top_k), top_p)
-    sampled = jnp.argmax(filtered + gumbel, axis=-1)
-    return jnp.where(temperature > 0, sampled, greedy).astype(jnp.int32)
+    with _scope("sampling"):
+        logits = logits.astype(jnp.float32)
+        greedy = jnp.argmax(logits, axis=-1)
+        safe_t = jnp.where(temperature > 0, temperature, 1.0)
+        scaled = logits / safe_t[:, None]
+        filtered = filter_top_p(filter_top_k(scaled, top_k), top_p)
+        sampled = jnp.argmax(filtered + gumbel, axis=-1)
+        return jnp.where(temperature > 0, sampled,
+                         greedy).astype(jnp.int32)

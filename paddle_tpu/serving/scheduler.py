@@ -31,6 +31,7 @@ import time
 
 from ..profiler import explainer as _explain
 from ..profiler import registry as _registry
+from ..profiler import span as _span
 from ..profiler import tracing as _tracing
 from .block_pool import PagePoolExhausted
 from .engine import FatalEngineError, StaleHandoffError
@@ -39,7 +40,16 @@ _counters = _registry.scoped_counters("serving", {
     "requests_submitted": 0, "requests_completed": 0,
     "requests_rejected": 0, "requests_timeout": 0, "requests_failed": 0,
     "step_retries": 0, "swap_failures": 0, "requeued_requests": 0,
-    "pool_exhausted": 0})
+    "pool_exhausted": 0, "sched_steps": 0, "queue_wait_ns": 0,
+    "admitted": 0})
+
+
+def _note_queue_wait(wait):
+    """One request left the queue for a slot after `wait` seconds."""
+    _counters["queue_wait_ns"] += int(wait * 1e9)
+    _counters["admitted"] += 1
+    _registry.timing("queue_wait", wait, scope="serving")
+    _registry.hist_record("queue_wait", wait)
 
 
 class QueueFullError(RuntimeError):
@@ -105,6 +115,10 @@ class GenerationRequest:
         self.trace_id = None
         self.first_tok_ts = None
         self.last_tok_ts = None
+        # time.monotonic stamp of every token, in order: its ends are
+        # first_tok_ts / last_tok_ts, its single gaps what a client sees
+        # between two tokens (another request's prefill included)
+        self.tok_ts = []
 
     @property
     def done(self):
@@ -306,6 +320,11 @@ class ContinuousBatchScheduler:
         re-check under the lock as before). Combined with the engine's
         prebuilt decode args this makes the scheduler->engine hop one
         fingerprint check + one executable call per steady iteration."""
+        with _span("serving.sched_step"):
+            _counters["sched_steps"] += 1
+            return self._step()
+
+    def _step(self):
         now = time.monotonic()
         if self._t0 is None:
             self._t0 = now
@@ -415,28 +434,24 @@ class ContinuousBatchScheduler:
         # slot per iteration — each bitwise-equal to plain decode's — and
         # stop conditions are applied per token in emission order.
         if self._active:
-            # decode-iteration span: ONE ring append per iteration when
-            # tracing is on (never per slot / per token), zero work off
-            it0 = _tracing.clock() if _tracing.enabled() else 0.0
+            # the engine's serving.decode_step span times the iteration;
+            # serving.emit the bookkeeping after it (one span, never per
+            # slot / per token)
             spec = getattr(self.engine, "decode_step_spec", None)
-            if spec is not None:
-                per_slot = self._decode_with_retry(spec)
-                now = time.monotonic()
+            out = self._decode_with_retry(spec or self.engine.decode_step)
+            now = time.monotonic()
+            with _span("serving.emit"):
                 for slot, req in list(self._active.items()):
-                    toks = per_slot[slot]
+                    if spec is None:
+                        self._append_token(req, int(out[slot]), now)
+                        continue
+                    toks = out[slot]
                     base = self.engine.slot_len(slot) - len(toks)
                     for i, t in enumerate(toks):
                         self._append_token(req, int(t), now,
                                            slot_len=base + i + 1)
                         if req.done:
                             break
-            else:
-                toks = self._decode_with_retry(self.engine.decode_step)
-                now = time.monotonic()
-                for slot, req in list(self._active.items()):
-                    self._append_token(req, int(toks[slot]), now)
-            if it0:
-                _tracing.add_span(None, "decode_iter", it0, _tracing.clock())
 
         self._update_throughput()
         return self.has_work()
@@ -480,6 +495,10 @@ class ContinuousBatchScheduler:
         pool pressure and the request was requeued (the caller must stop
         admitting this step — retrying immediately would spin); True for
         every terminal outcome (admitted, chunk-admitted or failed)."""
+        with _span("serving.admit", req.trace_id):
+            return self._admit_into(req, slot)
+
+    def _admit_into(self, req, slot):
         t_start = time.monotonic()
         begin = getattr(self.engine, "begin_prefill", None)
         if (self.prefill_chunk_tokens is not None and begin is not None
@@ -505,9 +524,7 @@ class ContinuousBatchScheduler:
             req.slot = slot
             req.status = RequestStatus.RUNNING
             self._prefilling[slot] = req
-            wait = t_start - req.submit_ts
-            _registry.timing("queue_wait", wait, scope="serving")
-            _registry.hist_record("queue_wait", wait)
+            _note_queue_wait(t_start - req.submit_ts)
             _tracing.add_span(req.trace_id, "queue_wait",
                               req.submit_ts, t_start)
             _tracing.flight("admit_chunked", rid=req.rid,
@@ -566,16 +583,14 @@ class ContinuousBatchScheduler:
         req.slot = slot
         req.status = RequestStatus.RUNNING
         self._active[slot] = req
-        wait = t_start - req.submit_ts
-        _registry.timing("queue_wait", wait, scope="serving")
-        _registry.hist_record("queue_wait", wait)
+        _note_queue_wait(t_start - req.submit_ts)
         now = time.monotonic()
         req.ttft_s = now - req.submit_ts
         _registry.timing("ttft", req.ttft_s, scope="serving")
         _registry.hist_record("ttft", req.ttft_s)
         _tracing.add_span(req.trace_id, "queue_wait", req.submit_ts, t_start)
-        _tracing.add_span(req.trace_id,
-                          "kv_adopt" if handoff else "admit", t_start, now)
+        if handoff:  # a local admission is the serving.admit span itself
+            _tracing.add_span(req.trace_id, "kv_adopt", t_start, now)
         _tracing.flight("admit", rid=req.rid, trace_id=req.trace_id,
                         slot=slot, handoff=handoff)
         self._append_token(req, first, now)
@@ -594,6 +609,7 @@ class ContinuousBatchScheduler:
         else:
             req.first_tok_ts = now
         req.last_tok_ts = now
+        req.tok_ts.append(now)
         if slot_len is None and req.slot is not None:
             slot_len = self.engine.slot_len(req.slot)
         if req.eos_id is not None and token == req.eos_id:

@@ -30,6 +30,7 @@ import time
 import zlib
 
 from ..profiler import explainer as _explain
+from ..profiler import span as _span
 from ..profiler import tracing as _tracing
 from .engine import FatalEngineError, GenerationEngine
 from .scheduler import (ContinuousBatchScheduler, GenerationRequest,
@@ -223,7 +224,7 @@ class GenerationServer:
             # idle = no decode in flight: a staged swap applies here too,
             # so following a checkpoint dir doesn't wait for traffic
             self.scheduler._apply_pending_swap()
-            with self._work:
+            with _span("serving.loop_idle"), self._work:
                 self._work.wait(self._idle_wait_s)
 
     @property

@@ -64,7 +64,7 @@ from ..core import lazy as _lazy
 from ..core.tensor import Tensor
 from ..profiler import explainer as _explain
 from ..profiler import registry as _registry
-from ..profiler import tracing as _tracing
+from ..profiler import span as _span
 from ..testing import faults as _faults
 from . import sampling as _sampling
 from .block_pool import BlockPool, PagePoolExhausted
@@ -686,10 +686,9 @@ class DraftVerifyEngine(GenerationEngine):
         (last, lens, keys, gen, temps, tks, tps, act, bt, dbt) = fast
         K = self.draft_k
         dstate = self._draft_arrays()
-        # spec-round span sits AROUND the two executable calls (PR 8
+        # the decode-step span sits AROUND the two executable calls (PR 8
         # contract: no span work inside the replayed round)
-        rt0 = _tracing.clock() if _tracing.enabled() else 0.0
-        with _registry.time_block("decode_step", scope="serving"):
+        with _span("serving.decode_step"):
             drafts, ndk, ndv = self._draft_round_jit(
                 dstate, tuple(self._dk), tuple(self._dv), last, lens,
                 keys, gen, temps, tks, tps, dbt)
@@ -706,9 +705,10 @@ class DraftVerifyEngine(GenerationEngine):
                 self._state_arrays(), tuple(self._k), tuple(self._v),
                 last, drafts, lens, keys, gen, temps, tks, tps,
                 act, bt)
-            sampled = np.asarray(sampled_d)
-            accepts = np.asarray(accepts_d)
-            emitted = np.asarray(emitted_d)
+            with _span("serving.decode_sync"):
+                sampled = np.asarray(sampled_d)
+                accepts = np.asarray(accepts_d)
+                emitted = np.asarray(emitted_d)
         self._k, self._v = list(nk), list(nv)
         self._fast = (nlast, nlens, keys, ngen, temps, tks, tps, act,
                       bt, dbt)
@@ -748,8 +748,6 @@ class DraftVerifyEngine(GenerationEngine):
         sc["tokens_generated"] += total
         _registry.gauge_set("serving.batch_occupancy",
                             n_active / self.max_batch_size)
-        if rt0:
-            _tracing.add_span(None, "spec_round", rt0, _tracing.clock())
         return out
 
     def _retire_old_generations(self):

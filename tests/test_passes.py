@@ -65,6 +65,10 @@ class TestPassFramework:
 class TestBF16Pass:
     def test_wraps_matmuls_and_still_trains(self):
         try:
+            # six steps on six different random batches: whether the last
+            # loss is below the first depended on the initial weights, and
+            # so on which tests the worker had run before (ROADMAP D14)
+            paddle.seed(7)
             main, startup, loss = _build_mlp_program()
             ctx = new_pass("auto_parallel_bf16").apply([main])
             assert ctx.get_attr("auto_parallel_bf16:wrapped_ops") >= 2

@@ -22,6 +22,7 @@ import json
 import os
 import subprocess
 import sys
+import time
 
 import pytest
 
@@ -348,3 +349,253 @@ class TestCrossPodTraceMerge:
             capture_output=True, text=True, timeout=60)
         assert out.returncode == 0, out.stderr
         assert f"trace {want}" in out.stdout
+
+
+# ------------------------------------------------------------------------
+# PR 27: the one span API (profiler/spans.py) and what it writes
+# ------------------------------------------------------------------------
+
+def _tiny_gpt(seed=11):
+    import paddle_tpu as paddle
+    from paddle_tpu.models.gpt import (GPTConfig, GPTForPretraining,
+                                      GPTModel)
+
+    paddle.seed(seed)
+    cfg = GPTConfig.preset("gpt2-tiny", vocab_size=96, seq_len=64)
+    return GPTForPretraining(GPTModel(cfg))
+
+
+def _host_lines(trace_dir):
+    """[(names on the line)] for every line of the host plane of the
+    newest .xplane.pb under trace_dir."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    pb = sorted(glob.glob(os.path.join(
+        trace_dir, "plugins", "profile", "*", "*.xplane.pb")))[-1]
+    data = ProfileData.from_file(pb)
+    return [[e.name for e in line.events]
+            for plane in data.planes if plane.name == "/host:CPU"
+            for line in plane.lines]
+
+
+class TestSpanApi:
+    def test_span_refuses_a_name_outside_the_table(self):
+        from paddle_tpu.profiler import RecordEvent, span, spans
+
+        with pytest.raises(ValueError, match="spans.SPANS"):
+            span("serving.not_in_the_table")
+        with pytest.raises(ValueError):
+            spans.scope("not_a_scope")
+        with pytest.raises(ValueError):
+            spans.device_name("pure")
+        before = dict(registry.counters("serving"))
+        with RecordEvent("anything a user likes"):
+            pass
+        # a user's event feeds no counter
+        assert registry.counters("serving") == before
+        # every table entry says what it covers, and names are scoped
+        for table in (spans.SPANS, spans.COUNTERS, spans.KERNELS,
+                      spans.EXECUTABLES, spans.SCOPES):
+            assert all(isinstance(v, str) and v for v in table.values())
+        assert all("." in n for n in list(spans.SPANS) + list(spans.COUNTERS))
+
+    def test_no_sink_on_only_the_counters_move(self):
+        from paddle_tpu.profiler import span, timeline
+
+        assert not tracing.enabled() and not timeline.active()
+        c0 = registry.counters("serving")
+        t0 = registry.timings("serving").get("serving.decode_step",
+                                             {"count": 0})["count"]
+        with span("serving.decode_step"):
+            with span("serving.decode_sync"):
+                pass
+        c1 = registry.counters("serving")
+        assert c1["decode_step_n"] == c0.get("decode_step_n", 0) + 1
+        assert c1["decode_sync_n"] == c0.get("decode_sync_n", 0) + 1
+        assert c1["decode_step_ns"] > c0.get("decode_step_ns", 0)
+        assert isinstance(c1["decode_step_ns"], int)
+        # the outer span holds the inner one
+        assert c1["decode_step_ns"] - c0.get("decode_step_ns", 0) \
+            >= c1["decode_sync_ns"] - c0.get("decode_sync_ns", 0)
+        # the reservoir of the same name is still fed (stats_dump, pods)
+        assert registry.timings("serving")["serving.decode_step"]["count"] \
+            == t0 + 1
+        assert "serving.decode_sync" not in registry.timings("serving")
+        assert tracing.pending_spans() == 0
+        assert timeline.stop() == []
+
+    def test_ring_on_the_span_lands_there_with_its_trace_id(self):
+        from paddle_tpu.profiler import span
+
+        tracing.enable()
+        with span("serving.admit", trace_id="tr27"):
+            pass
+        with span("serving.sched_step"):
+            pass
+        got = tracing.drain_spans()
+        assert [(s[0], s[1]) for s in got] == [
+            ("tr27", "serving.admit"), ("", "serving.sched_step")]
+        assert all(s[4] >= s[3] for s in got)
+
+    def test_profiler_window_puts_the_span_on_the_timeline(self):
+        from paddle_tpu.profiler import span, timeline
+
+        timeline.start()
+        try:
+            with span("train.step"):
+                pass
+        finally:
+            got = timeline.stop()
+        assert [s[0] for s in got] == ["train.step"]
+        assert got[0][3] >= got[0][2]
+
+    def test_record_event_begin_end_nests_as_before(self):
+        from paddle_tpu.profiler import RecordEvent, timeline
+
+        tracing.enable()
+        timeline.start()
+        try:
+            ev = RecordEvent("outer")
+            ev.begin()
+            ev.begin()
+            ev.end()
+            ev.end()
+            ev.end()  # unmatched: no-op
+            with RecordEvent("inner"):
+                pass
+        finally:
+            got = timeline.stop()
+        assert [s[0] for s in got] == ["outer", "outer", "inner"]
+        # innermost closes first, so the first span ends inside the second
+        assert got[0][2] >= got[1][2] and got[0][3] <= got[1][3]
+        # the same code as span: the ring holds them too
+        assert [s[1] for s in tracing.drain_spans()] == [
+            "outer", "outer", "inner"]
+
+
+class TestProgramSpans:
+    @pytest.fixture()
+    def server(self):
+        from paddle_tpu.serving import GenerationServer
+
+        srv = GenerationServer(_tiny_gpt(), max_batch_size=2,
+                               buckets=(8, 16), max_queue_size=8)
+        srv.start()
+        srv.generate([1, 2, 3], max_new_tokens=3)  # compile outside
+        yield srv
+        srv.shutdown(timeout=30)
+
+    def test_scheduler_thread_spans_reach_the_profiler_trace(
+            self, server, tmp_path):
+        import jax
+
+        from paddle_tpu.profiler import RecordEvent
+
+        fp0 = dict(registry.counters("fastpath"))
+        c0 = dict(registry.counters("serving"))
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+        try:
+            with RecordEvent("test.main_thread"):
+                server.generate([5, 6, 7, 8], max_new_tokens=8)
+                # a whole wait for work, begun and ended inside the trace
+                time.sleep(3 * server._idle_wait_s)
+        finally:
+            jax.profiler.stop_trace()
+        fp1 = registry.counters("fastpath")
+        c1 = registry.counters("serving")
+        steps = c1["decode_steps"] - c0["decode_steps"]
+        fast = fp1["decode_fast_steps"] - fp0["decode_fast_steps"]
+        rebuilt = fp1["decode_rebuilds"] - fp0["decode_rebuilds"]
+        assert fast >= 1 and rebuilt >= 1 and fast + rebuilt == steps
+        lines = _host_lines(str(tmp_path))
+        serving = [ln for ln in lines if "serving.sched_step" in ln]
+        # one thread did the serving work, and not the caller's
+        assert len(serving) == 1
+        assert "test.main_thread" not in serving[0]
+        assert any("test.main_thread" in ln for ln in lines)
+        names = serving[0]
+        # fast path and rebuild path alike: one span a decode iteration
+        assert names.count("serving.decode_step") == steps
+        assert names.count("serving.decode_sync") == steps
+        assert names.count("serving.emit") == steps
+        assert names.count("serving.prefill") == 1
+        assert names.count("serving.admit") == 1
+        assert names.count("serving.sched_step") \
+            == c1["sched_steps"] - c0["sched_steps"]
+        # the server thread waits for work before and after the request
+        assert "serving.loop_idle" in names
+
+    def test_counters_at_the_span_boundaries(self, server):
+        c0 = dict(registry.counters("serving"))
+        h = server.submit([9, 8, 7, 6, 5], max_new_tokens=6)
+        h.result(timeout=60)
+        # the request is handed over inside the last step: let that step's
+        # spans close before reading what they count
+        deadline = time.monotonic() + 10
+        while time.monotonic() < deadline:
+            c1 = registry.counters("serving")
+            if c1["sched_step_n"] - c0.get("sched_step_n", 0) \
+                    == c1["sched_steps"] - c0["sched_steps"]:
+                break
+            time.sleep(0.01)
+        d = {k: c1[k] - c0.get(k, 0) for k in c1
+             if isinstance(c1[k], int)}
+        n = d["decode_steps"]
+        assert n == 5  # the first token comes from the prefill
+        assert d["decode_step_n"] == n == d["decode_sync_n"] == d["emit_n"]
+        assert d["sched_step_n"] == d["sched_steps"] >= n
+        assert d["prefill_n"] == d["admit_n"] == d["admitted"] == 1
+        assert d["queue_wait_ns"] >= 0
+        # a 5-token prompt: the steps read 6, 7, 8, 9, 10 rows
+        assert d["kv_tokens_read"] == sum(range(6, 6 + n))
+        assert d["sched_step_ns"] >= d["decode_step_ns"] + d["prefill_ns"]
+        assert d["decode_step_ns"] >= d["decode_sync_ns"] > 0
+
+    def test_tok_ts_one_stamp_a_token(self, server):
+        h = server.submit([4, 3, 2, 1], max_new_tokens=7)
+        h.result(timeout=60)
+        assert len(h.tok_ts) == len(h.tokens) == 7
+        assert all(a <= b for a, b in zip(h.tok_ts, h.tok_ts[1:]))
+        assert h.tok_ts[0] == h.first_tok_ts
+        assert h.tok_ts[-1] == h.last_tok_ts
+
+    def test_train_step_span_and_executable_name(self):
+        import numpy as np
+
+        import paddle_tpu as paddle
+        from paddle_tpu import nn, optimizer
+        from paddle_tpu.profiler import spans
+
+        paddle.seed(3)
+        net = nn.Linear(4, 2)
+        opt = optimizer.SGD(0.1, parameters=net.parameters())
+
+        def step_fn(x):
+            loss = net(x).mean()
+            loss.backward()
+            opt.step()
+            opt.clear_grad()
+            return loss
+
+        train = paddle.jit.TrainStep(step_fn, net, opt)
+        x = paddle.to_tensor(np.ones((3, 4), np.float32))
+        c0 = dict(registry.counters("train"))
+        for _ in range(3):
+            train(x)
+        c1 = registry.counters("train")
+        assert c1["step_n"] - c0.get("step_n", 0) == 3
+        assert c1["step_ns"] > c0.get("step_ns", 0)
+        name = train._compiled.__name__
+        assert name == "train_step" and name in spans.EXECUTABLES
+        # and the engine's two executables
+        from paddle_tpu.serving import GenerationEngine
+
+        eng = GenerationEngine(_tiny_gpt(), max_batch_size=1, buckets=(8,))
+        assert eng._prefill_jit.__name__ == "serving_prefill"
+        assert eng._decode_jit.__name__ == "serving_decode"
+        eng.reprime()
+        assert eng._decode_jit.__name__ == "serving_decode"
