@@ -25,8 +25,9 @@ process; this parent never imports JAX):
   serve-1       GenerationServer on the paged engine, default kernel choice:
                 mixed prompt lengths, a shared prefix, greedy and sampled;
                 resolved kernel pallas, one decode executable, zero compiles
-                in the third wave, KV pools donated, pool audit clean, greedy
-                tokens equal to an engine built with paged_kernel="xla".
+                in the third wave, KV pools donated and stored row-major,
+                pool audit clean, greedy tokens equal to an engine built
+                with paged_kernel="xla".
   train-4       (>= 4 chips) fleet.init dp2 x mp2 use_spmd, eager loop under
                 lazy_eval(): first two losses equal to train-1's, step compiles
                 flat, no Python collectives, every parameter on four chips,
@@ -531,6 +532,7 @@ def _serve(ctx, refs, mesh=None):
         "prefix_hits": delta("prefix_hits"),
         "requests_failed": delta("requests_failed"),
         "kv_pools_donated": bool(pool0.is_deleted()),
+        "kv_pool_row_major": eng.stats()["kv_pool_row_major"],
         "requests": sum(len(w) for w in waves),
     }
     assert facts["decode_compiles"] == 1, facts
@@ -538,6 +540,7 @@ def _serve(ctx, refs, mesh=None):
     assert facts["kernel_fallbacks"] == 0 and facts["prefix_hits"] >= 1 \
         and facts["requests_failed"] == 0, facts
     assert facts["kv_pools_donated"] == ctx.on_tpu, facts
+    assert facts["kv_pool_row_major"] == 1, facts
     greedy = [i for i, (_, g, _) in enumerate(waves[0]) if g]
     facts["greedy_tokens"] = [toks[i] for i in greedy]
     facts["sampled_tokens"] = [t for i, t in enumerate(toks)

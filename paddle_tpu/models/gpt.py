@@ -158,67 +158,45 @@ class GPTAttention(nn.Layer):
         q, k, v = ops.unbind(qkv, axis=2)
         if cache is not None and block_tables is not None:
             # Paged-cache path (paddle_tpu.serving, ISSUE 10): `cache` is
-            # the SHARED fixed-shape block pool [num_blocks, block_size,
-            # H, Dh]; `block_tables` [B, M] maps each slot's logical
-            # block j to a physical pool block, so slots of wildly
-            # different lengths (and slots SHARING immutable prefix
-            # blocks) live in one buffer with zero copies. The T new rows
-            # scatter into the flattened pool at rows derived from the
-            # table; attention gathers each slot's logical view back out
-            # and masks exactly like the contiguous slot path. Block 0 is
-            # the reserved garbage block: writes for rows outside
+            # the SHARED fixed-shape block pool in its device form
+            # [num_blocks, block_size, H*Dh] (heads merged: the form the
+            # chip stores row by row, ops/kv_pool.py); `block_tables`
+            # [B, M] maps each slot's logical block j to a physical pool
+            # block, so slots of wildly different lengths (and slots
+            # SHARING immutable prefix blocks) live in one buffer with
+            # zero copies. The B*T new rows of H*Dh are written into the
+            # pool at (block, row) pairs derived from the table — in
+            # place when the step donates the pools; no view of the whole
+            # pool is formed, on the chip that would be a relayout.
+            # Attention reads each slot's logical view back out and masks
+            # exactly like the contiguous slot path. Block 0 is the
+            # reserved garbage block: writes for rows outside
             # [0, seq_len) (bucket padding, inactive decode lanes)
             # redirect there so they can never clobber live blocks.
             k_pool, v_pool = cache
-            Nb, bs = k_pool.shape[0], k_pool.shape[1]
-            M = block_tables.shape[1]
-            S = M * bs
-            rows = cache_offset.unsqueeze(1) + ops.arange(0, T,
-                                                          dtype="int32")
             with _scope("kv_write"):
-                blk = ops.clip(rows // bs, max=M - 1)
-                phys = ops.take_along_axis(block_tables, blk, axis=1)
-                writable = rows < seq_lens.unsqueeze(-1)
-                flat_rows = ops.where(writable, phys * bs + rows % bs,
-                                      ops.zeros_like(rows))
-                k_flat = k_pool.reshape(
-                    [Nb * bs, self.n_head, self.head_dim])
-                v_flat = v_pool.reshape(
-                    [Nb * bs, self.n_head, self.head_dim])
-                widx = ops.broadcast_to(
-                    flat_rows.reshape([B * T]).unsqueeze(-1).unsqueeze(-1),
-                    [B * T, self.n_head, self.head_dim])
-                k_flat = ops.put_along_axis(
-                    k_flat, widx,
-                    k.reshape([B * T, self.n_head, self.head_dim]), axis=0)
-                v_flat = ops.put_along_axis(
-                    v_flat, widx,
-                    v.reshape([B * T, self.n_head, self.head_dim]), axis=0)
+                new_k, new_v = F.paged_kv_write(
+                    k_pool, v_pool, k, v, block_tables, cache_offset,
+                    seq_lens)
             if paged_kernel in ("pallas", "interpret"):
                 # Fused read path (ISSUE 14): the Pallas kernel walks the
                 # block table inside the kernel, so the gathered
                 # [B, M*bs, H, Dh] view below never materializes. The
-                # scatter above is unchanged (T rows, garbage-block-0
+                # write above is the same (T rows, garbage-block-0
                 # redirect intact); only the O(M*bs) gather is fused.
                 # `paged_kernel` is a static per-engine choice
                 # (pallas_ops.select_paged_kernel) — never data.
-                with _scope("kv_write"):
-                    new_k = k_flat.reshape(k_pool.shape)
-                    new_v = v_flat.reshape(v_pool.shape)
                 out = F.paged_attention(q, new_k, new_v, block_tables,
                                         seq_lens, cache_offset,
                                         kernel=paged_kernel,
                                         mesh=paged_mesh)
                 out = self.out_proj(out.reshape([B, T, D]))
                 return out, (new_k, new_v)
-            slot_rows = ((block_tables * bs).unsqueeze(-1)
-                         + ops.arange(0, bs, dtype="int32")).reshape([B, S])
-            k_view = ops.gather(k_flat, slot_rows.reshape([-1]),
-                                axis=0).reshape(
-                                    [B, S, self.n_head, self.head_dim])
-            v_view = ops.gather(v_flat, slot_rows.reshape([-1]),
-                                axis=0).reshape(
-                                    [B, S, self.n_head, self.head_dim])
+            k_view = F.paged_kv_view(new_k, block_tables, self.n_head)
+            v_view = F.paged_kv_view(new_v, block_tables, self.n_head)
+            S = k_view.shape[1]
+            rows = cache_offset.unsqueeze(1) + ops.arange(0, T,
+                                                          dtype="int32")
             jpos = ops.arange(0, S, dtype="int32")
             mask = ops.logical_and(
                 jpos.unsqueeze(0).unsqueeze(0) <= rows.unsqueeze(-1),
@@ -229,8 +207,7 @@ class GPTAttention(nn.Layer):
                 is_causal=False, dropout_p=self.dropout_p,
                 training=self.training)
             out = self.out_proj(out.reshape([B, T, D]))
-            return out, (k_flat.reshape(k_pool.shape),
-                         v_flat.reshape(v_pool.shape))
+            return out, (new_k, new_v)
         if cache is not None and cache_offset is not None:
             # Slot-cache path (paddle_tpu.serving): `cache` is a
             # preallocated [B, S, H, Dh] buffer; the T new rows are written

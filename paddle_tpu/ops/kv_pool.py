@@ -1,0 +1,152 @@
+"""paddle_tpu.ops.kv_pool — the paged KV pool's form on the device.
+
+One K (or V) pool of one layer is ``[num_blocks, block_size, H * Dh]``:
+heads merged into the last axis, head ``h`` at lanes ``h*Dh:(h+1)*Dh``
+(heads major, so an 'mp' shard is a contiguous run of heads:
+``PartitionSpec(None, None, "mp")``). This module is the only place that
+knows it: allocation, the in-place write of a step's new rows, the view
+the gather path reads, the 4-D blocks of the KV-handoff payload, and the
+layout check behind the ``serving.kv_pool_row_major`` gauge.
+
+Why merged (PERF.md, PR 28): a TPU stores ``bf16[Nb, bs, H, 64]`` with the
+BLOCK index minor-most (layout ``{0,3,2,1:T(8,128)(2,1)}``: a 64-wide last
+dimension would waste half of every 128-lane tile), so whatever wants rows,
+a scatter or the Mosaic kernel, gets a whole-pool relayout in front and
+another behind. ``[Nb, bs, H*Dh]`` is plain row-major there, and the write
+below compiles to an in-place fusion on the donated buffer. Inside a jitted
+step no 4-D view of a whole pool is ever formed: on the chip that reshape
+is a relayout, not a bitcast. The host side (handoff payloads, tests)
+reshapes freely.
+"""
+from __future__ import annotations
+
+import numpy as np
+import jax
+import jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec
+
+
+def zeros(num_blocks, block_size, num_heads, head_dim, dtype):
+    """A fresh pool. Block 0 is the reserved garbage block."""
+    return jnp.zeros((num_blocks, block_size, num_heads * head_dim), dtype)
+
+
+def pspec(heads_sharded):
+    """Placement of a pool on a serving mesh: heads over 'mp' when they
+    divide, else replicated."""
+    return PartitionSpec(None, None, "mp") if heads_sharded \
+        else PartitionSpec()
+
+
+def allocate(attn_layers, num_blocks, block_size, dtype, mesh=None):
+    """The K pools and the V pools of a decoder, one of each per attention
+    layer (``n_head`` and ``head_dim`` read off the layer), placed on
+    ``mesh`` by :func:`pspec` when one is given."""
+    sharding = None
+    if mesh is not None:
+        mp = int(dict(zip(mesh.axis_names, mesh.devices.shape)).get("mp", 1))
+        sharding = NamedSharding(mesh, pspec(
+            mp > 1 and all(a.n_head % mp == 0 for a in attn_layers)))
+
+    def pools():
+        made = [zeros(num_blocks, block_size, a.n_head, a.head_dim, dtype)
+                for a in attn_layers]
+        if sharding is not None:
+            made = [jax.device_put(p, sharding) for p in made]
+        return made
+
+    return pools(), pools()
+
+
+def merged(pool):
+    """``pool`` in the device form. A 4-D ``[Nb, bs, H, Dh]`` pool (the
+    public op's older calling convention) is merged on entry: free on the
+    CPU, a relayout for whoever still passes 4-D on the chip."""
+    if pool.ndim == 4:
+        return pool.reshape(pool.shape[0], pool.shape[1], -1)
+    return pool
+
+
+def span_rows(block_tables, offsets, seq_lens, span, block_size):
+    """Where the ``span`` new rows of every slot go: (block ids, rows in
+    the block), each ``[B * span]`` int32. Row ``offsets[b] + t`` lands in
+    the slot's own block through its table row; rows outside
+    ``[0, seq_lens[b])`` (bucket padding, inactive decode lanes) all land
+    on row 0 of the reserved garbage block 0, so indices repeat there and
+    only there."""
+    bt = block_tables.astype(jnp.int32)
+    bs = jnp.int32(block_size)
+    rows = (offsets.astype(jnp.int32)[:, None]
+            + jnp.arange(span, dtype=jnp.int32)[None])
+    blk = jnp.minimum(rows // bs, jnp.int32(bt.shape[1] - 1))
+    phys = jnp.take_along_axis(bt, blk, axis=1)
+    writable = rows < seq_lens.astype(jnp.int32)[:, None]
+    zero = jnp.zeros_like(rows)
+    return (jnp.where(writable, phys, zero).reshape(-1),
+            jnp.where(writable, rows % bs, zero).reshape(-1))
+
+
+def write_rows(pool, rows, block_ids, row_ids):
+    """``pool`` with ``rows`` ``[N, H*Dh]`` written at ``(block_ids[n],
+    row_ids[n])``: a row scatter with a two-part index on the pool as it
+    is, no reshape for the compiler to turn into a relayout. On a donated
+    pool it updates the buffer in place (tests/test_tpu_lowering.py).
+    Repeated indices (the garbage row) make no promise about which row
+    wins, as the element scatter before it made none."""
+    return pool.at[block_ids, row_ids].set(rows.astype(pool.dtype))
+
+
+def write_span(k_pool, v_pool, k, v, block_tables, offsets, seq_lens):
+    """Both pools with a step's new rows ``k``, ``v`` ``[B, T, H, Dh]``
+    written through the block tables: :func:`span_rows`, then
+    :func:`write_rows` for each."""
+    B, T = k.shape[0], k.shape[1]
+    blk, row = span_rows(block_tables, offsets, seq_lens, T,
+                         k_pool.shape[1])
+    return (write_rows(k_pool, k.reshape(B * T, -1), blk, row),
+            write_rows(v_pool, v.reshape(B * T, -1), blk, row))
+
+
+def gather_view(pool, block_tables, num_heads):
+    """Every slot's logical ``[B, M*bs, H, Dh]`` view of the pool, whole
+    blocks gathered in table order (the XLA read path; the result is the
+    size of the view, never of the pool)."""
+    B, M = block_tables.shape
+    blocks = jnp.take(pool, block_tables.reshape(-1).astype(jnp.int32),
+                      axis=0)
+    return blocks.reshape(B, M * pool.shape[1], num_heads, -1)
+
+
+def export_blocks(pool, block_ids, num_heads):
+    """Host copy of the given blocks as the handoff payload carries them:
+    numpy ``[n, block_size, H, Dh]`` (the reshape is on the host, free)."""
+    idx = jnp.asarray(np.asarray(block_ids, np.int32))
+    got = np.asarray(jnp.take(pool, idx, axis=0))
+    return got.reshape(got.shape[0], got.shape[1], num_heads, -1)
+
+
+def import_blocks(pool, block_ids, blocks, put=jnp.asarray):
+    """``pool`` with payload ``blocks`` ``[n, block_size, H, Dh]`` written
+    at ``block_ids``. ``put`` places the host array (a mesh engine passes
+    its replicated placement)."""
+    idx = jnp.asarray(np.asarray(block_ids, np.int32))
+    host = np.asarray(blocks)
+    host = host.reshape(host.shape[0], host.shape[1], -1)
+    return pool.at[idx].set(put(host).astype(pool.dtype))
+
+
+def block_shape(pool, num_heads):
+    """``(block_size, H, Dh)`` of one payload block of this pool."""
+    return (int(pool.shape[1]), int(num_heads),
+            int(pool.shape[2]) // int(num_heads))
+
+
+def device_layout(pool):
+    """The pool's major-to-minor dimension order on its device, where jax
+    exposes it (``array.format``), else None. ``(0, 1, 2)`` is row-major:
+    a row of ``H*Dh`` is contiguous and a block is ``block_size`` of them."""
+    try:
+        order = pool.format.layout.major_to_minor
+    except Exception:  # no format on this array / backend
+        return None
+    return None if order is None else tuple(int(d) for d in order)

@@ -43,7 +43,8 @@ __all__ = [
     "cosine_similarity", "cosine_embedding_loss", "label_smooth",
     "log_loss", "square_error_cost", "sigmoid_focal_loss", "dice_loss",
     "ctc_loss", "triplet_margin_loss", "pairwise_distance", "npair_loss",
-    "scaled_dot_product_attention", "paged_attention", "sequence_mask",
+    "scaled_dot_product_attention", "paged_attention", "paged_kv_write",
+    "paged_kv_view", "sequence_mask",
     "temporal_shift", "channel_shuffle",
 ]
 
@@ -1102,11 +1103,37 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None,
     return out
 
 
+def paged_kv_write(k_pool, v_pool, key, value, block_tables, offsets,
+                   seq_lens, name=None):
+    """Write a step's new K/V rows ``[B, T, H, Dh]`` into the block pools
+    ``[num_blocks, block_size, H*Dh]`` (``ops/kv_pool.py``) through each
+    slot's ``block_tables`` [B, M] row: row ``offsets[b] + t`` of slot b,
+    rows outside ``[0, seq_lens[b])`` into the reserved garbage block 0.
+    A row scatter on the pool as it is — in place when the pools are
+    donated to the step. Returns the two pools. Inference-only."""
+    from . import kv_pool
+
+    return forward(kv_pool.write_span,
+                   (k_pool, v_pool, key, value, block_tables, offsets,
+                    seq_lens), name="paged_kv_write", nondiff=True)
+
+
+def paged_kv_view(pool, block_tables, num_heads, name=None):
+    """Every slot's logical ``[B, M*block_size, H, Dh]`` view of a block
+    pool, gathered whole blocks in table order (the XLA read path of
+    prefill; the fused kernel never forms it). Inference-only."""
+    from . import kv_pool
+
+    return forward(kv_pool.gather_view, (pool, block_tables),
+                   attrs={"num_heads": int(num_heads)},
+                   name="paged_kv_view", nondiff=True)
+
+
 def paged_attention(query, k_pool, v_pool, block_tables, seq_lens,
                     q_offsets, kernel="xla", mesh=None, name=None):
     """Fused paged-KV attention (ISSUE 14): ``query`` [B, T, H, Dh] reads
     each slot's logical KV view straight out of the shared block pool
-    [num_blocks, block_size, H, Dh] through its ``block_tables`` [B, M]
+    [num_blocks, block_size, H*Dh] through its ``block_tables`` [B, M]
     row — no gathered [B, M*bs, H, Dh] view is ever materialized on the
     Pallas routes. ``kernel`` is a STATIC choice ("pallas" | "interpret"
     | "xla"), resolved once per engine by

@@ -39,6 +39,7 @@ from ..core import lazy as _lazy
 from ..profiler import explainer as _explain
 from ..profiler import registry as _registry
 from ..profiler.spans import device_name as _kernel_name
+from . import kv_pool as _kv_pool
 
 # Kernel-selection telemetry (ISSUE 14): every resolution of a hot-path
 # kernel family bumps exactly one counter, so an operator can read which
@@ -518,15 +519,18 @@ def flash_attention(q, k, v, mask=None, causal=False, scale=None):
 #
 # Decode-path fused paged attention (ISSUE 14). The serving engine's paged
 # KV cache (PR 9) stores every slot's KV in a shared fixed-shape block pool
-# [num_blocks, block_size, H, Dh] addressed through per-slot int32 block
-# tables. The XLA path materializes a gathered [B, M*bs, H, Dh] view of the
-# pool and runs masked attention over it — two HBM round-trips XLA cannot
-# fuse. The Pallas kernel below walks the block table INSIDE the kernel
-# (vLLM PagedAttention / jax TPU paged_attention reference style): the
+# [num_blocks, block_size, H*Dh] (heads merged into the last axis: the form
+# the chip stores row by row, ops/kv_pool.py) addressed through per-slot
+# int32 block tables. The XLA path materializes a gathered [B, M*bs, H, Dh]
+# view of the pool and runs masked attention over it — two HBM round-trips
+# XLA cannot fuse. The Pallas kernel below walks the block table INSIDE the
+# kernel (vLLM PagedAttention / jax TPU paged_attention reference style): the
 # tables, lengths and query offsets ride scalar prefetch
 # (pltpu.PrefetchScalarGridSpec), so each grid step's BlockSpec index map
 # picks the one physical KV block that program needs and the pipeline DMAs
-# exactly that block HBM->VMEM. No gathered view ever exists.
+# exactly that block HBM->VMEM. No gathered view ever exists. Inside the
+# block a head is addressed by its 128-lane tile, never shifted out of it
+# (`_paged_attn_kernel`, `_heads_per_lane_tile`).
 #
 # One kernel serves both consumers:
 #   * decode:      q is a [B, 1, H, Dh] span (T=1), q_offsets = cursors;
@@ -558,17 +562,29 @@ PAGED_PARITY_TOL = {"float32": (3e-5, 3e-5), "bfloat16": (0.05, 0.05)}
 
 def _paged_attn_kernel(bt_ref, sl_ref, qo_ref, q_ref, k_ref, v_ref, o_ref,
                        m_scr, l_scr, acc_scr, *, scale, block_size,
-                       precision):
+                       precision, heads_per_tile):
     """Grid (B, M): program (b, j) folds logical block j of slot b into
     the slot's online-softmax state. Scratch (m/l/acc) persists across
-    the M dimension; the output block is written once, at the last j."""
+    the M dimension; the output block is written once, at the last j.
+
+    Head h of the merged ``[bs, H*Dh]`` block is taken out by the lane
+    tile it lives in, never by a lane shift: the ``W = heads_per_tile *
+    Dh`` lanes ``g*W:(g+1)*W`` (a whole 128-lane tile when ``Dh`` divides
+    128) are the operand of both dots, and head h's query ``q_ref[:, h]``
+    comes in ``[T, W]`` with zeros on its tile-mates' lanes, so the
+    contraction picks head h's keys; ``p @ v`` then holds head h's output
+    on its own lanes (the rest is dropped by the caller). With
+    ``heads_per_tile`` 1 this is a plain slice of ``Dh`` lanes."""
     b = pl.program_id(0)
     j = pl.program_id(1)
-    T, H = q_ref.shape[0], q_ref.shape[1]
+    T, H, W = q_ref.shape
     bs = jnp.int32(block_size)
     scale = _f32(scale)
     dot = functools.partial(jnp.dot, preferred_element_type=jnp.float32,
                             precision=precision)
+    dot_nt = functools.partial(  # q @ k.T without forming k.T
+        jax.lax.dot_general, dimension_numbers=(((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32, precision=precision)
 
     @pl.when(j == 0)
     def _init():
@@ -588,18 +604,21 @@ def _paged_attn_kernel(bt_ref, sl_ref, qo_ref, q_ref, k_ref, v_ref, o_ref,
         row = qo + jax.lax.broadcasted_iota(
             jnp.int32, (T, block_size), 0)
         mask = (pos <= row) & (pos < sl)
-        for h in range(H):  # static unroll: per-head [T, bs] MXU dots
-            qh = q_ref[:, h, :].astype(jnp.float32) * scale
-            kh = k_ref[:, h, :].astype(jnp.float32)
-            vh = v_ref[:, h, :].astype(jnp.float32)
-            s = dot(qh, kh.T)
-            s = jnp.where(mask, s, _NEG_INF)
-            m_new = jnp.maximum(m_scr[h], s.max(axis=-1, keepdims=True))
-            p = jnp.exp(s - m_new)
-            corr = jnp.exp(m_scr[h] - m_new)
-            l_scr[h] = l_scr[h] * corr + p.sum(axis=-1, keepdims=True)
-            acc_scr[h] = acc_scr[h] * corr + dot(p, vh)
-            m_scr[h] = m_new
+        for g in range(H // heads_per_tile):  # static unroll
+            kt = k_ref[:, g * W:(g + 1) * W].astype(jnp.float32)
+            vt = v_ref[:, g * W:(g + 1) * W].astype(jnp.float32)
+            for h in range(g * heads_per_tile, (g + 1) * heads_per_tile):
+                # per-head [T, bs] MXU dots
+                qh = q_ref[:, h, :].astype(jnp.float32) * scale
+                s = dot_nt(qh, kt)
+                s = jnp.where(mask, s, _NEG_INF)
+                m_new = jnp.maximum(m_scr[h],
+                                    s.max(axis=-1, keepdims=True))
+                p = jnp.exp(s - m_new)
+                corr = jnp.exp(m_scr[h] - m_new)
+                l_scr[h] = l_scr[h] * corr + p.sum(axis=-1, keepdims=True)
+                acc_scr[h] = acc_scr[h] * corr + dot(p, vt)
+                m_scr[h] = m_new
 
     @pl.when(j == pl.num_programs(1) - 1)
     def _finalize():
@@ -608,11 +627,30 @@ def _paged_attn_kernel(bt_ref, sl_ref, qo_ref, q_ref, k_ref, v_ref, o_ref,
             o_ref.dtype)
 
 
+def _heads_per_lane_tile(num_heads, head_dim):
+    """How many heads of the merged axis share one 128-lane tile: 128 //
+    head_dim where head_dim divides 128 and the heads fill whole tiles
+    (64-wide heads: 2), else 1 (128-wide heads own their tiles; an odd
+    width is sliced on lanes as it lies)."""
+    per = 128 // head_dim if head_dim < 128 and 128 % head_dim == 0 else 1
+    return per if num_heads % per == 0 else 1
+
+
 def _paged_attention_fused(q, k_pool, v_pool, block_tables, seq_lens,
                            q_offsets, scale, interpret):
     B, T, H, Dh = q.shape
     bs = int(k_pool.shape[1])
     M = int(block_tables.shape[1])
+    G = _heads_per_lane_tile(H, Dh)
+    W = G * Dh
+    if G > 1:
+        # head h's query on its own lanes of the tile, zeros on the rest:
+        # measured on the v5e, slicing 64-lane heads out of the tile costs
+        # a lane rotate for every second head and the kernel 2.42 ms a
+        # call where this form takes 1.51 (PERF.md, PR 28)
+        own = (jnp.arange(H)[:, None] % G == jnp.arange(G)[None])  # [H, G]
+        q = jnp.where(own[None, None, :, :, None], q[:, :, :, None, :],
+                      jnp.zeros((), q.dtype)).reshape(B, T, H, W)
 
     def q_map(b, j, bt, sl, qo):
         return (b, _i0(), _i0(), _i0())
@@ -625,32 +663,38 @@ def _paged_attention_fused(q, k_pool, v_pool, block_tables, seq_lens,
         # them anyway
         limit = jnp.minimum(qo[b] + jnp.int32(T), sl[b])
         last = jnp.maximum(pl.cdiv(limit, jnp.int32(bs)) - 1, _i0())
-        return (bt[b, jnp.minimum(j, last)], _i0(), _i0(), _i0())
+        return (bt[b, jnp.minimum(j, last)], _i0(), _i0())
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(B, M),
         in_specs=[
-            pl.BlockSpec((None, T, H, Dh), q_map),
-            pl.BlockSpec((None, bs, H, Dh), kv_map),
-            pl.BlockSpec((None, bs, H, Dh), kv_map),
+            pl.BlockSpec((None, T, H, W), q_map),
+            pl.BlockSpec((None, bs, H * Dh), kv_map),
+            pl.BlockSpec((None, bs, H * Dh), kv_map),
         ],
-        out_specs=pl.BlockSpec((None, T, H, Dh), q_map),
+        out_specs=pl.BlockSpec((None, T, H, W), q_map),
         scratch_shapes=[
-            pltpu.VMEM((H, T, 1), jnp.float32),   # running max
-            pltpu.VMEM((H, T, 1), jnp.float32),   # running denom
-            pltpu.VMEM((H, T, Dh), jnp.float32),  # fp32 accumulator
+            pltpu.VMEM((H, T, 1), jnp.float32),  # running max
+            pltpu.VMEM((H, T, 1), jnp.float32),  # running denom
+            pltpu.VMEM((H, T, W), jnp.float32),  # fp32 accumulator
         ],
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         functools.partial(_paged_attn_kernel, scale=scale, block_size=bs,
-                          precision=_dot_precision(q.dtype)),
+                          precision=_dot_precision(q.dtype),
+                          heads_per_tile=G),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, T, H, Dh), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, T, H, W), q.dtype),
         interpret=interpret,
         name=_kernel_name("paged_attention"),
     )(block_tables.astype(jnp.int32), seq_lens.astype(jnp.int32),
       q_offsets.astype(jnp.int32), q, k_pool, v_pool)
+    if G > 1:  # head h's output is on its own lanes of its tile
+        out = jnp.where(own[None, None, :, :, None],
+                        out.reshape(B, T, H, G, Dh),
+                        jnp.zeros((), out.dtype)).sum(axis=3)
+    return out
 
 
 def _mesh_mp_degree(mesh):
@@ -677,12 +721,13 @@ def _paged_attention_sharded(q, k_pool, v_pool, block_tables, seq_lens,
             f"mp={mp}; resolve the kernel with select_paged_kernel("
             "num_heads=...) so indivisible head counts demote to xla")
     head = P(None, None, "mp", None)
+    pool = _kv_pool.pspec(True)  # a shard's merged axis is its own heads
     repl = P()
     body = functools.partial(_paged_attention_fused, scale=scale,
                              interpret=interpret)
     return jax.shard_map(
         body, mesh=mesh,
-        in_specs=(head, head, head, repl, repl, repl),
+        in_specs=(head, pool, pool, repl, repl, repl),
         out_specs=head, check_vma=False,
     )(q, k_pool, v_pool, block_tables, seq_lens, q_offsets)
 
@@ -695,17 +740,9 @@ def paged_attention_xla(q, k_pool, v_pool, block_tables, seq_lens,
     oracle for the fused kernel and as the ``kernel="xla"`` route."""
     B, T, H, Dh = q.shape
     scale = float(scale) if scale is not None else Dh ** -0.5
-    Nb, bs = int(k_pool.shape[0]), int(k_pool.shape[1])
-    M = int(block_tables.shape[1])
-    S = M * bs
-    flat_k = k_pool.reshape(Nb * bs, H, Dh)
-    flat_v = v_pool.reshape(Nb * bs, H, Dh)
-    rows = ((block_tables.astype(jnp.int32) * bs)[:, :, None]
-            + jnp.arange(bs, dtype=jnp.int32)[None, None]).reshape(B, S)
-    k_view = jnp.take(flat_k, rows.reshape(-1), axis=0).reshape(
-        B, S, H, Dh)
-    v_view = jnp.take(flat_v, rows.reshape(-1), axis=0).reshape(
-        B, S, H, Dh)
+    k_view = _kv_pool.gather_view(_kv_pool.merged(k_pool), block_tables, H)
+    v_view = _kv_pool.gather_view(_kv_pool.merged(v_pool), block_tables, H)
+    S = k_view.shape[1]
     jpos = jnp.arange(S, dtype=jnp.int32)
     qrow = (q_offsets.astype(jnp.int32)[:, None]
             + jnp.arange(T, dtype=jnp.int32)[None])
@@ -719,7 +756,10 @@ def paged_attention_xla(q, k_pool, v_pool, block_tables, seq_lens,
 def paged_attention(q, k_pool, v_pool, block_tables, seq_lens, q_offsets,
                     kernel="xla", scale=None, mesh=None):
     """Paged-KV attention: ``q`` [B, T, H, Dh] over pools
-    [num_blocks, block_size, H, Dh] addressed by ``block_tables`` [B, M].
+    [num_blocks, block_size, H*Dh] (ops/kv_pool.py; a 4-D
+    [num_blocks, block_size, H, Dh] pool is merged on entry, which is a
+    whole-pool relayout on the chip: the engine never passes one)
+    addressed by ``block_tables`` [B, M].
     ``seq_lens`` [B] counts each slot's valid rows INCLUDING the span's
     own freshly-scattered rows; ``q_offsets`` [B] is the absolute
     position of span row 0. ``kernel``: "pallas" (compiled TPU),
@@ -738,6 +778,7 @@ def paged_attention(q, k_pool, v_pool, block_tables, seq_lens, q_offsets,
         raise ValueError(
             f"unknown paged-attention kernel {kernel!r} "
             "(expected pallas | interpret | xla)")
+    k_pool, v_pool = _kv_pool.merged(k_pool), _kv_pool.merged(v_pool)
     if _mesh_mp_degree(mesh) > 1:
         out = _paged_attention_sharded(q, k_pool, v_pool, block_tables,
                                        seq_lens, q_offsets, scale,
@@ -759,7 +800,7 @@ def paged_attention(q, k_pool, v_pool, block_tables, seq_lens, q_offsets,
     return out
 
 
-# largest pool block [block_size, H, Dh] the kernel's pipeline fits: K and V,
+# largest pool block [block_size, H*Dh] the kernel's pipeline fits: K and V,
 # double-buffered, share the 16 MiB of VMEM Mosaic scopes to one kernel with
 # the fp32 scratch. Compiled for the v5e, 2 MiB blocks fit and 4 MiB blocks
 # end in RESOURCE_EXHAUSTED (tests/test_tpu_lowering.py).
@@ -770,9 +811,9 @@ def paged_tileable(head_dim, block_size, dtype, num_heads=None):
     """Will Mosaic compile the kernel for this pool geometry? (The
     interpreter route has no such constraint.) Returns (ok, reason).
 
-    The pool block spans the whole (H, Dh) minor dims of the pool, so no
-    head_dim or block_size fails to tile: compiled for the v5e, every
-    head_dim in 32..256 x block_size in 4..32 x heads in 1..16 is accepted
+    The pool block spans the whole (block_size, H*Dh) minor dims of the
+    pool, so no head_dim or block_size fails to tile: compiled for the v5e,
+    every head_dim in 32..256 x block_size in 4..32 x heads in 1..16 is accepted
     in fp32 and bf16. What is refused is a dtype the body has no arithmetic
     for and a block too large for VMEM (judged when ``num_heads`` — the
     heads one shard holds — is given)."""

@@ -2,7 +2,8 @@
 
 Host-side bookkeeping for the serving engine's paged KV cache (ISSUE 10).
 The device side is a fixed-shape pool per layer — ``[num_blocks,
-block_size, heads, head_dim]`` — addressed through per-slot block tables;
+block_size, heads * head_dim]`` (``ops/kv_pool.py``) — addressed through
+per-slot block tables;
 nothing here ever touches a device array. Two pieces:
 
 * :class:`BlockPool` — a refcounted free list over physical block ids.

@@ -5,7 +5,8 @@ of scheduling is one decode ITERATION, not one request, so finished slots
 are evicted and refilled mid-flight without touching their neighbors. The
 cache is vLLM-style paged (Kwon et al., SOSP'23), adapted to XLA's
 static-shape world: per layer ONE fixed-shape block pool
-``[num_blocks, block_size, heads, head_dim]``, addressed through per-slot
+``[num_blocks, block_size, heads * head_dim]`` (heads merged: the form a
+TPU stores row by row, ``ops/kv_pool.py``), addressed through per-slot
 int32 block tables — an indirection gather per attention read buys
 (a) per-request memory proportional to ``prompt + max_new_tokens`` instead
 of a full ``max_seq_len`` slab, and (b) prefix sharing: a radix tree over
@@ -21,8 +22,8 @@ Compile discipline (the whole point on a TPU):
   prompt length, prefix length and the block table are data, never shapes,
   so cold prefills and prefix hits share one executable per bucket;
 * the decode step compiles exactly once — fixed ``[max_batch, 1]`` query,
-  in-place scatter writes into the flattened pool at block-table-derived
-  rows, valid-length masking instead of shape changes;
+  in-place row writes into the donated pool at block-table-derived
+  (block, row) pairs, valid-length masking instead of shape changes;
 * every per-request difference (current length, sampling config, RNG key,
   activity, block table) is an ARRAY argument, so no workload mix can
   retrace.
@@ -66,6 +67,7 @@ from ..core import autograd as _ag
 from ..core import lazy as _lazy
 from ..core import random as _random
 from ..core.tensor import Tensor
+from ..ops import kv_pool as _kv_pool
 from ..profiler import span as _span
 from ..profiler import explainer as _explain
 from ..profiler import registry as _registry
@@ -120,6 +122,40 @@ class FatalEngineError(RuntimeError):
     The scheduler's transient-retry path does NOT swallow this: it
     propagates to the server loop, which marks the replica dead so a
     supervisor can restart it and re-queue its requests."""
+
+
+def _note_pool_layout(pool):
+    """Set the gauge ``serving.kv_pool_row_major`` from the layout the
+    device gave a freshly built pool: 1 when it is stored row by row (the
+    form the in-place row write and the paged kernel take as it is), 0
+    when a jax / libtpu upgrade chose another — then every step pays
+    whole-pool relayouts again, and the explainer event says which layout
+    it was. None (no gauge) where jax does not expose the layout."""
+    order = _kv_pool.device_layout(pool)
+    if order is None:
+        return None
+    row_major = int(order == tuple(range(pool.ndim)))
+    _registry.gauge_set("serving.kv_pool_row_major", row_major)
+    _explain.record(
+        "kv_pool_layout", op="kv_pool", row_major=bool(row_major),
+        why=(f"pool {tuple(pool.shape)} {pool.dtype} is stored "
+             f"major-to-minor {order}: "
+             + ("row-major, rows are written in place" if row_major else
+                "NOT row-major — row writes and the paged kernel will "
+                "relayout the whole pool every step")))
+    return row_major
+
+
+def _pool_record(layer, name, pool, heads, mesh):
+    """One KV pool in ``describe_sharding()``: its device shape
+    ``[num_blocks, block_size, H*Dh]``, the H that the merged axis holds
+    (what tools/sharding_lint.py divides by 'mp') and its placement."""
+    from ..core.lazy import _spec_repr
+
+    return {"layer": layer, "pool": name,
+            "shape": [int(d) for d in pool.shape], "heads": int(heads),
+            "dtype": str(pool.dtype), "bytes": int(pool.nbytes),
+            "spec": _spec_repr(pool.sharding) if mesh is not None else None}
 
 
 def _default_buckets(max_seq_len):
@@ -210,7 +246,6 @@ class GenerationEngine:
         # input replicated — GSPMD partitions the compiled steps
         self._mesh = mesh
         self._repl = None
-        kv_sharding = None
         if mesh is not None:
             from jax.sharding import NamedSharding, PartitionSpec
 
@@ -224,13 +259,6 @@ class GenerationEngine:
                     getattr(t, "sharding_spec", None), mesh,
                     tuple(arr.shape))
                 t._data = jax.device_put(arr, NamedSharding(mesh, pspec))
-            axes = dict(zip(mesh.axis_names, mesh.devices.shape))
-            mp = int(axes.get("mp", 1))
-            heads_ok = mp > 1 and all(
-                blk.attn.n_head % mp == 0 for blk in gpt.blocks)
-            kv_sharding = NamedSharding(
-                mesh, PartitionSpec(None, None, "mp", None) if heads_ok
-                else PartitionSpec())
 
         # paged-attention kernel choice (ISSUE 14): resolved ONCE here —
         # "pallas" (compiled TPU kernel), "interpret" (same kernel body
@@ -267,14 +295,14 @@ class GenerationEngine:
             _registry.gauge_set("serving.mesh.paged_kernel_sharded",
                                 int(self._paged_mesh is not None))
 
-        Nb, bs = self.pool.num_blocks, self.block_size
-        self._kv_shapes = [(Nb, bs, blk.attn.n_head, blk.attn.head_dim)
-                           for blk in gpt.blocks]
-        self._k = [jnp.zeros(s, self._dtype) for s in self._kv_shapes]
-        self._v = [jnp.zeros(s, self._dtype) for s in self._kv_shapes]
-        if kv_sharding is not None:
-            self._k = [jax.device_put(a, kv_sharding) for a in self._k]
-            self._v = [jax.device_put(a, kv_sharding) for a in self._v]
+        # the pools, in the device form ops/kv_pool.py owns:
+        # [num_blocks, block_size, H*Dh] per layer, for K and for V
+        # (heads over 'mp' on a mesh they divide)
+        self._kv_heads = [blk.attn.n_head for blk in gpt.blocks]
+        self._k, self._v = _kv_pool.allocate(
+            [blk.attn for blk in gpt.blocks], self.pool.num_blocks,
+            self.block_size, self._dtype, mesh)
+        self._kv_row_major = _note_pool_layout(self._k[0])
 
         # host-side slot state, mirrored into the decode step as arrays
         B = self.max_batch_size
@@ -314,10 +342,12 @@ class GenerationEngine:
             self._base_key = jax.random.PRNGKey(int(rng_seed))
         self._seed_counter = itertools.count()
 
-        # donate the KV pools (args 1, 2) so the per-step cache update
-        # is truly in place on device — without it XLA copies the whole
-        # pool every decode step. Accelerator only: XLA-CPU
-        # intermittently SIGABRTs with many donated executables
+        # donate the KV pools (args 1, 2) so the per-step row write is
+        # in place on device: with the donation AND the pool's row-major
+        # form the step holds no pool-sized copy at all (without the
+        # donation XLA copies each pool once a step; with a 4-D pool it
+        # relaid each one out twice, PERF.md PR 28). Accelerator only:
+        # XLA-CPU intermittently SIGABRTs with many donated executables
         # co-resident in one process (hybrid_engine._compile has the
         # same gate for the same reason).
         self._donate = (1, 2) if jax.devices()[0].platform != "cpu" else ()
@@ -967,9 +997,12 @@ class GenerationEngine:
         trace = self._slot_trace.get(slot)
         t0 = _tracing.clock() if _tracing.enabled() else 0.0
         ids = list(self._slot_blocks[slot])
-        idx = jnp.asarray(np.asarray(ids, np.int32))
-        ks = [np.asarray(jnp.take(a, idx, axis=0)) for a in self._k]
-        vs = [np.asarray(jnp.take(a, idx, axis=0)) for a in self._v]
+        # the wire keeps [n, block_size, H, Dh] blocks, whatever the
+        # pool's device form: pods need no format change
+        ks = [_kv_pool.export_blocks(a, ids, h)
+              for a, h in zip(self._k, self._kv_heads)]
+        vs = [_kv_pool.export_blocks(a, ids, h)
+              for a, h in zip(self._v, self._kv_heads)]
         _counters["handoff_exports"] += 1
         if t0:
             _tracing.add_span(
@@ -1046,24 +1079,18 @@ class GenerationEngine:
                 f"handoff has {len(payload['kv_k'])} layers, engine has "
                 f"{len(self._k)} — different model")
         for li, kb in enumerate(payload["kv_k"]):
-            want = self._kv_shapes[li][1:]
+            want = _kv_pool.block_shape(self._k[li], self._kv_heads[li])
             if tuple(np.shape(kb))[1:] != tuple(want):
                 raise ValueError(
                     f"handoff layer {li} block shape "
                     f"{tuple(np.shape(kb))[1:]} != engine {tuple(want)}")
         fresh = self.pool.alloc(n, evict=self._evict)
-        idx = jnp.asarray(np.asarray(fresh, np.int32))
         try:
             for li in range(len(self._k)):
-                kb = jnp.asarray(np.asarray(payload["kv_k"][li]),
-                                 self._dtype)
-                vb = jnp.asarray(np.asarray(payload["kv_v"][li]),
-                                 self._dtype)
-                if self._repl is not None:
-                    kb = jax.device_put(kb, self._repl)
-                    vb = jax.device_put(vb, self._repl)
-                self._k[li] = self._k[li].at[idx].set(kb)
-                self._v[li] = self._v[li].at[idx].set(vb)
+                self._k[li] = _kv_pool.import_blocks(
+                    self._k[li], fresh, payload["kv_k"][li], self._put)
+                self._v[li] = _kv_pool.import_blocks(
+                    self._v[li], fresh, payload["kv_v"][li], self._put)
         except Exception:
             self.pool.decref(fresh)  # failed adoption leaks nothing
             raise
@@ -1237,7 +1264,8 @@ class GenerationEngine:
                "kv_blocks_in_use": self.pool.in_use(),
                "kv_blocks_free": self.pool.free_count(),
                "prefix_cache_nodes": len(self.prefix_cache),
-               "weight_generation": self.prefix_cache.generation}
+               "weight_generation": self.prefix_cache.generation,
+               "kv_pool_row_major": self._kv_row_major}
         if self._mesh is not None:
             out["mesh_axes"] = dict(zip(
                 self._mesh.axis_names,
@@ -1252,22 +1280,15 @@ class GenerationEngine:
         kernel, and one record per per-layer KV pool with its partition
         spec, so the lint can flag a mesh engine whose pools stayed
         replicated (the exact demotion ISSUE 16 removes)."""
-        from ..core.lazy import _spec_repr
-
         mesh = None
         if self._mesh is not None:
             mesh = {"axes": dict(zip(
                 self._mesh.axis_names,
                 (int(s) for s in self._mesh.devices.shape)))}
-        pools = []
-        for i, (k, v) in enumerate(zip(self._k, self._v)):
-            for name, a in (("k", k), ("v", v)):
-                pools.append({
-                    "layer": i, "pool": name,
-                    "shape": [int(d) for d in a.shape],
-                    "dtype": str(a.dtype), "bytes": int(a.nbytes),
-                    "spec": (_spec_repr(a.sharding)
-                             if self._mesh is not None else None)})
+        pools = [_pool_record(i, name, a, h, self._mesh)
+                 for i, (k, v, h) in enumerate(
+                     zip(self._k, self._v, self._kv_heads))
+                 for name, a in (("k", k), ("v", v))]
         return {"mesh": mesh,
                 "paged_kernel": self._paged_kernel,
                 "paged_kernel_sharded": self._paged_mesh is not None,

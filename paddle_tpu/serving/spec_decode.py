@@ -62,6 +62,7 @@ import jax.numpy as jnp
 from ..core import autograd as _ag
 from ..core import lazy as _lazy
 from ..core.tensor import Tensor
+from ..ops import kv_pool as _kv_pool
 from ..profiler import explainer as _explain
 from ..profiler import registry as _registry
 from ..profiler import span as _span
@@ -71,6 +72,7 @@ from .block_pool import BlockPool, PagePoolExhausted
 from .engine import GenerationEngine
 from .engine import _counters as _serving_counters
 from .engine import _fp_counters
+from .engine import _pool_record
 
 __all__ = ["DraftVerifyEngine"]
 
@@ -216,25 +218,9 @@ class DraftVerifyEngine(GenerationEngine):
         if draft_num_blocks is None:
             draft_num_blocks = 1 + B * self.blocks_per_slot
         self.draft_pool = BlockPool(draft_num_blocks, name="draft")
-        Nb, bs = self.draft_pool.num_blocks, self.block_size
-        self._dkv_shapes = [(Nb, bs, blk.attn.n_head, blk.attn.head_dim)
-                            for blk in dgpt.blocks]
-        self._dk = [jnp.zeros(s, self._ddtype) for s in self._dkv_shapes]
-        self._dv = [jnp.zeros(s, self._ddtype) for s in self._dkv_shapes]
-        if self._mesh is not None:
-            from jax.sharding import NamedSharding, PartitionSpec
-
-            axes = dict(zip(self._mesh.axis_names,
-                            self._mesh.devices.shape))
-            mp = int(axes.get("mp", 1))
-            dheads_ok = mp > 1 and all(
-                blk.attn.n_head % mp == 0 for blk in dgpt.blocks)
-            dkv = NamedSharding(
-                self._mesh,
-                PartitionSpec(None, None, "mp", None)
-                if dheads_ok else PartitionSpec())
-            self._dk = [jax.device_put(a, dkv) for a in self._dk]
-            self._dv = [jax.device_put(a, dkv) for a in self._dv]
+        self._dk, self._dv = _kv_pool.allocate(
+            [blk.attn for blk in dgpt.blocks], self.draft_pool.num_blocks,
+            self.block_size, self._ddtype, self._mesh)
         self._draft_tables = np.zeros((B, self.blocks_per_slot), np.int32)
         self._draft_blocks = [[] for _ in range(B)]
         # acceptance per weight generation (stats_dump "mesh serving"
@@ -808,16 +794,12 @@ class DraftVerifyEngine(GenerationEngine):
 
     def describe_sharding(self):
         desc = super().describe_sharding()
-        from ..core.lazy import _spec_repr
-
         for i, (k, v) in enumerate(zip(self._dk, self._dv)):
+            heads = self._dgpt.blocks[i].attn.n_head
             for name, a in (("k", k), ("v", v)):
                 desc["kv_pools"].append({
-                    "layer": i, "pool": f"draft_{name}", "draft": True,
-                    "shape": [int(d) for d in a.shape],
-                    "dtype": str(a.dtype), "bytes": int(a.nbytes),
-                    "spec": (_spec_repr(a.sharding)
-                             if self._mesh is not None else None)})
+                    **_pool_record(i, f"draft_{name}", a, heads,
+                                   self._mesh), "draft": True})
         desc["draft_paged_kernel"] = self._draft_kernel
         desc["draft_kernel_sharded"] = self._draft_mesh is not None
         return desc

@@ -19,7 +19,10 @@ bf16 additionally by where probabilities are rounded). Covers:
     PR 8 replay fingerprint is stable under kernel selection);
   * the ``kernel_mismatch`` fault provably trips the parity gate.
 
-Compiled-kernel tests are marked ``tpu`` (conftest skips them on CPU).
+The parity cases run on a 4-D pool passed to the public op (merged on
+entry) and on the engine's merged pool (PR 28). The compiled kernel is
+checked off-chip by tests/test_tpu_lowering.py and on the chip by
+chip_smoke.py.
 """
 import numpy as np
 import pytest
@@ -44,12 +47,16 @@ def _build_model(seed=11):
     return GPTForPretraining(GPTModel(cfg))
 
 
-def _case(B, T, H, Dh, Nb, bs, M, dtype=jnp.float32, seed=0):
-    """Random pools + per-lane tables over distinct nonzero blocks."""
+def _case(B, T, H, Dh, Nb, bs, M, dtype=jnp.float32, seed=0, form="4d"):
+    """Random pools + per-lane tables over distinct nonzero blocks. The
+    pools come 4-D ``[Nb, bs, H, Dh]`` (what the public op still takes,
+    merged on entry) or in the engine's device form ``[Nb, bs, H*Dh]``."""
     rng = np.random.default_rng(seed)
     q = jnp.asarray(rng.standard_normal((B, T, H, Dh)), dtype)
     kp = jnp.asarray(rng.standard_normal((Nb, bs, H, Dh)), dtype)
     vp = jnp.asarray(rng.standard_normal((Nb, bs, H, Dh)), dtype)
+    if form == "merged":
+        kp, vp = kp.reshape(Nb, bs, H * Dh), vp.reshape(Nb, bs, H * Dh)
     ids = rng.permutation(np.arange(1, Nb))[:B * M].reshape(B, M)
     bt = jnp.asarray(ids, jnp.int32)
     return q, kp, vp, bt
@@ -68,23 +75,25 @@ def _parity(q, kp, vp, bt, sl, qo):
     return fused
 
 
+@pytest.mark.parametrize("form", ["4d", "merged"])
 class TestKernelParity:
     @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-    def test_decode_straddles_block_boundaries(self, dtype):
+    def test_decode_straddles_block_boundaries(self, dtype, form):
         # bs=4: valid lengths 3 / 4 / 5 / 10 sit just under, exactly on,
         # just over and mid-way across block boundaries; T=1 decode rows
         # at the cursor (the engine's q_offset = seq_len - 1)
-        q, kp, vp, bt = _case(4, 1, 2, 16, 16, 4, 3, dtype=dtype)
+        q, kp, vp, bt = _case(4, 1, 2, 16, 16, 4, 3, dtype=dtype,
+                              form=form)
         sl = [3, 4, 5, 10]
         qo = [s - 1 for s in sl]
         _parity(q, kp, vp, bt, sl, qo)
 
-    def test_inactive_lane_on_garbage_block0(self):
+    def test_inactive_lane_on_garbage_block0(self, form):
         # lane 1 is released: zeroed table row, seq_len 1, cursor 0 —
         # every read lands in reserved block 0. Output must be finite
         # (denominator never 0), parity must hold, and the dead lane
         # must not perturb the live lanes' rows.
-        q, kp, vp, bt = _case(3, 1, 2, 16, 12, 4, 3)
+        q, kp, vp, bt = _case(3, 1, 2, 16, 12, 4, 3, form=form)
         bt = bt.at[1].set(0)
         sl, qo = [9, 1, 6], [8, 0, 5]
         out = _parity(q, kp, vp, bt, sl, qo)
@@ -96,14 +105,15 @@ class TestKernelParity:
                                       np.asarray(solo, np.float32))
 
     @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-    def test_verify_span_causal_mask(self, dtype):
+    def test_verify_span_causal_mask(self, dtype, form):
         # the [B, K+1] verify span: row t may read positions <= qo + t.
         # Parity first; then perturb the pool rows holding positions
         # BEYOND qo + 1 — span rows 0 and 1 must be bitwise unchanged
         # (causality), while some later row must change (the probe is
         # live, not vacuous).
         B, T, bs, M = 2, 4, 4, 4
-        q, kp, vp, bt = _case(B, T, 2, 16, 16, bs, M, dtype=dtype)
+        q, kp, vp, bt = _case(B, T, 2, 16, 16, bs, M, dtype=dtype,
+                              form=form)
         cur = [5, 9]
         sl = [c + T for c in cur]
         _parity(q, kp, vp, bt, sl, cur)
@@ -125,15 +135,57 @@ class TestKernelParity:
         assert not np.array_equal(np.asarray(base[:, 3], np.float32),
                                   np.asarray(bumped[:, 3], np.float32))
 
-    def test_ragged_batch_with_shared_blocks(self):
+    def test_ragged_batch_with_shared_blocks(self, form):
         # prefix-style aliasing: every lane's FIRST logical block is the
         # same physical block (a shared system prompt), lengths ragged
         # across the batch; parity must hold with the aliased reads
-        q, kp, vp, bt = _case(4, 1, 2, 16, 20, 4, 4)
+        q, kp, vp, bt = _case(4, 1, 2, 16, 20, 4, 4, form=form)
         bt = bt.at[:, 0].set(int(bt[0, 0]))
         sl = [2, 6, 11, 16]
         qo = [s - 1 for s in sl]
         _parity(q, kp, vp, bt, sl, qo)
+
+
+class TestHeadsOnLaneTiles:
+    """PR 28: the kernel takes head h out of the merged block by the
+    128-lane tile it lives in (its query zero-padded over the tile), not
+    by a lane shift. Geometries on both sides of that choice: heads that
+    share a tile (64-, 32-, 16-wide), heads that own theirs (128), a
+    head count that does not fill whole tiles (3 x 64) and a width that
+    divides nothing (24), each against the gather oracle."""
+
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+    @pytest.mark.parametrize("H,Dh,per_tile", [
+        (2, 64, 2), (4, 64, 2), (4, 32, 4), (8, 16, 8), (2, 128, 1),
+        (3, 64, 1), (2, 24, 1)])
+    def test_parity_and_tile_sharing(self, H, Dh, per_tile, dtype):
+        assert pallas_ops._heads_per_lane_tile(H, Dh) == per_tile
+        B, T, bs, M = 3, 3, 4, 3
+        q, kp, vp, bt = _case(B, T, H, Dh, 12, bs, M, dtype=dtype,
+                              form="merged", seed=H * Dh)
+        bt = bt.at[1].set(0)  # a dead lane on the garbage block
+        cur = [5, 0, 8]
+        out = _parity(q, kp, vp, bt, [c + T for c in cur], cur)
+        assert out.shape == (B, T, H, Dh)
+        assert bool(jnp.isfinite(out).all())
+
+    def test_tile_mates_do_not_leak_into_each_other(self):
+        # heads 0 and 1 share a lane tile: blowing up head 1's keys and
+        # values must leave head 0's output bitwise unchanged
+        B, T, H, Dh, bs, M = 2, 1, 2, 64, 4, 3
+        q, kp, vp, bt = _case(B, T, H, Dh, 10, bs, M, form="merged")
+        sl = jnp.asarray([7, 10], jnp.int32)
+        qo = sl - 1
+        base = pallas_ops.paged_attention(q, kp, vp, bt, sl, qo,
+                                          kernel="interpret")
+        kp2 = kp.at[:, :, Dh:].multiply(-7.0)
+        vp2 = vp.at[:, :, Dh:].add(100.0)
+        got = pallas_ops.paged_attention(q, kp2, vp2, bt, sl, qo,
+                                         kernel="interpret")
+        np.testing.assert_array_equal(np.asarray(base[:, :, 0]),
+                                      np.asarray(got[:, :, 0]))
+        assert not np.array_equal(np.asarray(base[:, :, 1]),
+                                  np.asarray(got[:, :, 1]))
 
 
 class TestKernelSelection:
@@ -376,7 +428,18 @@ class TestMeshShardedKernel:
         assert desc["paged_kernel_sharded"]
         assert all(pool["spec"] == [None, None, "mp"]
                    for pool in desc["kv_pools"])
-        assert self._lint_mod().lint_engine(desc, min_bytes=0) == []
+        assert all(pool["heads"] == 2 and len(pool["shape"]) == 3
+                   for pool in desc["kv_pools"])
+        lint = self._lint_mod().lint_engine
+        assert lint(desc, min_bytes=0) == []
+        # ... and the same pools left replicated are named by the lint
+        # (the merged shape carries no head axis: the record's "heads"
+        # is what the lint divides by mp)
+        flat = {**desc, "kv_pools": [{**pool, "spec": []}
+                                     for pool in desc["kv_pools"]]}
+        found = lint(flat, min_bytes=0)
+        assert len(found) == len(desc["kv_pools"])
+        assert "2 heads divide mp" in found[0]
         # zero post-warmup churn, same window as the single-chip gate
         sharded.prefill(0, [5, 9, 2, 7], seed=0)
         for _ in range(3):

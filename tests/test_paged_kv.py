@@ -13,7 +13,11 @@ Covers the acceptance gates:
     1 regression: a post-swap request with a cached prefix gets
     freshly-computed blocks);
   * mesh-sharded decode (mp=2 over the forced-host-device mesh) is
-    token-bitwise vs the single-chip engine for a gpt2-tiny-shaped model.
+    token-bitwise vs the single-chip engine for a gpt2-tiny-shaped model;
+  * the pool's device form (PR 28: heads merged, ``ops/kv_pool.py``): the
+    row write is bit-equal to the element scatter it replaced, padding
+    and dead lanes land only in garbage block 0, token streams are the
+    parent commit's, and the handoff payload keeps its 4-D blocks.
 """
 import time
 
@@ -425,6 +429,156 @@ class TestMeshShardedDecode:
                                 buckets=(8, 16), rng_seed=13,
                                 block_size=4)
         assert got_hit == _run_one(cold, p, 5, seed=14)
+
+
+def _element_scatter(pool4, new, bt, off, sl):
+    """The write as it was before PR 28, on a 4-D pool: the row index
+    broadcast over heads and head_dim, one element at a time."""
+    Nb, bs, H, Dh = pool4.shape
+    B, T = new.shape[:2]
+    M = bt.shape[1]
+    rows = off[:, None] + np.arange(T, dtype=np.int32)[None]
+    phys = np.take_along_axis(bt, np.minimum(rows // bs, M - 1), axis=1)
+    flat_rows = np.where(rows < sl[:, None], phys * bs + rows % bs, 0)
+    flat = np.array(pool4).reshape(Nb * bs, H, Dh)
+    for n, r in enumerate(flat_rows.reshape(-1)):  # last write wins
+        flat[r] = new.reshape(B * T, H, Dh)[n]
+    return flat.reshape(pool4.shape)
+
+
+class TestPoolDeviceForm:
+    """PR 28: the pool is ``[num_blocks, block_size, H*Dh]`` on the
+    device and the step's rows are written into it by rows."""
+
+    # (B, T, offsets, seq_lens, zeroed table rows): bs = 4, M = 4
+    CASES = {
+        "decode": (3, 1, [5, 0, 7], [6, 1, 8], [1]),  # lane 1 is dead
+        "verify_span_crosses_a_block_edge": (2, 5, [2, 6], [7, 11], []),
+        "prefill_at_a_prefix": (1, 8, [4], [9], []),  # 3 rows are padding
+        "span_past_the_table": (1, 4, [14], [16], []),
+    }
+
+    @pytest.mark.parametrize("case", sorted(CASES))
+    def test_row_write_bit_equal_to_the_element_scatter(self, case):
+        import jax.numpy as jnp
+
+        from paddle_tpu.nn import functional as F
+        from paddle_tpu.ops import kv_pool
+
+        B, T, off, sl, dead = self.CASES[case]
+        Nb, bs, H, Dh, M = 14, 4, 2, 8, 4
+        rng = np.random.default_rng(5)
+        pools4 = [rng.standard_normal((Nb, bs, H, Dh)).astype(np.float32)
+                  for _ in range(2)]
+        news = [rng.standard_normal((B, T, H, Dh)).astype(np.float32)
+                for _ in range(2)]
+        bt = rng.permutation(np.arange(1, Nb))[:B * M].reshape(B, M) \
+            .astype(np.int32)
+        bt[dead] = 0
+        off, sl = np.asarray(off, np.int32), np.asarray(sl, np.int32)
+        want = [_element_scatter(p, n, bt, off, sl)
+                for p, n in zip(pools4, news)]
+        got = F.paged_kv_write(
+            *(paddle.to_tensor(np.asarray(kv_pool.merged(jnp.asarray(p))))
+              for p in pools4),
+            *(paddle.to_tensor(n) for n in news),
+            paddle.to_tensor(bt), paddle.to_tensor(off),
+            paddle.to_tensor(sl))
+        for g, w, before in zip(got, want, pools4):
+            g = np.asarray(g.numpy())
+            assert g.shape == (Nb, bs, H * Dh)  # no 4-D pool comes back
+            g = g.reshape(Nb, bs, H, Dh)
+            np.testing.assert_array_equal(g, w)
+            # rows outside [0, seq_len) and dead lanes: garbage row only
+            live = sum(int(min(o + T, s) - o) for b, (o, s) in
+                       enumerate(zip(off, sl)) if b not in dead)
+            changed = np.argwhere((g != before).any(axis=(2, 3)))
+            assert len(changed) <= live + 1
+            assert all(tuple(c) == (0, 0) for c in changed
+                       if c[0] == 0), changed
+            np.testing.assert_array_equal(g[0, 1:], before[0, 1:])
+
+    def test_token_streams_are_the_parent_commits(self):
+        """Greedy and sampled streams of a toy engine, recorded on the
+        parent of PR 28 (4-D pools, element scatter) for this model and
+        seed: the pool's form changes no served token, on the gather
+        path and through the kernel's interpreter."""
+        want = [[74, 52, 52, 27, 85, 1, 1, 74, 82, 52],
+                [17, 58, 48, 51, 17, 76, 74, 48, 58, 58],
+                [74, 74, 51, 91, 82, 52, 85, 85, 85, 2]]
+        for kern in ("xla", "pallas"):
+            eng = GenerationEngine(_build_model(), max_batch_size=2,
+                                   buckets=(8, 16), rng_seed=5,
+                                   block_size=4, paged_kernel=kern)
+            rng = np.random.default_rng(3)
+            got = []
+            for n, kw in ((5, dict(seed=1)),
+                          (11, dict(seed=2, temperature=0.9, top_k=20)),
+                          (13, dict(seed=3))):
+                prompt = [int(x) for x in rng.integers(1, VOCAB, n)]
+                got.append(_run_one(eng, prompt, 10, **kw))
+            assert got == want, kern
+            eng.pool.audit()
+
+    def test_engine_pools_are_merged_and_the_gauge_says_row_major(self):
+        from paddle_tpu.profiler import explainer
+
+        eng = GenerationEngine(_build_model(), max_batch_size=2,
+                               buckets=(8,), block_size=4)
+        H, Dh = 2, 24
+        assert all(a.shape == (eng.pool.num_blocks, 4, H * Dh)
+                   for a in eng._k + eng._v)
+        assert eng.stats()["kv_pool_row_major"] == 1
+        assert registry.gauges()["serving.kv_pool_row_major"] == 1
+        ev = explainer.events(kind="kv_pool_layout")[-1]
+        assert ev["row_major"] and "(0, 1, 2)" in ev["why"]
+
+    def test_gauge_reads_zero_on_another_layout(self, monkeypatch):
+        from paddle_tpu.ops import kv_pool
+        from paddle_tpu.profiler import explainer
+
+        monkeypatch.setattr(kv_pool, "device_layout",
+                            lambda pool: (1, 2, 0))
+        eng = GenerationEngine(_build_model(), max_batch_size=1,
+                               buckets=(8,), block_size=4)
+        assert eng.stats()["kv_pool_row_major"] == 0
+        assert registry.gauges()["serving.kv_pool_row_major"] == 0
+        ev = explainer.events(kind="kv_pool_layout")[-1]
+        assert not ev["row_major"] and "(1, 2, 0)" in ev["why"]
+
+    def test_handoff_payload_keeps_its_4d_blocks(self):
+        """Export -> import between two engines: the wire's block arrays
+        stay [n, block_size, H, Dh] whatever the pool's device form, the
+        adopted blocks are bit-equal, and decoding goes on bitwise."""
+        prompt = [3, 5, 7, 9, 11, 2]
+        mono = GenerationEngine(_build_model(), max_batch_size=2,
+                                buckets=(8,), block_size=4, rng_seed=7)
+        want = _run_one(mono, prompt, 6, seed=4, temperature=0.8)
+        a = GenerationEngine(_build_model(), max_batch_size=2,
+                             buckets=(8,), block_size=4, rng_seed=7)
+        b = GenerationEngine(_build_model(), max_batch_size=2,
+                             buckets=(8,), block_size=4, rng_seed=99)
+        a.prefill(0, prompt, seed=4, temperature=0.8, max_new_tokens=6)
+        payload = a.export_request_kv(0)
+        n = payload["n_blocks"]
+        assert len(payload["kv_k"]) == 2  # layers
+        for blocks in payload["kv_k"] + payload["kv_v"]:
+            assert isinstance(blocks, np.ndarray)
+            assert blocks.shape == (n, 4, 2, 24)
+        got = [b.import_request_kv(1, payload, prompt_ids=prompt)]
+        again = b.export_request_kv(1)
+        for field in ("kv_k", "kv_v"):
+            for x, y in zip(payload[field], again[field]):
+                np.testing.assert_array_equal(x, y)
+        for _ in range(5):
+            got.append(int(b.decode_step()[1]))
+        assert got == want
+        # a block of another geometry is refused by name
+        payload["kv_k"][0] = payload["kv_k"][0].reshape(n, 4, 4, 12)
+        b.release(1)
+        with pytest.raises(ValueError, match="block shape"):
+            b.import_request_kv(1, payload)
+        b.pool.audit()
 
 
 class TestPagedSchedulingEdges:

@@ -155,10 +155,11 @@ def lint(desc, min_bytes=MIN_SHARDABLE_BYTES):
 def lint_engine(desc, min_bytes=MIN_SHARDABLE_BYTES):
     """Problem strings for a serving engine's ``describe_sharding()``
     dict (ISSUE 16): a mesh engine whose per-layer KV pool is replicated
-    while its HEAD dim (pools are [num_blocks, block_size, H, Dh];
-    serving shards whole heads, never blocks or head_dim) divides the
-    'mp' axis left the exact demotion this PR removed on the table —
-    every decode step gathers the full pool on every shard."""
+    while its HEADS (pools are [num_blocks, block_size, H*Dh], the
+    record's "heads" is H; serving shards whole heads, never blocks or
+    head_dim) divide the 'mp' axis left the exact demotion this PR
+    removed on the table — every decode step gathers the full pool on
+    every shard."""
     axes = _mesh_axes(desc)
     mp = axes.get("mp", 0)
     problems = []
@@ -171,13 +172,14 @@ def lint_engine(desc, min_bytes=MIN_SHARDABLE_BYTES):
         shape = pool.get("shape", ())
         tag = (f"kv pool layer {pool.get('layer')} "
                f"({pool.get('pool')}) {shape}/{pool.get('dtype')}")
-        if len(shape) == 4 and shape[2] and shape[2] % mp == 0 \
+        heads = pool.get("heads")
+        if heads and heads % mp == 0 \
                 and _is_replicated(spec) \
                 and pool.get("bytes", 0) >= min_bytes:
             problems.append(
-                f"{tag}: replicated on an mp={mp} mesh but its head dim "
-                f"({shape[2]}) divides mp — head-shard it "
-                f"(P(None, None, 'mp', None)) so each shard holds "
+                f"{tag}: replicated on an mp={mp} mesh but its {heads} "
+                f"heads divide mp — head-shard it "
+                f"(P(None, None, 'mp')) so each shard holds "
                 f"H/mp heads and the per-shard kernel route applies")
     return problems
 
