@@ -21,8 +21,9 @@ or is cut by its end counts for its part (a decode step of 470 ms, as PR 25
 found, finishes one or two requests in 30 s, and a tail over those alone
 would be no tail). Requests in flight at the close are then cancelled.
 
-Correctness is decided against reference/gpt.py (plain float32 forward,
-no cache): for some greedy requests of the window, each served token at a
+Correctness is decided against the family's plain reference
+(families/<family>.py: reference/gpt.py for `gpt`, a float32 forward with no
+cache): for some greedy requests of the window, each served token at a
 sample of positions must be the reference's argmax given the served
 prefix, or lie within `near_tie` of its top logit (random weights give
 near-uniform logits, and bf16 arithmetic legitimately decides such a tie).
@@ -37,9 +38,8 @@ import time
 
 import numpy as np
 
-import model as bench_model
+import families
 import stats
-from reference import gpt as reference
 
 
 def size_table(tr):
@@ -99,18 +99,17 @@ class Requests:
 
 
 def setup(run):
-    import jax
-    import jax.numpy as jnp
-
     from paddle_tpu.serving import GenerationEngine, GenerationServer
 
     tr, wl, say = run["traffic"], run["wl"], run["say"]
+    fam = families.of(run["cfg"])
     forbidden = sorted(k for k in os.environ if k.startswith("PADDLE_TPU_"))
     if forbidden:
         raise SystemExit(f"serve_closed_loop: unset {forbidden}: the cell "
                          "runs the package's defaults")
     t0 = time.perf_counter()
-    cfg, model = bench_model.build(run["cfg"], run["seed"])
+    cfg, model = fam.build(run["cfg"], run["seed"])
+    vocab = fam.vocab_size(run["cfg"])
     model.eval()
     eng_cfg = wl["engine"]
     clients = int(tr["clients"])
@@ -138,7 +137,7 @@ def setup(run):
     if eng.paged_kernel != want:
         raise SystemExit(f"serve_closed_loop: paged kernel resolved to "
                          f"{eng.paged_kernel!r}, expected {want!r}")
-    reqs = Requests(tr, cfg.vocab_size, run["seed"])
+    reqs = Requests(tr, vocab, run["seed"])
     t1 = time.perf_counter()
 
     # warm every executable the traffic uses: one request per bucket (both
@@ -147,7 +146,7 @@ def setup(run):
     for j, b in enumerate(eng.buckets):
         n = min(b, tr["prompt_len"][1])
         prompt = np.random.default_rng([run["seed"], 2, j]).integers(
-            1, cfg.vocab_size, n).tolist()
+            1, vocab, n).tolist()
         warm.append(server.submit(prompt, **reqs.options(
             2 * 10 ** 6 + j, 4, greedy=bool(j % 2))))
     for h in warm:
@@ -158,16 +157,13 @@ def setup(run):
 
     # the reference's executable, warmed on a dummy so the check after the
     # window only runs it
-    weights = {n: t._data for n, t in model.gpt.state_dict().items()}
-    L, K = int(tr["check"]["padded_len"]), int(tr["check"]["positions"])
-    score = jax.jit(lambda w, ids, at: reference.forward(
-        w, cfg.n_layer, cfg.n_head, ids, at))
-    score(weights, jnp.zeros((L,), jnp.int32),
-          jnp.zeros((K,), jnp.int32)).block_until_ready()
+    score = fam.reference_scorer(
+        run["cfg"], cfg, model, int(tr["check"]["padded_len"]),
+        int(tr["check"]["positions"]))
     t3 = time.perf_counter()
 
-    state = {"server": server, "eng": eng, "cfg": cfg, "reqs": reqs,
-             "score": score, "weights": weights, "records": [],
+    state = {"server": server, "eng": eng, "vocab": vocab, "reqs": reqs,
+             "score": score, "records": [],
              "refused": [], "stop": threading.Event(), "threads": [],
              "clients": clients, "live": [None] * clients}
     ramp_done = [threading.Event() for _ in range(clients)]
@@ -284,7 +280,7 @@ def check(run, state, samples):
     import jax.numpy as jnp
 
     say, tr = run["say"], run["traffic"]
-    server, eng, cfg = state["server"], state["eng"], state["cfg"]
+    server, eng, vocab = state["server"], state["eng"], state["vocab"]
     # cancel what is in flight, stop the clients, then audit the pool
     server.shutdown(drain=False, timeout=120)
     for th in state["threads"]:
@@ -312,7 +308,7 @@ def check(run, state, samples):
         _, _, opts, h = p["rec"]
         n, want = len(h.tokens), opts["max_new_tokens"]
         return (n != want if p["ended"] else n > want) \
-            or not all(0 <= t < cfg.vocab_size for t in h.tokens)
+            or not all(0 <= t < vocab for t in h.tokens)
 
     bad = [(p["rec"][3].status, len(p["rec"][3].tokens)) for p in parts
            if wrong(p)]
@@ -345,8 +341,8 @@ def check(run, state, samples):
         idx = np.unique(np.linspace(0, len(toks) - 1, K).astype(int))
         at = np.full(K, len(prompt) - 1, np.int32)
         at[:len(idx)] += idx
-        lg = np.asarray(state["score"](state["weights"], jnp.asarray(ids),
-                                       jnp.asarray(at)), np.float32)
+        lg = np.asarray(state["score"](jnp.asarray(ids), jnp.asarray(at)),
+                        np.float32)
         for k, i in enumerate(idx):
             gap = float(lg[k].max() - lg[k, toks[i]])
             worst = max(worst, gap)
@@ -370,4 +366,13 @@ def check(run, state, samples):
         f"{ms(samples['tpot_s'], 50)} p95 {ms(samples['tpot_s'], 95)}; ttft "
         f"ms over {len(samples['ttft_s'])} first tokens p50 "
         f"{ms(samples['ttft_s'], 50)} p95 {ms(samples['ttft_s'], 95)}")
+    run["compared"] = {
+        "worst_logit_gap": [worst, tie],
+        "greedy_requests_compared_at_least": [len(greedy), 1],
+        "requests_failed": [int(samples["failed"]), 0],
+        "requests_wrong_length_or_ids": [len(bad), 0],
+        "client_threads_alive": [len(alive), 0],
+        "pool_audit_violated": [int(isinstance(audit, str)), 0],
+        "compiles_in_window": [run["compiles_in_window"], 0],
+        "kernel_fallbacks": [c.get("serving.kernel.fallbacks") or 0, 0]}
     return ok and ref_ok

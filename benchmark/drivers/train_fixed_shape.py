@@ -14,8 +14,8 @@ import math
 import os
 import time
 
+import families
 import flops
-import model as bench_model
 
 
 def setup(run):
@@ -23,21 +23,22 @@ def setup(run):
     import jax.numpy as jnp
 
     import paddle_tpu as paddle
-    from paddle_tpu.models import GPTPretrainingCriterion
 
     tr, say = run["traffic"], run["say"]
+    fam = families.of(run["cfg"])
     forbidden = sorted(k for k in os.environ if k.startswith("PADDLE_TPU_"))
     if forbidden:
         raise SystemExit(f"train_fixed_shape: unset {forbidden}: the cell "
                          "runs the package's defaults")
     B, T = int(tr["batch"]), int(tr["seq_len"])
     t0 = time.perf_counter()
-    cfg, model = bench_model.build(run["cfg"], run["seed"])
+    cfg, model = fam.build(run["cfg"], run["seed"])
+    vocab = fam.vocab_size(run["cfg"])
     if T != cfg.seq_len:
         raise SystemExit(f"traffic seq_len {T} != model seq_len "
                          f"{cfg.seq_len}")
     opt_cfg = run["cfg"]["train"]
-    crit = GPTPretrainingCriterion()
+    crit = fam.criterion()
     opt = paddle.optimizer.AdamW(
         learning_rate=float(opt_cfg["lr"]),
         multi_precision=bool(opt_cfg["multi_precision"]),
@@ -57,7 +58,7 @@ def setup(run):
 
     @jax.jit
     def draw(key):
-        toks = jax.random.randint(key, (R, B, T), 0, cfg.vocab_size,
+        toks = jax.random.randint(key, (R, B, T), 0, vocab,
                                   dtype=jnp.int64)
         return toks, jnp.roll(toks, -1, axis=-1)
 
@@ -142,15 +143,23 @@ def check(run, state, samples):
     if not compiled_ok:
         say(f"{run['compiles_in_window']} compiles inside the window")
     tps = samples["tokens"] / samples["window_s"] / int(run["wl"]["chips"])
-    fpt = flops.gpt_train_flops_per_token(bench_model.sizes(run["cfg"]))
+    fpt = families.of(run["cfg"]).train_flops_per_token(run)
     disp = sorted(samples["dispatch_s"])
     line = (f"{samples['steps']} steps in {samples['window_s']:.3f} s: "
             f"{tps:.1f} tokens/s/chip, step "
             f"{1e3 * samples['window_s'] / samples['steps']:.2f} ms, "
-            f"dispatch median {1e3 * disp[len(disp) // 2]:.3f} ms; "
-            f"{fpt:.4e} FLOPs/token")
-    if run["on_tpu"]:
-        pk = flops.peak(run["peaks"], run["device_kind"], "bf16_flops_per_s")
-        line += f", MFU {100 * tps * fpt / pk:.2f} % of {pk:.3g}"
+            f"dispatch median {1e3 * disp[len(disp) // 2]:.3f} ms")
+    if fpt is not None:  # a family with no count says nothing of FLOPs
+        line += f"; {fpt:.4e} FLOPs/token"
+        if run["on_tpu"]:
+            pk = flops.peak(run["peaks"], run["device_kind"],
+                            "bf16_flops_per_s")
+            line += f", MFU {100 * tps * fpt / pk:.2f} % of {pk:.3g}"
     say(line)
+    run["compared"] = {
+        "final_loss_below_first": [final, first],
+        "flash_fallbacks": [facts["flash.fallbacks"], 0],
+        "flash_calls_off_the_expected_route":
+            [facts["flash.xla" if run["on_tpu"] else "flash.pallas"], 0],
+        "compiles_in_window": [run["compiles_in_window"], 0]}
     return ok and kernel_ok and compiled_ok

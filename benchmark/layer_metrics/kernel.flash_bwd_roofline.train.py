@@ -1,9 +1,9 @@
 """Flash-attention backward's share of its roofline: the least time one
-layer's backward can take (kernel_counts.flash_bwd_flops / flash_bwd_bytes)
+layer's backward can take (the family's kernel_work(run, "flash_bwd"))
 over the mean device time of one `flash_bwd_dkv` plus one `flash_bwd_dq`
 event inside complete `train_step` events (scope_reduce.py)."""
+import families
 import kernel_counts as kc
-import model as bench_model
 import scope_reduce
 
 META = {"name": "kernel.flash_bwd_roofline.train", "layer": "kernels",
@@ -14,15 +14,13 @@ META = {"name": "kernel.flash_bwd_roofline.train", "layer": "kernels",
 def read(run):
     got = scope_reduce.per_event(run, "kernels", "flash_bwd_dkv",
                                  "flash_bwd_dq")
-    if got is None:
+    work = families.of(run["cfg"]).kernel_work(run, "flash_bwd")
+    if got is None or work is None:
         return None
     seconds, n = got
     per_layer = seconds / (n / 2)  # one event of each a layer
-    sizes = bench_model.sizes(run["cfg"])
-    B = int(run["traffic"]["batch"]) // int(run["wl"]["chips"])
     least, bound = kc.least_seconds(
-        kc.flash_bwd_flops(sizes, B), kc.flash_bwd_bytes(sizes, B),
-        run["peaks"]["devices"][run["device_kind"]])
+        *work, run["peaks"]["devices"][run["device_kind"]])
     run["say"](f"flash_bwd: least {1e3 * least:.4f} ms a layer (bound: "
                f"{bound}), measured {1e3 * per_layer:.4f} ms (dkv + dq)")
     return 100.0 * least / per_layer

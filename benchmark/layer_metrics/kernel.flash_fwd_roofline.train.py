@@ -1,9 +1,9 @@
 """Flash-attention forward's share of its roofline: the least time one
-layer's call can take (kernel_counts.flash_fwd_flops / flash_fwd_bytes, the
+layer's call can take (the family's kernel_work(run, "flash_fwd"), the
 larger of the two bounds) over the mean device time of a `flash_fwd` event
 inside complete `train_step` events (scope_reduce.py)."""
+import families
 import kernel_counts as kc
-import model as bench_model
 import scope_reduce
 
 META = {"name": "kernel.flash_fwd_roofline.train", "layer": "kernels",
@@ -13,14 +13,12 @@ META = {"name": "kernel.flash_fwd_roofline.train", "layer": "kernels",
 
 def read(run):
     got = scope_reduce.per_event(run, "kernels", "flash_fwd")
-    if got is None:
+    work = families.of(run["cfg"]).kernel_work(run, "flash_fwd")
+    if got is None or work is None:
         return None
     seconds, n = got
-    sizes = bench_model.sizes(run["cfg"])
-    B = int(run["traffic"]["batch"]) // int(run["wl"]["chips"])
     least, bound = kc.least_seconds(
-        kc.flash_fwd_flops(sizes, B), kc.flash_fwd_bytes(sizes, B),
-        run["peaks"]["devices"][run["device_kind"]])
+        *work, run["peaks"]["devices"][run["device_kind"]])
     run["say"](f"flash_fwd: least {1e3 * least:.4f} ms a layer (bound: "
                f"{bound}), measured {1e3 * seconds / n:.4f} ms")
     return 100.0 * least / (seconds / n)

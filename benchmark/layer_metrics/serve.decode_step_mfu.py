@@ -1,11 +1,10 @@
 """Model FLOPs of a mean decode step of the window
-(kernel_counts.decode_step_flops: active slots and KV rows from the deltas
-of serving.active_slot_steps, kv_tokens_read, decode_steps) over the mean
+(the family's decode_step_work: for `gpt` active slots and KV rows from the
+deltas of serving.active_slot_steps, kv_tokens_read, decode_steps) over the mean
 device time of a complete `serving_decode` module event (scope_reduce.py)
 over the chip's bf16 peak."""
+import families
 import flops
-import kernel_counts as kc
-import model as bench_model
 import scope_reduce
 
 META = {"name": "serve.decode_step_mfu", "layer": "device", "unit": "%",
@@ -15,10 +14,10 @@ META = {"name": "serve.decode_step_mfu", "layer": "device", "unit": "%",
 
 def read(run):
     got = scope_reduce.per_event(run, "modules", "serving_decode")
-    means = kc.decode_step_means(run["counters"])
-    if got is None or means is None:
+    work = families.of(run["cfg"]).decode_step_work(run)
+    if got is None or work is None:
         return None
     seconds, n = got
-    fl = kc.decode_step_flops(bench_model.sizes(run["cfg"]), *means)
+    fl = work[0]
     pk = flops.peak(run["peaks"], run["device_kind"], "bf16_flops_per_s")
     return 100.0 * fl / (seconds / n) / pk

@@ -14,15 +14,21 @@ file found by name:
   configs/<config>.json      the model's sizes as run, its source
   traffic/<traffic>.json     driver + the parameters its generator reads
   drivers/<driver>.py        setup(run) -> state; window(run, state, seconds)
-                             -> samples; check(run, state, samples) -> bool
+                             -> samples; check(run, state, samples) -> bool,
+                             leaving in run["compared"] each number it
+                             compared: {name: [number, limit]}
   e2e_metrics/<name>.py      META + read(run): reported with --trace 0
   layer_metrics/<name>.py    META + read(run): reported with --trace 1
   rehearse/                  tiny cells marked "rehearsal": true — the only
                              ones allowed on a platform other than a TPU
 
 A metric file applies to a cell when META["drivers"] holds the cell's
-driver; a reader that finds nothing to read returns None and the metric is
-left out of the line.
+driver, or the driver that the cell's driver declares it samples as
+(SAMPLES_AS = "<driver>": the promise that its setup/window/check fill state,
+samples and the counters with the keys that driver's do); a reader that
+finds nothing to read returns None and the metric is left out of the line.
+What is specific to a model family, drivers and readers ask of
+families/<family>.py (families/__init__.py).
 """
 from __future__ import annotations
 
@@ -33,6 +39,7 @@ T_START = time.perf_counter()  # set-up is timed from here
 import argparse  # noqa: E402
 import importlib.util  # noqa: E402
 import json  # noqa: E402
+import math  # noqa: E402
 import os  # noqa: E402
 import shutil  # noqa: E402
 import sys  # noqa: E402
@@ -68,13 +75,21 @@ def find_workload(name):
                      f"rehearse/{name}.json")
 
 
-def metric_files(kind, driver):
-    """The readers in <kind>/ that apply to this driver, by file name."""
+def metric_files(kind, driver_name, driver):
+    """The readers in <kind>/ that apply to this driver, by file name: those
+    that name it, and those that name the driver it samples as."""
+    names = {driver_name}
+    as_ = getattr(driver, "SAMPLES_AS", None)
+    if as_ is not None:
+        if not os.path.exists(os.path.join(HERE, "drivers", f"{as_}.py")):
+            raise SystemExit(f"run.py: drivers/{driver_name}.py: SAMPLES_AS "
+                             f"= {as_!r}, and there is no drivers/{as_}.py")
+        names.add(as_)
     out = []
     for fn in sorted(os.listdir(os.path.join(HERE, kind))):
         if fn.endswith(".py"):
             mod = load_module(kind, fn)
-            if driver in mod.META["drivers"]:
+            if names & set(mod.META["drivers"]):
                 out.append(mod)
     return out
 
@@ -182,6 +197,10 @@ def main(argv=None):
         f"cache {cache_dir}")
 
     driver = load_module("drivers", driver_name + ".py")
+    # found now, so that a reader or a SAMPLES_AS that is not there stops
+    # the run before its set-up and not after its window
+    readers = metric_files("layer_metrics" if args.trace else "e2e_metrics",
+                           driver_name, driver)
     tracer = Tracer(args.trace, args.seconds,
                     float(traffic.get("trace_seconds", 3.0)),
                     os.path.join(ROOT, ".bench_trace"))
@@ -245,16 +264,25 @@ def main(argv=None):
                 f"{100 * (1 - red['busy_s'] / red['window_s']):.2f} %")
 
     metrics = {}
-    for mod in metric_files("layer_metrics" if args.trace
-                            else "e2e_metrics", driver_name):
+    for mod in readers:
         v = mod.read(run)
         if v is not None:
             metrics[mod.META["name"]] = {"value": float(v),
                                          "unit": mod.META["unit"]}
     out["metrics"] = metrics
     out["device"] = device
+    # each number compared beside its limit: the last key of the line and
+    # the last lines of standard error, which is what is kept of a run
+    # that is not correct
+    out["compared"] = {
+        k: [x if math.isfinite(x) else str(x) for x in pair]  # JSON has no NaN
+        for k, pair in run.get("compared", {}).items()}
     if not correct:
         say("NOT CORRECT — see the lines above")
+    for name, (value, limit) in out["compared"].items():
+        print(f"[bench] compared {name}: {value} (limit {limit})",
+              file=sys.stderr)
+    sys.stderr.flush()
     print(json.dumps(out), flush=True)
     return 0
 

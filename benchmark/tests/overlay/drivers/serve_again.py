@@ -1,0 +1,9 @@
+"""Driver `serve_again`, for benchmark/tests/test_families.py alone: a second
+driver added as a file. It drives drivers/serve_closed_loop.py's own
+functions, so it keeps that driver's promise and says so."""
+import wrap_driver
+
+SAMPLES_AS = "serve_closed_loop"
+
+_inner = wrap_driver.load(SAMPLES_AS)
+setup, window, check = _inner.setup, _inner.window, _inner.check
