@@ -1,5 +1,7 @@
-"""Flagship model zoo (BASELINE configs): GPT / BERT / ERNIE."""
-from . import bert, ernie, gpt  # noqa: F401
+"""Flagship model zoo (BASELINE configs): GPT / BERT / ERNIE, and the
+Xing4.0 decoder (MLA + dropless experts + hyper-connections) of the served
+path."""
+from . import bert, ernie, gpt, xing4  # noqa: F401
 from .bert import (BertConfig, BertForPretraining,  # noqa: F401
                    BertForSequenceClassification, BertModel,
                    BertPretrainingCriterion, bert_base, bert_tiny)
@@ -9,3 +11,4 @@ from .ernie import (ErnieConfig, ErnieForSequenceClassification,  # noqa: F401
 from .gpt import (GPTConfig, GPTForPretraining, GPTModel,  # noqa: F401
                   GPTPretrainingCriterion, gpt2_small, gpt3_1p3b, gpt3_6p7b,
                   gpt_tiny, gpt_tiny_moe)
+from .xing4 import Xing4Config, Xing4Model  # noqa: F401

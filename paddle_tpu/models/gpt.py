@@ -339,6 +339,12 @@ class GPTEmbeddings(nn.Layer):
                             self.position_embeddings(position_ids))
 
 
+def _tied_logits(hidden, w):
+    import jax.numpy as jnp
+
+    return hidden.astype(jnp.float32) @ w.T.astype(jnp.float32)
+
+
 class GPTModel(nn.Layer):
     """Reference auto_parallel_gpt_model.py GPTModel equivalent."""
 
@@ -370,6 +376,22 @@ class GPTModel(nn.Layer):
         for blk in self.blocks:
             x = blk(x)
         return self.ln_f(x)
+
+    # -- what serving.GenerationEngine asks of a decoder -------------------
+    @property
+    def max_positions(self):
+        return self.cfg.seq_len  # the learned position table
+
+    def kv_cache_spec(self):
+        """A K and a V row of n_head x head_dim a token a layer."""
+        from ..ops.kv_pool import CacheSpec
+
+        return CacheSpec("heads", [(blk.attn.n_head, blk.attn.head_dim)
+                                   for blk in self.blocks])
+
+    def serving_head(self):
+        """The tied head: the token embedding, and its logits in float32."""
+        return self.embeddings.word_embeddings.weight, _tied_logits
 
     def moe_aux_loss(self):
         """Weighted sum of every MoE block's load-balancing loss from

@@ -1,0 +1,177 @@
+"""Dropless expert layer for the served path: sigmoid router with a
+selection bias (DeepSeek-V3, arXiv:2412.19437 section 2.1.2), top-k without
+capacity, SwiGLU experts computed as grouped matmuls over rows sorted by
+expert, a shared expert every token passes through.
+
+`MoEMLP` (layer.py) pads every expert to a capacity and drops what does not
+fit — the price of its dense ``[G, S, E, C]`` masks. Here the shapes stay
+fixed another way: the ``N * k`` routed rows are sorted by expert and the
+group sizes are DATA (`lax.ragged_dot`; on a TPU XLA lowers it to a grouped
+matmul that walks the groups, on the CPU to its reference form), so no token
+is dropped however uneven the routing, one executable serves ``[32, 1]``
+decode and ``[1, 2048]`` prefill, and an expert nobody chose costs no weight
+read.
+
+The layer is TOLD which experts it holds: ``experts_held=(lo, hi)`` keeps
+the weights of experts ``lo..hi-1`` only, the router still scores all
+``num_experts``, and the result is those experts' part plus the shared
+expert — what one chip of an expert-parallel deployment computes before the
+exchange. On one chip that holds every expert this is the whole layer;
+nothing here stands in for absent chips.
+
+The arithmetic is written on the parameters' arrays (`Tensor._data`), so
+the autograd tape does not see it: this is the served path; training
+through it is open (ROADMAP).
+"""
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+
+from ...core.tensor import Tensor
+from ...ops.pallas_ops import _dot_precision
+from ...profiler import spans as _spans
+from ..initializer import Normal
+from ..layer.layers import Layer
+
+__all__ = ["DroplessMoE", "route_sigmoid_topk", "swiglu"]
+
+
+def swiglu(x, w_gate, w_up, w_down):
+    """``W_down(silu(W_gate x) * W_up x)`` with float32 accumulation."""
+    f32 = jnp.float32
+    g = jnp.dot(x, w_gate, preferred_element_type=f32)
+    u = jnp.dot(x, w_up, preferred_element_type=f32)
+    return jnp.dot((jax.nn.silu(g) * u).astype(x.dtype), w_down,
+                   preferred_element_type=f32)
+
+
+def route_sigmoid_topk(x, w_router, b_select, top_k, scaling, norm=True):
+    """(chosen [N, k] int32, weights [N, k] float32): float32 sigmoid
+    scores, the bias added for CHOOSING only, the chosen scores normalised
+    to sum 1 (``norm``) and multiplied by ``scaling``."""
+    s = jax.nn.sigmoid(jnp.dot(x.astype(jnp.float32),
+                               w_router.astype(jnp.float32)))
+    _, chosen = jax.lax.top_k(s + b_select.astype(jnp.float32), top_k)
+    picked = jnp.take_along_axis(s, chosen, axis=-1)
+    if norm:
+        picked = picked / (picked.sum(-1, keepdims=True) + 1e-20)
+    return chosen.astype(jnp.int32), picked * jnp.float32(scaling)
+
+
+class DroplessMoE(Layer):
+    """forward(x [B, T, d], valid=None) -> y [B, T, d] float32: the held
+    routed experts' part of the layer plus the shared expert. The router
+    scores ``x`` in the precision it comes in (hand it the float32 normed
+    input: a choice between near-tied experts is discontinuous, and a bf16
+    rounding of the input flips it); the experts read it in their weights'
+    dtype. ``valid``
+    [B, T] bool marks the rows that are tokens (bucket padding is routed
+    nowhere and costs no expert row). After a forward
+    ``last_experts_hit`` holds the number of held experts that were given
+    at least one row (an int32 scalar of that trace)."""
+
+    def __init__(self, d_model, d_ff, num_experts, top_k, n_shared=1,
+                 routed_scaling_factor=1.0, norm_topk_prob=True,
+                 experts_held=None, init_std=0.02, bias_std=0.02,
+                 dtype=None):
+        super().__init__()
+        lo, hi = experts_held or (0, num_experts)
+        if not 0 <= lo < hi <= num_experts:
+            raise ValueError(f"experts_held {experts_held!r} is not a "
+                             f"range of the {num_experts} experts")
+        if not 1 <= top_k <= num_experts:
+            raise ValueError(f"top_k {top_k} of {num_experts} experts")
+        self.num_experts, self.top_k = int(num_experts), int(top_k)
+        self.experts_held = (int(lo), int(hi))
+        self.scaling = float(routed_scaling_factor)
+        self.norm_topk_prob = bool(norm_topk_prob)
+        self.d_ff = int(d_ff)
+        held = hi - lo
+        init = Normal(0.0, init_std)
+
+        def param(shape, initializer=init, dt=dtype):
+            return self.create_parameter(list(shape), dtype=dt,
+                                         default_initializer=initializer)
+
+        # the router and its selection bias stay float32: scores decide
+        # discontinuously, and the two are 0.23 M numbers. A seeded bias
+        # of 0.02 is the spacing of the scores around the k-th of 64: it
+        # changes some choices and leaves the experts' load even (at 0.1
+        # a few experts take most rows: 60 % of 64 hit by 128 rows on the
+        # chip, not the 87 % of an even load)
+        self.router = Layer()
+        self.router.weight = param((d_model, num_experts), dt="float32")
+        self.router.bias = param((num_experts,), Normal(0.0, bias_std),
+                                 dt="float32")
+        # the held experts, stacked: gate and up side by side, so a row
+        # meets its expert's first matmul once
+        self.experts = Layer()
+        self.experts.gate_up = param((held, d_model, 2 * d_ff))
+        self.experts.down = param((held, d_ff, d_model))
+        self.shared = None
+        if n_shared:
+            self.shared = Layer()
+            for name, shape in (("gate_proj", (d_model, n_shared * d_ff)),
+                                ("up_proj", (d_model, n_shared * d_ff)),
+                                ("down_proj", (n_shared * d_ff, d_model))):
+                lin = Layer()
+                lin.weight = param(shape)
+                setattr(self.shared, name, lin)
+        self.last_experts_hit = None
+
+    def forward(self, x, valid=None):
+        xa = x._data if isinstance(x, Tensor) else x
+        B, T, d = xa.shape
+        y, self.last_experts_hit = self._compute(
+            xa.reshape(B * T, d),
+            None if valid is None else
+            (valid._data if isinstance(valid, Tensor) else valid
+             ).reshape(B * T))
+        return Tensor(y.reshape(B, T, d))
+
+    def _compute(self, x, valid):
+        N, d = x.shape
+        k, F = self.top_k, self.d_ff
+        lo, hi = self.experts_held
+        held = hi - lo
+        with _spans.scope("moe_router"):
+            routed_from, x = x, x.astype(self.experts.gate_up._data.dtype)
+            chosen, weight = route_sigmoid_topk(
+                routed_from, self.router.weight._data,
+                self.router.bias._data, k,
+                self.scaling, self.norm_topk_prob)
+            mine = (chosen >= lo) & (chosen < hi)
+            if valid is not None:
+                mine = mine & valid[:, None]
+            # rows of experts held elsewhere (and padding) sort behind the
+            # last group: no group owns them, no expert computes them
+            group = jnp.where(mine, chosen - lo, held).reshape(N * k)
+            order = jnp.argsort(group, stable=True).astype(jnp.int32)
+            sizes = jnp.bincount(group, length=held + 1)[:held].astype(
+                jnp.int32)
+            back = jnp.zeros((N * k,), jnp.int32).at[order].set(
+                jnp.arange(N * k, dtype=jnp.int32))
+        with _spans.scope("moe_experts"):
+            rows = x[order // k]  # [N*k, d], sorted by expert
+            # the precision is named: XLA:TPU's grouped matmul has no
+            # float32-contract form for bf16 operands, which the package's
+            # global "highest" would ask of it
+            grouped = functools.partial(
+                jax.lax.ragged_dot, group_sizes=sizes,
+                precision=_dot_precision(x.dtype),
+                preferred_element_type=jnp.float32)
+            h = grouped(rows, self.experts.gate_up._data)
+            h = (jax.nn.silu(h[:, :F]) * h[:, F:]).astype(x.dtype)
+            out = grouped(h, self.experts.down._data)
+            # back to token order; a row no held expert owns adds nothing
+            # (whatever the grouped matmul left in it)
+            out = jnp.where(mine.reshape(N * k, 1), out[back], 0.0)
+            y = (out.reshape(N, k, d) * weight[:, :, None]).sum(1)
+            if self.shared is not None:
+                y = y + swiglu(x, self.shared.gate_proj.weight._data,
+                               self.shared.up_proj.weight._data,
+                               self.shared.down_proj.weight._data)
+        return y, (sizes > 0).sum().astype(jnp.int32)

@@ -17,6 +17,15 @@ below compiles to an in-place fusion on the donated buffer. Inside a jitted
 step no 4-D view of a whole pool is ever formed: on the chip that reshape
 is a relayout, not a bitcast. The host side (handoff payloads, tests)
 reshapes freely.
+
+A decoder says what its layers cache through a :class:`CacheSpec`
+(``decoder.kv_cache_spec()``). ``"heads"`` is the above. ``"latent"`` (MLA)
+is ONE pool a layer, ``[num_blocks, block_size, W]``: a token's row is its
+normalised latent ``c_kv`` (``rank`` lanes) followed by the rotated key all
+heads share (``rope_dim`` lanes), zero-padded to ``W``, the next multiple
+of 128 lanes, so that the row is whole lane tiles (512 + 64 -> 640: stored
+row-major on a v5e like the merged form, AOT, PERF.md) and the decode kernel
+scores a block with one dot over the row.
 """
 from __future__ import annotations
 
@@ -24,6 +33,66 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec
+
+
+_LANES = 128
+
+
+class CacheSpec:
+    """What a decoder's layers keep a token: ``kind`` "heads" with
+    ``layers`` = [(n_head, head_dim), ...] (a K and a V pool a layer), or
+    "latent" with ``layers`` = [(rank, rope_dim), ...] (one pool a layer)."""
+
+    def __init__(self, kind, layers):
+        if kind not in ("heads", "latent"):
+            raise ValueError(f"unknown cache kind {kind!r}")
+        self.kind = kind
+        self.layers = [tuple(int(x) for x in l) for l in layers]
+
+    def row_width(self, layer=0):
+        """Lanes of one pool's row in this layer."""
+        a, b = self.layers[layer]
+        if self.kind == "heads":
+            return a * b
+        return -(-(a + b) // _LANES) * _LANES
+
+    def heads(self, layer=0):
+        """The heads a pool's row holds (what an 'mp' shard divides): a
+        latent row is shared by every head."""
+        return self.layers[layer][0] if self.kind == "heads" else 1
+
+    def allocate(self, num_blocks, block_size, dtype, mesh=None):
+        """``(first, second)`` pools of every layer: the K and the V pools
+        of a "heads" cache (heads over 'mp' on a mesh they divide); the
+        latent pools and none of a "latent" one (no mesh: the engine
+        refuses one for this kind)."""
+        if self.kind == "latent":
+            return ([jnp.zeros((num_blocks, block_size, self.row_width(i)),
+                               dtype) for i in range(len(self.layers))], [])
+        sharding = None
+        if mesh is not None:
+            mp = int(dict(zip(mesh.axis_names,
+                              mesh.devices.shape)).get("mp", 1))
+            sharding = NamedSharding(mesh, pspec(
+                mp > 1 and all(h % mp == 0 for h, _ in self.layers)))
+
+        def pools():
+            made = [zeros(num_blocks, block_size, h, dh, dtype)
+                    for h, dh in self.layers]
+            if sharding is not None:
+                made = [jax.device_put(p, sharding) for p in made]
+            return made
+
+        return pools(), pools()
+
+    def describe(self):
+        """"heads: K and V rows of 2048" / "latent: one row of 640 (512 +
+        64 rope, padded)" — for stats() and the layout explainer."""
+        a, b = self.layers[0]
+        if self.kind == "heads":
+            return f"heads: a K and a V row of {a} x {b} = {a * b} a layer"
+        return (f"latent: one row of {self.row_width()} a layer ({a} latent "
+                f"+ {b} rotated key, padded to whole lane tiles)")
 
 
 def zeros(num_blocks, block_size, num_heads, head_dim, dtype):
@@ -36,26 +105,6 @@ def pspec(heads_sharded):
     divide, else replicated."""
     return PartitionSpec(None, None, "mp") if heads_sharded \
         else PartitionSpec()
-
-
-def allocate(attn_layers, num_blocks, block_size, dtype, mesh=None):
-    """The K pools and the V pools of a decoder, one of each per attention
-    layer (``n_head`` and ``head_dim`` read off the layer), placed on
-    ``mesh`` by :func:`pspec` when one is given."""
-    sharding = None
-    if mesh is not None:
-        mp = int(dict(zip(mesh.axis_names, mesh.devices.shape)).get("mp", 1))
-        sharding = NamedSharding(mesh, pspec(
-            mp > 1 and all(a.n_head % mp == 0 for a in attn_layers)))
-
-    def pools():
-        made = [zeros(num_blocks, block_size, a.n_head, a.head_dim, dtype)
-                for a in attn_layers]
-        if sharding is not None:
-            made = [jax.device_put(p, sharding) for p in made]
-        return made
-
-    return pools(), pools()
 
 
 def merged(pool):
@@ -105,6 +154,14 @@ def write_span(k_pool, v_pool, k, v, block_tables, offsets, seq_lens):
                          k_pool.shape[1])
     return (write_rows(k_pool, k.reshape(B * T, -1), blk, row),
             write_rows(v_pool, v.reshape(B * T, -1), blk, row))
+
+
+def latent_view(pool, block_tables):
+    """Every slot's logical ``[B, M*bs, W]`` view of a latent pool."""
+    B, M = block_tables.shape
+    blocks = jnp.take(pool, block_tables.reshape(-1).astype(jnp.int32),
+                      axis=0)
+    return blocks.reshape(B, M * pool.shape[1], pool.shape[2])
 
 
 def gather_view(pool, block_tables, num_heads):

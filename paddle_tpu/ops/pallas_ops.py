@@ -914,6 +914,159 @@ def select_paged_kernel(requested=None, *, head_dim, block_size, dtype,
     return kind, reason
 
 
+# ====================== latent (MLA) paged attention =========================
+#
+# Absorbed decode over a pool of one latent row a token (ops/kv_pool.py,
+# kind "latent"): the row is [c_kv | k_rope | 0] on W lanes, every head's
+# query arrives as [q_nope W_uk | q_rope | 0] on the same lanes, so one dot
+# over the row scores a block for ALL heads (an MQA shape: M = heads for the
+# MXU) and p @ rows gives every head's latent output on the first `rank`
+# lanes (the rest is dropped by the caller, who applies W_uv). A latent
+# block is read once for all heads. One query row a slot (T = 1): the
+# engine refuses spec decode for this cache kind.
+#
+# Grid (B, ceil(M / G)): program (b, j) folds G consecutive logical blocks
+# of slot b (G x block_size keys, 128 at block 16) into the slot's online-
+# softmax state; the pool comes in G times, each operand's index map
+# picking one of the program's blocks through the table, so a program is
+# G small DMAs and two dots and the per-program cost is paid once for 128
+# keys. Dead tail blocks clamp to the slot's last live block (no DMA) and
+# the fold is `pl.when`-ed off, as in `paged_attention`.
+
+_MLA_KEYS_PER_PROGRAM = 128
+
+
+def _mla_paged_kernel(bt_ref, sl_ref, q_ref, *refs, scale, block_size,
+                      blocks, precision):
+    k_refs, o_ref = refs[:blocks], refs[blocks]
+    m_scr, l_scr, acc_scr = refs[blocks + 1:]
+    b = pl.program_id(0)
+    j = pl.program_id(1)
+    H = q_ref.shape[0]
+    span = blocks * block_size
+    dot = functools.partial(jnp.dot, preferred_element_type=jnp.float32,
+                            precision=precision)
+    dot_nt = functools.partial(  # q @ rows.T without forming rows.T
+        jax.lax.dot_general, dimension_numbers=(((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32, precision=precision)
+
+    @pl.when(j == 0)
+    def _init():
+        m_scr[...] = jnp.full_like(m_scr, _NEG_INF)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    sl = sl_ref[b]
+
+    @pl.when(j * jnp.int32(span) < sl)
+    def _fold():
+        rows = jnp.concatenate([r[...] for r in k_refs],
+                               axis=0).astype(jnp.float32)  # [span, W]
+        q = q_ref[...].astype(jnp.float32) * _f32(scale)
+        s = dot_nt(q, rows)  # [H, span]: every head against the blocks
+        pos = j * jnp.int32(span) + jax.lax.broadcasted_iota(
+            jnp.int32, (H, span), 1)
+        s = jnp.where(pos < sl, s, _NEG_INF)
+        m_new = jnp.maximum(m_scr[...], s.max(axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        corr = jnp.exp(m_scr[...] - m_new)
+        l_scr[...] = l_scr[...] * corr + p.sum(axis=-1, keepdims=True)
+        acc_scr[...] = acc_scr[...] * corr + dot(p, rows)
+        m_scr[...] = m_new
+
+    @pl.when(j == pl.num_programs(1) - 1)
+    def _finalize():
+        o_ref[...] = (acc_scr[...] / jnp.maximum(l_scr[...], _TINY)
+                      ).astype(o_ref.dtype)
+
+
+def _mla_paged_fused(q, pool, block_tables, seq_lens, scale, interpret):
+    B, H, W = q.shape
+    bs = int(pool.shape[1])
+    M = int(block_tables.shape[1])
+    G = max(1, min(_MLA_KEYS_PER_PROGRAM // bs, M))
+
+    def q_map(b, j, bt, sl):
+        return (b, _i0(), _i0())
+
+    def row_map(g):
+        def index(b, j, bt, sl):
+            last = jnp.maximum(pl.cdiv(sl[b], jnp.int32(bs)) - 1, _i0())
+            return (bt[b, jnp.minimum(j * jnp.int32(G) + jnp.int32(g),
+                                      jnp.minimum(last, jnp.int32(M - 1)))],
+                    _i0(), _i0())
+        return index
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(B, -(-M // G)),
+        in_specs=[pl.BlockSpec((None, H, W), q_map)]
+        + [pl.BlockSpec((None, bs, W), row_map(g)) for g in range(G)],
+        out_specs=pl.BlockSpec((None, H, W), q_map),
+        scratch_shapes=[
+            pltpu.VMEM((H, 1), jnp.float32),  # running max
+            pltpu.VMEM((H, 1), jnp.float32),  # running denom
+            pltpu.VMEM((H, W), jnp.float32),  # fp32 accumulator
+        ],
+    )
+    return pl.pallas_call(
+        functools.partial(_mla_paged_kernel, scale=scale, block_size=bs,
+                          blocks=G, precision=_dot_precision(q.dtype)),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, H, W), q.dtype),
+        interpret=interpret,
+        name=_kernel_name("mla_paged_attention"),
+    )(block_tables.astype(jnp.int32), seq_lens.astype(jnp.int32), q,
+      *([pool] * G))
+
+
+def mla_paged_attention_xla(q, pool, block_tables, seq_lens, scale):
+    """The gather-path reference and the ``kernel="xla"`` route: each
+    slot's logical view of the latent pool, masked to its length."""
+    view = _kv_pool.latent_view(pool, block_tables)  # [B, S, W]
+    s = jnp.einsum("bhw,bsw->bhs", q, view,
+                   preferred_element_type=jnp.float32) * _f32(scale)
+    live = (jnp.arange(view.shape[1], dtype=jnp.int32)[None, None]
+            < seq_lens.astype(jnp.int32)[:, None, None])
+    p = jax.nn.softmax(jnp.where(live, s, _NEG_INF), axis=-1)
+    p = jnp.where(live, p, _f32(0))  # a lane of length 0 reads nothing
+    return jnp.einsum("bhs,bsw->bhw", p.astype(q.dtype), view,
+                      preferred_element_type=jnp.float32).astype(q.dtype)
+
+
+def mla_paged_attention(q, pool, block_tables, seq_lens, scale,
+                        kernel="xla"):
+    """Absorbed MLA decode attention: ``q`` [B, H, W] (one query row a
+    slot, every head on the latent row's lanes) over a latent pool
+    [num_blocks, block_size, W] addressed by ``block_tables`` [B, M];
+    ``seq_lens`` [B] counts each slot's valid rows including the one just
+    written. Returns [B, H, W]: p @ rows, of which the caller keeps the
+    latent lanes. ``kernel`` as in :func:`paged_attention`; resolve it once
+    per engine with :func:`select_mla_paged_kernel`."""
+    if kernel == "xla":
+        return mla_paged_attention_xla(q, pool, block_tables, seq_lens,
+                                       scale)
+    if kernel not in ("pallas", "interpret"):
+        raise ValueError(
+            f"unknown paged-attention kernel {kernel!r} "
+            "(expected pallas | interpret | xla)")
+    return _mla_paged_fused(q, pool, block_tables, seq_lens, float(scale),
+                            interpret=(kernel == "interpret"))
+
+
+def select_mla_paged_kernel(requested=None, *, row_width, block_size,
+                            dtype):
+    """:func:`select_paged_kernel` for the latent family: the same
+    requests, environment variable, counters and loud fallbacks. What is
+    refused on a TPU is a pool dtype without arithmetic in the body and a
+    block over the VMEM limit (a row is whole 128-lane tiles by
+    ``CacheSpec``'s padding). There is no mesh route: the engine refuses a
+    mesh for this cache kind."""
+    return select_paged_kernel(
+        requested, head_dim=row_width, block_size=block_size, dtype=dtype,
+        num_heads=1, family="mla_paged_attention")
+
+
 # =========================== fused softmax mask ==============================
 
 def fused_softmax_mask(x, mask):

@@ -75,7 +75,15 @@ COUNTERS = {
     "serving.kv_tokens_read": "sum of the active slots' lengths at each "
                               "decode step: the KV rows that step's "
                               "attention had to read (plain decode; a "
-                              "speculative round does not count them)",
+                              "speculative round does not count them); "
+                              "a row is whatever the cache keeps a token",
+    "serving.moe_layer_steps": "expert layers x decode steps (host)",
+    "serving.moe_routed_rows": "active slots x experts per token, a "
+                               "layer-step (host)",
+    "serving.moe_experts_hit": "distinct held experts given >= 1 row, "
+                               "summed over the expert layers of each "
+                               "decode step: counted in the executable, "
+                               "read in the transfer that brings the tokens",
 }
 
 # Mosaic kernels (`pl.pallas_call(name=...)`): the custom call's HLO
@@ -85,6 +93,8 @@ KERNELS = {
     "flash_bwd_dq": "flash attention backward -> dQ",
     "flash_bwd_dkv": "flash attention backward -> (dK, dV)",
     "paged_attention": "paged decode/verify attention over the block pool",
+    "mla_paged_attention": "absorbed latent (MLA) decode attention over "
+                           "the latent block pool, all heads a block",
 }
 
 # Jitted steps: the function's name, so the `XLA Modules` event and the
@@ -106,6 +116,14 @@ SCOPES = {
                "logits, and the cross-entropy in training",
     "sampling": "serving/sampling.py: top-k/top-p filter and Gumbel argmax",
     "optimizer": "optimizer.step(): clip, decay and the update rule",
+    "hc_mix": "models/xing4.py: a sublayer's hyper-connection coefficients "
+              "(stream norm, maps, Sinkhorn) and the stream mixing",
+    "mla_absorb": "models/xing4.py: decode's absorbed projections, "
+                  "q_nope W_uk before and o_lat W_uv after the kernel",
+    "moe_router": "nn/moe/dropless.py: sigmoid scores, biased top-k, the "
+                  "sort of the routed rows by expert",
+    "moe_experts": "nn/moe/dropless.py: the grouped matmuls of the held "
+                   "experts and the weighted sum back to tokens",
 }
 
 

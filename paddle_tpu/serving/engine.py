@@ -6,7 +6,9 @@ are evicted and refilled mid-flight without touching their neighbors. The
 cache is vLLM-style paged (Kwon et al., SOSP'23), adapted to XLA's
 static-shape world: per layer ONE fixed-shape block pool
 ``[num_blocks, block_size, heads * head_dim]`` (heads merged: the form a
-TPU stores row by row, ``ops/kv_pool.py``), addressed through per-slot
+TPU stores row by row, ``ops/kv_pool.py``) for K and for V — or, for a
+decoder whose layers cache one latent row a token (MLA), one pool
+``[num_blocks, block_size, W]`` — addressed through per-slot
 int32 block tables — an indirection gather per attention read buys
 (a) per-request memory proportional to ``prompt + max_new_tokens`` instead
 of a full ``max_seq_len`` slab, and (b) prefix sharing: a radix tree over
@@ -45,6 +47,18 @@ slowdown. Host spans (``serving.prefill`` / ``serving.decode_step`` /
 ``serving.decode_sync``, names from ``profiler.spans.SPANS``) and
 ``serving.*`` counters/timings ride the same observability stack as the
 training runtime.
+
+What the engine asks of a decoder (``model`` or ``model.gpt``), and nothing
+else: ``kv_cache_spec()`` — the rows its layers cache a token
+(``ops.kv_pool.CacheSpec``); ``serving_head()`` — the weight the logits
+come from (tied or not) and ``logits(hidden, weight)``; ``max_positions``;
+a ``forward`` that takes the paged-cache arguments and returns the
+final-normed hidden states with the written pools; optionally
+``step_counters()`` — small device-side counts of the last forward (the
+decode step hands them over in the same transfer as the tokens) and
+``host_step_counts(n_active)``. What a cache kind does not support (a
+latent cache: a ``mesh``, the KV handoff, spec decode) raises a
+``TypeError`` naming the feature and the kind.
 
 Slot lifecycle: free → (admission: blocks allocated/shared, suffix
 prefill, first token sampled) → active (each decode step appends one row
@@ -85,7 +99,8 @@ _counters = _registry.scoped_counters("serving", {
     "prefix_inserted_blocks": 0, "prefix_evicted_blocks": 0,
     "kv_blocks_hwm": 0, "handoff_exports": 0, "handoff_imports": 0,
     "handoff_stale": 0, "chunked_prefills": 0, "prefill_chunks": 0,
-    "kv_tokens_read": 0})
+    "kv_tokens_read": 0, "moe_layer_steps": 0, "moe_routed_rows": 0,
+    "moe_experts_hit": 0})
 
 # Decode replay fast path (ISSUE 9, same machinery as lazy.ReplayStep):
 # in the steady window a decode iteration is one fingerprint check (the
@@ -124,7 +139,7 @@ class FatalEngineError(RuntimeError):
     supervisor can restart it and re-queue its requests."""
 
 
-def _note_pool_layout(pool):
+def _note_pool_layout(pool, cache):
     """Set the gauge ``serving.kv_pool_row_major`` from the layout the
     device gave a freshly built pool: 1 when it is stored row by row (the
     form the in-place row write and the paged kernel take as it is), 0
@@ -138,7 +153,9 @@ def _note_pool_layout(pool):
     _registry.gauge_set("serving.kv_pool_row_major", row_major)
     _explain.record(
         "kv_pool_layout", op="kv_pool", row_major=bool(row_major),
-        why=(f"pool {tuple(pool.shape)} {pool.dtype} is stored "
+        cache_kind=cache.kind, row_width=cache.row_width(),
+        why=(f"{cache.describe()}; pool {tuple(pool.shape)} {pool.dtype} "
+             f"is stored "
              f"major-to-minor {order}: "
              + ("row-major, rows are written in place" if row_major else
                 "NOT row-major — row writes and the paged kernel will "
@@ -184,19 +201,29 @@ class GenerationEngine:
                  max_seq_len=None, rng_seed=None, block_size=16,
                  num_blocks=None, mesh=None, paged_kernel=None):
         gpt = getattr(model, "gpt", model)
-        if not hasattr(gpt, "blocks") or not hasattr(gpt, "embeddings"):
+        lacks = [a for a in ("kv_cache_spec", "serving_head",
+                             "max_positions") if not hasattr(gpt, a)]
+        if lacks:
             raise TypeError(
-                "GenerationEngine needs a GPTModel-shaped decoder "
-                "(blocks + embeddings + ln_f); got "
-                f"{type(model).__name__}")
+                "GenerationEngine serves a decoder that answers "
+                "kv_cache_spec() (the rows its layers cache a token), "
+                "serving_head() (the head's weight and its logits) and "
+                "max_positions, and whose forward takes the paged-cache "
+                "arguments (models.GPTModel, models.Xing4Model); "
+                f"{type(model).__name__} lacks {lacks}")
         self._model = model
         self._gpt = gpt
-        cfg = gpt.cfg
-        self.max_seq_len = int(max_seq_len or cfg.seq_len)
-        if self.max_seq_len > cfg.seq_len:
+        self._cache = gpt.kv_cache_spec()
+        if mesh is not None and self._cache.kind != "heads":
+            raise TypeError(
+                f"GenerationEngine(mesh=...) is not supported for a "
+                f"{self._cache.kind!r} cache: a latent row is shared by "
+                "every head, so there is no head axis to place over 'mp'")
+        self.max_seq_len = int(max_seq_len or gpt.max_positions)
+        if self.max_seq_len > gpt.max_positions:
             raise ValueError(
                 f"max_seq_len {self.max_seq_len} exceeds the model's "
-                f"position-embedding range {cfg.seq_len}")
+                f"position-embedding range {gpt.max_positions}")
         self.max_batch_size = int(max_batch_size)
         if self.max_batch_size < 1:
             raise ValueError("max_batch_size must be >= 1")
@@ -235,10 +262,14 @@ class GenerationEngine:
         # compiled steps
         self._state = dict(gpt.state_dict())
         self._names = list(self._state)
-        wt = gpt.embeddings.word_embeddings.weight
-        self._emb_idx = next(
+        wt, self._head_logits = gpt.serving_head()
+        self._head_idx = next(
             i for i, n in enumerate(self._names) if self._state[n] is wt)
         self._dtype = wt._data.dtype
+        # small device-side counts a decode step hands over with its
+        # tokens (an expert layer's experts hit), by name
+        self._step_counter_names = tuple(
+            getattr(gpt, "step_counter_names", ()))
 
         # mesh-sharded decode: weights placed by their sharding_spec
         # annotations (same param_pspec derivation as the SPMD train
@@ -271,11 +302,18 @@ class GenerationEngine:
         # its [1, L] spans amortize the gather anyway).
         from ..ops import pallas_ops as _pallas_ops
 
-        self._paged_kernel, self._paged_kernel_reason = \
-            _pallas_ops.select_paged_kernel(
-                paged_kernel, head_dim=gpt.blocks[0].attn.head_dim,
-                block_size=self.block_size, dtype=self._dtype, mesh=mesh,
-                num_heads=gpt.blocks[0].attn.n_head)
+        if self._cache.kind == "latent":
+            self._paged_kernel, self._paged_kernel_reason = \
+                _pallas_ops.select_mla_paged_kernel(
+                    paged_kernel, row_width=self._cache.row_width(),
+                    block_size=self.block_size, dtype=self._dtype)
+        else:
+            heads, head_dim = self._cache.layers[0]
+            self._paged_kernel, self._paged_kernel_reason = \
+                _pallas_ops.select_paged_kernel(
+                    paged_kernel, head_dim=head_dim,
+                    block_size=self.block_size, dtype=self._dtype,
+                    mesh=mesh, num_heads=heads)
         # per-shard fused route (ISSUE 16): when the fused kernel
         # survived mesh resolution, decode calls it through shard_map
         # with head-sharded q/pools — a static closure constant like the
@@ -297,12 +335,13 @@ class GenerationEngine:
 
         # the pools, in the device form ops/kv_pool.py owns:
         # [num_blocks, block_size, H*Dh] per layer, for K and for V
-        # (heads over 'mp' on a mesh they divide)
-        self._kv_heads = [blk.attn.n_head for blk in gpt.blocks]
-        self._k, self._v = _kv_pool.allocate(
-            [blk.attn for blk in gpt.blocks], self.pool.num_blocks,
-            self.block_size, self._dtype, mesh)
-        self._kv_row_major = _note_pool_layout(self._k[0])
+        # (heads over 'mp' on a mesh they divide); a latent cache has
+        # one pool a layer, in _k, and _v stays empty
+        self._kv_heads = [self._cache.heads(i)
+                          for i in range(len(self._cache.layers))]
+        self._k, self._v = self._cache.allocate(
+            self.pool.num_blocks, self.block_size, self._dtype, mesh)
+        self._kv_row_major = _note_pool_layout(self._k[0], self._cache)
 
         # host-side slot state, mirrored into the decode step as arrays
         B = self.max_batch_size
@@ -366,6 +405,10 @@ class GenerationEngine:
         self._fast = None
         self._decode_since_audit = 0
         self._audit_every = _lazy.AUDIT_EVERY
+        # what a decode step adds to the host's counters besides its own
+        # (an expert layer's layer-steps and routed rows)
+        self._host_step_counts = getattr(gpt, "host_step_counts",
+                                         lambda n_active: {})
 
     def _put(self, x):
         """Host → device for step inputs: plain asarray single-chip,
@@ -483,7 +526,7 @@ class GenerationEngine:
         return cached
 
     def _forward_slot(self, state_arrays, ids, positions, ks, vs, offsets,
-                      seq_lens, block_tables, kernel=None):
+                      seq_lens, block_tables, kernel=None, counts=None):
         """Run the model's paged-cache forward path on traced arrays by
         temporarily binding them into the layer parameters (the
         jit.StaticFunction state-swap idiom). Trace-time only — the jitted
@@ -491,7 +534,9 @@ class GenerationEngine:
         attention read path (None = XLA gather): a static string, fixed
         per compiled step. The fused kinds additionally close over the
         engine's per-shard mesh (ISSUE 16) so a mesh engine runs the
-        kernel body per head-shard through shard_map."""
+        kernel body per head-shard through shard_map. ``counts``, a dict,
+        receives the decoder's device-side step counters (taken from the
+        decoder inside this trace either way, so none outlives it)."""
         paged_mesh = self._paged_mesh \
             if kernel in ("pallas", "interpret") else None
         old = {n: self._state[n]._data for n in self._names}
@@ -499,16 +544,21 @@ class GenerationEngine:
             self._state[n]._data = arr
         try:
             with _ag.no_grad(), _lazy.lazy_guard(False):
-                caches = [(Tensor(k), Tensor(v)) for k, v in zip(ks, vs)]
+                caches = [(Tensor(k), Tensor(v)) for k, v in zip(ks, vs)] \
+                    if vs else [(Tensor(k),) for k in ks]
                 hidden, new_caches = self._gpt(
                     Tensor(ids), position_ids=Tensor(positions),
                     caches=caches, cache_offsets=Tensor(offsets),
                     seq_lens=Tensor(seq_lens),
                     block_tables=Tensor(block_tables),
                     paged_kernel=kernel, paged_mesh=paged_mesh)
+                if self._step_counter_names:
+                    got = self._gpt.step_counters()
+                    if counts is not None:
+                        counts.update(got)
             return (hidden._data,
                     tuple(c[0]._data for c in new_caches),
-                    tuple(c[1]._data for c in new_caches))
+                    tuple(c[1]._data for c in new_caches) if vs else ())
         finally:
             for n in self._names:
                 self._state[n]._data = old[n]
@@ -537,9 +587,9 @@ class GenerationEngine:
             jnp.broadcast_to(last_local[:, None, None],
                              (1, 1, hidden.shape[2])).astype(jnp.int32),
             axis=1)[:, 0]
-        w = state_arrays[self._emb_idx]
+        w = state_arrays[self._head_idx]
         with _spans.scope("lm_head"):
-            logits = last.astype(jnp.float32) @ w.T.astype(jnp.float32)
+            logits = self._head_logits(last, w)
         gum = _sampling.gumbel_rows(key[None], jnp.zeros((1,), jnp.int32),
                                     logits.shape[-1])
         tok = _sampling.sample_tokens(logits, temp, top_k, top_p, gum)
@@ -559,18 +609,23 @@ class GenerationEngine:
         device instead of re-uploading host mirrors every iteration."""
         ids = last_tokens[:, None]
         positions = jnp.minimum(cur_lens, self.max_seq_len - 1)[:, None]
+        counts = {}
         hidden, nk, nv = self._forward_slot(
             state_arrays, ids, positions, ks, vs,
             positions[:, 0], cur_lens + 1, block_tables,
-            kernel=self._paged_kernel)
-        w = state_arrays[self._emb_idx]
+            kernel=self._paged_kernel, counts=counts)
+        w = state_arrays[self._head_idx]
         with _spans.scope("lm_head"):
-            logits = (hidden[:, 0].astype(jnp.float32)
-                      @ w.T.astype(jnp.float32))
+            logits = self._head_logits(hidden[:, 0], w)
         gum = _sampling.gumbel_rows(keys, gen_idx, logits.shape[-1])
         toks = _sampling.sample_tokens(logits, temps, top_ks, top_ps, gum)
         adv = active.astype(cur_lens.dtype)
         new_last = jnp.where(active, toks, last_tokens)
+        if self._step_counter_names:
+            # behind the B tokens, in the one array the host reads
+            toks = jnp.concatenate([toks, jnp.stack(
+                [counts[n] for n in self._step_counter_names]
+            ).astype(toks.dtype)])
         return (toks, nk, nv, new_last, cur_lens + adv,
                 gen_idx + adv.astype(gen_idx.dtype))
 
@@ -979,6 +1034,13 @@ class GenerationEngine:
         return tok
 
     # --------------------------------------------- prefill→decode handoff --
+    def _heads_cache_only(self, feature):
+        if self._cache.kind != "heads":
+            raise TypeError(
+                f"{feature} is not supported for a {self._cache.kind!r} "
+                "cache yet: its payload and its verify span are written "
+                "for K and V rows of heads")
+
     def export_request_kv(self, slot):
         """Serialize an active slot's paged-KV state for a cross-pod
         handoff (disaggregated serving, ISSUE 11): the slot's physical
@@ -991,6 +1053,7 @@ class GenerationEngine:
         there is token-BITWISE with decoding here — a prefill pod can
         hand its finished prompt KV to a decode pod and the stream is
         indistinguishable from a monolithic pod's."""
+        self._heads_cache_only("the KV handoff (export_request_kv)")
         if not self._active[slot]:
             raise RuntimeError(f"slot {slot} is not active; nothing to "
                                "export")
@@ -1050,6 +1113,7 @@ class GenerationEngine:
         the prompt's full blocks into THIS engine's prefix cache too, so
         a handed-off shared prefix keeps earning hits on the decode
         pod."""
+        self._heads_cache_only("the KV handoff (import_request_kv)")
         if self._active[slot]:
             raise RuntimeError(f"slot {slot} is still active")
         t0 = _tracing.clock() if _tracing.enabled() else 0.0
@@ -1173,7 +1237,10 @@ class GenerationEngine:
             toks_d, *rest = self._decode_jit(*args)
             with _span("serving.decode_sync"):
                 toks = np.asarray(toks_d)
-        return (toks, *rest)
+        B = self.max_batch_size
+        for name, n in zip(self._step_counter_names, toks[B:]):
+            _counters[name] += int(n)
+        return (toks[:B], *rest)
 
     def _decode_rebuild(self, active, n_active):
         """Off-steady decode: rebuild the device-side slot state from the
@@ -1209,6 +1276,8 @@ class GenerationEngine:
         self._gen_idx[active] += 1
         self._last_tokens[active] = toks[active]
         c["decode_steps"] += 1
+        for name, n in self._host_step_counts(n_active).items():
+            c[name] += n
         c["active_slot_steps"] += n_active
         c["tokens_generated"] += n_active
         _registry.gauge_set("serving.batch_occupancy",
@@ -1265,7 +1334,9 @@ class GenerationEngine:
                "kv_blocks_free": self.pool.free_count(),
                "prefix_cache_nodes": len(self.prefix_cache),
                "weight_generation": self.prefix_cache.generation,
-               "kv_pool_row_major": self._kv_row_major}
+               "kv_pool_row_major": self._kv_row_major,
+               "kv_cache_kind": self._cache.kind,
+               "kv_row_width": self._cache.row_width()}
         if self._mesh is not None:
             out["mesh_axes"] = dict(zip(
                 self._mesh.axis_names,
@@ -1285,10 +1356,11 @@ class GenerationEngine:
             mesh = {"axes": dict(zip(
                 self._mesh.axis_names,
                 (int(s) for s in self._mesh.devices.shape)))}
-        pools = [_pool_record(i, name, a, h, self._mesh)
-                 for i, (k, v, h) in enumerate(
-                     zip(self._k, self._v, self._kv_heads))
-                 for name, a in (("k", k), ("v", v))]
+        names = ("k", "v") if self._v else ("latent",)
+        layers = zip(self._k, self._v) if self._v else zip(self._k)
+        pools = [_pool_record(i, name, a, self._kv_heads[i], self._mesh)
+                 for i, layer in enumerate(layers)
+                 for name, a in zip(names, layer)]
         return {"mesh": mesh,
                 "paged_kernel": self._paged_kernel,
                 "paged_kernel_sharded": self._paged_mesh is not None,

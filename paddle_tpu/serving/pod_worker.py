@@ -72,7 +72,12 @@ def _build_model(spec):
         return getattr(importlib.import_module(mod), fn)(
             **(spec.get("kwargs") or {}))
     if kind != "gpt":
-        raise ValueError(f"unknown model kind {kind!r}")
+        raise ValueError(
+            f"pod model spec kind {kind!r}: the built-in builder makes GPT "
+            "models only ({'kind': 'gpt', 'seed': s, 'config': {GPTConfig "
+            "keys}}); give any other decoder the engine serves (one that "
+            "answers kv_cache_spec / serving_head / max_positions) as "
+            "{'factory': 'module:function', 'kwargs': {...}}")
     import paddle_tpu as paddle
     from paddle_tpu.models.gpt import (GPTConfig, GPTForPretraining,
                                        GPTModel)

@@ -62,7 +62,6 @@ import jax.numpy as jnp
 from ..core import autograd as _ag
 from ..core import lazy as _lazy
 from ..core.tensor import Tensor
-from ..ops import kv_pool as _kv_pool
 from ..profiler import explainer as _explain
 from ..profiler import registry as _registry
 from ..profiler import span as _span
@@ -146,6 +145,7 @@ class DraftVerifyEngine(GenerationEngine):
                     "spec decode has no batch or pipeline dimension to "
                     "map them to", axes=extra)
         super().__init__(model, **kw)
+        self._heads_cache_only("spec_decode (DraftVerifyEngine)")
         self.draft_k = int(draft_k)
         if self.draft_k < 1:
             raise ValueError("draft_k must be >= 1")
@@ -218,9 +218,9 @@ class DraftVerifyEngine(GenerationEngine):
         if draft_num_blocks is None:
             draft_num_blocks = 1 + B * self.blocks_per_slot
         self.draft_pool = BlockPool(draft_num_blocks, name="draft")
-        self._dk, self._dv = _kv_pool.allocate(
-            [blk.attn for blk in dgpt.blocks], self.draft_pool.num_blocks,
-            self.block_size, self._ddtype, self._mesh)
+        self._dk, self._dv = dgpt.kv_cache_spec().allocate(
+            self.draft_pool.num_blocks, self.block_size, self._ddtype,
+            self._mesh)
         self._draft_tables = np.zeros((B, self.blocks_per_slot), np.int32)
         self._draft_blocks = [[] for _ in range(B)]
         # acceptance per weight generation (stats_dump "mesh serving"
@@ -367,7 +367,7 @@ class DraftVerifyEngine(GenerationEngine):
             state, ids, positions, ks, vs, cur_lens,
             cur_lens + K + 1, block_tables,
             kernel=self._paged_kernel)
-        w = state[self._emb_idx]
+        w = state[self._head_idx]
         B = ids.shape[0]
         flat = hidden.astype(jnp.float32).reshape(B * (K + 1), -1)
         logits = flat @ w.T.astype(jnp.float32)

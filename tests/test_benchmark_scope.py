@@ -20,11 +20,17 @@ def bench_path():
     sys.path.remove(BENCH)
 
 
-def _cfg(name):
-    import model as bench_model
-
+def _cfg_json(name):
     with open(os.path.join(BENCH, "configs", name + ".json")) as f:
-        return bench_model.sizes(json.load(f))
+        return json.load(f)
+
+
+def _cfg(name):
+    """A configuration's sizes, as its family reads them."""
+    import families
+
+    cfg_json = _cfg_json(name)
+    return families.of(cfg_json).sizes(cfg_json)
 
 
 def test_selfcheck_on_the_recorded_stretches(bench_path):
@@ -88,6 +94,45 @@ def test_names_read_are_names_the_program_writes(bench_path):
             <= set(spans.SPANS) | {"unattributed"}
     assert all(n.startswith(scope_reduce.PROGRAM_SPANS)
                for n in spans.SPANS)
+    # what the xing4 family's readers look for (no recorded stretch yet)
+    assert "mla_paged_attention" in spans.KERNELS
+    assert {"serving.moe_layer_steps", "serving.moe_routed_rows",
+            "serving.moe_experts_hit", "serving.kv_tokens_read"} \
+        <= set(spans.COUNTERS)
+    assert {"hc_mix", "mla_absorb", "moe_router", "moe_experts"} \
+        <= set(spans.SCOPES)
+
+
+def test_counts_at_the_xing4_cells_sizes(bench_path):
+    """ISSUE 30's hand numbers for the cut that is run (1 dense + 5 expert
+    layers, all 64 experts, the whole vocabulary)."""
+    import families
+
+    c = _cfg_json("xing4.0-29b-a4b")
+    fam = families.of(c)
+    p = fam.param_counts(c)
+    assert p["attn"] == pytest.approx(28.41e6, rel=1e-3)
+    assert p["hc"] == pytest.approx(0.72e6, rel=1e-2)
+    assert p["expert"] == 3 * 3584 * 1024 and p["shared"] == p["expert"]
+    assert p["total"] == pytest.approx(4.793e9, rel=1e-3)
+    assert fam.vocab_size(c) == 131072 and fam.criterion() is None
+    assert fam.sizes(c)["kv_lora_rank"] == 512
+    counters = {"serving.decode_steps": 10, "serving.active_slot_steps": 320,
+                "serving.kv_tokens_read": 600000,
+                "serving.moe_layer_steps": 50,
+                "serving.moe_experts_hit": 2775}
+    run = {"cfg": c, "counters": counters}
+    flops, nbytes = fam.decode_step_work(run)
+    # 1.020 B active parameters a token; 55.5 of 64 experts hit: ~7.7 GB of
+    # weights + 60,000 rows x 1,152 B x 6 layers
+    assert flops == pytest.approx(2 * 1.020e9 * 32 + 69632 * 60000 * 6,
+                                  rel=1e-3)
+    assert nbytes == pytest.approx(7.71e9 + 60000 * 1152 * 6, rel=5e-3)
+    assert fam.kernel_work(run, "mla_paged_attention") \
+        == (69632 * 60000, 1152 * 60000)
+    assert fam.kernel_work(run, "paged_attention") is None
+    assert fam.train_flops_per_token(run) is None
+    assert fam.decode_step_work({"cfg": c, "counters": {}}) is None
 
 
 def test_counts_at_the_cells_sizes(bench_path):
@@ -124,39 +169,62 @@ def test_every_per_layer_entry_has_its_reader():
     try:
         import run as bench_run
 
-        drivers = {}
+        drivers = {}  # cell -> its driver and the one it samples as
         for w in bm["workloads"]:
             with open(os.path.join(BENCH, "traffic",
                                    w["traffic"] + ".json")) as f:
-                drivers[w["name"]] = json.load(f)["driver"]
+                name = json.load(f)["driver"]
+            mod = bench_run.load_module("drivers", name + ".py")
+            drivers[w["name"]] = {name, getattr(mod, "SAMPLES_AS", name)}
         for entry in bm["per_layer"]:
             mod = bench_run.load_module("layer_metrics",
                                         entry["name"] + ".py")
             for key in ("name", "unit", "better", "source", "layer",
                         "moves"):
                 assert mod.META[key] == entry[key], (entry["name"], key)
-            assert {drivers[w] for w in entry["workloads"]} \
-                <= set(mod.META["drivers"])
+            for w in entry["workloads"]:
+                assert drivers[w] & set(mod.META["drivers"]), \
+                    (entry["name"], w)
     finally:
         sys.path.remove(BENCH)
 
 
-def test_rehearsal_reads_the_counter_metrics_and_no_device_metric():
+def _rehearse(cell, seed):
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     r = subprocess.run(
         [sys.executable, os.path.join(BENCH, "run.py"), "--workload",
-         "rehearse_serve", "--seed", "7", "--seconds", "2", "--trace", "1"],
+         cell, "--seed", str(seed), "--seconds", "2", "--trace", "1"],
         cwd=REPO, env=env, capture_output=True, text=True, timeout=600)
     assert r.returncode == 0, r.stdout[-2000:] + r.stderr[-2000:]
     out = json.loads(r.stdout.strip().splitlines()[-1])
-    got = out["metrics"]
     assert out["correct"]
+    return out["metrics"]
+
+
+def test_rehearsal_reads_the_counter_metrics_and_no_device_metric():
+    got = _rehearse("rehearse_serve", 7)
     for name in ("serve.decode_call_ms", "serve.sched_self_ms",
                  "serve.prefill_call_ms", "serve.queue_wait_ms",
                  "serve.loop_idle_share", "serve.itl_p99_ms"):
         assert got[name]["value"] >= 0, name
     # no device, so nothing is written under a device metric's name
     for name in ("serve.decode_step_mfu", "serve.decode_step_roofline",
+                 "kernel.paged_attn_roofline.serve",
+                 "device.idle_attributed_share.serve"):
+        assert name not in got
+
+
+def test_rehearsal_of_the_xing4_family_reads_its_counter_metric():
+    """The second family end to end on the CPU: the latent cache, the
+    dropless experts and the four-stream residual under the same driver,
+    `correct` against reference/xing4.py, the expert counter read, and no
+    number under a device metric's name (a seed over 2**31, as the
+    driver's are)."""
+    got = _rehearse("rehearse_serve_xing4", 3000000030)
+    assert 0 < got["moe.experts_hit_share.serve"]["value"] <= 100
+    assert got["serve.decode_call_ms"]["value"] >= 0
+    for name in ("serve.decode_step_mfu", "serve.decode_step_roofline",
+                 "kernel.mla_paged_attn_roofline.serve",
                  "kernel.paged_attn_roofline.serve",
                  "device.idle_attributed_share.serve"):
         assert name not in got

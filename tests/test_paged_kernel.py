@@ -555,3 +555,55 @@ class TestKernelMismatchFault:
                                           kernel="interpret")
         np.testing.assert_allclose(np.asarray(good), np.asarray(ref),
                                    atol=atol, rtol=rtol)
+
+
+class TestLatentKernelParity:
+    """`mla_paged_attention`: the kernel body through the interpreter
+    against the XLA gather route, over a latent pool [Nb, bs, W]. Tolerance:
+    PAGED_PARITY_TOL, the paged family's own (f32 differs by reduction
+    order only; bf16 keeps probabilities in f32 where the XLA route rounds
+    them)."""
+
+    @staticmethod
+    def _case(B, H, W, Nb, bs, M, dtype, seed=0):
+        rng = np.random.default_rng(seed)
+        q = jnp.asarray(rng.standard_normal((B, H, W)), dtype)
+        pool = jnp.asarray(rng.standard_normal((Nb, bs, W)), dtype)
+        ids = rng.permutation(np.arange(1, Nb))[:B * M].reshape(B, M)
+        return q, pool, jnp.asarray(ids, jnp.int32)
+
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+    def test_ragged_lengths_and_a_zero_length_lane(self, dtype):
+        # bs=4, 8 blocks a program's worth of keys = 32: lengths just
+        # under / on / over a block and a program boundary, a released lane
+        # (zeroed table row, length 1: reads garbage block 0) and a lane of
+        # length 0 (reads nothing: zeros from both routes)
+        q, pool, bt = self._case(6, 4, 128, 80, 4, 12, dtype)
+        bt = bt.at[4].set(0)
+        sl = jnp.asarray([3, 4, 33, 47, 1, 0], jnp.int32)
+        fused = pallas_ops.mla_paged_attention(q, pool, bt, sl, 0.17,
+                                               kernel="interpret")
+        ref = pallas_ops.mla_paged_attention(q, pool, bt, sl, 0.17,
+                                             kernel="xla")
+        atol, rtol = pallas_ops.PAGED_PARITY_TOL[jnp.dtype(dtype).name]
+        np.testing.assert_allclose(np.asarray(fused, np.float32),
+                                   np.asarray(ref, np.float32),
+                                   atol=atol, rtol=rtol)
+        assert bool(jnp.isfinite(fused).all())
+        assert not np.asarray(fused[5], np.float32).any()
+        # dead lanes do not perturb the live ones
+        solo = pallas_ops.mla_paged_attention(q[:4], pool, bt[:4], sl[:4],
+                                              0.17, kernel="interpret")
+        np.testing.assert_array_equal(np.asarray(fused[:4], np.float32),
+                                      np.asarray(solo, np.float32))
+
+    def test_selection_and_refusals(self):
+        kind, why = pallas_ops.select_mla_paged_kernel(
+            "pallas", row_width=640, block_size=16, dtype=jnp.bfloat16)
+        assert kind == "interpret" and "interpreter" in why
+        assert pallas_ops.select_mla_paged_kernel(
+            None, row_width=640, block_size=16,
+            dtype=jnp.bfloat16)[0] == "xla"
+        with pytest.raises(ValueError, match="unknown paged-attention"):
+            pallas_ops.mla_paged_attention(None, None, None, None, 1.0,
+                                           kernel="interpet")

@@ -227,6 +227,24 @@ compile_("paged_mp4", *paged(1, 64, jnp.bfloat16,
                              pool=NamedSharding(mp, P(None, None, "mp"))))
 compile_("paged_merged_T1_dh64", *paged(1, 64, jnp.bfloat16, one, one,
                                         pool=one))
+# the xing4 cell's kernels at its sizes: the latent (MLA) decode kernel, 32
+# slots x 32 heads over 640-lane rows, 5,633 blocks of 16, 176 table columns;
+# and XLA:TPU's own grouped matmul for the dropless expert layer's decode
+# rows, with the precision the layer names (bf16 operands under the package's
+# global "highest" end in Mosaic's "Bad lhs type")
+compile_("xing4_mla_paged",
+         lambda *x: po.mla_paged_attention(*x, 0.1, kernel="pallas"),
+         sds((32, 32, 640), jnp.bfloat16, sharding=one),
+         sds((5633, 16, 640), jnp.bfloat16, sharding=one),
+         sds((32, 176), jnp.int32, sharding=one),
+         sds((32,), jnp.int32, sharding=one))
+compile_("xing4_grouped_matmul",
+         lambda x, w, g: jax.lax.ragged_dot(
+             x, w, g, precision=po._dot_precision(x.dtype),
+             preferred_element_type=jnp.float32),
+         sds((128, 3584), jnp.bfloat16, sharding=one),
+         sds((64, 3584, 2048), jnp.bfloat16, sharding=one),
+         sds((64,), jnp.int32, sharding=one))
 # the serving cell's layer: the step's new rows written into donated pools,
 # then the kernel over them (gpt3-1.3b: 3,073 blocks x 16 x 32 heads x 64,
 # 32 slots, 128 table columns). The pools must enter row-major and stay
@@ -341,10 +359,24 @@ def test_aot_compile_for_v5e():
     assert all(v["custom_calls"] == 1 for k, v in res.items()
                if k.startswith("paged_"))
     _SERVE_LAYERS.update((k, v) for k, v in res.items()
-                         if k.startswith("serve_layer_"))
+                         if k.startswith(("serve_layer_", "xing4_")))
 
 
 _SERVE_LAYERS: dict = {}
+
+
+def test_latent_kernel_and_grouped_matmul_compile_for_v5e():
+    """The xing4 cell's two kernels at its own sizes (the AOT child's
+    result of the test above): Mosaic takes `mla_paged_attention`, and
+    XLA:TPU lowers `lax.ragged_dot` to ITS grouped matmul (a custom call
+    with a metadata call in front, no dense [E, m, k] expansion) when the
+    precision is named as `nn/moe/dropless.py` names it."""
+    if not _SERVE_LAYERS:
+        pytest.skip("test_aot_compile_for_v5e did not compile here")
+    assert _SERVE_LAYERS["xing4_mla_paged"] == {"custom_calls": 1,
+                                                "collectives": 0}
+    assert _SERVE_LAYERS["xing4_grouped_matmul"] == {"custom_calls": 2,
+                                                     "collectives": 0}
 
 
 @pytest.mark.parametrize("T", [1, 5])  # decode, spec verify (K+1)
