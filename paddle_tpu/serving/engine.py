@@ -100,7 +100,7 @@ _counters = _registry.scoped_counters("serving", {
     "kv_blocks_hwm": 0, "handoff_exports": 0, "handoff_imports": 0,
     "handoff_stale": 0, "chunked_prefills": 0, "prefill_chunks": 0,
     "kv_tokens_read": 0, "moe_layer_steps": 0, "moe_routed_rows": 0,
-    "moe_experts_hit": 0})
+    "moe_experts_hit": 0, "sample_topk_steps": 0, "sample_topp_steps": 0})
 
 # Decode replay fast path (ISSUE 9, same machinery as lazy.ReplayStep):
 # in the steady window a decode iteration is one fingerprint check (the
@@ -618,7 +618,10 @@ class GenerationEngine:
         with _spans.scope("lm_head"):
             logits = self._head_logits(hidden[:, 0], w)
         gum = _sampling.gumbel_rows(keys, gen_idx, logits.shape[-1])
-        toks = _sampling.sample_tokens(logits, temps, top_ks, top_ps, gum)
+        # a released slot keeps its last knobs: as a greedy lane it asks
+        # for no filter pass (its token is discarded either way)
+        toks = _sampling.sample_tokens(
+            logits, jnp.where(active, temps, 0.0), top_ks, top_ps, gum)
         adv = active.astype(cur_lens.dtype)
         new_last = jnp.where(active, toks, last_tokens)
         if self._step_counter_names:
@@ -1276,12 +1279,22 @@ class GenerationEngine:
         self._gen_idx[active] += 1
         self._last_tokens[active] = toks[active]
         c["decode_steps"] += 1
+        self._count_filter_steps(active)
         for name, n in self._host_step_counts(n_active).items():
             c[name] += n
         c["active_slot_steps"] += n_active
         c["tokens_generated"] += n_active
         _registry.gauge_set("serving.batch_occupancy",
                             n_active / self.max_batch_size)
+
+    def _count_filter_steps(self, active):
+        """Whether this step's sampling ran its top-k / top-p passes:
+        ``sampling.sample_tokens`` gates each on the same test of the same
+        knobs (``_decode_pure`` hands it inactive lanes as greedy)."""
+        on = active & (self._temps > 0)
+        _counters["sample_topk_steps"] += int((on & (self._top_ks > 0)).any())
+        _counters["sample_topp_steps"] += int(
+            (on & (self._top_ps < 1.0)).any())
 
     def _audit_fast(self, fast):
         """Periodic decode audit: the device-side slot state must equal

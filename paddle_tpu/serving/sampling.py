@@ -20,11 +20,25 @@ Sampling itself uses the Gumbel-max trick (argmax(logits + gumbel) ~
 Categorical(softmax(logits))): one argmax over the already-materialized
 logits row instead of a cumulative-sum search, and the same code path as
 greedy (which just omits the noise).
+
+Both filters keep ``logits >= threshold(row)``, and the threshold is found by
+SELECTION over the row, never by ordering it: a sort of the vocabulary to
+read one value was the largest device group of a decode step. Every float32
+has an int32 image with the same order (``_order_keys``); the threshold's
+image is built bit by bit from the top, one compare-and-reduce pass over the
+block a bit (``_select_key``). For top-k the pass counts, so the threshold is
+the exact k-th largest value, ties and all, for any k — which is why
+``lax.approx_max_k`` is not used (its recall is below 1: it may miss one of
+the k, and the kept set would no longer be what the request asked for). A
+row's threshold reads that row alone, so batch composition still cannot reach
+a request's tokens. A batch in which no row asks for a filter runs none of
+its passes (``lax.cond`` on the knobs, which are data: still one executable).
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 from ..profiler.spans import scope as _scope
 
@@ -52,30 +66,84 @@ def gumbel_rows(key_data, token_idx, vocab):
         return jax.vmap(row)(key_data, token_idx)
 
 
+def _flip_negatives(i):
+    """Flip the low 31 bits of the negative int32s (its own inverse)."""
+    return jnp.where(i < 0, i ^ jnp.int32(0x7FFFFFFF), i)
+
+
+def _order_keys(x):
+    """int32 image of float32 `x` whose signed order is the float order
+    (``-inf`` lowest; ``-0.0`` one below ``+0.0``, which compare equal again
+    once mapped back)."""
+    return _flip_negatives(lax.bitcast_convert_type(x, jnp.int32))
+
+
+def _key_floats(keys):
+    """The float32 whose ``_order_keys`` image is `keys`."""
+    return lax.bitcast_convert_type(_flip_negatives(keys), jnp.float32)
+
+
+def _select_key(keys, target, weights=None):
+    """Per row of int32 `keys` ``[B, V]``, the LARGEST int32 ``t`` with
+    ``sum(weights[keys >= t]) >= target`` (`weights` None counts ids), or
+    the lowest int32 where no ``t`` reaches `target`. The sum can only fall
+    as ``t`` rises, so ``t`` is built from its top bit down: 32 passes of
+    one compare-and-reduce over the block, no ordering of it."""
+
+    def bit(i, t):
+        # the sign bit first (lowest int32 -> 0), then 30..0 set in turn
+        cand = t ^ jnp.left_shift(jnp.int32(1), 31 - i)
+        hit = keys >= cand[:, None]
+        if weights is None:
+            mass = jnp.sum(hit, axis=-1, dtype=jnp.int32)
+        else:
+            mass = jnp.sum(jnp.where(hit, weights, 0.0), axis=-1)
+        return jnp.where(mass >= target, cand, t)
+
+    lowest = jnp.full(keys.shape[:1], jnp.iinfo(jnp.int32).min, jnp.int32)
+    return lax.fori_loop(0, 32, bit, lowest)
+
+
+def top_k_threshold(logits, top_k):
+    """``[B]`` float32: each row's `top_k`-th largest logit, exactly (k is
+    clipped to ``[1, V]``, so ``top_k > V`` gives the row's minimum)."""
+    k = jnp.clip(top_k, 1, logits.shape[-1]).astype(jnp.int32)
+    return _key_floats(_select_key(_order_keys(logits), k))
+
+
+def top_p_threshold(logits, top_p):
+    """``[B]`` float32: the lowest value level of each row whose PRECEDING
+    probability mass (the ids strictly above it) is ``< top_p``. A level
+    ``v`` survives iff the mass at or above the next float up is below
+    `top_p`, so the threshold is the largest ``t`` whose mass at or above it
+    reaches `top_p`: the same selection as top-k, over probabilities."""
+    p = jnp.clip(top_p, 1e-6, 1.0)
+    probs = jax.nn.softmax(logits, axis=-1)
+    return _key_floats(_select_key(_order_keys(logits), p, probs))
+
+
 def filter_top_k(logits, top_k):
-    """Keep each row's `top_k` highest logits (ties keep all tied values —
-    the standard sort-threshold caveat); ``top_k <= 0`` disables the filter
-    for that row. Shapes: logits ``[B, V]`` float, top_k ``[B]`` int."""
-    V = logits.shape[-1]
-    sorted_desc = jnp.sort(logits, axis=-1)[:, ::-1]
-    kth = jnp.take_along_axis(
-        sorted_desc, jnp.clip(top_k - 1, 0, V - 1)[:, None], axis=-1)
-    keep = (top_k[:, None] <= 0) | (logits >= kth)
+    """Keep each row's `top_k` highest logits; ties at the k-th value keep
+    every tied id. ``top_k <= 0`` disables the filter for that row. Shapes:
+    logits ``[B, V]`` float32, top_k ``[B]`` int."""
+    keep = (top_k[:, None] <= 0) | (
+        logits >= top_k_threshold(logits, top_k)[:, None])
     return jnp.where(keep, logits, -jnp.inf)
 
 
 def filter_top_p(logits, top_p):
-    """Nucleus filter: keep each row's smallest prefix of descending-sorted
-    tokens whose PRECEDING cumulative probability is < top_p (so the top-1
-    token always survives, even for tiny p); ``top_p >= 1`` disables the
-    filter for that row. Operates on already temperature-scaled logits."""
-    p = jnp.clip(top_p, 1e-6, 1.0)[:, None]
-    sorted_desc = jnp.sort(logits, axis=-1)[:, ::-1]
-    probs = jax.nn.softmax(sorted_desc, axis=-1)
-    before = jnp.cumsum(probs, axis=-1) - probs
-    kept = jnp.where(before < p, sorted_desc, jnp.inf)
-    threshold = jnp.min(kept, axis=-1, keepdims=True)
-    keep = (top_p[:, None] >= 1.0) | (logits >= threshold)
+    """Nucleus filter: keep each row's ids from the top down while the
+    PRECEDING cumulative probability is < top_p (so the top-1 token always
+    survives, even for tiny p; ids tied in value stand or fall together);
+    ``top_p >= 1`` disables the filter for that row. Operates on already
+    temperature-scaled logits.
+
+    The mass above a level is a float32 sum in the reduction's order, not a
+    cumulative sum down a sorted row: the kept set can differ from a sorted
+    form's only for an id whose preceding mass lies within float32 summation
+    error (~1e-6) of `top_p`."""
+    keep = (top_p[:, None] >= 1.0) | (
+        logits >= top_p_threshold(logits, top_p)[:, None])
     return jnp.where(keep, logits, -jnp.inf)
 
 
@@ -85,13 +153,29 @@ def sample_tokens(logits, temperature, top_k, top_p, gumbel):
 
     All inputs are arrays (``logits [B, V]``, knobs ``[B]``, ``gumbel
     [B, V]``) so the call is shape-stable regardless of the per-request
-    configs in the batch."""
+    configs in the batch. Each filter's passes run only if some sampling
+    row asks for it; a greedy row asks for neither."""
     with _scope("sampling"):
         logits = logits.astype(jnp.float32)
         greedy = jnp.argmax(logits, axis=-1)
-        safe_t = jnp.where(temperature > 0, temperature, 1.0)
+        sampling = temperature > 0
+        safe_t = jnp.where(sampling, temperature, 1.0)
         scaled = logits / safe_t[:, None]
-        filtered = filter_top_p(filter_top_k(scaled, top_k), top_p)
+        k_on = sampling & (top_k > 0)
+        p_on = sampling & (top_p < 1.0)
+        everything = jnp.full(scaled.shape[:1], -jnp.inf, jnp.float32)
+
+        def kept(threshold):
+            return jnp.where(scaled >= threshold[:, None], scaled, -jnp.inf)
+
+        kth = jnp.where(k_on, lax.cond(
+            jnp.any(k_on), lambda: top_k_threshold(scaled, top_k),
+            lambda: everything), -jnp.inf)
+        nucleus = jnp.where(p_on, lax.cond(
+            jnp.any(p_on), lambda: top_p_threshold(kept(kth), top_p),
+            lambda: everything), -jnp.inf)
+        # an id stays iff it clears both thresholds (-inf where a row's
+        # filter is off): the higher one
+        filtered = kept(jnp.maximum(kth, nucleus))
         sampled = jnp.argmax(filtered + gumbel, axis=-1)
-        return jnp.where(temperature > 0, sampled,
-                         greedy).astype(jnp.int32)
+        return jnp.where(sampling, sampled, greedy).astype(jnp.int32)

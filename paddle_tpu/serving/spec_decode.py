@@ -730,6 +730,7 @@ class DraftVerifyEngine(GenerationEngine):
                 self._retire_old_generations()
         sc = _serving_counters
         sc["decode_steps"] += 1
+        self._count_filter_steps(active)
         sc["active_slot_steps"] += n_active
         sc["tokens_generated"] += total
         _registry.gauge_set("serving.batch_occupancy",

@@ -273,6 +273,102 @@ class TestServerFrontend:
         assert req.status == RequestStatus.QUEUED  # still alive
 
 
+# --- the sort-based forms the selection replaced (the parent of PR 31, line
+# for line), kept here as the oracle of what a filter keeps ----------------
+def _sorted_top_k(logits, top_k):
+    import jax.numpy as jnp
+
+    V = logits.shape[-1]
+    sorted_desc = jnp.sort(logits, axis=-1)[:, ::-1]
+    kth = jnp.take_along_axis(
+        sorted_desc, jnp.clip(top_k - 1, 0, V - 1)[:, None], axis=-1)
+    keep = (top_k[:, None] <= 0) | (logits >= kth)
+    return jnp.where(keep, logits, -jnp.inf)
+
+
+def _sorted_top_p(logits, top_p):
+    import jax
+    import jax.numpy as jnp
+
+    p = jnp.clip(top_p, 1e-6, 1.0)[:, None]
+    sorted_desc = jnp.sort(logits, axis=-1)[:, ::-1]
+    probs = jax.nn.softmax(sorted_desc, axis=-1)
+    before = jnp.cumsum(probs, axis=-1) - probs
+    kept = jnp.where(before < p, sorted_desc, jnp.inf)
+    threshold = jnp.min(kept, axis=-1, keepdims=True)
+    keep = (top_p[:, None] >= 1.0) | (logits >= threshold)
+    return jnp.where(keep, logits, -jnp.inf)
+
+
+def _sorted_sample_tokens(logits, temperature, top_k, top_p, gumbel):
+    import jax.numpy as jnp
+
+    logits = logits.astype(jnp.float32)
+    greedy = jnp.argmax(logits, axis=-1)
+    safe_t = jnp.where(temperature > 0, temperature, 1.0)
+    scaled = logits / safe_t[:, None]
+    filtered = _sorted_top_p(_sorted_top_k(scaled, top_k), top_p)
+    sampled = jnp.argmax(filtered + gumbel, axis=-1)
+    return jnp.where(temperature > 0, sampled, greedy).astype(jnp.int32)
+
+
+_HEAD = np.asarray([0.3, 0.2, 0.15, 0.1, 0.08, 0.06, 0.05, 0.03, 0.02, 0.01])
+
+
+def _tied_rows(rng, B, V):
+    """bf16-rounded logits (so the k-th value is tied with its neighbours at
+    the cells' vocabularies), with the rows a filter can trip over: one
+    holding -inf, one holding both zeros, one of nothing but zeros."""
+    import jax.numpy as jnp
+
+    x = np.array(jnp.asarray(rng.normal(size=(B, V)) * 3, jnp.float32)
+                 .astype(jnp.bfloat16).astype(jnp.float32))
+    x[0, :5] = -np.inf
+    x[1, 3], x[1, 4] = -0.0, 0.0
+    x[2, 0::2], x[2, 1::2] = -0.0, 0.0
+    return x
+
+
+def _lumpy_rows(rng, B, V):
+    """Rows whose mass sits in ten ids (`_HEAD`, placed at random) over a
+    tail of < 1e-6 in all: the cumulative mass moves in steps of >= 0.01, so
+    a `top_p` between two steps is far from any id's boundary."""
+    x = (rng.normal(size=(B, V)) - 30.0).astype(np.float32)
+    for b in range(B):
+        ids = rng.choice(V, len(_HEAD), replace=False)
+        x[b, ids] = np.log(_HEAD).astype(np.float32)
+    return x
+
+
+def _boundary_margin(filtered, top_p):
+    """How far, in mass, each nucleus row's `top_p` lies from the nearest
+    id's preceding mass (float64 over the filtered, scaled logits)."""
+    out = []
+    for row, p in zip(np.asarray(filtered, np.float64), top_p):
+        if p >= 1.0:
+            continue
+        row = np.sort(row[row > -np.inf])[::-1]
+        probs = np.exp(row - row[0])
+        before = np.cumsum(probs / probs.sum()) - probs / probs.sum()
+        out.append(np.abs(before - p).min())
+    return min(out) if out else 1.0
+
+
+def _kept_cases():
+    cases = []
+    for V in (32, 50304, 131072):
+        for k in (1, 2, 40, "V", "V+8", 0, -1):
+            cases.append(pytest.param("top_k", V, k, id=f"top_k-V{V}-k{k}"))
+        cases.append(pytest.param("top_p", V, None, id=f"top_p-V{V}"))
+        cases.append(pytest.param("mixed", V, None, id=f"mixed-V{V}"))
+    for p in (0.75, 0.5, 0.8):
+        cases.append(pytest.param("handmade", 5, p, id=f"handmade-p{p}"))
+    cases.append(pytest.param("all_greedy", 50304, None, id="all-greedy"))
+    cases.append(pytest.param("all_disabled", 50304, None,
+                              id="all-disabled"))
+    return cases
+
+
 class TestSampling:
     def _logits(self):
         rng = np.random.default_rng(0)
@@ -335,6 +431,117 @@ class TestSampling:
             jnp.asarray(gum)))
         np.testing.assert_array_equal(toks[[0, 2]],
                                       logits.argmax(-1)[[0, 2]])
+
+
+    @pytest.mark.parametrize("kind,V,arg", _kept_cases())
+    def test_kept_set_equals_the_sorted_form(self, kind, V, arg):
+        """PR 31: the thresholds come from selection (32 compare-and-reduce
+        passes over the row's order-preserving integer image), never from
+        ordering the vocabulary. What is kept is the sorted form's, bit for
+        bit for top-k; for top-p wherever `top_p` is not within float32
+        summation error of an id's preceding mass."""
+        import jax
+        import jax.numpy as jnp
+
+        rng = np.random.default_rng(31)
+        B = 8
+        if kind == "top_k":
+            k = {"V": V, "V+8": V + 8}.get(arg, arg)
+            x = jnp.asarray(_tied_rows(rng, B, V))
+            ks = jnp.full((B,), k, jnp.int32)
+            got = np.asarray(jax.jit(sampling.filter_top_k)(x, ks))
+            want = np.asarray(jax.jit(_sorted_top_k)(x, ks))
+            np.testing.assert_array_equal(got, want)
+            if k > 0:  # ties at the k-th value keep every tied id
+                assert ((got == np.asarray(x)).sum(-1) >= min(k, V)).all()
+        elif kind == "top_p":
+            x = _lumpy_rows(rng, B, V)
+            ps = np.asarray([0.25, 0.4, 0.58, 0.7, 0.79, 0.86, 0.925, 0.965],
+                            np.float32)
+            assert _boundary_margin(x, ps) >= 1e-4
+            got = np.asarray(jax.jit(sampling.filter_top_p)(
+                jnp.asarray(x), jnp.asarray(ps)))
+            want = np.asarray(jax.jit(_sorted_top_p)(
+                jnp.asarray(x), jnp.asarray(ps)))
+            np.testing.assert_array_equal(got, want)
+            np.testing.assert_array_equal(
+                (got > -np.inf).sum(-1), [1, 2, 3, 4, 5, 6, 7, 8])
+        elif kind == "handmade":
+            row = jnp.asarray(np.log(np.asarray(
+                [[0.5, 0.3, 0.1, 0.06, 0.04]], np.float32)))
+            p = jnp.asarray([arg], np.float32)
+            got = np.asarray(sampling.filter_top_p(row, p))[0] > -np.inf
+            want = np.asarray(_sorted_top_p(row, p))[0] > -np.inf
+            if arg == 0.8:
+                # 0.5 + 0.3 IS top_p: whether id 2's preceding mass reads
+                # under 0.8 is the summation order's to say (the one place
+                # the two forms may part); the ids around it are not
+                assert got[:2].all() and not got[3:].any()
+                assert want[:2].all() and not want[3:].any()
+            else:
+                np.testing.assert_array_equal(got, want)
+                assert got.sum() == {0.75: 2, 0.5: 1}[arg]
+        else:
+            # whole batches through sample_tokens: the tokens are the
+            # sorted form's (same gumbel, same kept set)
+            x = _tied_rows(rng, B, V)
+            x[5:] = _lumpy_rows(rng, 3, V)
+            temps = np.asarray([0, .8, 1., 0, .5, 1., 1., 1.], np.float32)
+            ks = np.asarray([0, 40, 0, 5, -1, V + 8, 6, 0], np.int32)
+            ps = np.asarray([1, 1, 1, .5, 1, .7, .58, .925], np.float32)
+            if kind == "all_greedy":  # neither branch of either cond runs
+                temps = np.zeros(B, np.float32)
+            elif kind == "all_disabled":
+                ks, ps = np.zeros(B, np.int32), np.ones(B, np.float32)
+                temps = np.full(B, 0.7, np.float32)
+            scaled = x / np.where(temps > 0, temps, 1.0)[:, None]
+            on = temps > 0
+            filtered = np.asarray(_sorted_top_k(
+                jnp.asarray(scaled), jnp.asarray(ks)))
+            assert _boundary_margin(filtered[on], ps[on]) >= 1e-4
+            gum = jnp.asarray(rng.gumbel(size=(B, V)), jnp.float32)
+            args = (jnp.asarray(x), jnp.asarray(temps), jnp.asarray(ks),
+                    jnp.asarray(ps), gum)
+            got = np.asarray(jax.jit(sampling.sample_tokens)(*args))
+            want = np.asarray(jax.jit(_sorted_sample_tokens)(*args))
+            np.testing.assert_array_equal(got, want)
+            np.testing.assert_array_equal(got[~on], x.argmax(-1)[~on])
+            if kind == "all_disabled":
+                np.testing.assert_array_equal(
+                    got, np.asarray(jnp.asarray(scaled) + gum).argmax(-1))
+
+
+    def test_filter_step_counters_follow_the_active_slots_knobs(self):
+        """`serving.sample_topk_steps` / `sample_topp_steps` count the decode
+        steps whose sampling ran that filter's passes: some ACTIVE slot
+        samples (temperature > 0) with the filter on. A greedy slot, and the
+        knobs a released slot leaves behind, count nothing; one decode
+        executable serves all of it."""
+        from paddle_tpu.serving import GenerationEngine
+
+        eng = GenerationEngine(_build_model(), max_batch_size=2,
+                               buckets=(8,), rng_seed=3)
+
+        def steps(n):
+            before = eng.stats()
+            for _ in range(n):
+                eng.decode_step()
+            after = eng.stats()
+            return tuple(after[k] - before[k] for k in (
+                "decode_steps", "sample_topk_steps", "sample_topp_steps",
+                "decode_compiles"))
+
+        eng.prefill(0, [5, 6, 7], top_k=4, top_p=0.5)  # greedy: knobs idle
+        assert steps(3)[:3] == (3, 0, 0)
+        eng.prefill(1, [8, 9], temperature=0.8, top_k=4, seed=1)
+        assert steps(2) == (2, 2, 0, 0)
+        eng.release(1)
+        assert steps(2) == (2, 0, 0, 0)
+        eng.prefill(1, [8, 9], temperature=0.8, top_p=0.9, seed=2)
+        assert steps(2) == (2, 0, 2, 0)
+        eng.release(1)
+        eng.prefill(1, [8, 9], temperature=0.8, top_k=3, top_p=0.9, seed=2)
+        assert steps(2) == (2, 2, 2, 0)
 
 
 class TestLegacyCachePath:

@@ -116,6 +116,43 @@ class TestCrossLowering:
         assert "4x1024x8x64" in text.replace(" ", "")
 
 
+    def test_spec_decode_sampling_steps_gate_their_filters(self):
+        """The drafter's scan step and the verify step sample through
+        `sampling.sample_tokens` (rows replicated K+1 times): the
+        `lax.cond` around each filter and the `fori_loop` of its passes
+        trace inside the scan and lower for the TPU, with no sort left."""
+        from paddle_tpu.models.gpt import (GPTConfig, GPTForPretraining,
+                                          GPTModel)
+        from paddle_tpu.serving import DraftVerifyEngine
+
+        def build(n_layer, d_model):
+            return GPTForPretraining(GPTModel(GPTConfig(
+                vocab_size=96, n_layer=n_layer, n_head=2, d_model=d_model,
+                seq_len=64)))
+
+        eng = DraftVerifyEngine(build(2, 48), build(1, 32), draft_k=3,
+                                max_batch_size=2, buckets=(8,), rng_seed=9,
+                                block_size=4, paged_kernel="xla")
+        tail = tuple(jnp.asarray(a) for a in (
+            eng._cur_lens, eng._keys, eng._gen_idx, eng._temps,
+            eng._top_ks, eng._top_ps))
+        last = jnp.asarray(eng._last_tokens)
+        steps = {
+            "draft": (eng._draft_round_pure, (
+                eng._draft_arrays(), tuple(eng._dk), tuple(eng._dv), last)
+                + tail + (jnp.asarray(eng._draft_tables),)),
+            "verify": (eng._verify_pure, (
+                eng._state_arrays(), tuple(eng._k), tuple(eng._v), last,
+                eng._garbage_drafts) + tail + (
+                jnp.asarray(eng._active), jnp.asarray(eng._block_tables))),
+        }
+        for name, (fn, args) in steps.items():
+            text = _lowered_text(fn, *args)
+            assert "stablehlo.sort" not in text, name
+            assert text.count("stablehlo.case") >= 2, name
+            assert "stablehlo.while" in text, name
+
+
 class TestFlashMeshPlan:
     """flash_attention's resolution under a mesh (platform patched in)."""
 
@@ -323,6 +360,60 @@ for T in (1, 5):
     except Exception as e:
         out[f"serve_layer_T{T}"] = f"{type(e).__name__}: {e}"[:600]
 
+# the serving engines' own executables at toy depth and the cells'
+# vocabularies and slots: sampling finds its thresholds by selection (PR 31),
+# so neither `serving_decode` nor `serving_prefill` may hold a sort whose
+# operand has the vocabulary as its minor dimension (the parent's two cost
+# 36 % of the xing4 cell's decode step), and each filter sits behind its
+# `conditional`
+from paddle_tpu.models import GPTModel, Xing4Config, Xing4Model
+from paddle_tpu.serving.engine import GenerationEngine
+
+def engine_avals(eng, L):
+    av = lambda a: sds(np.shape(a), a.dtype, sharding=one)
+    head = (tuple(av(a) for a in eng._state_arrays()),
+            tuple(av(a) for a in eng._k), tuple(av(a) for a in eng._v))
+    decode = head + tuple(av(a) for a in (
+        eng._last_tokens, eng._cur_lens, eng._keys, eng._gen_idx,
+        eng._temps, eng._top_ks, eng._top_ps, eng._active,
+        eng._block_tables))
+    row = lambda a: sds((1,), a.dtype, sharding=one)
+    prefill = head + (sds((1, L), jnp.int32, sharding=one),
+                      row(eng._cur_lens), row(eng._cur_lens),
+                      av(eng._block_tables[:1]), av(eng._keys[0]),
+                      row(eng._temps), row(eng._top_ks), row(eng._top_ps))
+    return {"decode": (eng._decode_pure, decode),
+            "prefill": (eng._prefill_pure, prefill)}
+
+def vocab_sorts(text, V):
+    return [ln.strip()[:120] for ln in text.splitlines()
+            if re.search(r" sort\(", ln)
+            and re.search(rf",{V}\]", ln.split(" sort(")[0])]
+
+toys = {
+    "gpt": (50304, lambda V: GPTModel(GPTConfig(
+        n_layer=1, n_head=2, d_model=128, seq_len=256, vocab_size=V,
+        dtype="bfloat16"))),
+    "xing4": (131072, lambda V: Xing4Model(Xing4Config.preset(
+        "tiny", vocab_size=V, num_hidden_layers=2, dtype="bfloat16"))),
+}
+for fam, (V, build) in toys.items():
+    try:
+        m = build(V)
+        m.eval()
+        eng = GenerationEngine(m, max_batch_size=32, buckets=(64,),
+                               max_seq_len=256, rng_seed=0)
+        for step, (fn, avals) in engine_avals(eng, 64).items():
+            text = jax.jit(fn).trace(*avals).lower(
+                lowering_platforms=("tpu",)).compile().as_text()
+            out[f"sampling_{fam}_{step}"] = {
+                "vocab_sorts": vocab_sorts(text, V),
+                "conditionals": len(re.findall(r" conditional\(", text)),
+                "whiles": len(re.findall(r" while\(", text)),
+                "kernel": eng.paged_kernel}
+    except Exception as e:
+        out[f"sampling_{fam}"] = f"{type(e).__name__}: {e}"[:600]
+
 mesh = Mesh(np.array(devs).reshape(2, 2), ("dp", "mp"))
 lazy.set_spmd_mesh(mesh)
 b = sds((8, 1024, 16, 64), jnp.bfloat16,
@@ -359,7 +450,8 @@ def test_aot_compile_for_v5e():
     assert all(v["custom_calls"] == 1 for k, v in res.items()
                if k.startswith("paged_"))
     _SERVE_LAYERS.update((k, v) for k, v in res.items()
-                         if k.startswith(("serve_layer_", "xing4_")))
+                         if k.startswith(("serve_layer_", "xing4_",
+                                          "sampling_")))
 
 
 _SERVE_LAYERS: dict = {}
@@ -377,6 +469,25 @@ def test_latent_kernel_and_grouped_matmul_compile_for_v5e():
                                                 "collectives": 0}
     assert _SERVE_LAYERS["xing4_grouped_matmul"] == {"custom_calls": 2,
                                                      "collectives": 0}
+
+
+@pytest.mark.parametrize("step", ["decode", "prefill"])
+@pytest.mark.parametrize("family", ["gpt", "xing4"])
+def test_serving_steps_hold_no_sort_over_the_vocabulary_on_v5e(family, step):
+    """`serving_decode` and `serving_prefill` of both engines, at toy depth
+    and the cells' vocabularies (50,304 and 131,072 ids; 32 slots, one
+    prompt), compiled for the described v5e (the AOT child's result of the
+    test above): no `sort` whose operand has the vocabulary as its minor
+    dimension — the parent of PR 31 held two a step, `%sort` over
+    f32[32,131072] being the first device group of the xing4 cell — and
+    each filter's passes (a `while`) behind their own `conditional`."""
+    if not _SERVE_LAYERS:
+        pytest.skip("test_aot_compile_for_v5e did not compile here")
+    got = _SERVE_LAYERS.get(f"sampling_{family}_{step}",
+                            _SERVE_LAYERS.get(f"sampling_{family}"))
+    assert isinstance(got, dict), got
+    assert got["vocab_sorts"] == [], got
+    assert got["conditionals"] >= 2 and got["whiles"] >= 2, got
 
 
 @pytest.mark.parametrize("T", [1, 5])  # decode, spec verify (K+1)
