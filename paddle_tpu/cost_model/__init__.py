@@ -1,9 +1,9 @@
 """paddle.cost_model (reference `python/paddle/cost_model/cost_model.py` +
 `static_op_benchmark.json`): per-op timing data for planners/tuners.
 
-Static cost data here is produced by `tools/op_bench.py` snapshots instead
-of the reference's frozen 2021 CI JSON; `profile_measure` measures a real
-program through the Executor."""
+Static cost data here is a snapshot the caller passes (`static_cost_file=`:
+`{op: {"fwd_ms", "fwd_bwd_ms"}}`) instead of the reference's frozen 2021 CI
+JSON; `profile_measure` measures a real program through the Executor."""
 from __future__ import annotations
 
 import json
@@ -56,16 +56,14 @@ class CostModel:
 
     # ----------------------------------------------------------- static data
     def static_cost_data(self):
-        """Load the op-timing snapshot (tools/op_bench.py --out format)."""
+        """Load the op-timing snapshot passed as ``static_cost_file=``."""
         if self._static_data is None:
-            path = self._static_file or os.path.join(
-                os.path.dirname(os.path.abspath(__file__)),
-                "static_op_benchmark.json")
-            if not os.path.isfile(path):
+            path = self._static_file
+            if path is None or not os.path.isfile(path):
                 raise FileNotFoundError(
-                    f"no op-benchmark snapshot at {path}; generate one with "
-                    "`python tools/op_bench.py --out "
-                    "paddle_tpu/cost_model/static_op_benchmark.json`")
+                    f"no op-timing snapshot at {path!r}: pass "
+                    "CostModel(static_cost_file=<json>) a file of "
+                    '{op: {"fwd_ms": ..., "fwd_bwd_ms": ...}}')
             with open(path) as f:
                 self._static_data = json.load(f)
         return self._static_data

@@ -30,10 +30,9 @@ MANIFEST.json schema (v1)::
 Async saves: `save()` snapshots device buffers to host numpy on the
 caller (train) thread — the only part that must see a consistent
 step boundary — and hands serialization + disk I/O to a single writer
-thread, so the train loop never blocks on storage (the bench.py ratio
-gate runs with this on).  Retention keeps the newest `max_to_keep`
-committed checkpoints; pruning runs on the writer thread after each
-commit and never touches the checkpoint just written.
+thread, so the train loop never blocks on storage.  Retention keeps the
+newest `max_to_keep` committed checkpoints; pruning runs on the writer
+thread after each commit and never touches the checkpoint just written.
 
 Telemetry (PR-3 registry): `checkpoint.saves/async_saves/restores/
 skipped_corrupt/pruned` counters, `checkpoint:save.snapshot/save.write/

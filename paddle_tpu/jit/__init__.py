@@ -12,7 +12,7 @@ into `lax.cond`/`lax.while_loop` conversion calls (runtime-dispatched, so
 eager/python semantics are untouched; unconvertible functions fall back
 unchanged). `TrainStep` compiles forward+backward+optimizer into ONE XLA
 executable — the TPU answer to the reference's per-op executor overhead and
-the engine under bench.py.
+the engine under the benchmark's training cell.
 
 `paddle.jit.save` exports StableHLO via `jax.export` + a params archive —
 the inference-deployment artifact (reference: inference program + params,

@@ -19,7 +19,6 @@ TPU-first design decisions:
 from __future__ import annotations
 
 import math
-import warnings
 
 import numpy as np
 
@@ -27,28 +26,6 @@ from .. import nn, ops
 from ..nn import functional as F
 from ..nn.initializer import Normal
 from ..profiler.spans import scope as _scope
-
-# one-time nudge off the growing-concat KV-cache path (below): it changes
-# the [B, t] cache shapes every generated token, so XLA recompiles the
-# whole decode step per token — serving.GenerationEngine's bucketed slot
-# cache is the shape-stable replacement (compiles once, then replays)
-_legacy_cache_warned = False
-
-
-def _warn_legacy_cache():
-    global _legacy_cache_warned
-    if _legacy_cache_warned:
-        return
-    _legacy_cache_warned = True
-    warnings.warn(
-        "GPTModel's growing-concat KV-cache path (caches= without "
-        "cache_offsets=) concatenates onto the cache, so every generated "
-        "token changes tensor shapes and forces a fresh XLA compile of the "
-        "decode step. For real generation use "
-        "paddle_tpu.serving.GenerationEngine, which preallocates a "
-        "bucketed slot cache and compiles the decode step exactly once.",
-        UserWarning, stacklevel=4)
-
 
 class GPTConfig:
     PRESETS = {
@@ -156,7 +133,7 @@ class GPTAttention(nn.Layer):
         B, T, D = x.shape
         qkv = self.qkv_proj(x).reshape([B, T, 3, self.n_head, self.head_dim])
         q, k, v = ops.unbind(qkv, axis=2)
-        if cache is not None and block_tables is not None:
+        if cache is not None:
             # Paged-cache path (paddle_tpu.serving, ISSUE 10): `cache` is
             # the SHARED fixed-shape block pool in its device form
             # [num_blocks, block_size, H*Dh] (heads merged: the form the
@@ -168,8 +145,9 @@ class GPTAttention(nn.Layer):
             # pool at (block, row) pairs derived from the table — in
             # place when the step donates the pools; no view of the whole
             # pool is formed, on the chip that would be a relayout.
-            # Attention reads each slot's logical view back out and masks
-            # exactly like the contiguous slot path. Block 0 is the
+            # Attention reads each slot's logical view back out under a
+            # causal-by-absolute-position AND valid-length mask, so
+            # neither stale rows nor bucket padding leak in. Block 0 is the
             # reserved garbage block: writes for rows outside
             # [0, seq_len) (bucket padding, inactive decode lanes)
             # redirect there so they can never clobber live blocks.
@@ -208,48 +186,10 @@ class GPTAttention(nn.Layer):
                 training=self.training)
             out = self.out_proj(out.reshape([B, T, D]))
             return out, (new_k, new_v)
-        if cache is not None and cache_offset is not None:
-            # Slot-cache path (paddle_tpu.serving): `cache` is a
-            # preallocated [B, S, H, Dh] buffer; the T new rows are written
-            # in place at per-slot positions cache_offset[b]..+T (a
-            # dynamic_update_slice-style scatter — fixed shapes, so the
-            # whole step compiles once), and attention reads the full
-            # buffer under a causal-by-absolute-position AND valid-length
-            # mask so neither stale slot rows nor bucket padding leak in.
-            k_buf, v_buf = cache
-            S = k_buf.shape[1]
-            rows = cache_offset.unsqueeze(1) + ops.arange(0, T,
-                                                          dtype="int32")
-            idx = ops.broadcast_to(
-                rows.unsqueeze(-1).unsqueeze(-1),
-                [B, T, self.n_head, self.head_dim])
-            k_buf = ops.put_along_axis(k_buf, idx, k, axis=1)
-            v_buf = ops.put_along_axis(v_buf, idx, v, axis=1)
-            jpos = ops.arange(0, S, dtype="int32")
-            mask = ops.logical_and(
-                jpos.unsqueeze(0).unsqueeze(0) <= rows.unsqueeze(-1),
-                jpos.unsqueeze(0).unsqueeze(0)
-                < seq_lens.unsqueeze(-1).unsqueeze(-1))
-            out = F.scaled_dot_product_attention(
-                q, k_buf, v_buf, attn_mask=mask.unsqueeze(1),
-                is_causal=False, dropout_p=self.dropout_p,
-                training=self.training)
-            out = self.out_proj(out.reshape([B, T, D]))
-            return out, (k_buf, v_buf)
-        if cache is not None:
-            k = ops.concat([cache[0], k], axis=1)
-            v = ops.concat([cache[1], v], axis=1)
-            new_cache = (k, v)
-            out = F.scaled_dot_product_attention(
-                q, k, v, is_causal=False, dropout_p=self.dropout_p,
-                training=self.training)
-        else:
-            new_cache = None
-            out = F.scaled_dot_product_attention(
-                q, k, v, is_causal=True, dropout_p=self.dropout_p,
-                training=self.training)
-        out = self.out_proj(out.reshape([B, T, D]))
-        return out if new_cache is None else (out, new_cache)
+        out = F.scaled_dot_product_attention(
+            q, k, v, is_causal=True, dropout_p=self.dropout_p,
+            training=self.training)
+        return self.out_proj(out.reshape([B, T, D]))
 
 
 class GPTMLP(nn.Layer):
@@ -361,8 +301,13 @@ class GPTModel(nn.Layer):
     def forward(self, input_ids, position_ids=None, caches=None,
                 cache_offsets=None, seq_lens=None, block_tables=None,
                 paged_kernel=None, paged_mesh=None):
-        if caches is not None and cache_offsets is None:
-            _warn_legacy_cache()
+        if caches is not None and block_tables is None:
+            raise TypeError(
+                "GPTModel: caches= are the paged KV pools of "
+                "paddle_tpu.serving.GenerationEngine and need "
+                "block_tables= (with cache_offsets= and seq_lens=); there "
+                "is no other cache form. Generate through GenerationEngine "
+                "or GenerationServer")
         x = self.embeddings(input_ids, position_ids)
         if caches is not None:
             new_caches = []

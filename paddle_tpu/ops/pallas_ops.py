@@ -51,8 +51,7 @@ _paged_counters = _registry.scoped_counters("serving", {
     "kernel.pallas": 0, "kernel.xla": 0, "kernel.interpret": 0,
     "kernel.fallbacks": 0})
 _flash_counters = _registry.scoped_counters("kernel", {
-    "flash.pallas": 0, "flash.stock": 0, "flash.xla": 0,
-    "flash.fallbacks": 0})
+    "flash.pallas": 0, "flash.xla": 0, "flash.fallbacks": 0})
 
 
 def _note_kernel_fallback(family, reason, **detail):
@@ -371,49 +370,6 @@ def _attention_xla(q, k, v, mask=None, causal=False, scale=None):
     return jnp.einsum("bnqk,bknh->bqnh", probs, v)
 
 
-def _stock_flash():
-    """Opt-in (PADDLE_TPU_STOCK_FLASH=1): jax's library TPU flash-attention
-    kernel. Profiled on this v5e it is NOT faster than the in-repo kernel
-    (its bwd dkv/dq kernels measured 868ms vs our jvp's 203ms per 5
-    gpt2-medium steps), so the in-repo kernel stays the default; the flag
-    exists for future jaxlib/Mosaic versions. Constraints: its index maps
-    need PADDLE_TPU_X64=0 and Mosaic rejects its bf16 dots under matmul
-    precision "highest"."""
-    if not _env_flag("PADDLE_TPU_STOCK_FLASH"):
-        return None
-    if jax.config.jax_enable_x64:
-        return None
-    if jax.config.jax_default_matmul_precision == "highest":
-        return None  # Mosaic rejects the kernel's bf16 dots at HIGHEST
-    try:
-        from jax.experimental.pallas.ops.tpu import flash_attention as fa
-
-        return fa
-    except ImportError:  # pragma: no cover
-        return None
-
-
-def _flash_block_env(name, default, T):
-    """``PADDLE_TPU_FLASH_BLOCK_Q/K`` override, honored only when it is a
-    positive divisor of the sequence length (a partial block would
-    silently drop tail rows)."""
-    import warnings
-
-    raw = os.environ.get(name)
-    if raw is None:
-        return default
-    try:
-        val = int(raw)
-    except ValueError:
-        warnings.warn(f"{name}={raw!r} is not an int; using {default}")
-        return default
-    if val <= 0 or T % val:
-        warnings.warn(f"{name}={val} does not divide seq_len {T}; using "
-                      f"{default}")
-        return default
-    return val
-
-
 def _flash_shape_refusal(q, k, mask):
     """Why the Pallas flash kernel cannot take this call (None = it can)."""
     T, H = q.shape[1], q.shape[3]
@@ -468,7 +424,7 @@ def flash_attention(q, k, v, mask=None, causal=False, scale=None):
     """[B, T, N, H] attention; Pallas on TPU when tileable, XLA otherwise.
     Under an installed SPMD mesh the kernel runs per shard (see
     :func:`_flash_mesh_spec`)."""
-    B, T, N, H = q.shape
+    B, T, N, _ = q.shape
     on_tpu = _on_tpu()
     why = _flash_shape_refusal(q, k, mask) if on_tpu else "not on tpu"
     spec = None
@@ -476,24 +432,10 @@ def flash_attention(q, k, v, mask=None, causal=False, scale=None):
     if mesh is not None:
         spec, why = _flash_mesh_spec(mesh, B, N)
     if why is None:
-        fa = _stock_flash()
-        if fa is not None:
-            _flash_counters["flash.stock"] += 1
-            sm_scale = float(scale) if scale is not None else H ** -0.5
-
-            def kernel(q, k, v):  # library kernel layout is [B, N, T, H]
-                out = fa.flash_attention(
-                    q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
-                    v.transpose(0, 2, 1, 3), causal=causal,
-                    sm_scale=sm_scale)
-                return out.transpose(0, 2, 1, 3)
-        else:
-            _flash_counters["flash.pallas"] += 1
-            blk = 256 if T % 256 == 0 else 128
-            kernel = functools.partial(
-                _flash_attention_tpu, causal=causal, scale=scale,
-                block_q=_flash_block_env("PADDLE_TPU_FLASH_BLOCK_Q", blk, T),
-                block_k=_flash_block_env("PADDLE_TPU_FLASH_BLOCK_K", blk, T))
+        _flash_counters["flash.pallas"] += 1
+        blk = 256 if T % 256 == 0 else 128
+        kernel = functools.partial(_flash_attention_tpu, causal=causal,
+                                   scale=scale, block_q=blk, block_k=blk)
         if spec is not None:
             kernel = jax.shard_map(kernel, mesh=mesh, in_specs=(spec,) * 3,
                                    out_specs=spec, check_vma=False)

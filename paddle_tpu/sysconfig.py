@@ -48,8 +48,8 @@ def ensure_native_built(lib_name=None):
 
 def enable_compile_cache():
     """Turn on JAX's persistent compilation cache for an entry-point script
-    (chip_smoke.py, bench.py) and return its directory. One rule: where
-    ``JAX_COMPILATION_CACHE_DIR`` is set the cache is placed from outside —
+    (chip_smoke.py, benchmark/run.py) and return its directory. One rule:
+    where ``JAX_COMPILATION_CACHE_DIR`` is set the cache is placed from outside —
     jax has already read it, and nothing is set in code; otherwise it goes
     to ``<checkout>/.jax_cache``, a fixed path (never derived from tempfile,
     a pid or the clock — a cache that moves never hits). Library entry

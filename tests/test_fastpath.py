@@ -142,6 +142,48 @@ class TestReplayStep:
         assert dispatch.ops_dispatched() == d0
         assert _fp()["ops_dispatched_per_step"] == 0
 
+    def test_replay_window_holds_with_tracing_and_a_checkpoint_interval(
+            self, tmp_path):
+        """The steady window's obligations, with span tracing armed and an
+        async checkpoint handed to the writer thread every 5th step: every
+        step of the window is a fast-path hit that dispatches no op, is
+        captured and donated, and nothing demotes."""
+        from paddle_tpu.incubate import checkpoint as ckpt
+        from paddle_tpu.profiler import tracing
+
+        x, y = _data()
+        xt, yt = paddle.to_tensor(x), paddle.to_tensor(y)
+        net, opt = _make()
+        step = lazy.ReplayStep(lambda: _body(net, opt, xt, yt),
+                               optimizers=opt, audit_every=100)
+        mgr = ckpt.CheckpointManager(str(tmp_path), max_to_keep=2,
+                                     async_save=True)
+        was_on = tracing.enabled()
+        tracing.enable()
+        try:
+            for _ in range(25):
+                float(step())
+            assert step.armed
+            window = 20
+            s0, c0, d0 = lazy.stats(), _fp(), dispatch.ops_dispatched()
+            for i in range(1, window + 1):
+                float(step())
+                if i % 5 == 0:
+                    mgr.save(ckpt.capture_training_state(net, opt), step=i)
+            s1, c1, d1 = lazy.stats(), _fp(), dispatch.ops_dispatched()
+            mgr.wait()
+        finally:
+            if not was_on:
+                tracing.disable()
+        assert c1["hits"] - c0["hits"] == window
+        assert c1["misses"] == c0["misses"]
+        assert c1["replay_ops_dispatched"] == c0["replay_ops_dispatched"]
+        assert d1 == d0
+        assert c1["demotions"] == c0["demotions"]
+        assert s1["captured_steps"] - s0["captured_steps"] == window
+        assert s1["donated_steps"] - s0["donated_steps"] == window
+        assert ckpt.list_steps(str(tmp_path)) == [15, 20]
+
     def test_mutate_signature_caught_by_audit(self):
         """A perturbation the per-step fingerprint cannot see (a pinned
         leaf VALUE — identity and aval unchanged) is caught by the

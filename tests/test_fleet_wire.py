@@ -19,6 +19,7 @@ Unit coverage for the framed KV transport and its chaos layer:
     replayed, never to a caller-visible error.
 """
 import io
+import socket
 import threading
 import time
 
@@ -229,17 +230,23 @@ class TestLoopback:
 
     def test_budget_exhaustion_raises_not_fakes(self):
         # a dead destination: every attempt fails, DataPlaneError after
-        # the bounded budget — the caller owns the fallback
-        lis = wire.DataPlaneListener(lambda *a: None)
-        host, port = lis.host, lis.port
-        lis.close()
-        time.sleep(0.05)
+        # the bounded budget — the caller owns the fallback. The port is
+        # HELD for the whole test by a socket that is bound and never
+        # listens: a listener's port, once given back, goes to the next
+        # port-0 bind of any process on the machine (another test
+        # worker's pod listener took the payload and acknowledged it)
+        dead = socket.socket(socket.AF_INET, socket.SOCK_STREAM)
+        dead.bind(("127.0.0.1", 0))
+        host, port = dead.getsockname()
         snd = wire.FrameSender(host, port, connect_timeout=0.2,
                                attempt_timeout=0.3, retries=1,
                                backoff=0.01)
-        with pytest.raises(wire.DataPlaneError):
-            snd.send_payload("r2", self._payload(), deadline=1.5)
-        snd.close()
+        try:
+            with pytest.raises(wire.DataPlaneError):
+                snd.send_payload("r2", self._payload(), deadline=1.5)
+        finally:
+            snd.close()
+            dead.close()
 
     def test_corrupt_frames_counted_never_decoded(self):
         before = dict(wire.stats())

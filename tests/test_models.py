@@ -46,26 +46,33 @@ class TestGPT:
         assert losses[-1] < losses[0]
 
     def test_kv_cache_decode(self):
+        """Incremental equals full, through the one cache form the model
+        has: paged pools (ops/kv_pool.py) under an identity block table,
+        a 5-token prefill and then a token a step."""
+        import jax.numpy as jnp
+
         from paddle_tpu.models import gpt_tiny
 
         rng = np.random.default_rng(1)
         m = gpt_tiny(vocab_size=64)
         m.eval()
         toks = _ids(rng, 64, (1, 8))
+        bs, M = 4, 2
+        ks, vs = m.gpt.kv_cache_spec().allocate(1 + M, bs, jnp.float32)
+        caches = [(paddle.Tensor(k), paddle.Tensor(v))
+                  for k, v in zip(ks, vs)]
+        table = paddle.to_tensor(np.arange(1, 1 + M, dtype=np.int32)[None])
+        w = m.gpt.embeddings.word_embeddings.weight
         with paddle.no_grad():
             full = m(toks)
-            caches = [None] * len(m.gpt.blocks)
-            caches = [(paddle.zeros([1, 0, blk.attn.n_head,
-                                     blk.attn.head_dim]),
-                       paddle.zeros([1, 0, blk.attn.n_head,
-                                     blk.attn.head_dim]))
-                      for blk in m.gpt.blocks]
             outs = []
-            for t in range(8):
-                pos = paddle.to_tensor(np.array([[t]], np.int64))
-                x, caches = m.gpt(toks[:, t:t + 1], position_ids=pos,
-                                  caches=caches)
-                w = m.gpt.embeddings.word_embeddings.weight
+            for lo, hi in [(0, 5), (5, 6), (6, 7), (7, 8)]:
+                pos = paddle.to_tensor(np.arange(lo, hi, dtype=np.int64)[None])
+                x, caches = m.gpt(
+                    toks[:, lo:hi], position_ids=pos, caches=caches,
+                    cache_offsets=paddle.to_tensor(np.array([lo], np.int32)),
+                    seq_lens=paddle.to_tensor(np.array([hi], np.int32)),
+                    block_tables=table)
                 outs.append(paddle.matmul(x, w, transpose_y=True))
             inc = paddle.concat(outs, axis=1)
         np.testing.assert_allclose(full.numpy(), inc.numpy(), rtol=2e-2,

@@ -423,7 +423,7 @@ class TestStatsDumpCLI:
         assert stats_dump.main([str(p)]) == 0
         out = capsys.readouterr().out
         assert "fwd" in out and "lazy.cache_hits" in out
-        # telemetry JSONL form (bench.py output)
+        # telemetry JSONL form
         p2 = tmp_path / "t.log"
         p2.write_text('garbage\n' + json.dumps(
             {"metric": "telemetry", "counters": {"a.b": 1},

@@ -13,7 +13,6 @@ Covers the acceptance gates:
 """
 import threading
 import time
-import warnings
 
 import numpy as np
 import pytest
@@ -545,36 +544,28 @@ class TestSampling:
 
 
 class TestLegacyCachePath:
-    def test_growing_concat_cache_warns_once(self):
-        from paddle_tpu.models import gpt as gpt_mod
-
+    def test_caches_without_block_tables_is_a_type_error(self):
+        """The paged pools are the model's one cache form: a call that
+        hands it a growing or a per-slot contiguous cache is told where
+        generation lives."""
         m = _build_model(seed=3)
         toks = paddle.to_tensor(
             np.random.default_rng(0).integers(
                 1, VOCAB, (1, 4)).astype(np.int64))
-        caches = [(paddle.zeros([1, 0, blk.attn.n_head,
-                                 blk.attn.head_dim]),
-                   paddle.zeros([1, 0, blk.attn.n_head,
-                                 blk.attn.head_dim]))
-                  for blk in m.gpt.blocks]
-        gpt_mod._legacy_cache_warned = False
+        blk = m.gpt.blocks[0].attn
+        grown = [(paddle.zeros([1, 0, blk.n_head, blk.head_dim]),) * 2
+                 for _ in m.gpt.blocks]
+        slots = [(paddle.zeros([1, 8, blk.n_head, blk.head_dim]),) * 2
+                 for _ in m.gpt.blocks]
+        zero = paddle.to_tensor(np.zeros([1], np.int32))
         with paddle.no_grad():
-            with warnings.catch_warnings(record=True) as rec:
-                warnings.simplefilter("always")
-                _, caches = m.gpt(toks[:, :1], caches=caches)
-            hits = [w for w in rec
-                    if "serving.GenerationEngine" in str(w.message)]
-            assert len(hits) == 1
-            assert "compile" in str(hits[0].message)
-            # one-time: the next decode step stays quiet
-            with warnings.catch_warnings(record=True) as rec2:
-                warnings.simplefilter("always")
-                m.gpt(toks[:, 1:2],
-                      position_ids=paddle.to_tensor(
-                          np.asarray([[1]], np.int64)),
-                      caches=caches)
-            assert not [w for w in rec2
-                        if "serving.GenerationEngine" in str(w.message)]
+            for kw in (dict(caches=grown),
+                       dict(caches=slots, cache_offsets=zero,
+                            seq_lens=zero + 1)):
+                with pytest.raises(TypeError) as e:
+                    m.gpt(toks[:, :1], **kw)
+                assert "block_tables=" in str(e.value)
+                assert "GenerationEngine" in str(e.value)
 
 
 # =========================================================================

@@ -445,3 +445,30 @@ class TestFleetIntegration:
         assert st["router"]["handoffs"] >= 3
         assert st["pods"][0]["handoff_exports"] >= 3
         assert st["pods"][1]["handoff_imports"] >= 3
+
+    def test_binary_plane_carries_every_handoff(self, fleet_factory):
+        """The default data plane streams EVERY prefill->decode KV bundle
+        pod to pod: no handoff rides back inline as JSON, nothing fails,
+        and the traffic adds no decode compile after the warm-up."""
+        fleet = fleet_factory(roles=["prefill", "decode"])
+        assert fleet.data_plane == "binary"
+        fleet.generate([9, 8, 7], max_new_tokens=4,
+                       result_timeout=_timeout(180))  # warm both pods
+        f0 = dict(registry.counters("fleet"))
+        compiles0 = {p: d.get("decode_compiles")
+                     for p, d in fleet.stats()["pods"].items()}
+        rng = np.random.default_rng(9)
+        reqs = [fleet.submit([int(t) for t in rng.integers(1, VOCAB, 3 + i)],
+                             max_new_tokens=6) for i in range(6)]
+        for r in reqs:
+            r.result(_timeout(180))
+        assert [r.status for r in reqs] == ["done"] * 6
+        st = fleet.stats()
+        f1 = registry.counters("fleet")
+        assert f1["handoffs_binary"] - f0["handoffs_binary"] == 6
+        assert f1["handoffs_fallback"] == f0["handoffs_fallback"]
+        assert f1["handoff_bytes"] > f0["handoff_bytes"]
+        assert f1["requests_failed"] == f0["requests_failed"]
+        assert st["data_plane"]["tx_bytes"] > 0
+        assert {p: d.get("decode_compiles")
+                for p, d in st["pods"].items()} == compiles0

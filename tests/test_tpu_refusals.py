@@ -85,10 +85,13 @@ class TestEntryPointsRefuseOffChip:
         assert account["legs"]["train-4"] == "not run (1 chips)"
 
     def test_bench(self):
-        r = _run(["bench.py"])
+        # the benchmark the driver runs: a cell off the chip refuses, names
+        # the platform it found and prints no result line
+        r = _run(["benchmark/run.py", "--workload", "gpt2m_train_bs8_s1024",
+                  "--seed", "1", "--seconds", "30"])
         assert r.returncode != 0
-        assert "JAX found platform 'cpu'" in r.stderr
-        assert "degraded" not in r.stdout and "cached" not in r.stdout
+        assert "needs a TPU; JAX found platform 'cpu'" in r.stderr
+        assert r.stdout.strip() == ""
 
     def test_set_device_tpu(self):
         with pytest.raises(RuntimeError, match="platform is 'cpu'"):

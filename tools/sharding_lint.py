@@ -32,9 +32,10 @@ Checks:
     mesh-complete fast path removed.
 
 Pure stdlib on purpose — no paddle_tpu / jax import, so it lints a
-dumped JSON anywhere (CI box, laptop). bench.py --spmd calls `lint()`
-in-process on the live description and reports problems as warnings;
-the CLI exits 1 when problems are found.
+dumped JSON anywhere (CI box, laptop). The tests call `lint()` in-process
+on the live description (tests/test_spmd.py, test_spmd_pp.py,
+test_moe.py, test_paged_kernel.py); the CLI exits 1 when problems are
+found.
 
 Usage:
     python tools/sharding_lint.py plan.json

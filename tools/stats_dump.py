@@ -4,8 +4,7 @@
 Accepts any of:
   * a chrome-trace JSON exported by `profiler.export_chrome_tracing`
     (host spans + embedded telemetry snapshot),
-  * a bench.py log / JSONL stream containing `{"metric": "telemetry"}`
-    lines,
+  * a log / JSONL stream containing `{"metric": "telemetry"}` lines,
   * a bare counters/snapshot JSON dict.
 
 Pure stdlib on purpose — no paddle_tpu / jax import, so it runs anywhere
