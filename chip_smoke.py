@@ -601,10 +601,11 @@ def leg_kernels(ctx, refs):
         B, M = 4, 8
         nb = 1 + B * M
         # head_dim 64 and 128 in both dtypes at decode (T=1) and verify
-        # (T=K+1) spans, plus one geometry no tile size divides
+        # (T=K+1) spans, plus one head width no tile size divides (16 x 80
+        # = 1280 lanes: the merged row has to be whole 128-lane tiles)
         cases = [(dh, 16, 16, dt, T) for dh in (64, 128)
                  for dt in (jnp.bfloat16, jnp.float32) for T in (1, 5)]
-        cases.append((80, 12, 8, jnp.float32, 3))
+        cases.append((80, 16, 8, jnp.float32, 3))
         for dh, H, bs, dt, T in cases:
             q = jnp.asarray(rng.normal(size=(B, T, H, dh)), dt)
             kp = jnp.asarray(rng.normal(size=(nb, bs, H, dh)), dt)

@@ -459,20 +459,26 @@ def flash_attention(q, k, v, mask=None, causal=False, scale=None):
 
 # =========================== paged attention =================================
 #
-# Decode-path fused paged attention (ISSUE 14). The serving engine's paged
-# KV cache (PR 9) stores every slot's KV in a shared fixed-shape block pool
-# [num_blocks, block_size, H*Dh] (heads merged into the last axis: the form
-# the chip stores row by row, ops/kv_pool.py) addressed through per-slot
-# int32 block tables. The XLA path materializes a gathered [B, M*bs, H, Dh]
-# view of the pool and runs masked attention over it — two HBM round-trips
-# XLA cannot fuse. The Pallas kernel below walks the block table INSIDE the
-# kernel (vLLM PagedAttention / jax TPU paged_attention reference style): the
-# tables, lengths and query offsets ride scalar prefetch
-# (pltpu.PrefetchScalarGridSpec), so each grid step's BlockSpec index map
-# picks the one physical KV block that program needs and the pipeline DMAs
-# exactly that block HBM->VMEM. No gathered view ever exists. Inside the
-# block a head is addressed by its 128-lane tile, never shifted out of it
-# (`_paged_attn_kernel`, `_heads_per_lane_tile`).
+# Decode-path fused paged attention (ISSUE 14; the walk and the body of
+# PR 33). The serving engine's paged KV cache (PR 9) stores every slot's KV
+# in a shared fixed-shape block pool [num_blocks, block_size, H*Dh] (heads
+# merged into the last axis: the form the chip stores row by row,
+# ops/kv_pool.py) addressed through per-slot int32 block tables. The XLA
+# path materializes a gathered [B, M*bs, H, Dh] view of the pool and runs
+# masked attention over it — two HBM round-trips XLA cannot fuse. The Pallas
+# kernel below walks the block table INSIDE the kernel: the tables, lengths
+# and query offsets ride scalar prefetch (pltpu.PrefetchScalarGridSpec), the
+# pools stay in HBM (pl.ANY), and program (b, j) copies the live blocks of
+# its span of G consecutive table columns into VMEM itself, one DMA a
+# block, one live span ahead of the fold (`_paged_attn_kernel`). No gathered
+# view ever exists, a dead table column costs neither a DMA nor a program's
+# bookkeeping for an operand, and all heads of a span are folded by two
+# dots (`_paged_plan` sizes the span and the head groups from the geometry).
+# Measured on the v5e (PERF.md, PR 33): a BlockSpec operand costs ~0.07 us
+# of the pipeline's scalar bookkeeping a grid step whether its block moves
+# or not, so B x M x 2 block operands were 0.66 ms a call at the chat
+# cell's shapes however many keys a program folded; with its own copies the
+# kernel runs at 84-92 % of the HBM time of the rows it reads.
 #
 # One kernel serves both consumers:
 #   * decode:      q is a [B, 1, H, Dh] span (T=1), q_offsets = cursors;
@@ -497,85 +503,245 @@ def flash_attention(q, k, v, mask=None, causal=False, scale=None):
 # accumulation-order delta).
 
 # per-dtype |fused - xla| bounds (atol, rtol): fp32 differs only by
-# f32 reduction order; bf16 additionally keeps probabilities in f32
-# where the XLA path rounds them to bf16 before the PV matmul
+# f32 reduction order; bf16 additionally keeps 16 bits of a probability
+# where the XLA path rounds it to bf16 before the PV matmul
 PAGED_PARITY_TOL = {"float32": (3e-5, 3e-5), "bfloat16": (0.05, 0.05)}
 
 
-def _paged_attn_kernel(bt_ref, sl_ref, qo_ref, q_ref, k_ref, v_ref, o_ref,
-                       m_scr, l_scr, acc_scr, *, scale, block_size,
-                       precision, heads_per_tile):
-    """Grid (B, M): program (b, j) folds logical block j of slot b into
-    the slot's online-softmax state. Scratch (m/l/acc) persists across
-    the M dimension; the output block is written once, at the last j.
+def _paged_attn_kernel(bt_ref, sl_ref, qo_ref, q_ref, k_hbm, v_hbm, o_ref,
+                       k_buf, v_buf, sem, turn, qbd_scr, m_scr, l_scr,
+                       acc_scr, *, scale, head_dim, lanes, precision):
+    """Grid (B, ceil(M / G)): program (b, j) folds the G consecutive
+    logical blocks ``j*G .. j*G+G-1`` of slot b (``G * block_size`` keys)
+    into the slot's online-softmax state, for all heads at once. Scratch
+    persists across the grid; the output block is written once, at the
+    last j.
 
-    Head h of the merged ``[bs, H*Dh]`` block is taken out by the lane
-    tile it lives in, never by a lane shift: the ``W = heads_per_tile *
-    Dh`` lanes ``g*W:(g+1)*W`` (a whole 128-lane tile when ``Dh`` divides
-    128) are the operand of both dots, and head h's query ``q_ref[:, h]``
-    comes in ``[T, W]`` with zeros on its tile-mates' lanes, so the
-    contraction picks head h's keys; ``p @ v`` then holds head h's output
-    on its own lanes (the rest is dropped by the caller). With
-    ``heads_per_tile`` 1 this is a plain slice of ``Dh`` lanes."""
+    The walk. The pools stay in HBM; a program copies exactly the live
+    blocks of its span into one half of ``k_buf`` / ``v_buf``
+    ``[2, G, block_size, H*Dh]``, one DMA a block, and the copies run one
+    live program ahead: before it waits for its own blocks a program
+    starts the copies of the next span that has any (the next of its
+    slot, else the first of the next slot), into the other half. A
+    program past its slot's last live block starts nothing, waits for
+    nothing and folds nothing. ``turn`` holds which half the next live
+    program reads.
+
+    The body. The merged axis is taken in groups of ``lanes`` lanes (whole
+    128-lane tiles holding whole heads; all of it in the serving cells). A
+    group's heads meet the span in ONE dot: its query block holds head h's
+    query on row h, on head h's own lanes and zeros on the rest
+    (``qbd_scr``, built once a slot), so ``qbd @ K.T`` is every head's
+    scores ``[heads, span]`` lane-dense, the max and the sum run along
+    the span, and ``p @ V`` holds head h's output on row h under head h's
+    lanes (the finalizer keeps those). No head is sliced, rotated or
+    addressed. A verify span's T rows are T such row blocks (M of the
+    dots), each masked to its own position."""
     b = pl.program_id(0)
     j = pl.program_id(1)
-    T, H, W = q_ref.shape
-    bs = jnp.int32(block_size)
-    scale = _f32(scale)
+    _, G, block_size, HD = k_buf.shape
+    T = q_ref.shape[0]
+    n_groups = HD // lanes
+    Hp = qbd_scr.shape[1] // T  # a group's heads, padded to whole sublanes
+    span = G * block_size
+    i32 = jnp.int32
     dot = functools.partial(jnp.dot, preferred_element_type=jnp.float32,
                             precision=precision)
     dot_nt = functools.partial(  # q @ k.T without forming k.T
         jax.lax.dot_general, dimension_numbers=(((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32, precision=precision)
+    # own[h, c]: lane c of the group belongs to head h of the group (rows
+    # past the group's heads own nothing: zero queries, dropped outputs)
+    head = jax.lax.broadcasted_iota(i32, (Hp, lanes), 0)
+    lane = jax.lax.broadcasted_iota(i32, (Hp, lanes), 1)
+    own = ((lane >= head * i32(head_dim))
+           & (lane < (head + i32(1)) * i32(head_dim)))
+
+    def limit_of(slot):  # highest key position a row of `slot` reads, excl.
+        return jnp.minimum(qo_ref[slot] + i32(T), sl_ref[slot])
+
+    def blocks_of(slot):
+        # table columns the slot's rows read; its first span always walks
+        # one (an inactive lane: the reserved block 0, finite garbage)
+        return jnp.maximum(pl.cdiv(limit_of(slot), i32(block_size)), i32(1))
+
+    def copies(half, g, blk=_i0()):
+        return (pltpu.make_async_copy(k_hbm.at[blk], k_buf.at[half, g],
+                                      sem.at[half, _i0()]),
+                pltpu.make_async_copy(v_hbm.at[blk], v_buf.at[half, g],
+                                      sem.at[half, i32(1)]))
+
+    def fetch(slot, first, half):  # start: blocks first.. of slot's span
+        def start(g, _):
+            for c in copies(half, g, bt_ref[slot, first + g]):
+                c.start()
+            return _
+        jax.lax.fori_loop(
+            _i0(), jnp.minimum(blocks_of(slot) - first, i32(G)), start, _i0())
+
+    n_blk = blocks_of(b)
+    limit = limit_of(b)
+
+    @pl.when((b == 0) & (j == 0))
+    def _first():
+        # rows of a half that no copy has reached yet meet p = 0 in the
+        # fold: they must be finite
+        k_buf[...] = jnp.zeros_like(k_buf)
+        v_buf[...] = jnp.zeros_like(v_buf)
+        turn[0] = _i0()
+        fetch(b, _i0(), _i0())
 
     @pl.when(j == 0)
     def _init():
         m_scr[...] = jnp.full_like(m_scr, _NEG_INF)
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
+        for g in range(n_groups):  # static unrolls
+            for t in range(T):
+                qt = q_ref[t:t + 1, g * lanes:(g + 1) * lanes].astype(
+                    jnp.float32)  # the select runs on 32-bit lanes
+                qbd_scr[g, t * Hp:(t + 1) * Hp, :] = jnp.where(
+                    own, qt, _f32(0)).astype(qbd_scr.dtype)
 
-    sl = sl_ref[b]
-    qo = qo_ref[b]
-    # highest key position any span row may read, exclusive
-    limit = jnp.minimum(qo + jnp.int32(T), sl)
+    @pl.when(j * i32(G) < n_blk)
+    def _walk():
+        half = turn[0]
+        more = (j + i32(1)) * i32(G) < n_blk
 
-    @pl.when(j * bs < limit)
-    def _fold():
-        pos = j * bs + jax.lax.broadcasted_iota(
-            jnp.int32, (T, block_size), 1)
-        row = qo + jax.lax.broadcasted_iota(
-            jnp.int32, (T, block_size), 0)
-        mask = (pos <= row) & (pos < sl)
-        for g in range(H // heads_per_tile):  # static unroll
-            kt = k_ref[:, g * W:(g + 1) * W].astype(jnp.float32)
-            vt = v_ref[:, g * W:(g + 1) * W].astype(jnp.float32)
-            for h in range(g * heads_per_tile, (g + 1) * heads_per_tile):
-                # per-head [T, bs] MXU dots
-                qh = q_ref[:, h, :].astype(jnp.float32) * scale
-                s = dot_nt(qh, kt)
-                s = jnp.where(mask, s, _NEG_INF)
-                m_new = jnp.maximum(m_scr[h],
+        @pl.when(more)
+        def _ahead_in_slot():
+            fetch(b, (j + i32(1)) * i32(G), i32(1) - half)
+
+        @pl.when(jnp.logical_not(more) & (b + i32(1) < pl.num_programs(0)))
+        def _ahead_next_slot():
+            fetch(b + i32(1), _i0(), i32(1) - half)
+
+        def wait(g, _):
+            for c in copies(half, g):
+                c.wait()
+            return _
+        jax.lax.fori_loop(
+            _i0(), jnp.minimum(n_blk - j * i32(G), i32(G)), wait, _i0())
+        turn[0] = i32(1) - half
+
+        @pl.when(j * i32(span) < limit)
+        def _fold():
+            k = k_buf[half].reshape(span, HD)
+            v = v_buf[half].reshape(span, HD)
+            pos = j * i32(span) + jax.lax.broadcasted_iota(
+                i32, (T * Hp, span), 1)
+            # row block t reads key positions < min(qo + t + 1, sl)
+            row = jax.lax.broadcasted_iota(i32, (T * Hp, 1), 0)
+            t_of = sum(((row >= i32(t * Hp)).astype(i32)
+                        for t in range(1, T)), jnp.zeros_like(row))
+            mask = pos < jnp.minimum(qo_ref[b] + t_of + i32(1), sl_ref[b])
+            for g in range(n_groups):
+                kg = k[:, g * lanes:(g + 1) * lanes]
+                vg = v[:, g * lanes:(g + 1) * lanes]
+                # the pool's dtype goes to the MXU as it is: the query
+                # unscaled (exact), the scale on the float32 scores
+                s = jnp.where(mask, dot_nt(qbd_scr[g], kg) * _f32(scale),
+                              _NEG_INF)
+                m_new = jnp.maximum(m_scr[g],
                                     s.max(axis=-1, keepdims=True))
                 p = jnp.exp(s - m_new)
-                corr = jnp.exp(m_scr[h] - m_new)
-                l_scr[h] = l_scr[h] * corr + p.sum(axis=-1, keepdims=True)
-                acc_scr[h] = acc_scr[h] * corr + dot(p, vt)
-                m_scr[h] = m_new
+                corr = jnp.exp(m_scr[g] - m_new)
+                l_scr[g] = l_scr[g] * corr + p.sum(axis=-1, keepdims=True)
+                if vg.dtype == jnp.float32:
+                    pv = dot(p, vg)
+                else:
+                    # p as two pool-dtype terms, as many more rows of the
+                    # same dot: a bf16 pool's probabilities keep 16 bits
+                    hi = p.astype(vg.dtype)
+                    lo = (p - hi.astype(jnp.float32)).astype(vg.dtype)
+                    pv = dot(jnp.concatenate([hi, lo], axis=0), vg)
+                    pv = pv[:T * Hp] + pv[T * Hp:]
+                acc_scr[g] = acc_scr[g] * corr + pv
+                m_scr[g] = m_new
 
     @pl.when(j == pl.num_programs(1) - 1)
     def _finalize():
-        l = jnp.maximum(l_scr[...], _TINY)
-        o_ref[...] = (acc_scr[...] / l).transpose(1, 0, 2).astype(
-            o_ref.dtype)
+        for g in range(n_groups):
+            out = acc_scr[g] / jnp.maximum(l_scr[g], _TINY)
+            for t in range(T):
+                o_ref[t:t + 1, g * lanes:(g + 1) * lanes] = jnp.where(
+                    own, out[t * Hp:(t + 1) * Hp], _f32(0)).sum(
+                        axis=0, keepdims=True).astype(o_ref.dtype)
 
 
-def _heads_per_lane_tile(num_heads, head_dim):
-    """How many heads of the merged axis share one 128-lane tile: 128 //
-    head_dim where head_dim divides 128 and the heads fill whole tiles
-    (64-wide heads: 2), else 1 (128-wide heads own their tiles; an odd
-    width is sliced on lanes as it lies)."""
-    per = 128 // head_dim if head_dim < 128 and 128 % head_dim == 0 else 1
-    return per if num_heads % per == 0 else 1
+# What one program of the heads kernel may hold in VMEM: both halves of the
+# span's K and V blocks, the span relaid out for the MXU, and the float32
+# working set (block-diagonal queries, accumulator, one dot's result).
+# Mosaic scopes 16 MiB to a kernel; compiled for the v5e, a 2 MiB block
+# (8 MiB of copy buffers, G = 1) fits and a 4 MiB block ends in
+# RESOURCE_EXHAUSTED (tests/test_tpu_lowering.py).
+_PAGED_VMEM_BUDGET = 12 << 20
+# keys one program folds, lanes one dot contracts and query rows it takes,
+# at most: measured on the v5e at the serving cells' shapes (PERF.md, PR 33:
+# 256 keys beat 128 by 14 % and tie 512; all 2048 lanes in one dot beat
+# four dots of 512 by 5 % at T = 1 and lose 13 % at T = 5's 160 rows)
+_PAGED_MAX_SPAN_KEYS = 256
+_PAGED_MAX_GROUP_LANES = 2048
+_PAGED_MAX_DOT_ROWS = 64
+
+
+def _group_rows(lanes, head_dim, span_rows):
+    """Rows of a head group's query block: ``span_rows`` row blocks of the
+    group's heads, each padded to whole sublanes (8)."""
+    return span_rows * (-(-(lanes // head_dim) // 8) * 8)
+
+
+def _paged_group_lanes(num_heads, head_dim, span_rows=1):
+    """Lanes of the merged axis one dot of the heads kernel contracts:
+    whole 128-lane tiles holding whole heads, the largest such divisor of
+    ``H * Dh`` within ``_PAGED_MAX_GROUP_LANES`` whose query block
+    (:func:`_group_rows`) stays within ``_PAGED_MAX_DOT_ROWS``; all of the
+    axis where heads and tiles
+    never line up (12 x 80: the interpreter only, `paged_tileable`)."""
+    width = num_heads * head_dim
+    unit = math.lcm(head_dim, 128)
+    if width % unit:
+        return width
+    units = width // unit
+    fits = [k for k in range(1, units + 1)
+            if units % k == 0 and k * unit <= _PAGED_MAX_GROUP_LANES
+            and _group_rows(k * unit, head_dim, span_rows)
+            <= _PAGED_MAX_DOT_ROWS]
+    return max(fits, default=1) * unit
+
+
+def _paged_plan(block_size, num_heads, head_dim, dtype, span_rows=1,
+                table_cols=None):
+    """(G, lanes, rows) for one pool geometry: G blocks a program, the
+    lanes of a head group and the rows ``T * Hp`` of its query block. G is
+    what ``_PAGED_VMEM_BUDGET`` holds beside the float32 working set, at
+    most ``_PAGED_MAX_SPAN_KEYS`` keys and the table's columns; 0 when not
+    even one block fits."""
+    item = jnp.dtype(dtype).itemsize
+    lanes = _paged_group_lanes(num_heads, head_dim, span_rows)
+    rows = _group_rows(lanes, head_dim, span_rows)
+    groups = num_heads * head_dim // lanes
+    block = block_size * num_heads * head_dim * item
+    # every group's queries (pool dtype) and accumulator, one group's dot
+    # result and rescaled accumulator
+    work = rows * lanes * (groups * (item + 4) + 8)
+    room = _PAGED_VMEM_BUDGET - work
+    if room < 4 * block:
+        return 0, lanes, rows
+    G = max(1, min(_PAGED_MAX_SPAN_KEYS // block_size, room // (6 * block)))
+    G = 1 << (G.bit_length() - 1)  # whole MXU tiles of keys at block 16
+    if table_cols is not None:
+        G = min(G, table_cols)
+    return int(G), lanes, rows
+
+
+def paged_keys_per_program(block_size, num_heads, head_dim, dtype,
+                           table_cols):
+    """Keys one program of the heads kernel folds at decode (T = 1) for
+    this geometry (``num_heads``: the heads one shard holds): the gauge
+    ``serving.paged_keys_per_program``."""
+    return block_size * max(1, _paged_plan(
+        block_size, num_heads, head_dim, dtype, 1, table_cols)[0])
 
 
 def _paged_attention_fused(q, k_pool, v_pool, block_tables, seq_lens,
@@ -583,60 +749,42 @@ def _paged_attention_fused(q, k_pool, v_pool, block_tables, seq_lens,
     B, T, H, Dh = q.shape
     bs = int(k_pool.shape[1])
     M = int(block_tables.shape[1])
-    G = _heads_per_lane_tile(H, Dh)
-    W = G * Dh
-    if G > 1:
-        # head h's query on its own lanes of the tile, zeros on the rest:
-        # measured on the v5e, slicing 64-lane heads out of the tile costs
-        # a lane rotate for every second head and the kernel 2.42 ms a
-        # call where this form takes 1.51 (PERF.md, PR 28)
-        own = (jnp.arange(H)[:, None] % G == jnp.arange(G)[None])  # [H, G]
-        q = jnp.where(own[None, None, :, :, None], q[:, :, :, None, :],
-                      jnp.zeros((), q.dtype)).reshape(B, T, H, W)
+    G, lanes, rows = _paged_plan(bs, H, Dh, k_pool.dtype, T, M)
+    G = max(G, 1)  # a geometry paged_tileable refuses: Mosaic says why
+    groups = H * Dh // lanes
 
     def q_map(b, j, bt, sl, qo):
-        return (b, _i0(), _i0(), _i0())
-
-    def kv_map(b, j, bt, sl, qo):
-        # clamp the dead tail (blocks past the slot's live length) to the
-        # last LIVE block: the pipeline skips the DMA when consecutive
-        # grid steps map to the same physical block, so padded table rows
-        # cost no HBM traffic — and the fold body is @pl.when-ed off for
-        # them anyway
-        limit = jnp.minimum(qo[b] + jnp.int32(T), sl[b])
-        last = jnp.maximum(pl.cdiv(limit, jnp.int32(bs)) - 1, _i0())
-        return (bt[b, jnp.minimum(j, last)], _i0(), _i0())
+        return (b, _i0(), _i0())
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(B, M),
-        in_specs=[
-            pl.BlockSpec((None, T, H, W), q_map),
-            pl.BlockSpec((None, bs, H * Dh), kv_map),
-            pl.BlockSpec((None, bs, H * Dh), kv_map),
-        ],
-        out_specs=pl.BlockSpec((None, T, H, W), q_map),
+        grid=(B, -(-M // G)),
+        in_specs=[pl.BlockSpec((None, T, H * Dh), q_map),
+                  pl.BlockSpec(memory_space=pl.ANY),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((None, T, H * Dh), q_map),
         scratch_shapes=[
-            pltpu.VMEM((H, T, 1), jnp.float32),  # running max
-            pltpu.VMEM((H, T, 1), jnp.float32),  # running denom
-            pltpu.VMEM((H, T, W), jnp.float32),  # fp32 accumulator
+            pltpu.VMEM((2, G, bs, H * Dh), k_pool.dtype),  # K, two halves
+            pltpu.VMEM((2, G, bs, H * Dh), v_pool.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),  # [half, K | V]
+            pltpu.SMEM((1,), jnp.int32),  # the half the next span reads
+            pltpu.VMEM((groups, rows, lanes), k_pool.dtype),  # queries
+            pltpu.VMEM((groups, rows, 1), jnp.float32),  # running max
+            pltpu.VMEM((groups, rows, 1), jnp.float32),  # running denom
+            pltpu.VMEM((groups, rows, lanes), jnp.float32),  # accumulator
         ],
     )
     out = pl.pallas_call(
-        functools.partial(_paged_attn_kernel, scale=scale, block_size=bs,
-                          precision=_dot_precision(q.dtype),
-                          heads_per_tile=G),
+        functools.partial(_paged_attn_kernel, scale=scale, head_dim=Dh,
+                          lanes=lanes,
+                          precision=_dot_precision(k_pool.dtype)),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, T, H, W), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, T, H * Dh), q.dtype),
         interpret=interpret,
         name=_kernel_name("paged_attention"),
     )(block_tables.astype(jnp.int32), seq_lens.astype(jnp.int32),
-      q_offsets.astype(jnp.int32), q, k_pool, v_pool)
-    if G > 1:  # head h's output is on its own lanes of its tile
-        out = jnp.where(own[None, None, :, :, None],
-                        out.reshape(B, T, H, G, Dh),
-                        jnp.zeros((), out.dtype)).sum(axis=3)
-    return out
+      q_offsets.astype(jnp.int32), q.reshape(B, T, H * Dh), k_pool, v_pool)
+    return out.reshape(B, T, H, Dh)
 
 
 def _mesh_mp_degree(mesh):
@@ -742,34 +890,35 @@ def paged_attention(q, k_pool, v_pool, block_tables, seq_lens, q_offsets,
     return out
 
 
-# largest pool block [block_size, H*Dh] the kernel's pipeline fits: K and V,
-# double-buffered, share the 16 MiB of VMEM Mosaic scopes to one kernel with
-# the fp32 scratch. Compiled for the v5e, 2 MiB blocks fit and 4 MiB blocks
-# end in RESOURCE_EXHAUSTED (tests/test_tpu_lowering.py).
-_PAGED_MAX_BLOCK_BYTES = 2 << 20
-
-
 def paged_tileable(head_dim, block_size, dtype, num_heads=None):
     """Will Mosaic compile the kernel for this pool geometry? (The
     interpreter route has no such constraint.) Returns (ok, reason).
 
-    The pool block spans the whole (block_size, H*Dh) minor dims of the
-    pool, so no head_dim or block_size fails to tile: compiled for the v5e,
-    every head_dim in 32..256 x block_size in 4..32 x heads in 1..16 is accepted
-    in fp32 and bf16. What is refused is a dtype the body has no arithmetic
-    for and a block too large for VMEM (judged when ``num_heads`` — the
-    heads one shard holds — is given)."""
+    A program copies whole ``(block_size, H*Dh)`` pool blocks, so no
+    head_dim or block_size fails to tile as long as the merged row is whole
+    128-lane tiles; how many blocks a program folds and how the heads are
+    grouped for its dots come from :func:`_paged_plan`. What is refused is
+    a dtype the body has no arithmetic for and, judged when ``num_heads`` —
+    the heads one shard holds — is given, a merged row that is not whole
+    lane tiles (12 x 80 = 960: Mosaic cannot slice a block of it for the
+    copy) and a geometry of which not one block fits ``_PAGED_VMEM_BUDGET``
+    beside the working set."""
     dt = jnp.dtype(dtype)
     if dt not in (jnp.dtype(jnp.float32), jnp.dtype(jnp.bfloat16)):
         return False, f"pool dtype {dt.name} not in (float32, bfloat16)"
-    if num_heads is not None:
+    if num_heads is not None and num_heads * head_dim % 128:
+        return False, (
+            f"a merged row of {num_heads} x {head_dim} = "
+            f"{num_heads * head_dim} lanes is not whole 128-lane tiles: "
+            "the kernel's block copies need them")
+    if num_heads is not None \
+            and not _paged_plan(block_size, num_heads, head_dim, dt)[0]:
         block = block_size * num_heads * head_dim * dt.itemsize
-        if block > _PAGED_MAX_BLOCK_BYTES:
-            return False, (
-                f"one KV block [{block_size}, {num_heads}, {head_dim}] "
-                f"{dt.name} is {block / 2 ** 20:.1f} MiB; K and V blocks, "
-                "double-buffered, would not fit the kernel's VMEM (limit "
-                f"{_PAGED_MAX_BLOCK_BYTES >> 20} MiB per block)")
+        return False, (
+            f"one KV block [{block_size}, {num_heads}, {head_dim}] "
+            f"{dt.name} is {block / 2 ** 20:.1f} MiB; K and V blocks, "
+            "double-buffered, would not fit the kernel's VMEM beside its "
+            f"working set (budget {_PAGED_VMEM_BUDGET >> 20} MiB)")
     return True, "tileable"
 
 
@@ -873,9 +1022,17 @@ def select_paged_kernel(requested=None, *, head_dim, block_size, dtype,
 # picking one of the program's blocks through the table, so a program is
 # G small DMAs and two dots and the per-program cost is paid once for 128
 # keys. Dead tail blocks clamp to the slot's last live block (no DMA) and
-# the fold is `pl.when`-ed off, as in `paged_attention`.
+# the fold is `pl.when`-ed off. (The heads kernel's walk no longer takes its
+# blocks as operands: ROADMAP S1 says what that is worth here.)
 
 _MLA_KEYS_PER_PROGRAM = 128
+
+
+def mla_keys_per_program(block_size, table_cols):
+    """Keys one program of the latent kernel folds: the gauge
+    ``serving.paged_keys_per_program`` for a latent cache."""
+    return block_size * max(1, min(_MLA_KEYS_PER_PROGRAM // block_size,
+                                   table_cols))
 
 
 def _mla_paged_kernel(bt_ref, sl_ref, q_ref, *refs, scale, block_size,
@@ -926,7 +1083,7 @@ def _mla_paged_fused(q, pool, block_tables, seq_lens, scale, interpret):
     B, H, W = q.shape
     bs = int(pool.shape[1])
     M = int(block_tables.shape[1])
-    G = max(1, min(_MLA_KEYS_PER_PROGRAM // bs, M))
+    G = mla_keys_per_program(bs, M) // bs
 
     def q_map(b, j, bt, sl):
         return (b, _i0(), _i0())

@@ -139,13 +139,16 @@ class FatalEngineError(RuntimeError):
     supervisor can restart it and re-queue its requests."""
 
 
-def _note_pool_layout(pool, cache):
+def _note_pool_layout(pool, cache, keys_per_program):
     """Set the gauge ``serving.kv_pool_row_major`` from the layout the
     device gave a freshly built pool: 1 when it is stored row by row (the
     form the in-place row write and the paged kernel take as it is), 0
     when a jax / libtpu upgrade chose another — then every step pays
     whole-pool relayouts again, and the explainer event says which layout
-    it was. None (no gauge) where jax does not expose the layout."""
+    it was. None (no gauge) where jax does not expose the layout. Beside
+    it the gauge ``serving.paged_keys_per_program``: the keys one program
+    of the engine's paged kernel folds (0: the gather path, no kernel)."""
+    _registry.gauge_set("serving.paged_keys_per_program", keys_per_program)
     order = _kv_pool.device_layout(pool)
     if order is None:
         return None
@@ -154,12 +157,15 @@ def _note_pool_layout(pool, cache):
     _explain.record(
         "kv_pool_layout", op="kv_pool", row_major=bool(row_major),
         cache_kind=cache.kind, row_width=cache.row_width(),
+        paged_keys_per_program=keys_per_program,
         why=(f"{cache.describe()}; pool {tuple(pool.shape)} {pool.dtype} "
              f"is stored "
              f"major-to-minor {order}: "
              + ("row-major, rows are written in place" if row_major else
                 "NOT row-major — row writes and the paged kernel will "
-                "relayout the whole pool every step")))
+                "relayout the whole pool every step")
+             + f"; the paged kernel folds {keys_per_program} keys a "
+             "program"))
     return row_major
 
 
@@ -341,7 +347,19 @@ class GenerationEngine:
                           for i in range(len(self._cache.layers))]
         self._k, self._v = self._cache.allocate(
             self.pool.num_blocks, self.block_size, self._dtype, mesh)
-        self._kv_row_major = _note_pool_layout(self._k[0], self._cache)
+        if self._paged_kernel == "xla":
+            self._paged_keys_per_program = 0
+        elif self._cache.kind == "latent":
+            self._paged_keys_per_program = _pallas_ops.mla_keys_per_program(
+                self.block_size, self.blocks_per_slot)
+        else:
+            shards = _pallas_ops._mesh_mp_degree(self._paged_mesh)
+            self._paged_keys_per_program = \
+                _pallas_ops.paged_keys_per_program(
+                    self.block_size, heads // shards, head_dim, self._dtype,
+                    self.blocks_per_slot)
+        self._kv_row_major = _note_pool_layout(
+            self._k[0], self._cache, self._paged_keys_per_program)
 
         # host-side slot state, mirrored into the decode step as arrays
         B = self.max_batch_size
@@ -1348,6 +1366,7 @@ class GenerationEngine:
                "prefix_cache_nodes": len(self.prefix_cache),
                "weight_generation": self.prefix_cache.generation,
                "kv_pool_row_major": self._kv_row_major,
+               "paged_keys_per_program": self._paged_keys_per_program,
                "kv_cache_kind": self._cache.kind,
                "kv_row_width": self._cache.row_width()}
         if self._mesh is not None:

@@ -17,7 +17,11 @@ bf16 additionally by where probabilities are rounded). Covers:
     choices, including the spec-decode verify span;
   * the zero-post-warmup-compile gate with the kernel layer active (the
     PR 8 replay fingerprint is stable under kernel selection);
-  * the ``kernel_mismatch`` fault provably trips the parity gate.
+  * the ``kernel_mismatch`` fault provably trips the parity gate;
+  * the span walk of PR 33 (a program copies and folds G blocks for all
+    heads): lengths on and off block and span edges against the gather
+    oracle AND a float64 numpy oracle, every head geometry the lowering
+    test compiles, one block a program for a large block.
 
 The parity cases run on a 4-D pool passed to the public op (merged on
 entry) and on the engine's merged pool (PR 28). The compiled kernel is
@@ -147,19 +151,20 @@ class TestKernelParity:
 
 
 class TestHeadsOnLaneTiles:
-    """PR 28: the kernel takes head h out of the merged block by the
-    128-lane tile it lives in (its query zero-padded over the tile), not
-    by a lane shift. Geometries on both sides of that choice: heads that
+    """The kernel takes the merged axis in groups of whole 128-lane tiles
+    holding whole heads and meets a group's heads in one dot (head h's
+    query on row h, on its own lanes, zeros on the rest; PR 33), never by
+    a lane shift. Geometries on every side of that choice: heads that
     share a tile (64-, 32-, 16-wide), heads that own theirs (128), a
     head count that does not fill whole tiles (3 x 64) and a width that
     divides nothing (24), each against the gather oracle."""
 
     @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-    @pytest.mark.parametrize("H,Dh,per_tile", [
-        (2, 64, 2), (4, 64, 2), (4, 32, 4), (8, 16, 8), (2, 128, 1),
-        (3, 64, 1), (2, 24, 1)])
-    def test_parity_and_tile_sharing(self, H, Dh, per_tile, dtype):
-        assert pallas_ops._heads_per_lane_tile(H, Dh) == per_tile
+    @pytest.mark.parametrize("H,Dh,lanes", [
+        (2, 64, 128), (4, 64, 256), (4, 32, 128), (8, 16, 128),
+        (2, 128, 256), (3, 64, 192), (2, 24, 48)])
+    def test_parity_and_head_groups(self, H, Dh, lanes, dtype):
+        assert pallas_ops._paged_group_lanes(H, Dh) == lanes
         B, T, bs, M = 3, 3, 4, 3
         q, kp, vp, bt = _case(B, T, H, Dh, 12, bs, M, dtype=dtype,
                               form="merged", seed=H * Dh)
@@ -186,6 +191,116 @@ class TestHeadsOnLaneTiles:
                                       np.asarray(got[:, :, 0]))
         assert not np.array_equal(np.asarray(base[:, :, 1]),
                                   np.asarray(got[:, :, 1]))
+
+
+def _oracle64(q, kp, vp, bt, sl, qo):
+    """Float64 numpy attention over each slot's gathered rows: key j is
+    valid for row t iff j <= qo + t and j < sl."""
+    q, kp, vp = (np.asarray(a, np.float64) for a in (q, kp, vp))
+    bt, sl, qo = (np.asarray(a) for a in (bt, sl, qo))
+    B, T, H, Dh = q.shape
+    out = np.zeros_like(q)
+    for b in range(B):
+        k = kp[bt[b]].reshape(-1, H, Dh)
+        v = vp[bt[b]].reshape(-1, H, Dh)
+        j = np.arange(k.shape[0])
+        for t in range(T):
+            ok = (j <= qo[b] + t) & (j < sl[b])
+            s = np.einsum("hd,jhd->hj", q[b, t], k) * Dh ** -0.5
+            s = np.where(ok[None], s, -np.inf)
+            p = np.exp(s - s.max(axis=1, keepdims=True))
+            out[b, t] = np.einsum("hj,jhd->hd",
+                                  p / p.sum(axis=1, keepdims=True), v)
+    return out
+
+
+def _against_both(q, kp, vp, bt, sl, qo):
+    """Interpret route against the gather route and the float64 oracle;
+    returns the worst |fused - oracle|."""
+    out = _parity(q, kp, vp, bt, sl, qo)
+    want = _oracle64(q, kp, vp, bt, sl, qo)
+    atol, rtol = pallas_ops.PAGED_PARITY_TOL[jnp.dtype(q.dtype).name]
+    got = np.asarray(out, np.float64)
+    np.testing.assert_allclose(got, want, atol=atol, rtol=rtol)
+    return float(np.abs(got - want).max())
+
+
+class TestSpanWalk:
+    """PR 33: program (b, j) copies the live blocks of its span of G table
+    columns itself, one span ahead, and folds them for all heads in two
+    dots. A small span (2 blocks of 4 keys, set in the test) puts lengths
+    on every side of a block's and a span's edge in a table of 3 spans."""
+
+    @pytest.fixture
+    def span8(self, monkeypatch):
+        monkeypatch.setattr(pallas_ops, "_PAGED_MAX_SPAN_KEYS", 8)
+
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+    @pytest.mark.parametrize("T", [1, 5])
+    @pytest.mark.parametrize("length", [1, 6, 8, 12, 13, 16, 21, 24])
+    def test_lengths_against_both_oracles(self, span8, length, T, dtype):
+        # 1 key; mid-block; a span's edge; a block's edge mid-span;
+        # mid-block mid-span; the second span's edge; the last span; the
+        # full table. Slot 1 is an inactive lane (zeroed table row), slot 2
+        # a neighbour whose copies run ahead of and behind the probe's.
+        bs, M = 4, 6
+        assert pallas_ops._paged_plan(bs, 2, 64, dtype, T, M)[0] == 2
+        q, kp, vp, bt = _case(3, T, 2, 64, 20, bs, M, dtype=dtype,
+                              form="merged", seed=length + T)
+        bt = bt.at[1].set(0)
+        sl = [length, 1, 11]
+        qo = [max(n - T, 0) for n in sl]  # T = 5: offsets mid-span
+        _against_both(q, kp, vp, bt, sl, qo)
+        out = pallas_ops.paged_attention(
+            q, kp, vp, bt, jnp.asarray(sl, jnp.int32),
+            jnp.asarray(qo, jnp.int32), kernel="interpret")
+        assert bool(jnp.isfinite(out).all())
+
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+    @pytest.mark.parametrize("T", [1, 5])
+    @pytest.mark.parametrize("H,Dh", [(32, 64), (16, 128), (12, 80)])
+    def test_head_geometries_at_the_real_span(self, H, Dh, T, dtype):
+        # block 16, 20 table columns: two programs a slot at 16 blocks a
+        # program (three at 8: a float32 row of 2048 lanes is twice the
+        # bytes, and the budget halves the span); lengths end in the first
+        # span, on a span's edge and in the last; q_offsets of the verify
+        # span mid-span
+        bs, M = 16, 20
+        wide_f32 = dtype == jnp.float32 and H * Dh == 2048
+        assert pallas_ops._paged_plan(bs, H, Dh, dtype, T, M)[0] \
+            == (8 if wide_f32 else 16)
+        q, kp, vp, bt = _case(3, T, H, Dh, 61, bs, M, dtype=dtype,
+                              form="merged", seed=H + T)
+        sl = [77, 256, 311]
+        _against_both(q, kp, vp, bt, sl, [n - T for n in sl])
+
+    def test_one_block_a_program_for_a_large_block(self):
+        # 64 heads x 256 x 32 rows of float32: a 2 MiB block, so the
+        # budget leaves a program one block (and refuses the 4 MiB one)
+        H, Dh, bs, M = 64, 256, 32, 3
+        assert pallas_ops._paged_plan(bs, H, Dh, jnp.float32, 1, M)[0] == 1
+        assert pallas_ops._paged_plan(2 * bs, H, Dh, jnp.float32)[0] == 0
+        q, kp, vp, bt = _case(2, 1, H, Dh, 7, bs, M, form="merged", seed=9)
+        _against_both(q, kp, vp, bt, [40, 96], [39, 95])
+
+    def test_error_at_the_chat_cells_geometry_is_no_larger_than_before(self):
+        # gpt3-1.3b's pool (32 x 64 heads, block 16, bf16, 128 table
+        # columns, 8 programs a slot) at lengths the chat cell holds.
+        # PARENT: the 16-keys-a-program body of PR 32 (commit ac7b8f9)
+        # compiled on the chip (TPU v5 lite), this case, this oracle (my
+        # chip run, PR 33). The new body gave 0.000954921 there and gives it
+        # here: its dots take the pool's dtype, so the interpreter rounds
+        # what the chip rounds.
+        PARENT = 0.0012225186840707503
+        lens = [137, 528, 1391]
+        q, kp, vp, bt = _case(3, 1, 32, 64, 3 * 128 + 1, 16, 128,
+                              dtype=jnp.bfloat16, form="merged", seed=33)
+        need = -(-np.asarray(lens) // 16)
+        bt = jnp.where(jnp.arange(128)[None] < need[:, None], bt, 0)
+        assert pallas_ops._paged_plan(16, 32, 64, jnp.bfloat16, 1, 128)[0] \
+            == 16
+        worst = _against_both(q, kp, vp, bt, lens, [n - 1 for n in lens])
+        assert worst <= PARENT, (worst, PARENT)
 
 
 class TestKernelSelection:

@@ -533,6 +533,23 @@ class TestPoolDeviceForm:
         ev = explainer.events(kind="kv_pool_layout")[-1]
         assert ev["row_major"] and "(0, 1, 2)" in ev["why"]
 
+    @pytest.mark.parametrize("kern,keys", [("xla", 0), ("pallas", 64)])
+    def test_gauge_says_the_keys_a_program_of_the_kernel_folds(self, kern,
+                                                              keys):
+        # 2 heads x 24, block 4, 16 table columns: the plan would take 256
+        # keys, the table has 64; the gather path has no program
+        from paddle_tpu.profiler import explainer
+
+        eng = GenerationEngine(_build_model(), max_batch_size=2,
+                               buckets=(8,), block_size=4,
+                               paged_kernel=kern)
+        assert eng.blocks_per_slot == 16
+        assert eng.stats()["paged_keys_per_program"] == keys
+        assert registry.gauges()["serving.paged_keys_per_program"] == keys
+        ev = explainer.events(kind="kv_pool_layout")[-1]
+        assert ev["paged_keys_per_program"] == keys
+        assert f"folds {keys} keys a program" in ev["why"]
+
     def test_gauge_reads_zero_on_another_layout(self, monkeypatch):
         from paddle_tpu.ops import kv_pool
         from paddle_tpu.profiler import explainer
