@@ -1,7 +1,8 @@
 """Flagship model zoo (BASELINE configs): GPT / BERT / ERNIE, and the
-Xing4.0 decoder (MLA + dropless experts + hyper-connections) of the served
-path."""
-from . import bert, ernie, gpt, xing4  # noqa: F401
+Xing4.0 decoder (MLA + dropless experts + hyper-connections) and the
+Cohere2-MoE decoder (grouped-query window and full layers, a parallel
+attention + expert block) of the served path."""
+from . import bert, cohere2_moe, ernie, gpt, xing4  # noqa: F401
 from .bert import (BertConfig, BertForPretraining,  # noqa: F401
                    BertForSequenceClassification, BertModel,
                    BertPretrainingCriterion, bert_base, bert_tiny)
@@ -11,4 +12,5 @@ from .ernie import (ErnieConfig, ErnieForSequenceClassification,  # noqa: F401
 from .gpt import (GPTConfig, GPTForPretraining, GPTModel,  # noqa: F401
                   GPTPretrainingCriterion, gpt2_small, gpt3_1p3b, gpt3_6p7b,
                   gpt_tiny, gpt_tiny_moe)
+from .cohere2_moe import Cohere2MoeConfig, Cohere2MoeModel  # noqa: F401
 from .xing4 import Xing4Config, Xing4Model  # noqa: F401

@@ -50,11 +50,13 @@ def swiglu(x, w_gate, w_up, w_down):
 
 def route_sigmoid_topk(x, w_router, b_select, top_k, scaling, norm=True):
     """(chosen [N, k] int32, weights [N, k] float32): float32 sigmoid
-    scores, the bias added for CHOOSING only, the chosen scores normalised
-    to sum 1 (``norm``) and multiplied by ``scaling``."""
+    scores, the bias (None: the router has none) added for CHOOSING only,
+    the chosen scores normalised to sum 1 (``norm``) and multiplied by
+    ``scaling``."""
     s = jax.nn.sigmoid(jnp.dot(x.astype(jnp.float32),
                                w_router.astype(jnp.float32)))
-    _, chosen = jax.lax.top_k(s + b_select.astype(jnp.float32), top_k)
+    _, chosen = jax.lax.top_k(
+        s if b_select is None else s + b_select.astype(jnp.float32), top_k)
     picked = jnp.take_along_axis(s, chosen, axis=-1)
     if norm:
         picked = picked / (picked.sum(-1, keepdims=True) + 1e-20)
@@ -63,7 +65,10 @@ def route_sigmoid_topk(x, w_router, b_select, top_k, scaling, norm=True):
 
 class DroplessMoE(Layer):
     """forward(x [B, T, d], valid=None) -> y [B, T, d] float32: the held
-    routed experts' part of the layer plus the shared expert. The router
+    routed experts' part of the layer plus the shared experts (``n_shared``
+    of them side by side in one matmul; their outputs summed, or with
+    ``shared_combine="average"`` their mean; ``select_bias=False``: a
+    router that chooses by its scores alone). The router
     scores ``x`` in the precision it comes in (hand it the float32 normed
     input: a choice between near-tied experts is discontinuous, and a bf16
     rounding of the input flips it); the experts read it in their weights'
@@ -76,7 +81,8 @@ class DroplessMoE(Layer):
     def __init__(self, d_model, d_ff, num_experts, top_k, n_shared=1,
                  routed_scaling_factor=1.0, norm_topk_prob=True,
                  experts_held=None, init_std=0.02, bias_std=0.02,
-                 dtype=None):
+                 dtype=None, select_bias=True, shared_combine="sum",
+                 rows_at_a_time=None):
         super().__init__()
         lo, hi = experts_held or (0, num_experts)
         if not 0 <= lo < hi <= num_experts:
@@ -84,11 +90,21 @@ class DroplessMoE(Layer):
                              f"range of the {num_experts} experts")
         if not 1 <= top_k <= num_experts:
             raise ValueError(f"top_k {top_k} of {num_experts} experts")
+        if shared_combine not in ("sum", "average"):
+            raise ValueError(f"shared_combine {shared_combine!r} is not "
+                             "'sum' or 'average'")
+        # n experts side by side give their sum: the mean is that over n
+        self.shared_scale = 1.0 / n_shared \
+            if n_shared and shared_combine == "average" else None
         self.num_experts, self.top_k = int(num_experts), int(top_k)
         self.experts_held = (int(lo), int(hi))
         self.scaling = float(routed_scaling_factor)
         self.norm_topk_prob = bool(norm_topk_prob)
         self.d_ff = int(d_ff)
+        # a long prompt's tokens this many at a time (None: all at once):
+        # the sorted rows and both matmuls' float32 results are N * k rows
+        # whatever share of the experts is held here
+        self.rows_at_a_time = rows_at_a_time
         held = hi - lo
         init = Normal(0.0, init_std)
 
@@ -104,8 +120,9 @@ class DroplessMoE(Layer):
         # chip, not the 87 % of an even load)
         self.router = Layer()
         self.router.weight = param((d_model, num_experts), dt="float32")
-        self.router.bias = param((num_experts,), Normal(0.0, bias_std),
-                                 dt="float32")
+        if select_bias:
+            self.router.bias = param((num_experts,), Normal(0.0, bias_std),
+                                     dt="float32")
         # the held experts, stacked: gate and up side by side, so a row
         # meets its expert's first matmul once
         self.experts = Layer()
@@ -125,11 +142,21 @@ class DroplessMoE(Layer):
     def forward(self, x, valid=None):
         xa = x._data if isinstance(x, Tensor) else x
         B, T, d = xa.shape
-        y, self.last_experts_hit = self._compute(
-            xa.reshape(B * T, d),
-            None if valid is None else
-            (valid._data if isinstance(valid, Tensor) else valid
-             ).reshape(B * T))
+        x2 = xa.reshape(B * T, d)
+        v = None if valid is None else (
+            valid._data if isinstance(valid, Tensor) else valid
+        ).reshape(B * T)
+        C = self.rows_at_a_time
+        if C and B * T > C and B * T % C == 0:
+            if v is None:
+                v = jnp.ones((B * T,), bool)
+            y, hit = jax.lax.map(lambda a: self._compute(*a),
+                                 (x2.reshape(-1, C, d), v.reshape(-1, C)))
+            # of the chunks' counts the largest: a count of experts, not
+            # of rows (only decode, one chunk, reads it)
+            self.last_experts_hit = hit.max()
+        else:
+            y, self.last_experts_hit = self._compute(x2, v)
         return Tensor(y.reshape(B, T, d))
 
     def _compute(self, x, valid):
@@ -137,11 +164,12 @@ class DroplessMoE(Layer):
         k, F = self.top_k, self.d_ff
         lo, hi = self.experts_held
         held = hi - lo
+        bias = getattr(self.router, "bias", None)
         with _spans.scope("moe_router"):
             routed_from, x = x, x.astype(self.experts.gate_up._data.dtype)
             chosen, weight = route_sigmoid_topk(
                 routed_from, self.router.weight._data,
-                self.router.bias._data, k,
+                None if bias is None else bias._data, k,
                 self.scaling, self.norm_topk_prob)
             mine = (chosen >= lo) & (chosen < hi)
             if valid is not None:
@@ -170,8 +198,13 @@ class DroplessMoE(Layer):
             # (whatever the grouped matmul left in it)
             out = jnp.where(mine.reshape(N * k, 1), out[back], 0.0)
             y = (out.reshape(N, k, d) * weight[:, :, None]).sum(1)
-            if self.shared is not None:
-                y = y + swiglu(x, self.shared.gate_proj.weight._data,
-                               self.shared.up_proj.weight._data,
-                               self.shared.down_proj.weight._data)
+        if self.shared is not None:
+            with _spans.scope("moe_experts" if self.shared_scale is None
+                              else "moe_shared"):
+                shared = swiglu(x, self.shared.gate_proj.weight._data,
+                                self.shared.up_proj.weight._data,
+                                self.shared.down_proj.weight._data)
+                if self.shared_scale is not None:
+                    shared = shared * jnp.float32(self.shared_scale)
+                y = y + shared
         return y, (sizes > 0).sum().astype(jnp.int32)

@@ -29,6 +29,8 @@ scores a block with one dot over the row.
 """
 from __future__ import annotations
 
+import collections
+
 import numpy as np
 import jax
 import jax.numpy as jnp
@@ -40,14 +42,41 @@ _LANES = 128
 
 class CacheSpec:
     """What a decoder's layers keep a token: ``kind`` "heads" with
-    ``layers`` = [(n_head, head_dim), ...] (a K and a V pool a layer), or
-    "latent" with ``layers`` = [(rank, rope_dim), ...] (one pool a layer)."""
+    ``layers`` = [(n_kv_head, head_dim), ...] (a K and a V pool a layer;
+    the heads of a ROW, which grouped-query attention reads with more
+    query heads than that), or "latent" with ``layers`` = [(rank,
+    rope_dim), ...] (one pool a layer).
 
-    def __init__(self, kind, layers):
+    ``windows`` (heads only) says per layer how many keys a query may see,
+    itself among them, or None for every earlier key. A window layer's
+    state is a RING: a slot keeps ``ring_blocks`` = window / block_size + 1
+    blocks of it whatever its length, position ``p`` lives in ring block
+    ``(p // block_size) % ring_blocks`` (one block over the window, so the
+    block a new row lands in never holds a key that row may still see), and
+    its pools are sized by the window, not by ``max_seq_len``. A slot's
+    ring blocks are its own from engine build: blocks ``1 + slot * ring
+    ..``, block 0 the garbage block as in every pool. All window layers of
+    a decoder share one window."""
+
+    def __init__(self, kind, layers, windows=None, q_per_kv=1):
         if kind not in ("heads", "latent"):
             raise ValueError(f"unknown cache kind {kind!r}")
         self.kind = kind
+        # query heads that read each head of a row (grouped queries): the
+        # pools do not care, the paged kernel's plan does
+        self.q_per_kv = int(q_per_kv)
         self.layers = [tuple(int(x) for x in l) for l in layers]
+        self.windows = [None if w is None else int(w) for w in (
+            windows or [None] * len(self.layers))]
+        distinct = {w for w in self.windows if w is not None}
+        if len(self.windows) != len(self.layers) or len(distinct) > 1 \
+                or any(w < 1 for w in distinct) \
+                or (distinct and kind != "heads"):
+            raise ValueError(
+                f"CacheSpec: windows {windows!r} must give every layer of "
+                "a 'heads' cache None or the one window (>= 1) the "
+                "decoder's window layers share")
+        self.window = distinct.pop() if distinct else None
 
     def row_width(self, layer=0):
         """Lanes of one pool's row in this layer."""
@@ -61,9 +90,26 @@ class CacheSpec:
         latent row is shared by every head."""
         return self.layers[layer][0] if self.kind == "heads" else 1
 
-    def allocate(self, num_blocks, block_size, dtype, mesh=None):
+    def window_layers(self):
+        return [i for i, w in enumerate(self.windows) if w is not None]
+
+    def ring_blocks(self, block_size):
+        """Blocks of one slot's ring in a window layer (0: no such
+        layer)."""
+        return ring_blocks(self.window, block_size) if self.window else 0
+
+    def layer_blocks(self, layer, num_blocks, block_size, slots):
+        """Blocks of this layer's pools: the engine's ``num_blocks`` for a
+        full layer, the garbage block and every slot's ring for a window
+        layer."""
+        if self.windows[layer] is None:
+            return int(num_blocks)
+        return 1 + int(slots) * self.ring_blocks(block_size)
+
+    def allocate(self, num_blocks, block_size, dtype, mesh=None, slots=0):
         """``(first, second)`` pools of every layer: the K and the V pools
-        of a "heads" cache (heads over 'mp' on a mesh they divide); the
+        of a "heads" cache (heads over 'mp' on a mesh they divide; a window
+        layer's sized by ``slots`` rings, see :meth:`layer_blocks`); the
         latent pools and none of a "latent" one (no mesh: the engine
         refuses one for this kind)."""
         if self.kind == "latent":
@@ -77,22 +123,61 @@ class CacheSpec:
                 mp > 1 and all(h % mp == 0 for h, _ in self.layers)))
 
         def pools():
-            made = [zeros(num_blocks, block_size, h, dh, dtype)
-                    for h, dh in self.layers]
+            made = [zeros(self.layer_blocks(i, num_blocks, block_size,
+                                            slots), block_size, h, dh, dtype)
+                    for i, (h, dh) in enumerate(self.layers)]
             if sharding is not None:
                 made = [jax.device_put(p, sharding) for p in made]
             return made
 
         return pools(), pools()
 
+    def layer_tables(self, block_tables, block_size):
+        """What each layer's forward addresses its pools through. One kind
+        of layer: the engine's table as it is. Full and window layers: the
+        engine's table row is the full layers' columns followed by the
+        slot's ``ring_blocks`` ring columns, and this gives every layer its
+        own part."""
+        if self.window is None:
+            return block_tables
+        ring = self.ring_blocks(block_size)
+        full, rings = block_tables[:, :-ring], block_tables[:, -ring:]
+        return [full if w is None else rings for w in self.windows]
+
     def describe(self):
-        """"heads: K and V rows of 2048" / "latent: one row of 640 (512 +
-        64 rope, padded)" — for stats() and the layout explainer."""
-        a, b = self.layers[0]
-        if self.kind == "heads":
-            return f"heads: a K and a V row of {a} x {b} = {a * b} a layer"
-        return (f"latent: one row of {self.row_width()} a layer ({a} latent "
-                f"+ {b} rotated key, padded to whole lane tiles)")
+        """Every kind of layer the cache has, with how many of each: "3 x
+        heads: a K and a V row of 8 x 128 = 1024 a layer, window 4096 (a
+        ring of 257 blocks a slot at block 16); 1 x heads: ..., every
+        key" — for stats() and the layout explainer."""
+        kinds = collections.Counter(zip(self.layers, self.windows))
+        said = []
+        for ((a, b), w), n in kinds.items():
+            if self.kind == "heads":
+                what = (f"heads: a K and a V row of {a} x {b} = {a * b} a "
+                        "layer")
+                if self.window is not None:
+                    what += (", every earlier key" if w is None else
+                             f", the last {w} keys in a ring")
+            else:
+                what = (f"latent: one row of "
+                        f"{self.row_width(self.layers.index((a, b)))} a "
+                        f"layer ({a} latent + {b} rotated key, padded to "
+                        "whole lane tiles)")
+            said.append(what if len(kinds) == 1 else f"{n} x {what}")
+        return "; ".join(said)
+
+
+def ring_blocks(window, block_size):
+    """Blocks of a slot's ring for ``window`` keys: one over the window."""
+    return -(-int(window) // int(block_size)) + 1
+
+
+def ring_table(slots, ring):
+    """The ring columns of every slot's table row, numpy ``[slots, ring]``:
+    slot s owns blocks ``1 + s * ring ..`` of each window layer's pools
+    from engine build to the end (nothing allocates or frees them)."""
+    return (1 + np.arange(slots, dtype=np.int32)[:, None] * ring
+            + np.arange(ring, dtype=np.int32)[None])
 
 
 def zeros(num_blocks, block_size, num_heads, head_dim, dtype):
@@ -145,13 +230,52 @@ def write_rows(pool, rows, block_ids, row_ids):
     return pool.at[block_ids, row_ids].set(rows.astype(pool.dtype))
 
 
-def write_span(k_pool, v_pool, k, v, block_tables, offsets, seq_lens):
+def ring_span_rows(ring_tables, offsets, seq_lens, span, block_size,
+                   window):
+    """:func:`span_rows` for a window layer's ring: row ``offsets[b] + t``
+    = position p lands in the slot's ring column ``(p // block_size) %
+    ring``. Only the rows a later query can still see are written, ``p >=
+    seq_lens[b] - window`` (of a prompt longer than the window the head
+    never lands, so no two rows of one call meet in a ring block); the rest
+    and the padding go to the garbage row."""
+    bt = ring_tables.astype(jnp.int32)
+    bs = jnp.int32(block_size)
+    sl = seq_lens.astype(jnp.int32)[:, None]
+    rows = (offsets.astype(jnp.int32)[:, None]
+            + jnp.arange(span, dtype=jnp.int32)[None])
+    phys = jnp.take_along_axis(
+        bt, (rows // bs) % jnp.int32(bt.shape[1]), axis=1)
+    writable = (rows < sl) & (rows >= sl - jnp.int32(window))
+    zero = jnp.zeros_like(rows)
+    return (jnp.where(writable, phys, zero).reshape(-1),
+            jnp.where(writable, rows % bs, zero).reshape(-1))
+
+
+def ring_positions(seq_lens, ring, block_size):
+    """The position each row of a slot's gathered ring view ``[B, ring *
+    block_size]`` holds when the slot has ``seq_lens[b]`` rows: column c
+    holds the newest logical block ``lb <= last`` with ``lb % ring == c``
+    (negative where the ring has not come round to it: nothing written)."""
+    last = (seq_lens.astype(jnp.int32)[:, None] - 1) // jnp.int32(block_size)
+    col = jnp.arange(ring, dtype=jnp.int32)[None]
+    block = last - (last - col) % jnp.int32(ring)
+    return (block[:, :, None] * jnp.int32(block_size)
+            + jnp.arange(block_size, dtype=jnp.int32)[None, None]
+            ).reshape(seq_lens.shape[0], ring * block_size)
+
+
+def write_span(k_pool, v_pool, k, v, block_tables, offsets, seq_lens,
+               window=None):
     """Both pools with a step's new rows ``k``, ``v`` ``[B, T, H, Dh]``
-    written through the block tables: :func:`span_rows`, then
-    :func:`write_rows` for each."""
+    written through the block tables: :func:`span_rows` (a window layer's
+    ring: :func:`ring_span_rows`), then :func:`write_rows` for each."""
     B, T = k.shape[0], k.shape[1]
-    blk, row = span_rows(block_tables, offsets, seq_lens, T,
-                         k_pool.shape[1])
+    if window is None:
+        blk, row = span_rows(block_tables, offsets, seq_lens, T,
+                             k_pool.shape[1])
+    else:
+        blk, row = ring_span_rows(block_tables, offsets, seq_lens, T,
+                                  k_pool.shape[1], window)
     return (write_rows(k_pool, k.reshape(B * T, -1), blk, row),
             write_rows(v_pool, v.reshape(B * T, -1), blk, row))
 

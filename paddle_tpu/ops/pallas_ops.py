@@ -510,7 +510,8 @@ PAGED_PARITY_TOL = {"float32": (3e-5, 3e-5), "bfloat16": (0.05, 0.05)}
 
 def _paged_attn_kernel(bt_ref, sl_ref, qo_ref, q_ref, k_hbm, v_hbm, o_ref,
                        k_buf, v_buf, sem, turn, qbd_scr, m_scr, l_scr,
-                       acc_scr, *, scale, head_dim, lanes, precision):
+                       acc_scr, *, scale, head_dim, lanes, precision,
+                       q_per_kv=1, window=None):
     """Grid (B, ceil(M / G)): program (b, j) folds the G consecutive
     logical blocks ``j*G .. j*G+G-1`` of slot b (``G * block_size`` keys)
     into the slot's online-softmax state, for all heads at once. Scratch
@@ -536,11 +537,27 @@ def _paged_attn_kernel(bt_ref, sl_ref, qo_ref, q_ref, k_hbm, v_hbm, o_ref,
     the span, and ``p @ V`` holds head h's output on row h under head h's
     lanes (the finalizer keeps those). No head is sliced, rotated or
     addressed. A verify span's T rows are T such row blocks (M of the
-    dots), each masked to its own position."""
+    dots), each masked to its own position.
+
+    Grouped queries (``q_per_kv`` > 1: the pool's row holds Hkv heads and
+    ``q_per_kv`` query heads read each). The query arrives ``[T * q_per_kv,
+    Hkv * Dh]``, row ``t * q_per_kv + r`` holding query head ``h * q_per_kv
+    + r`` on key/value head h's lanes; a group is the fewest key/value
+    heads that fill whole lane tiles (one, at 128-wide heads), and its row
+    block t is those heads' ``q_per_kv`` queries each, one under the
+    other: a key/value head's queries meet the span in one dot with no
+    other head's lanes in it.
+
+    A window (``window`` keys, the query's own among them; the table is
+    then the slot's RING, ``ops/kv_pool.py``): the walk starts at the
+    logical block that holds the oldest key the span's first row may see
+    and ends at the newest, logical block lb sits in table column ``lb %
+    ring``, and the rows of the oldest block that fell out of the window
+    are masked like the rows past the slot's length."""
     b = pl.program_id(0)
     j = pl.program_id(1)
     _, G, block_size, HD = k_buf.shape
-    T = q_ref.shape[0]
+    T = q_ref.shape[0] // q_per_kv
     n_groups = HD // lanes
     Hp = qbd_scr.shape[1] // T  # a group's heads, padded to whole sublanes
     span = G * block_size
@@ -554,15 +571,24 @@ def _paged_attn_kernel(bt_ref, sl_ref, qo_ref, q_ref, k_hbm, v_hbm, o_ref,
     # past the group's heads own nothing: zero queries, dropped outputs)
     head = jax.lax.broadcasted_iota(i32, (Hp, lanes), 0)
     lane = jax.lax.broadcasted_iota(i32, (Hp, lanes), 1)
+    if q_per_kv > 1:  # row hl * q_per_kv + r: a query of the group's head hl
+        head = head // i32(q_per_kv)
     own = ((lane >= head * i32(head_dim))
            & (lane < (head + i32(1)) * i32(head_dim)))
 
     def limit_of(slot):  # highest key position a row of `slot` reads, excl.
         return jnp.minimum(qo_ref[slot] + i32(T), sl_ref[slot])
 
+    def base_of(slot):  # the logical block the slot's walk starts at
+        return jnp.maximum(qo_ref[slot] - i32(window - 1),
+                           _i0()) // i32(block_size)
+
     def blocks_of(slot):
         # table columns the slot's rows read; its first span always walks
         # one (an inactive lane: the reserved block 0, finite garbage)
+        if window is not None:
+            return jnp.maximum(pl.cdiv(limit_of(slot), i32(block_size))
+                               - base_of(slot), i32(1))
         return jnp.maximum(pl.cdiv(limit_of(slot), i32(block_size)), i32(1))
 
     def copies(half, g, blk=_i0()):
@@ -573,7 +599,10 @@ def _paged_attn_kernel(bt_ref, sl_ref, qo_ref, q_ref, k_hbm, v_hbm, o_ref,
 
     def fetch(slot, first, half):  # start: blocks first.. of slot's span
         def start(g, _):
-            for c in copies(half, g, bt_ref[slot, first + g]):
+            col = first + g
+            if window is not None:  # logical block -> its ring column
+                col = (base_of(slot) + col) % i32(bt_ref.shape[1])
+            for c in copies(half, g, bt_ref[slot, col]):
                 c.start()
             return _
         jax.lax.fori_loop(
@@ -581,6 +610,8 @@ def _paged_attn_kernel(bt_ref, sl_ref, qo_ref, q_ref, k_hbm, v_hbm, o_ref,
 
     n_blk = blocks_of(b)
     limit = limit_of(b)
+    # position of the walk's first key (a window's: its oldest live block)
+    origin = None if window is None else base_of(b) * i32(block_size)
 
     @pl.when((b == 0) & (j == 0))
     def _first():
@@ -598,8 +629,17 @@ def _paged_attn_kernel(bt_ref, sl_ref, qo_ref, q_ref, k_hbm, v_hbm, o_ref,
         acc_scr[...] = jnp.zeros_like(acc_scr)
         for g in range(n_groups):  # static unrolls
             for t in range(T):
-                qt = q_ref[t:t + 1, g * lanes:(g + 1) * lanes].astype(
+                qt = q_ref[t * q_per_kv:(t + 1) * q_per_kv,
+                           g * lanes:(g + 1) * lanes].astype(
                     jnp.float32)  # the select runs on 32-bit lanes
+                if q_per_kv > 1:
+                    # the q_per_kv queries once under each head of the
+                    # group, rows up to whole sublanes zero
+                    n_kv = lanes // head_dim
+                    qt = jnp.concatenate(
+                        [qt] * n_kv + [jnp.zeros(
+                            (Hp - n_kv * q_per_kv, lanes), jnp.float32)
+                        ] * (Hp > n_kv * q_per_kv), axis=0)
                 qbd_scr[g, t * Hp:(t + 1) * Hp, :] = jnp.where(
                     own, qt, _f32(0)).astype(qbd_scr.dtype)
 
@@ -624,17 +664,22 @@ def _paged_attn_kernel(bt_ref, sl_ref, qo_ref, q_ref, k_hbm, v_hbm, o_ref,
             _i0(), jnp.minimum(n_blk - j * i32(G), i32(G)), wait, _i0())
         turn[0] = i32(1) - half
 
-        @pl.when(j * i32(span) < limit)
+        @pl.when(j * i32(span) < limit if window is None
+                 else origin + j * i32(span) < limit)
         def _fold():
             k = k_buf[half].reshape(span, HD)
             v = v_buf[half].reshape(span, HD)
             pos = j * i32(span) + jax.lax.broadcasted_iota(
                 i32, (T * Hp, span), 1)
+            if window is not None:
+                pos = origin + pos
             # row block t reads key positions < min(qo + t + 1, sl)
             row = jax.lax.broadcasted_iota(i32, (T * Hp, 1), 0)
             t_of = sum(((row >= i32(t * Hp)).astype(i32)
                         for t in range(1, T)), jnp.zeros_like(row))
             mask = pos < jnp.minimum(qo_ref[b] + t_of + i32(1), sl_ref[b])
+            if window is not None:
+                mask = mask & (pos > qo_ref[b] + t_of - i32(window))
             for g in range(n_groups):
                 kg = k[:, g * lanes:(g + 1) * lanes]
                 vg = v[:, g * lanes:(g + 1) * lanes]
@@ -664,9 +709,16 @@ def _paged_attn_kernel(bt_ref, sl_ref, qo_ref, q_ref, k_hbm, v_hbm, o_ref,
         for g in range(n_groups):
             out = acc_scr[g] / jnp.maximum(l_scr[g], _TINY)
             for t in range(T):
-                o_ref[t:t + 1, g * lanes:(g + 1) * lanes] = jnp.where(
-                    own, out[t * Hp:(t + 1) * Hp], _f32(0)).sum(
-                        axis=0, keepdims=True).astype(o_ref.dtype)
+                mine = jnp.where(own, out[t * Hp:(t + 1) * Hp], _f32(0))
+                if q_per_kv > 1:  # query r of every head of the group
+                    o_ref[t * q_per_kv:(t + 1) * q_per_kv,
+                          g * lanes:(g + 1) * lanes] = sum(
+                        mine[h * q_per_kv:(h + 1) * q_per_kv]
+                        for h in range(lanes // head_dim)
+                    ).astype(o_ref.dtype)
+                    continue
+                o_ref[t:t + 1, g * lanes:(g + 1) * lanes] = mine.sum(
+                    axis=0, keepdims=True).astype(o_ref.dtype)
 
 
 # What one program of the heads kernel may hold in VMEM: both halves of the
@@ -681,23 +733,32 @@ _PAGED_VMEM_BUDGET = 12 << 20
 # 256 keys beat 128 by 14 % and tie 512; all 2048 lanes in one dot beat
 # four dots of 512 by 5 % at T = 1 and lose 13 % at T = 5's 160 rows)
 _PAGED_MAX_SPAN_KEYS = 256
+# ... and with grouped queries (PERF.md, PR 35: a row of 8 x 128 lanes is
+# half the bytes of the cells' 32 x 64, and 512 keys beat 256 by 17-25 % at
+# every group width; the multi-head cells stay at 256)
+_PAGED_MAX_SPAN_KEYS_GROUPED = 512
 _PAGED_MAX_GROUP_LANES = 2048
 _PAGED_MAX_DOT_ROWS = 64
 
 
-def _group_rows(lanes, head_dim, span_rows):
+def _group_rows(lanes, head_dim, span_rows, q_per_kv=1):
     """Rows of a head group's query block: ``span_rows`` row blocks of the
-    group's heads, each padded to whole sublanes (8)."""
-    return span_rows * (-(-(lanes // head_dim) // 8) * 8)
+    group's heads (``q_per_kv`` queries each), each padded to whole
+    sublanes (8)."""
+    return span_rows * (-(-(lanes // head_dim * q_per_kv) // 8) * 8)
 
 
-def _paged_group_lanes(num_heads, head_dim, span_rows=1):
+def _paged_group_lanes(num_heads, head_dim, span_rows=1, q_per_kv=1):
     """Lanes of the merged axis one dot of the heads kernel contracts:
     whole 128-lane tiles holding whole heads, the largest such divisor of
     ``H * Dh`` within ``_PAGED_MAX_GROUP_LANES`` whose query block
     (:func:`_group_rows`) stays within ``_PAGED_MAX_DOT_ROWS``; all of the
     axis where heads and tiles
-    never line up (12 x 80: the interpreter only, `paged_tileable`)."""
+    never line up (12 x 80: the interpreter only, `paged_tileable`). With
+    grouped queries a head brings ``q_per_kv`` rows, so the same bound on
+    the rows gives narrower groups (8 x 128 with 16 queries a head: 4 heads,
+    64 rows against 512 lanes; PERF.md, PR 35: one head a group, 16 rows
+    against 128 lanes, is 1.9 x slower, all 8 heads 1.3 x)."""
     width = num_heads * head_dim
     unit = math.lcm(head_dim, 128)
     if width % unit:
@@ -705,21 +766,21 @@ def _paged_group_lanes(num_heads, head_dim, span_rows=1):
     units = width // unit
     fits = [k for k in range(1, units + 1)
             if units % k == 0 and k * unit <= _PAGED_MAX_GROUP_LANES
-            and _group_rows(k * unit, head_dim, span_rows)
+            and _group_rows(k * unit, head_dim, span_rows, q_per_kv)
             <= _PAGED_MAX_DOT_ROWS]
     return max(fits, default=1) * unit
 
 
 def _paged_plan(block_size, num_heads, head_dim, dtype, span_rows=1,
-                table_cols=None):
+                table_cols=None, q_per_kv=1):
     """(G, lanes, rows) for one pool geometry: G blocks a program, the
     lanes of a head group and the rows ``T * Hp`` of its query block. G is
     what ``_PAGED_VMEM_BUDGET`` holds beside the float32 working set, at
     most ``_PAGED_MAX_SPAN_KEYS`` keys and the table's columns; 0 when not
     even one block fits."""
     item = jnp.dtype(dtype).itemsize
-    lanes = _paged_group_lanes(num_heads, head_dim, span_rows)
-    rows = _group_rows(lanes, head_dim, span_rows)
+    lanes = _paged_group_lanes(num_heads, head_dim, span_rows, q_per_kv)
+    rows = _group_rows(lanes, head_dim, span_rows, q_per_kv)
     groups = num_heads * head_dim // lanes
     block = block_size * num_heads * head_dim * item
     # every group's queries (pool dtype) and accumulator, one group's dot
@@ -728,7 +789,9 @@ def _paged_plan(block_size, num_heads, head_dim, dtype, span_rows=1,
     room = _PAGED_VMEM_BUDGET - work
     if room < 4 * block:
         return 0, lanes, rows
-    G = max(1, min(_PAGED_MAX_SPAN_KEYS // block_size, room // (6 * block)))
+    span = _PAGED_MAX_SPAN_KEYS if q_per_kv == 1 \
+        else _PAGED_MAX_SPAN_KEYS_GROUPED
+    G = max(1, min(span // block_size, room // (6 * block)))
     G = 1 << (G.bit_length() - 1)  # whole MXU tiles of keys at block 16
     if table_cols is not None:
         G = min(G, table_cols)
@@ -736,22 +799,27 @@ def _paged_plan(block_size, num_heads, head_dim, dtype, span_rows=1,
 
 
 def paged_keys_per_program(block_size, num_heads, head_dim, dtype,
-                           table_cols):
+                           table_cols, q_per_kv=1):
     """Keys one program of the heads kernel folds at decode (T = 1) for
-    this geometry (``num_heads``: the heads one shard holds): the gauge
+    this geometry (``num_heads``: the heads of a pool's row one shard
+    holds, ``q_per_kv`` query heads reading each): the gauge
     ``serving.paged_keys_per_program``."""
     return block_size * max(1, _paged_plan(
-        block_size, num_heads, head_dim, dtype, 1, table_cols)[0])
+        block_size, num_heads, head_dim, dtype, 1, table_cols, q_per_kv)[0])
 
 
 def _paged_attention_fused(q, k_pool, v_pool, block_tables, seq_lens,
-                           q_offsets, scale, interpret):
-    B, T, H, Dh = q.shape
+                           q_offsets, scale, interpret, window=None):
+    B, T, Hq, Dh = q.shape
     bs = int(k_pool.shape[1])
     M = int(block_tables.shape[1])
-    G, lanes, rows = _paged_plan(bs, H, Dh, k_pool.dtype, T, M)
+    H = int(k_pool.shape[2]) // Dh  # the heads of a pool's row
+    qpk = Hq // H
+    G, lanes, rows = _paged_plan(bs, H, Dh, k_pool.dtype, T, M, qpk)
     G = max(G, 1)  # a geometry paged_tileable refuses: Mosaic says why
     groups = H * Dh // lanes
+    if qpk > 1:  # query r of every key/value head side by side on row r
+        q = q.reshape(B, T, H, qpk, Dh).transpose(0, 1, 3, 2, 4)
 
     def q_map(b, j, bt, sl, qo):
         return (b, _i0(), _i0())
@@ -759,10 +827,10 @@ def _paged_attention_fused(q, k_pool, v_pool, block_tables, seq_lens,
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(B, -(-M // G)),
-        in_specs=[pl.BlockSpec((None, T, H * Dh), q_map),
+        in_specs=[pl.BlockSpec((None, T * qpk, H * Dh), q_map),
                   pl.BlockSpec(memory_space=pl.ANY),
                   pl.BlockSpec(memory_space=pl.ANY)],
-        out_specs=pl.BlockSpec((None, T, H * Dh), q_map),
+        out_specs=pl.BlockSpec((None, T * qpk, H * Dh), q_map),
         scratch_shapes=[
             pltpu.VMEM((2, G, bs, H * Dh), k_pool.dtype),  # K, two halves
             pltpu.VMEM((2, G, bs, H * Dh), v_pool.dtype),
@@ -777,13 +845,19 @@ def _paged_attention_fused(q, k_pool, v_pool, block_tables, seq_lens,
     out = pl.pallas_call(
         functools.partial(_paged_attn_kernel, scale=scale, head_dim=Dh,
                           lanes=lanes,
-                          precision=_dot_precision(k_pool.dtype)),
+                          precision=_dot_precision(k_pool.dtype),
+                          q_per_kv=qpk, window=window),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, T, H * Dh), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, T * qpk, H * Dh), q.dtype),
         interpret=interpret,
-        name=_kernel_name("paged_attention"),
+        name=_kernel_name("paged_attention" if window is None
+                          else "paged_attention_window"),
     )(block_tables.astype(jnp.int32), seq_lens.astype(jnp.int32),
-      q_offsets.astype(jnp.int32), q.reshape(B, T, H * Dh), k_pool, v_pool)
+      q_offsets.astype(jnp.int32), q.reshape(B, T * qpk, H * Dh), k_pool,
+      v_pool)
+    if qpk > 1:
+        return out.reshape(B, T, qpk, H, Dh).transpose(
+            0, 1, 3, 2, 4).reshape(B, T, Hq, Dh)
     return out.reshape(B, T, H, Dh)
 
 
@@ -823,28 +897,43 @@ def _paged_attention_sharded(q, k_pool, v_pool, block_tables, seq_lens,
 
 
 def paged_attention_xla(q, k_pool, v_pool, block_tables, seq_lens,
-                        q_offsets, scale=None):
+                        q_offsets, scale=None, window=None):
     """The gather-path reference: materialize each slot's logical
     [M*bs] view of the pool and run masked attention over it. Same
     semantics as GPTAttention's PR 9 paged branch; serves as the parity
-    oracle for the fused kernel and as the ``kernel="xla"`` route."""
+    oracle for the fused kernel and as the ``kernel="xla"`` route. A pool
+    whose row holds fewer heads than ``q`` has is read grouped (query head
+    h reads head ``h // (Hq / Hkv)``); with a ``window`` the table is the
+    slot's ring and a view row's position comes from
+    ``kv_pool.ring_positions``."""
     B, T, H, Dh = q.shape
     scale = float(scale) if scale is not None else Dh ** -0.5
-    k_view = _kv_pool.gather_view(_kv_pool.merged(k_pool), block_tables, H)
-    v_view = _kv_pool.gather_view(_kv_pool.merged(v_pool), block_tables, H)
+    k_pool, v_pool = _kv_pool.merged(k_pool), _kv_pool.merged(v_pool)
+    Hkv = int(k_pool.shape[2]) // Dh
+    k_view = _kv_pool.gather_view(k_pool, block_tables, Hkv)
+    v_view = _kv_pool.gather_view(v_pool, block_tables, Hkv)
+    if Hkv != H:
+        k_view = jnp.repeat(k_view, H // Hkv, axis=2)
+        v_view = jnp.repeat(v_view, H // Hkv, axis=2)
     S = k_view.shape[1]
-    jpos = jnp.arange(S, dtype=jnp.int32)
+    jpos = jnp.arange(S, dtype=jnp.int32)[None, None, :]
     qrow = (q_offsets.astype(jnp.int32)[:, None]
             + jnp.arange(T, dtype=jnp.int32)[None])
-    mask = ((jpos[None, None, :] <= qrow[:, :, None])
-            & (jpos[None, None, :]
-               < seq_lens.astype(jnp.int32)[:, None, None]))
+    if window is not None:
+        jpos = _kv_pool.ring_positions(
+            seq_lens, int(block_tables.shape[1]),
+            int(k_pool.shape[1]))[:, None, :]
+    mask = ((jpos <= qrow[:, :, None])
+            & (jpos < seq_lens.astype(jnp.int32)[:, None, None]))
+    if window is not None:
+        mask = mask & (jpos > qrow[:, :, None] - jnp.int32(window)) \
+            & (jpos >= 0)
     return _attention_xla(q, k_view, v_view, mask=mask[:, None],
                           scale=scale)
 
 
 def paged_attention(q, k_pool, v_pool, block_tables, seq_lens, q_offsets,
-                    kernel="xla", scale=None, mesh=None):
+                    kernel="xla", scale=None, mesh=None, window=None):
     """Paged-KV attention: ``q`` [B, T, H, Dh] over pools
     [num_blocks, block_size, H*Dh] (ops/kv_pool.py; a 4-D
     [num_blocks, block_size, H, Dh] pool is merged on entry, which is a
@@ -859,11 +948,24 @@ def paged_attention(q, k_pool, v_pool, block_tables, seq_lens, q_offsets,
     through :func:`jax.shard_map` with head-sharded q/pools — the
     kernel body is unchanged, each shard just sees H/mp heads. Resolve
     the choice ONCE per engine with :func:`select_paged_kernel` — it
-    must never vary per step or the serving replay fast path retraces."""
+    must never vary per step or the serving replay fast path retraces.
+
+    Grouped queries need no argument: a pool whose row holds ``Hkv < H``
+    heads is read by ``H / Hkv`` query heads a head. ``window`` (keys a
+    query may see, its own among them) says ``block_tables`` holds each
+    slot's ring (``ops/kv_pool.py``); the kernel is then called
+    ``paged_attention_window`` in the trace. A ring has no mesh route and
+    holds no verify span (its newest rows would overwrite keys its oldest
+    still sees): T must be 1."""
     scale = float(scale) if scale is not None else q.shape[-1] ** -0.5
+    if window is not None and (q.shape[1] != 1
+                               or _mesh_mp_degree(mesh) > 1):
+        raise TypeError("paged_attention(window=...): a ring takes one "
+                        "query row a slot and no mesh")
     if kernel == "xla":
         return paged_attention_xla(q, k_pool, v_pool, block_tables,
-                                   seq_lens, q_offsets, scale=scale)
+                                   seq_lens, q_offsets, scale=scale,
+                                   window=window)
     if kernel not in ("pallas", "interpret"):
         raise ValueError(
             f"unknown paged-attention kernel {kernel!r} "
@@ -877,7 +979,8 @@ def paged_attention(q, k_pool, v_pool, block_tables, seq_lens, q_offsets,
     else:
         out = _paged_attention_fused(q, k_pool, v_pool, block_tables,
                                      seq_lens, q_offsets, scale,
-                                     interpret=(kernel == "interpret"))
+                                     interpret=(kernel == "interpret"),
+                                     window=window)
     # kernel_mismatch fault (testing/faults.py): perturb ONE element of
     # the fused output so parity gates provably trip. Trace-time firing:
     # the perturbation is baked into whichever executable traces while

@@ -73,10 +73,21 @@ COUNTERS = {
                              "admitted requests",
     "serving.admitted": "requests that left the queue for a slot",
     "serving.kv_tokens_read": "sum of the active slots' lengths at each "
-                              "decode step: the KV rows that step's "
-                              "attention had to read (plain decode; a "
+                              "decode step: one full layer's rows, the KV "
+                              "rows that step's attention had to read in a "
+                              "layer that keeps every row (plain decode; a "
                               "speculative round does not count them); "
                               "a row is whatever the cache keeps a token",
+    "serving.kv_window_rows_read": "sum over the active slots of min(length, "
+                                   "window) at each decode step: the rows "
+                                   "ONE window layer's attention read from "
+                                   "its ring (host; 0 for a cache without "
+                                   "window layers)",
+    "serving.cache_refusals": "features this engine's cache cannot give "
+                              "(a mesh, the handoff, chunked prefill, spec "
+                              "decode over a latent cache or a ring; prefix "
+                              "sharing switched off for a ring), each with "
+                              "a `cache_feature_refused` explainer event",
     "serving.moe_layer_steps": "expert layers x decode steps (host)",
     "serving.moe_routed_rows": "active slots x experts per token, a "
                                "layer-step (host)",
@@ -86,13 +97,27 @@ COUNTERS = {
                                "read in the transfer that brings the tokens",
 }
 
+# Gauges set once at engine build, beside `serving.kv_pool_row_major` and
+# `serving.paged_keys_per_program` (engine.py `_note_pool_layout`).
+GAUGES = {
+    "serving.kv_layers_full": "layers whose pools keep every row of a slot",
+    "serving.kv_layers_window": "layers whose pools keep a ring of the last "
+                                "`window` rows a slot",
+    "serving.kv_window_blocks": "blocks of one slot's ring in a window "
+                                "layer: window / block_size + 1 (0: none)",
+}
+
 # Mosaic kernels (`pl.pallas_call(name=...)`): the custom call's HLO
 # instruction is named from it, whoever calls the kernel.
 KERNELS = {
     "flash_fwd": "flash attention forward -> (out, lse)",
     "flash_bwd_dq": "flash attention backward -> dQ",
     "flash_bwd_dkv": "flash attention backward -> (dK, dV)",
-    "paged_attention": "paged decode/verify attention over the block pool",
+    "paged_attention": "paged decode/verify attention over the block pool "
+                       "(every earlier key; grouped queries or not)",
+    "paged_attention_window": "the same kernel over a window layer's ring: "
+                              "only the blocks that hold the last `window` "
+                              "keys",
     "mla_paged_attention": "absorbed latent (MLA) decode attention over "
                            "the latent block pool, all heads a block",
 }
@@ -123,7 +148,17 @@ SCOPES = {
     "moe_router": "nn/moe/dropless.py: sigmoid scores, biased top-k, the "
                   "sort of the routed rows by expert",
     "moe_experts": "nn/moe/dropless.py: the grouped matmuls of the held "
-                   "experts and the weighted sum back to tokens",
+                   "experts and the weighted sum back to tokens (and the "
+                   "shared expert where its output is summed)",
+    "moe_shared": "nn/moe/dropless.py: the shared experts where their "
+                  "outputs are averaged (models/cohere2_moe.py)",
+    "attn_window": "models/cohere2_moe.py: a sliding-window layer's "
+                   "attention: QKV, rotary, the ring write, the kernel or "
+                   "the prefill walk bounded by the window, output "
+                   "projection",
+    "attn_full": "models/cohere2_moe.py: a full (no-position) layer's "
+                 "attention: QKV, the pool write, the kernel or the prefill "
+                 "walk over every earlier key, output projection",
 }
 
 

@@ -57,8 +57,20 @@ final-normed hidden states with the written pools; optionally
 ``step_counters()`` — small device-side counts of the last forward (the
 decode step hands them over in the same transfer as the tokens) and
 ``host_step_counts(n_active)``. What a cache kind does not support (a
-latent cache: a ``mesh``, the KV handoff, spec decode) raises a
-``TypeError`` naming the feature and the kind.
+latent cache: a ``mesh``, the KV handoff, spec decode; a cache with window
+layers, whose state is a ring of the last ``window`` rows a slot: a
+``mesh``, prefix sharing, the KV handoff, chunked prefill, spec decode) is
+refused with a ``TypeError`` naming the feature and the kind, counted in
+``serving.cache_refusals`` and explained (``cache_feature_refused``); prefix
+sharing, which is no call but a default, is switched off at engine build
+and said so the same way.
+
+Two kinds of cache state side by side (``CacheSpec.windows``): a full
+layer's pools are the above; a window layer's hold ``1 + max_batch *
+ring`` blocks, slot s owning blocks ``1 + s * ring ..`` for good. A slot's
+table row is the full layers' columns followed by its ring columns; each
+layer's forward gets its own part (``CacheSpec.layer_tables``). Admission
+budgets the full layers' blocks; the rings need no budget.
 
 Slot lifecycle: free → (admission: blocks allocated/shared, suffix
 prefill, first token sampled) → active (each decode step appends one row
@@ -99,7 +111,8 @@ _counters = _registry.scoped_counters("serving", {
     "prefix_inserted_blocks": 0, "prefix_evicted_blocks": 0,
     "kv_blocks_hwm": 0, "handoff_exports": 0, "handoff_imports": 0,
     "handoff_stale": 0, "chunked_prefills": 0, "prefill_chunks": 0,
-    "kv_tokens_read": 0, "moe_layer_steps": 0, "moe_routed_rows": 0,
+    "kv_tokens_read": 0, "kv_window_rows_read": 0,
+    "cache_refusals": 0, "moe_layer_steps": 0, "moe_routed_rows": 0,
     "moe_experts_hit": 0, "sample_topk_steps": 0, "sample_topp_steps": 0})
 
 # Decode replay fast path (ISSUE 9, same machinery as lazy.ReplayStep):
@@ -139,7 +152,7 @@ class FatalEngineError(RuntimeError):
     supervisor can restart it and re-queue its requests."""
 
 
-def _note_pool_layout(pool, cache, keys_per_program):
+def _note_pool_layout(pools, cache, keys_per_program, ring, pool_bytes):
     """Set the gauge ``serving.kv_pool_row_major`` from the layout the
     device gave a freshly built pool: 1 when it is stored row by row (the
     form the in-place row write and the paged kernel take as it is), 0
@@ -149,18 +162,35 @@ def _note_pool_layout(pool, cache, keys_per_program):
     it the gauge ``serving.paged_keys_per_program``: the keys one program
     of the engine's paged kernel folds (0: the gather path, no kernel)."""
     _registry.gauge_set("serving.paged_keys_per_program", keys_per_program)
-    order = _kv_pool.device_layout(pool)
-    if order is None:
+    windows = cache.window_layers()
+    _registry.gauge_set("serving.kv_layers_window", len(windows))
+    _registry.gauge_set("serving.kv_layers_full",
+                        len(cache.layers) - len(windows))
+    _registry.gauge_set("serving.kv_window_blocks", ring)
+    # one pool of each kind of layer says it for its kind
+    kinds = {}
+    for i, pool in enumerate(pools):
+        kinds.setdefault(cache.windows[i], pool)
+    orders = {w: _kv_pool.device_layout(p) for w, p in kinds.items()}
+    if any(o is None for o in orders.values()):
         return None
-    row_major = int(order == tuple(range(pool.ndim)))
+    row_major = int(all(o == tuple(range(kinds[w].ndim))
+                        for w, o in orders.items()))
     _registry.gauge_set("serving.kv_pool_row_major", row_major)
+    said = "; ".join(
+        ("" if len(kinds) == 1 else
+         f"{'a full' if w is None else 'a window'} layer's ")
+        + f"pool {tuple(p.shape)} {p.dtype} is stored major-to-minor "
+        f"{orders[w]}" for w, p in kinds.items())
     _explain.record(
         "kv_pool_layout", op="kv_pool", row_major=bool(row_major),
         cache_kind=cache.kind, row_width=cache.row_width(),
         paged_keys_per_program=keys_per_program,
-        why=(f"{cache.describe()}; pool {tuple(pool.shape)} {pool.dtype} "
-             f"is stored "
-             f"major-to-minor {order}: "
+        window=cache.window, ring_blocks=ring, pool_bytes=pool_bytes,
+        why=(f"{cache.describe()}; {pool_bytes / 1e9:.2f} GB of pools"
+             + (f" (a window layer's ring is {ring} blocks a slot)"
+                if ring else "")
+             + f"; {said}: "
              + ("row-major, rows are written in place" if row_major else
                 "NOT row-major — row writes and the paged kernel will "
                 "relayout the whole pool every step")
@@ -215,16 +245,19 @@ class GenerationEngine:
                 "kv_cache_spec() (the rows its layers cache a token), "
                 "serving_head() (the head's weight and its logits) and "
                 "max_positions, and whose forward takes the paged-cache "
-                "arguments (models.GPTModel, models.Xing4Model); "
+                "arguments (models.GPTModel, models.Xing4Model, "
+                "models.Cohere2MoeModel); "
                 f"{type(model).__name__} lacks {lacks}")
         self._model = model
         self._gpt = gpt
         self._cache = gpt.kv_cache_spec()
         if mesh is not None and self._cache.kind != "heads":
-            raise TypeError(
+            self._refuse(
                 f"GenerationEngine(mesh=...) is not supported for a "
                 f"{self._cache.kind!r} cache: a latent row is shared by "
                 "every head, so there is no head axis to place over 'mp'")
+        if mesh is not None:
+            self.require_full_layers("GenerationEngine(mesh=...)")
         self.max_seq_len = int(max_seq_len or gpt.max_positions)
         if self.max_seq_len > gpt.max_positions:
             raise ValueError(
@@ -256,6 +289,22 @@ class GenerationEngine:
             num_blocks = 1 + self.max_batch_size * self.blocks_per_slot
         self.pool = BlockPool(num_blocks)
         self.prefix_cache = RadixPrefixCache(self.pool, self.block_size)
+        # window layers keep a ring of their last rows a slot: the blocks
+        # of a slot's ring are its own for good (ops/kv_pool.py), and a
+        # prefix block shared by refcount has no ring to go with it —
+        # such a decoder shares no prefixes
+        self._ring = self._cache.ring_blocks(self.block_size)
+        self._ring_table = _kv_pool.ring_table(self.max_batch_size,
+                                               self._ring)
+        self._prefix_sharing = not self._ring
+        if self._ring:
+            self._note_refusal(
+                "prefix sharing (the radix prefix cache) is off for a "
+                f"cache with window layers (layers "
+                f"{self._cache.window_layers()} keep a ring of the last "
+                f"{self._cache.window} rows a slot): a shared prefix "
+                "block has no ring rows to go with it, and prefill "
+                "attends to the call's own rows only")
 
         # generation is inference: dropout off, or padded lanes would
         # perturb nothing but sampled RNG streams would diverge
@@ -346,7 +395,8 @@ class GenerationEngine:
         self._kv_heads = [self._cache.heads(i)
                           for i in range(len(self._cache.layers))]
         self._k, self._v = self._cache.allocate(
-            self.pool.num_blocks, self.block_size, self._dtype, mesh)
+            self.pool.num_blocks, self.block_size, self._dtype, mesh,
+            slots=self.max_batch_size)
         if self._paged_kernel == "xla":
             self._paged_keys_per_program = 0
         elif self._cache.kind == "latent":
@@ -357,9 +407,11 @@ class GenerationEngine:
             self._paged_keys_per_program = \
                 _pallas_ops.paged_keys_per_program(
                     self.block_size, heads // shards, head_dim, self._dtype,
-                    self.blocks_per_slot)
+                    self.blocks_per_slot, self._cache.q_per_kv)
+        self._kv_pool_bytes = sum(int(p.nbytes) for p in self._k + self._v)
         self._kv_row_major = _note_pool_layout(
-            self._k[0], self._cache, self._paged_keys_per_program)
+            self._k, self._cache, self._paged_keys_per_program, self._ring,
+            self._kv_pool_bytes)
 
         # host-side slot state, mirrored into the decode step as arrays
         B = self.max_batch_size
@@ -374,7 +426,9 @@ class GenerationEngine:
         # per-slot block tables: row of physical block ids, zero-padded
         # (block 0 = reserved garbage block); _slot_blocks holds the ids
         # each slot has a pool reference on
-        self._block_tables = np.zeros((B, self.blocks_per_slot), np.int32)
+        # a cache with window layers: the slot's ring columns behind them
+        self._block_tables = np.zeros((B, self.blocks_per_slot + self._ring),
+                                      np.int32)
         self._slot_blocks = [[] for _ in range(B)]
         # chunked prefill (ISSUE 12): slot -> in-progress admission state.
         # A mid-prefill slot is RESERVED — neither free (its blocks are
@@ -564,11 +618,16 @@ class GenerationEngine:
             with _ag.no_grad(), _lazy.lazy_guard(False):
                 caches = [(Tensor(k), Tensor(v)) for k, v in zip(ks, vs)] \
                     if vs else [(Tensor(k),) for k in ks]
+                # one kind of layer: the table as it is; full and window
+                # layers: each layer its own part
+                tables = self._cache.layer_tables(block_tables,
+                                                  self.block_size)
                 hidden, new_caches = self._gpt(
                     Tensor(ids), position_ids=Tensor(positions),
                     caches=caches, cache_offsets=Tensor(offsets),
                     seq_lens=Tensor(seq_lens),
-                    block_tables=Tensor(block_tables),
+                    block_tables=[Tensor(t) for t in tables]
+                    if isinstance(tables, list) else Tensor(tables),
                     paged_kernel=kernel, paged_mesh=paged_mesh)
                 if self._step_counter_names:
                     got = self._gpt.step_counters()
@@ -838,13 +897,23 @@ class GenerationEngine:
                 f"(max_seq_len={self.max_seq_len})")
         return prompt
 
-    def _admit_blocks(self, prompt, max_new_tokens):
+    def _table_row(self, slot, table_ids):
+        """A slot's table row: its full-layer blocks in order, zero-padded,
+        then (window layers) the ring blocks the slot owns."""
+        row = np.zeros(self._block_tables.shape[1], np.int32)
+        row[:len(table_ids)] = table_ids
+        if self._ring:
+            row[self.blocks_per_slot:] = self._ring_table[slot]
+        return row
+
+    def _admit_blocks(self, slot, prompt, max_new_tokens):
         """Match + pin the longest cached block-aligned prefix (capped so
         the prompt's last token is always recomputed — its hidden state
         feeds the first sample) and allocate the rest of the worst-case
         budget. Returns (table_ids, bt_row, matched_prefix_len)."""
         bs = self.block_size
-        matched = self.prefix_cache.match(prompt)
+        matched = self.prefix_cache.match(prompt) \
+            if self._prefix_sharing else []
         max_full = (len(prompt) - 1) // bs
         matched = matched[:max_full]
         P = len(matched) * bs
@@ -857,9 +926,7 @@ class GenerationEngine:
             self.pool.decref(matched)
             raise
         table_ids = matched + fresh
-        bt_row = np.zeros(self.blocks_per_slot, np.int32)
-        bt_row[:len(table_ids)] = table_ids
-        return table_ids, bt_row, P
+        return table_ids, self._table_row(slot, table_ids), P
 
     def _prefill_call(self, window, end, start, bt_row, key, temperature,
                       top_k, top_p):
@@ -923,7 +990,7 @@ class GenerationEngine:
         else:
             _counters["prefix_misses"] += 1
         bs = self.block_size
-        full = len(prompt) // bs
+        full = len(prompt) // bs if self._prefix_sharing else 0
         if full:
             created = self.prefix_cache.insert(prompt[:full * bs],
                                                table_ids[:full])
@@ -964,7 +1031,8 @@ class GenerationEngine:
         prompt = self._check_prompt(slot, prompt_ids)
         trace = _tracing.trace_id_for_seed(seed) if seed is not None \
             else None
-        table_ids, bt_row, P = self._admit_blocks(prompt, max_new_tokens)
+        table_ids, bt_row, P = self._admit_blocks(slot, prompt,
+                                                  max_new_tokens)
         key = self._request_key(seed)
         try:
             with _tracing.span(trace, "prefill"):
@@ -993,10 +1061,12 @@ class GenerationEngine:
         first token, so decode iterations for in-flight streams
         interleave between chunks instead of stalling behind one long
         prompt. Returns the number of pending chunks."""
+        self.require_full_layers("chunked prefill (begin_prefill)")
         prompt = self._check_prompt(slot, prompt_ids)
         bs = self.block_size
         chunk = max(bs, (int(chunk_tokens or bs) // bs) * bs)
-        table_ids, bt_row, P = self._admit_blocks(prompt, max_new_tokens)
+        table_ids, bt_row, P = self._admit_blocks(slot, prompt,
+                                                  max_new_tokens)
         try:
             self._reserve_extra(slot, prompt, max_new_tokens)
         except Exception:
@@ -1055,12 +1125,39 @@ class GenerationEngine:
         return tok
 
     # --------------------------------------------- prefill→decode handoff --
+    def _note_refusal(self, why):
+        """A feature this engine's cache cannot give: counted
+        (``serving.cache_refusals``) and explained
+        (``cache_feature_refused``), whether it then raises or, like
+        prefix sharing, is a default switched off."""
+        _counters["cache_refusals"] += 1
+        _explain.record("cache_feature_refused", op="serving.engine",
+                        cache_kind=self._cache.kind,
+                        window_layers=self._cache.window_layers(), why=why)
+
+    def _refuse(self, why):
+        self._note_refusal(why)
+        raise TypeError(why)
+
+    def require_full_layers(self, feature):
+        """Refuse what a ring cannot hold: window layers keep the last
+        ``window`` rows of a slot in blocks that are the slot's own."""
+        if self._cache.window is not None:
+            self._refuse(
+                f"{feature} is not supported for a cache with window "
+                f"layers yet: layers {self._cache.window_layers()} keep a "
+                f"ring of the last {self._cache.window} rows a slot, "
+                "which has no head-sharded placement, no payload form, "
+                "and holds neither a verify span nor the rows an earlier "
+                "call wrote beyond the window")
+
     def _heads_cache_only(self, feature):
         if self._cache.kind != "heads":
-            raise TypeError(
+            self._refuse(
                 f"{feature} is not supported for a {self._cache.kind!r} "
                 "cache yet: its payload and its verify span are written "
                 "for K and V rows of heads")
+        self.require_full_layers(feature)
 
     def export_request_kv(self, slot):
         """Serialize an active slot's paged-KV state for a cross-pod
@@ -1179,8 +1276,7 @@ class GenerationEngine:
         except Exception:
             self.pool.decref(fresh)  # failed adoption leaks nothing
             raise
-        bt_row = np.zeros(self.blocks_per_slot, np.int32)
-        bt_row[:n] = fresh
+        bt_row = self._table_row(slot, fresh)
         if prompt_ids is not None:
             prompt = np.asarray(prompt_ids, np.int32).reshape(-1)
             full = min(len(prompt) // self.block_size, n)
@@ -1294,6 +1390,9 @@ class GenerationEngine:
         # them: each active slot's length with its new row
         self._cur_lens[active] += 1
         c["kv_tokens_read"] += int(self._cur_lens[active].sum())
+        if self._ring:  # what ONE window layer read: its ring's live rows
+            c["kv_window_rows_read"] += int(np.minimum(
+                self._cur_lens[active], self._cache.window).sum())
         self._gen_idx[active] += 1
         self._last_tokens[active] = toks[active]
         c["decode_steps"] += 1
@@ -1368,13 +1467,30 @@ class GenerationEngine:
                "kv_pool_row_major": self._kv_row_major,
                "paged_keys_per_program": self._paged_keys_per_program,
                "kv_cache_kind": self._cache.kind,
-               "kv_row_width": self._cache.row_width()}
+               "kv_row_width": self._cache.row_width(),
+               "kv_cache": self._cache.describe(),
+               "kv_window": self._cache.window,
+               "kv_window_blocks": self._ring,
+               "kv_layers": self._layer_records(),
+               "kv_pool_bytes": self._kv_pool_bytes,
+               "prefix_sharing": self._prefix_sharing}
         if self._mesh is not None:
             out["mesh_axes"] = dict(zip(
                 self._mesh.axis_names,
                 (int(s) for s in self._mesh.devices.shape)))
             out["paged_kernel_sharded"] = self._paged_mesh is not None
         return out
+
+    def _layer_records(self):
+        """Every layer's cache state: the heads (or latent rank) and width
+        of its row, its window, its pools' blocks and bytes."""
+        return [{"layer": i, "heads": self._kv_heads[i],
+                 "row_width": self._cache.row_width(i),
+                 "window": self._cache.windows[i],
+                 "blocks": int(k.shape[0]),
+                 "pool_bytes": int(k.nbytes) + (
+                     int(self._v[i].nbytes) if self._v else 0)}
+                for i, k in enumerate(self._k)]
 
     def describe_sharding(self):
         """JSON-able placement description of the engine's hot buffers —

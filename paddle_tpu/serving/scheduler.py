@@ -153,6 +153,12 @@ class ContinuousBatchScheduler:
         # in-flight stream for its whole prefill. None disables.
         self.prefill_chunk_tokens = None if prefill_chunk_tokens is None \
             else int(prefill_chunk_tokens)
+        if self.prefill_chunk_tokens is not None \
+                and hasattr(engine, "require_full_layers"):
+            # a ring of window rows does not hold what an earlier chunk
+            # wrote beyond the window: refused at build, not per request
+            engine.require_full_layers(
+                "chunked prefill (prefill_chunk_tokens)")
         self._queue: collections.deque = collections.deque()
         self._active: dict = {}  # slot -> request
         self._prefilling: dict = {}  # slot -> request (chunked admission)

@@ -21,7 +21,9 @@ bf16 additionally by where probabilities are rounded). Covers:
   * the span walk of PR 33 (a program copies and folds G blocks for all
     heads): lengths on and off block and span edges against the gather
     oracle AND a float64 numpy oracle, every head geometry the lowering
-    test compiles, one block a program for a large block.
+    test compiles, one block a program for a large block;
+  * grouped queries (Hq/Hkv in {1, 4, 16}) and a window layer's ring that
+    has wrapped (PR 35), against the gather route and a float64 oracle.
 
 The parity cases run on a 4-D pool passed to the public op (merged on
 entry) and on the engine's merged pool (PR 28). The compiled kernel is
@@ -301,6 +303,107 @@ class TestSpanWalk:
             == 16
         worst = _against_both(q, kp, vp, bt, lens, [n - 1 for n in lens])
         assert worst <= PARENT, (worst, PARENT)
+
+
+class TestGroupedQueriesAndWindow:
+    """PR 35: a pool whose row holds Hkv heads read by Hq = R x Hkv query
+    heads (a key/value head's R queries meet a span in one dot), and a
+    window layer's ring: position p in ring block (p // bs) % ring, the
+    walk from the block of the oldest key the query may see, the oldest
+    block's rows that fell out masked. The interpreter's kernel body
+    against the gather route AND a float64 oracle that knows neither
+    groups nor rings (it is handed each slot's keys in position order)."""
+
+    @staticmethod
+    def _ring_case(Hkv, R, Dh, window, lens, bs=4, dtype=jnp.float32,
+                   seed=0):
+        """Pools filled a row at a time, position by position, as a slot's
+        decode steps fill them: a ring overwrites itself; rows nothing
+        wrote hold noise (the mask must keep them out)."""
+        from paddle_tpu.ops import kv_pool
+
+        rng = np.random.default_rng(seed)
+        B, W = len(lens), Hkv * Dh
+        if window is None:
+            M = -(-max(lens) // bs) + 1
+            tables = 1 + np.arange(B * M, dtype=np.int32).reshape(B, M)
+        else:
+            M = kv_pool.ring_blocks(window, bs)
+            tables = kv_pool.ring_table(B, M)
+        kp = rng.standard_normal((1 + B * M, bs, W))
+        vp = rng.standard_normal((1 + B * M, bs, W))
+        keys = []  # per slot: the rows by position, for the oracle
+        for b, n in enumerate(lens):
+            rows = rng.standard_normal((2, n, W))
+            for p in range(n):
+                col = p // bs if window is None else (p // bs) % M
+                kp[tables[b, col], p % bs] = rows[0, p]
+                vp[tables[b, col], p % bs] = rows[1, p]
+            keys.append(rows)
+        q = jnp.asarray(rng.standard_normal((B, 1, Hkv * R, Dh)), dtype)
+        return (q, jnp.asarray(kp, dtype), jnp.asarray(vp, dtype),
+                jnp.asarray(tables), keys)
+
+    @staticmethod
+    def _oracle(q, keys, window, Hkv, dtype):
+        q = np.asarray(q, np.float64)
+        B, _, Hq, Dh = q.shape
+        out = np.zeros_like(q)
+        for b, rows in enumerate(keys):
+            k, v = (np.asarray(jnp.asarray(r, dtype), np.float64).reshape(
+                -1, Hkv, Dh) for r in rows)
+            lo = 0 if window is None else max(0, k.shape[0] - window)
+            for h in range(Hq):
+                g = h // (Hq // Hkv)
+                s = k[lo:, g] @ q[b, 0, h] * Dh ** -0.5
+                p = np.exp(s - s.max())
+                out[b, 0, h] = (p / p.sum()) @ v[lo:, g]
+        return out
+
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+    @pytest.mark.parametrize("window", [None, 8, 64])  # none, < len, > len
+    @pytest.mark.parametrize("Hkv,R,Dh", [(2, 1, 64), (2, 4, 32),
+                                          (1, 16, 128), (4, 4, 64)])
+    def test_against_both_oracles(self, Hkv, R, Dh, window, dtype):
+        lens = [5, 30, 1, 23]  # 30 rows wrap a ring of 3 blocks of 4 twice
+        q, kp, vp, bt, keys = self._ring_case(Hkv, R, Dh, window, lens,
+                                              dtype=dtype)
+        sl = jnp.asarray(lens, jnp.int32)
+        got = {k: np.asarray(pallas_ops.paged_attention(
+            q, kp, vp, bt, sl, sl - 1, kernel=k, window=window), np.float64)
+            for k in ("interpret", "xla")}
+        want = self._oracle(q, keys, window, Hkv, dtype)
+        atol, rtol = pallas_ops.PAGED_PARITY_TOL[jnp.dtype(dtype).name]
+        for k in got:
+            np.testing.assert_allclose(got[k], want, atol=atol, rtol=rtol,
+                                       err_msg=k)
+
+    def test_plan_and_names(self):
+        """At the cell's geometry (8 key/value heads of 128, 16 queries
+        each) a group is 4 key/value heads: 64 query rows against 512
+        lanes, 2 groups, 512 keys a program (measured, PERF.md PR 35) — and
+        grouped queries leave the multi-head plan where it was."""
+        assert pallas_ops._paged_plan(16, 8, 128, jnp.bfloat16, 1, 560,
+                                      16) == (32, 512, 64)
+        assert pallas_ops._paged_plan(16, 8, 128, jnp.bfloat16, 1, 257,
+                                      16) == (32, 512, 64)
+        assert pallas_ops.paged_keys_per_program(
+            16, 8, 128, jnp.bfloat16, 560, 16) == 512
+        assert pallas_ops._paged_plan(16, 32, 64, jnp.bfloat16, 1, 128) \
+            == (16, 2048, 32)
+        assert pallas_ops._paged_plan(16, 4, 64, jnp.bfloat16, 1, 64, 4)[1:] \
+            == (256, 16)  # all four 64-wide heads: 4 x 4 rows
+        from paddle_tpu.profiler import spans
+        assert {"paged_attention", "paged_attention_window"} <= set(
+            spans.KERNELS)
+
+    def test_a_ring_takes_one_row_a_slot_and_no_mesh(self):
+        q, kp, vp, bt, _ = self._ring_case(2, 2, 32, 8, [9, 3])
+        sl = jnp.asarray([9, 3], jnp.int32)
+        with pytest.raises(TypeError, match="one query row"):
+            pallas_ops.paged_attention(
+                jnp.concatenate([q, q], axis=1), kp, vp, bt, sl, sl - 2,
+                kernel="xla", window=8)
 
 
 class TestKernelSelection:

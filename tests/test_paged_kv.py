@@ -625,3 +625,159 @@ class TestPagedSchedulingEdges:
         assert eng.blocks_needed(5, 4) == 3       # ceil(9/4)
         assert eng.blocks_needed(5, 500) == 16    # capped at max_seq 64
         assert eng.blocks_needed(5, None) == 16   # unknown budget: worst
+
+
+# ------------------------------ two kinds of cache state side by side (PR 35)
+class TestWindowAndFullLayers:
+    """`CacheSpec(windows=...)`: a window layer's state is a ring of the
+    last `window` rows a slot, sized by the window; a full layer's pools
+    stay as they are. The engine allocates both, budgets admission by the
+    full layers, gives each layer its own table, and refuses — counted,
+    with the reason — what cannot work over a ring."""
+
+    @pytest.fixture(scope="class")
+    def tiny(self):
+        from paddle_tpu.models import Cohere2MoeConfig, Cohere2MoeModel
+
+        paddle.seed(3)
+        model = Cohere2MoeModel(Cohere2MoeConfig.preset("tiny"))
+        model.eval()
+        return model
+
+    def test_spec_sizes_rings_by_the_window(self):
+        import jax.numpy as jnp
+        from paddle_tpu.ops import kv_pool
+
+        # the cell's geometry: 4 full-length layers would be 4.70 GB
+        spec = kv_pool.CacheSpec("heads", [(8, 128)] * 4,
+                                 windows=[4096] * 3 + [None])
+        assert spec.window == 4096 and spec.window_layers() == [0, 1, 2]
+        assert spec.ring_blocks(16) == 257  # one block over the window
+        nb = 1 + 32 * 560
+        blocks = [spec.layer_blocks(i, nb, 16, 32) for i in range(4)]
+        assert blocks == [1 + 32 * 257] * 3 + [nb]
+        row = 16 * 1024 * 2 * 2  # a block of K and of V, bf16
+        assert round(sum(blocks) * row / 1e9, 2) == 2.79
+        assert round(4 * nb * row / 1e9, 2) == 4.70
+        assert "3 x heads" in spec.describe() \
+            and "the last 4096 keys in a ring" in spec.describe() \
+            and "1 x heads" in spec.describe()
+        # one kind of layer: said as before, and the table is the table
+        plain = kv_pool.CacheSpec("heads", [(32, 64)] * 2)
+        assert plain.describe() == \
+            "heads: a K and a V row of 32 x 64 = 2048 a layer"
+        bt = jnp.zeros((2, 5), jnp.int32)
+        assert plain.layer_tables(bt, 16) is bt and plain.ring_blocks(16) == 0
+        with pytest.raises(ValueError, match="one window"):
+            kv_pool.CacheSpec("heads", [(2, 16)] * 2, windows=[8, 16])
+        with pytest.raises(ValueError, match="one window"):
+            kv_pool.CacheSpec("latent", [(32, 8)], windows=[8])
+
+    def test_ring_addressing(self):
+        """Position p lands in ring column (p // bs) % ring of the slot's
+        own blocks; of a prompt longer than the window only the last
+        `window` rows land, so no two rows of a call meet; padding and the
+        head go to the garbage row."""
+        import jax.numpy as jnp
+        from paddle_tpu.ops import kv_pool
+
+        bs, window = 4, 8
+        ring = kv_pool.ring_blocks(window, bs)
+        assert ring == 3
+        table = kv_pool.ring_table(2, ring)
+        assert table.tolist() == [[1, 2, 3], [4, 5, 6]]
+        blk, row = kv_pool.ring_span_rows(
+            jnp.asarray(table[1:]), jnp.asarray([0]), jnp.asarray([22]), 24,
+            bs, window)
+        blk, row = np.asarray(blk), np.asarray(row)
+        live = np.arange(24)[(np.arange(24) >= 14) & (np.arange(24) < 22)]
+        assert (blk[:14] == 0).all() and (blk[22:] == 0).all()
+        assert blk[live].tolist() == [4 + (p // bs) % ring for p in live]
+        assert row[live].tolist() == [p % bs for p in live]
+        assert len({(b, r) for b, r in zip(blk[live], row[live])}) == 8
+        # a decode step's one row, whatever the length
+        blk, row = kv_pool.ring_span_rows(
+            jnp.asarray(table), jnp.asarray([37, 5]), jnp.asarray([38, 6]),
+            1, bs, window)
+        assert np.asarray(blk).tolist() == [1 + (37 // bs) % ring,
+                                            4 + (5 // bs) % ring]
+        # where a slot of 22 rows keeps each position
+        pos = np.asarray(kv_pool.ring_positions(jnp.asarray([22]), ring, bs))
+        assert pos[0].tolist() == [12, 13, 14, 15, 16, 17, 18, 19,
+                                   20, 21, 22, 23]
+
+    def test_engine_allocates_both_and_tells_every_layer(self, tiny):
+        from paddle_tpu.profiler import explainer
+
+        explainer.clear()
+        eng = GenerationEngine(tiny, max_batch_size=2, buckets=(32, 64),
+                               max_seq_len=128, block_size=8, rng_seed=0)
+        st = eng.stats()
+        assert st["kv_cache_kind"] == "heads" and st["kv_window"] == 24
+        assert st["kv_window_blocks"] == 4 and not st["prefix_sharing"]
+        assert [(r["heads"], r["window"], r["blocks"])
+                for r in st["kv_layers"]] == [(2, 24, 9)] * 3 + [(2, None, 33)]
+        assert st["kv_pool_bytes"] == sum(
+            int(k.nbytes) + int(v.nbytes) for k, v in zip(eng._k, eng._v))
+        assert eng._block_tables.shape == (2, 16 + 4)
+        assert registry.gauges().get("serving.kv_layers_window") == 3 \
+            and registry.gauges().get("serving.kv_layers_full") == 1 \
+            and registry.gauges().get("serving.kv_window_blocks") == 4
+        said = [e for e in explainer.events()
+                if e.get("kind") == "kv_pool_layout"]
+        assert said and "3 x heads" in said[-1]["why"] \
+            and "ring is 4 blocks a slot" in said[-1]["why"]
+        # serve two requests past the window, then everything comes back
+        before = registry.counters("serving")
+        rng = np.random.default_rng(0)
+        for slot, n in enumerate((50, 9)):
+            eng.prefill(slot, rng.integers(1, 512, n).tolist(),
+                        max_new_tokens=30)
+        assert eng._block_tables[1, 16:].tolist() == [5, 6, 7, 8]
+        for _ in range(20):
+            eng.decode_step()
+        after = registry.counters("serving")
+        full = after["kv_tokens_read"] - before["kv_tokens_read"]
+        win = after["kv_window_rows_read"] - before["kv_window_rows_read"]
+        assert full == sum(50 + t + 9 + t for t in range(1, 21))
+        assert win == sum(24 + min(9 + t, 24) for t in range(1, 21))
+        assert after["prefix_hits"] == before["prefix_hits"] \
+            and len(eng.prefix_cache) == 0
+        eng.release(0), eng.release(1)
+        audit = eng.pool.audit()
+        assert audit["in_use"] == 0 and audit["free"] == audit["total"]
+        assert (eng._block_tables == 0).all()
+
+    def test_what_a_ring_cannot_hold_is_refused_and_counted(self, tiny):
+        from paddle_tpu.profiler import explainer
+        from paddle_tpu.serving.spec_decode import DraftVerifyEngine
+
+        explainer.clear()
+        c0 = registry.counters("serving")["cache_refusals"]
+        eng = GenerationEngine(tiny, max_batch_size=2, buckets=(32,),
+                               max_seq_len=64, block_size=8, rng_seed=0)
+        # prefix sharing is a default, not a call: switched off, and said
+        assert registry.counters("serving")["cache_refusals"] == c0 + 1
+        eng.prefill(0, list(range(1, 20)), max_new_tokens=4)
+        for feature, call in (
+                ("handoff", lambda: eng.export_request_kv(0)),
+                ("handoff", lambda: eng.import_request_kv(1, {})),
+                ("chunked prefill", lambda: eng.begin_prefill(
+                    1, list(range(1, 20)), chunk_tokens=8)),
+                ("chunked prefill", lambda: GenerationServer(
+                    engine=eng, prefill_chunk_tokens=8)),
+                ("mesh", lambda: GenerationEngine(
+                    tiny, max_batch_size=2, mesh=object())),
+                ("spec_decode", lambda: DraftVerifyEngine(
+                    tiny, tiny, max_batch_size=2, buckets=(32,),
+                    max_seq_len=64, block_size=8))):
+            with pytest.raises(TypeError, match=f"{feature}.*window "
+                               r"layers.*layers \[0, 1, 2\].*ring"):
+                call()
+        # six raised (+ the two engines built inside said prefix sharing)
+        assert registry.counters("serving")["cache_refusals"] >= c0 + 7
+        why = [e["why"] for e in explainer.events()
+               if e.get("kind") == "cache_feature_refused"]
+        assert any("prefix sharing" in w for w in why) \
+            and any("handoff" in w for w in why)
+        eng.release(0)

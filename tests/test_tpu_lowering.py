@@ -276,6 +276,27 @@ for cell, (slots, blocks) in {"chat": (32, 3073),
                                           nb=blocks))
     out[f"paged_{cell}_cell"]["keys_per_program"] = \
         po.paged_keys_per_program(16, 32, 64, jnp.bfloat16, 128)
+# a kernel's Mosaic body, debug info (paths, lines) set aside
+import base64, hashlib, re
+from jax._src.interpreters import mlir
+from jax._src.lib.mlir import ir
+
+def body_sha256(fn, *avals):
+    body = re.search(r"body.22: .22([A-Za-z0-9+/=]+)",
+                     jax.jit(fn).trace(*avals).lower(
+                         lowering_platforms=("tpu",)).as_text()).group(1)
+    ctx = mlir.make_ir_context()
+    ctx.allow_unregistered_dialects = True
+    with ctx:
+        asm = ir.Module.parse(base64.b64decode(body)).operation.get_asm(
+            enable_debug_info=False)
+    return hashlib.sha256(asm.encode()).hexdigest()
+
+try:
+    out["paged_chat_cell"]["body_sha256"] = body_sha256(*paged(
+        1, 64, jnp.bfloat16, one, one, H=32, pool=one, B=32, M=128, nb=3073))
+except Exception as e:
+    out["paged_chat_cell_body"] = f"{type(e).__name__}: {e}"[:600]
 # the xing4 cell's kernels at its sizes: the latent (MLA) decode kernel, 32
 # slots x 32 heads over 640-lane rows, 5,633 blocks of 16, 176 table columns;
 # and XLA:TPU's own grouped matmul for the dropless expert layer's decode
@@ -287,21 +308,8 @@ mla = (lambda *x: po.mla_paged_attention(*x, 0.1, kernel="pallas"),
        sds((32, 176), jnp.int32, sharding=one),
        sds((32,), jnp.int32, sharding=one))
 compile_("xing4_mla_paged", *mla)
-# the latent kernel's Mosaic body, debug info (paths, lines) set aside
-import base64, hashlib, re
-from jax._src.interpreters import mlir
-from jax._src.lib.mlir import ir
 try:
-    body = re.search(r"body.22: .22([A-Za-z0-9+/=]+)",
-                     jax.jit(mla[0]).trace(*mla[1:]).lower(
-                         lowering_platforms=("tpu",)).as_text()).group(1)
-    ctx = mlir.make_ir_context()
-    ctx.allow_unregistered_dialects = True
-    with ctx:
-        asm = ir.Module.parse(base64.b64decode(body)).operation.get_asm(
-            enable_debug_info=False)
-    out["xing4_mla_paged"]["body_sha256"] = hashlib.sha256(
-        asm.encode()).hexdigest()
+    out["xing4_mla_paged"]["body_sha256"] = body_sha256(*mla)
     out["xing4_mla_paged"]["keys_per_program"] = \
         po.mla_keys_per_program(16, 176)
 except Exception as e:
@@ -390,6 +398,53 @@ for T in (1, 5):
             "custom_calls": c.as_text().count('"tpu_custom_call"')}
     except Exception as e:
         out[f"serve_layer_T{T}"] = f"{type(e).__name__}: {e}"[:600]
+
+# the command-a-plus cell's two kinds of attention layer at its own sizes
+# (PR 35: 128 query heads over 8 key/value heads of 128, 32 slots; a full
+# layer's pools of 1 + 32 x 560 blocks behind 560 table columns, a window
+# layer's of 1 + 32 x 257 behind the slot's 257 ring columns), donated: both
+# kinds of pool enter row-major and are written in place, one custom call a
+# layer under its own name
+from paddle_tpu.models.cohere2_moe import (Cohere2MoeAttention,
+                                            Cohere2MoeConfig)
+
+cmd_cfg = Cohere2MoeConfig(dtype="bfloat16", num_hidden_layers=1,
+                           vocab_size=128, experts_held=(0, 1))
+for kind, sliding, cols, blocks in (("window", True, 257, 1 + 32 * 257),
+                                    ("full", False, 560, 1 + 32 * 560)):
+    cattn = Cohere2MoeAttention(cmd_cfg, sliding)
+    cattn.eval()
+    pool = sds((blocks, 16, 1024), jnp.bfloat16, sharding=one)
+    POOL = blocks * 16 * 1024
+
+    def cmd_layer(u, kp, vp, bt, off, sl, cattn=cattn):
+        with ag.no_grad(), lazy.lazy_guard(False):
+            y, (nk, nv) = cattn(u, off[:, None], cache=(kp, vp),
+                                cache_offset=off, seq_lens=sl,
+                                block_tables=bt, paged_kernel="pallas")
+        return y, nk, nv
+
+    try:
+        c = jax.jit(cmd_layer, donate_argnums=(1, 2)).trace(
+            sds((32, 1, 4096), jnp.bfloat16, sharding=one), pool, pool,
+            sds((32, cols), jnp.int32, sharding=one),
+            sds((32,), jnp.int32, sharding=one),
+            sds((32,), jnp.int32, sharding=one)).lower(
+                lowering_platforms=("tpu",)).compile()
+        text = c.as_text()
+        out[f"commanda_layer_{kind}"] = {
+            "pool_bytes": POOL * 2,
+            "pool_layout": list(c.input_formats[0][1].layout.major_to_minor),
+            "pool_sized": pool_sized(text),
+            "temp_bytes": int(c.memory_analysis().temp_size_in_bytes),
+            "alias_bytes": int(c.memory_analysis().alias_size_in_bytes),
+            "custom_calls": text.count('"tpu_custom_call"'),
+            "kernels": sorted(set(re.findall(
+                r"%(paged_attention\w*?)(?:\.\d+)? = ", text))),
+            "keys_per_program": po.paged_keys_per_program(
+                16, 8, 128, jnp.bfloat16, cols, 16)}
+    except Exception as e:
+        out[f"commanda_layer_{kind}"] = f"{type(e).__name__}: {e}"[:600]
 
 # the serving engines' own executables at toy depth and the cells'
 # vocabularies and slots: sampling finds its thresholds by selection (PR 31),
@@ -485,15 +540,23 @@ def test_aot_compile_for_v5e():
     assert all(v["custom_calls"] == 1 for k, v in res.items()
                if k.startswith("paged_"))
     # both gpt serving cells: one custom call a layer, 256 keys a program
+    # PR 35 taught the heads kernel grouped queries and a window; the body
+    # Mosaic is handed at the chat cell's geometry is PR 33's (commit
+    # 4abdfaf), byte for byte once paths and lines are set aside. A PR that
+    # means to change the multi-head kernel replaces the digest.
+    assert res["paged_chat_cell"].pop("body_sha256") \
+        == PAGED_CHAT_BODY_SHA256, res.get("paged_chat_cell_body")
     for cell in ("paged_chat_cell", "paged_long_prefill_cell"):
         assert res[cell] == {"custom_calls": 1, "collectives": 0,
                              "keys_per_program": 256}, res[cell]
     _SERVE_LAYERS.update((k, v) for k, v in res.items()
                          if k.startswith(("serve_layer_", "xing4_",
-                                          "sampling_")))
+                                          "sampling_", "commanda_")))
 
 
 _SERVE_LAYERS: dict = {}
+PAGED_CHAT_BODY_SHA256 = (
+    "272c8a5eeab65e345bf54fd326f0eb7606a7194b06f5a590744a4a01389a830e")
 LATENT_BODY_SHA256 = (
     "4d8e0df8e25229452bc5f7e3359b5d94b5833ef70664c81897e04bccc6ab27ac")
 
@@ -563,3 +626,33 @@ def test_serving_layer_writes_its_kv_rows_in_place_on_v5e(T):
         + got["pool_sized"].get("dynamic-update-slice", 0) >= 2, got
     assert got["temp_bytes"] < got["pool_bytes"], got  # (c)
     assert got["alias_bytes"] >= 2 * got["pool_bytes"], got  # donated
+
+
+@pytest.mark.parametrize("kind", ["window", "full"])
+def test_window_and_full_pools_are_row_major_and_written_in_place_on_v5e(kind):
+    """The command-a-plus cell's two kinds of attention layer, compiled for
+    the described v5e with donated pools at the cell's sizes (PR 35; the AOT
+    child's result of the test above): a window layer's ring pools (1 + 32 x
+    257 blocks of 16 rows of 8 x 128) and a full layer's (1 + 32 x 560)
+    enter row-major, nothing of a pool's size exists but the pools and the
+    in-place write, the temporaries stay under one pool, and the layer's one
+    custom call carries its kind's name: 16 query heads a key/value head
+    through `paged_attention_window` / `paged_attention`, 512 keys a
+    program."""
+    if not _SERVE_LAYERS:
+        pytest.skip("test_aot_compile_for_v5e did not compile here")
+    got = _SERVE_LAYERS[f"commanda_layer_{kind}"]
+    assert isinstance(got, dict), got
+    assert got["custom_calls"] == 1 and got["keys_per_program"] == 512
+    assert got["kernels"] == ["paged_attention_window" if kind == "window"
+                              else "paged_attention"], got
+    assert got["pool_layout"] == [0, 1, 2], got
+    extra = {op: n for op, n in got["pool_sized"].items()
+             if op not in ("parameter", "bitcast", "write", "scatter",
+                           "dynamic-update-slice")}
+    assert not extra, got
+    assert got["pool_sized"].get("write", 0) \
+        + got["pool_sized"].get("scatter", 0) \
+        + got["pool_sized"].get("dynamic-update-slice", 0) >= 2, got
+    assert got["temp_bytes"] < got["pool_bytes"], got
+    assert got["alias_bytes"] >= 2 * got["pool_bytes"], got
