@@ -507,7 +507,8 @@ def _serve(ctx, refs, mesh=None):
     c0 = dict(registry.counters("serving"))
 
     def delta(name):
-        return registry.counters("serving")[name] - c0[name]
+        # a span's counters exist from its first use on
+        return registry.counters("serving")[name] - c0.get(name, 0)
 
     # default kernel choice: no paged_kernel argument, no env
     server = _serve_engine(ctx, model, **kw)
@@ -526,6 +527,9 @@ def _serve(ctx, refs, mesh=None):
     facts = {
         **_compile_facts(warm, steady),
         "paged_kernel": eng.paged_kernel,
+        "prefill_kernel": eng.stats()["prefill_kernel"],
+        "prefill_flash_calls": delta("prefill_flash_calls"),
+        "prefill_calls": delta("prefill_n"),
         "decode_compiles": delta("decode_compiles"),
         "prefill_compiles": delta("prefill_compiles"),
         "kernel_fallbacks": delta("kernel.fallbacks"),
@@ -541,6 +545,10 @@ def _serve(ctx, refs, mesh=None):
         and facts["requests_failed"] == 0, facts
     assert facts["kv_pools_donated"] == ctx.on_tpu, facts
     assert facts["kv_pool_row_major"] == 1, facts
+    # the prompt span reads through the kernel the decode step resolved
+    assert facts["prefill_kernel"] == want_kernel, facts
+    assert facts["prefill_flash_calls"] == (
+        facts["prefill_calls"] if ctx.on_tpu else 0), facts
     greedy = [i for i, (_, g, _) in enumerate(waves[0]) if g]
     facts["greedy_tokens"] = [toks[i] for i in greedy]
     facts["sampled_tokens"] = [t for i, t in enumerate(toks)
@@ -621,6 +629,29 @@ def leg_kernels(ctx, refs):
             key = f"paged.dh{dh}.{jnp.dtype(dt).name}"
             worst[key] = max(worst.get(key, 0.0),
                              float(np.abs(got - ref).max()))
+        # the prompt span's kernel (flash_prefill): a bucket of 64 rows a
+        # slot, cold and behind a cached prefix, the second slot's prompt
+        # shorter than the bucket
+        for dh, H, dt in ((64, 16, jnp.bfloat16), (128, 16, jnp.bfloat16),
+                          (64, 16, jnp.float32)):
+            q = jnp.asarray(rng.normal(size=(2, 64, H, dh)), dt)
+            kp = jnp.asarray(rng.normal(size=(nb, 16, H * dh)), dt)
+            vp = jnp.asarray(rng.normal(size=(nb, 16, H * dh)), dt)
+            bt = jnp.asarray(1 + rng.permutation(2 * M).reshape(2, M),
+                             jnp.int32)
+            for qo, sl in (((0, 0), (64, 37)), ((48, 16), (112, 61))):
+                qo, sl = jnp.asarray(qo, jnp.int32), jnp.asarray(sl, jnp.int32)
+                got, ref = (np.asarray(po.flash_prefill(
+                    q, kp, vp, bt, sl, qo, kernel=k), np.float32)
+                    for k in (kind, "xla"))
+                atol, rtol = po.PAGED_PARITY_TOL[jnp.dtype(dt).name]
+                for b in range(2):  # the prompt's rows, not the padding
+                    n = int(sl[b] - qo[b])
+                    np.testing.assert_allclose(got[b, :n], ref[b, :n],
+                                               atol=atol, rtol=rtol)
+                    key = f"prefill.dh{dh}.{jnp.dtype(dt).name}"
+                    worst[key] = max(worst.get(key, 0.0), float(
+                        np.abs(got[b, :n] - ref[b, :n]).max()))
         if ctx.on_tpu:
             for (b, t, n, h), dt in (((2, 512, 8, 64), jnp.bfloat16),
                                      ((2, 512, 4, 128), jnp.bfloat16),

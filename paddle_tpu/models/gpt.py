@@ -163,11 +163,19 @@ class GPTAttention(nn.Layer):
                 # write above is the same (T rows, garbage-block-0
                 # redirect intact); only the O(M*bs) gather is fused.
                 # `paged_kernel` is a static per-engine choice
-                # (pallas_ops.select_paged_kernel) — never data.
-                out = F.paged_attention(q, new_k, new_v, block_tables,
-                                        seq_lens, cache_offset,
-                                        kernel=paged_kernel,
-                                        mesh=paged_mesh)
+                # (pallas_ops.select_paged_kernel) — never data. Which
+                # kernel reads is the span's static T: a prompt span
+                # (a bucket of rows, ISSUE 36) folds the slot's keys a
+                # block of query rows at a time in `flash_prefill`;
+                # decode's one row and a verify span's K + 1 stay with
+                # `paged_attention`. Same mask, same operands.
+                from ..ops.pallas_ops import prefill_span
+
+                read = F.flash_prefill if prefill_span(T) \
+                    else F.paged_attention
+                out = read(q, new_k, new_v, block_tables, seq_lens,
+                           cache_offset, kernel=paged_kernel,
+                           mesh=paged_mesh)
                 out = self.out_proj(out.reshape([B, T, D]))
                 return out, (new_k, new_v)
             k_view = F.paged_kv_view(new_k, block_tables, self.n_head)
@@ -323,6 +331,12 @@ class GPTModel(nn.Layer):
         return self.ln_f(x)
 
     # -- what serving.GenerationEngine asks of a decoder -------------------
+    # the cache path's prompt span reads the slot's rows through whatever
+    # kernel the engine passes (GPTAttention.forward): the engine resolves
+    # its prefill kernel for such a decoder and leaves another's prefill to
+    # its own forward
+    prefill_reads_pools = True
+
     @property
     def max_positions(self):
         return self.cfg.seq_len  # the learned position table

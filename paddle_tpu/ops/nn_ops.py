@@ -43,7 +43,8 @@ __all__ = [
     "cosine_similarity", "cosine_embedding_loss", "label_smooth",
     "log_loss", "square_error_cost", "sigmoid_focal_loss", "dice_loss",
     "ctc_loss", "triplet_margin_loss", "pairwise_distance", "npair_loss",
-    "scaled_dot_product_attention", "paged_attention", "paged_kv_write",
+    "scaled_dot_product_attention", "paged_attention", "flash_prefill",
+    "paged_kv_write",
     "paged_kv_view", "sequence_mask",
     "temporal_shift", "channel_shuffle",
 ]
@@ -1149,6 +1150,25 @@ def paged_attention(query, k_pool, v_pool, block_tables, seq_lens,
 
     return forward(f, (query, k_pool, v_pool, block_tables, seq_lens,
                        q_offsets), name="paged_attention", nondiff=True)
+
+
+def flash_prefill(query, k_pool, v_pool, block_tables, seq_lens,
+                  q_offsets, kernel="xla", mesh=None, name=None):
+    """The prompt span's attention (ISSUE 36): ``query`` [B, T, H, Dh], a
+    bucket of new rows a slot already written to the pools, reads the
+    slot's rows through ``block_tables`` under :func:`paged_attention`'s
+    mask, a block of query rows at a time with a running softmax: no
+    [H, T, S] scores, no mask array and no gathered view on the Pallas
+    routes. ``kernel`` is STATIC, resolved once per engine by
+    ``pallas_ops.select_prefill_kernel``. Inference-only (nondiff)."""
+    from . import pallas_ops
+
+    def f(q, kp, vp, bt, sl, qo):
+        return pallas_ops.flash_prefill(q, kp, vp, bt, sl, qo,
+                                        kernel=kernel, mesh=mesh)
+
+    return forward(f, (query, k_pool, v_pool, block_tables, seq_lens,
+                       q_offsets), name="flash_prefill", nondiff=True)
 
 
 def sequence_mask(lengths, maxlen=None, dtype="int64", name=None):

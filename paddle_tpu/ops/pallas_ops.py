@@ -58,9 +58,10 @@ def _note_kernel_fallback(family, reason, **detail):
     """A Pallas-eligible call resolved to the XLA path: name the shape or
     platform reason in the explainer ring so the slowdown is loud. Each
     family bumps its OWN fallback counter — serving.kernel.fallbacks is
-    the paged decode/verify family's serving-health signal and must not
-    be inflated by training flash traces."""
-    if family.startswith("flash"):
+    the serving kernels' health signal (the paged decode/verify family and
+    the prompt span's `flash_prefill`) and must not be inflated by
+    training flash traces."""
+    if family == "flash_attention":
         _flash_counters["flash.fallbacks"] += 1
     else:
         _paged_counters["kernel.fallbacks"] += 1
@@ -868,32 +869,28 @@ def _mesh_mp_degree(mesh):
     return int(dict(mesh.shape).get("mp", 1))
 
 
-def _paged_attention_sharded(q, k_pool, v_pool, block_tables, seq_lens,
-                             q_offsets, scale, interpret, mesh):
-    """Per-shard fused kernel under ``jax.shard_map``: pools and q are
-    head-sharded over the mesh's 'mp' axis, block tables / seq_lens /
-    q_offsets ride in replicated, and each shard runs the UNMODIFIED
-    kernel body over its local heads. The kernel computes every head
-    independently (per-head scratch rows, no cross-head reduction), so
-    the sharded result is bitwise the single-chip result. check_vma is
-    off because pallas_call carries no replication rule."""
+def _per_head_shard(body, mesh, num_heads):
+    """``body(q, k_pool, v_pool, block_tables, seq_lens, q_offsets)`` per
+    shard under ``jax.shard_map``: pools and q are head-sharded over the
+    mesh's 'mp' axis, block tables / seq_lens / q_offsets ride in
+    replicated, and each shard runs the UNMODIFIED kernel body over its
+    local heads. The kernels compute every head independently (per-head
+    scratch rows, no cross-head reduction), so the sharded result is
+    bitwise the single-chip result. check_vma is off because pallas_call
+    carries no replication rule."""
     mp = _mesh_mp_degree(mesh)
-    H = int(q.shape[2])
-    if H % mp:  # select_paged_kernel prevents this; defensive
+    if num_heads % mp:  # select_paged_kernel prevents this; defensive
         raise ValueError(
-            f"paged_attention: {H} heads do not divide over mesh axis "
-            f"mp={mp}; resolve the kernel with select_paged_kernel("
+            f"paged_attention: {num_heads} heads do not divide over mesh "
+            f"axis mp={mp}; resolve the kernel with select_paged_kernel("
             "num_heads=...) so indivisible head counts demote to xla")
     head = P(None, None, "mp", None)
     pool = _kv_pool.pspec(True)  # a shard's merged axis is its own heads
     repl = P()
-    body = functools.partial(_paged_attention_fused, scale=scale,
-                             interpret=interpret)
     return jax.shard_map(
         body, mesh=mesh,
         in_specs=(head, pool, pool, repl, repl, repl),
-        out_specs=head, check_vma=False,
-    )(q, k_pool, v_pool, block_tables, seq_lens, q_offsets)
+        out_specs=head, check_vma=False)
 
 
 def paged_attention_xla(q, k_pool, v_pool, block_tables, seq_lens,
@@ -971,16 +968,12 @@ def paged_attention(q, k_pool, v_pool, block_tables, seq_lens, q_offsets,
             f"unknown paged-attention kernel {kernel!r} "
             "(expected pallas | interpret | xla)")
     k_pool, v_pool = _kv_pool.merged(k_pool), _kv_pool.merged(v_pool)
+    body = functools.partial(_paged_attention_fused, scale=scale,
+                             interpret=(kernel == "interpret"),
+                             window=window)
     if _mesh_mp_degree(mesh) > 1:
-        out = _paged_attention_sharded(q, k_pool, v_pool, block_tables,
-                                       seq_lens, q_offsets, scale,
-                                       interpret=(kernel == "interpret"),
-                                       mesh=mesh)
-    else:
-        out = _paged_attention_fused(q, k_pool, v_pool, block_tables,
-                                     seq_lens, q_offsets, scale,
-                                     interpret=(kernel == "interpret"),
-                                     window=window)
+        body = _per_head_shard(body, mesh, int(q.shape[2]))
+    out = body(q, k_pool, v_pool, block_tables, seq_lens, q_offsets)
     # kernel_mismatch fault (testing/faults.py): perturb ONE element of
     # the fused output so parity gates provably trip. Trace-time firing:
     # the perturbation is baked into whichever executable traces while
@@ -1106,6 +1099,371 @@ def select_paged_kernel(requested=None, *, head_dim, block_size, dtype,
                    f"(local heads {num_heads // mp})")
     _paged_counters[f"kernel.{kind}"] += 1
     return kind, reason
+
+
+# ============================ flash prefill ==================================
+#
+# The prompt span's read (ISSUE 36): one slot's T new rows (a whole prompt, a
+# prefix hit's remainder or one chunk, T a bucket) attend to the slot's rows
+# in the pools, their own among them (the call wrote them just before).
+# Semantics are the gather path's and the paged kernel's: key position j is
+# valid for span row t iff  j <= q_offsets[b] + t  AND  j < seq_lens[b]; the
+# offset and the length are DATA (scalar prefetch), so a cold prefill, a
+# prefix hit and a chunk share one executable a bucket.
+#
+# The gather path formed [H, T, S] float32 scores in HBM, a [T, S] mask and a
+# per-head relayout of the slot's gathered view, at the table's whole width
+# whatever the prompt (PERF.md, PR 36: 88 + 19 of a 147 ms prefill). Here the
+# pools stay in HBM (pl.ANY) as in the paged kernel; the first program of a
+# slot starts one DMA a LIVE block of the slot into a VMEM copy of the
+# slot's rows as they lie ([spans, span, H*Dh]: no head is transposed) and
+# every program waits only for the spans it is the first to read, so the
+# copies run behind the first query blocks' dots. A program is one block of
+# ``block_q`` query rows, all heads: the merged axis is taken in groups of
+# whole lane tiles holding whole heads (128 lanes: two 64-wide heads), a
+# group's heads meet a span block-diagonally as in the paged kernel (head h's
+# queries on rows h*block_q.., its own lanes, zeros on the rest), so no head
+# is sliced, rotated or addressed, and the online softmax state of a group
+# lives in the loop's carry. Spans are at ABSOLUTE key positions (span k =
+# keys k*span .. (k+1)*span-1 whatever the offset) and fold in rising order,
+# so a row folds the same keys in the same order in a one-shot, a chunked
+# and a prefix-hit prefill. What is dead is skipped, not masked: spans past
+# the block's last visible key are never read, spans wholly visible take the
+# loop without the mask, and a query block wholly past the prompt (the
+# bucket's padding) writes zeros.
+
+# rows of one query block and keys of one span (whole blocks of the pool),
+# at most: measured on the v5e at the two gpt cells' shapes (PERF.md, PR 36:
+# 128 x 512 beat 256 x 256 by 22 % at the 2048 bucket and 13-30 % at the 512
+# and 1024 buckets and tied it at 256 — the row statistics are columns of
+# `heads * block_q` rows whatever the span, so a longer span amortises them,
+# and a shorter query block wastes less above the diagonal; 1024 keys lost
+# 10-15 %). A bucket takes the largest power of two <= the first that
+# divides it.
+_PREFILL_MAX_BLOCK_Q = 128
+_PREFILL_MAX_SPAN_KEYS = 512
+# the slot's K and V rows held in VMEM, at most, and what the body needs
+# beside them (query and output blocks, one group's scores and accumulator);
+# their sum is the kernel's `vmem_limit_bytes` (a v5e core has 128 MiB)
+_PREFILL_MAX_RESIDENT = 40 << 20
+_PREFILL_WORK_BYTES = 16 << 20
+# a span of at least this many rows, in whole 16-row tiles, is a PROMPT span
+# (decode's T = 1 and a verify span's T = K + 1 stay with the paged kernel)
+_PREFILL_MIN_ROWS = 16
+
+
+def prefill_span(rows):
+    """Is a span of ``rows`` new rows a slot a prompt span, the flash
+    prefill kernel's (True), or a decode / verify span, the paged kernel's
+    (False)? Static: read from the shape."""
+    return rows >= _PREFILL_MIN_ROWS and rows % _PREFILL_MIN_ROWS == 0
+
+
+def _prefill_group_lanes(num_heads, head_dim):
+    """Lanes of one head group: the fewest whole 128-lane tiles holding
+    whole heads (64-wide heads: two a group, so the block-diagonal dot
+    contracts the MXU's full depth and wastes no pass); all of the merged
+    axis where heads and tiles never line up (the interpreter only,
+    `prefill_tileable`)."""
+    width = num_heads * head_dim
+    unit = math.lcm(head_dim, 128)
+    return width if width % unit else unit
+
+
+def _prefill_plan(rows, block_size, table_cols, block_q=None, span=None):
+    """(block_q, blocks a span) for a span of ``rows`` query rows over a
+    table of ``table_cols`` blocks. The span depends on the pool's geometry
+    only, never on ``rows``: every bucket folds a row's keys in the same
+    order."""
+    if block_q is None:
+        block_q = _PREFILL_MAX_BLOCK_Q
+        while block_q > _PREFILL_MIN_ROWS and rows % block_q:
+            block_q //= 2
+        if rows % block_q:  # the interpreter only: one block of all rows
+            block_q = rows
+    G = max(1, min((span or _PREFILL_MAX_SPAN_KEYS) // block_size,
+                   table_cols))
+    return int(block_q), int(G)
+
+
+def _prefill_resident_bytes(table_cols, G, block_size, width, dtype):
+    """Bytes of VMEM the kernel's copy of one slot's K and V rows takes:
+    the table's columns rounded up to whole spans of ``G`` blocks."""
+    return (2 * -(-table_cols // G) * G * block_size * width
+            * jnp.dtype(dtype).itemsize)
+
+
+def _flash_prefill_kernel(bt_ref, sl_ref, qo_ref, q_ref, k_hbm, v_hbm, o_ref,
+                          k_scr, v_scr, sem, ready, *, scale, head_dim,
+                          lanes, precision):
+    """Grid (B, T / block_q): program (b, i) is query rows ``i*block_q ..``
+    of slot b against the slot's keys. ``k_scr`` / ``v_scr`` ``[spans,
+    span, H*Dh]`` hold the slot's rows, copied by program (b, 0) one DMA a
+    live block; ``sem[k]`` counts span k's copies and ``ready`` how many
+    spans this slot's programs have waited for."""
+    b = pl.program_id(0)
+    i = pl.program_id(1)
+    n_spans, span, HD = k_scr.shape
+    block_q = q_ref.shape[0]
+    bs = k_hbm.shape[1]
+    G = span // bs
+    heads = lanes // head_dim  # heads of a group
+    n_groups = HD // lanes
+    rows = heads * block_q
+    i32 = jnp.int32
+    dot = functools.partial(jnp.dot, preferred_element_type=jnp.float32,
+                            precision=precision)
+    dot_nt = functools.partial(  # q @ k.T without forming k.T
+        jax.lax.dot_general, dimension_numbers=(((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32, precision=precision)
+    # a power-of-two scale (64-wide heads: 1/8) is exact on the query in
+    # any dtype; any other goes on the float32 scores
+    fold_scale = math.frexp(scale)[0] == 0.5
+
+    off, sl = qo_ref[b], sl_ref[b]
+    total = jnp.minimum(off + pl.num_programs(1) * i32(block_q), sl)
+    n_blk = pl.cdiv(total, i32(bs))  # blocks any row of the call reads
+    row0 = off + i * i32(block_q)  # position of the block's first row
+    live = row0 < sl  # the block holds a row of the prompt
+    limit = jnp.minimum(row0 + i32(block_q), sl)  # keys it reads: < limit
+
+    def copies(col, blk=_i0()):
+        k, r = col // i32(G), pl.multiple_of((col % i32(G)) * i32(bs), bs)
+        return (pltpu.make_async_copy(
+                    k_hbm.at[blk], k_scr.at[k, pl.ds(r, bs)],
+                    sem.at[k, _i0()]),
+                pltpu.make_async_copy(
+                    v_hbm.at[blk], v_scr.at[k, pl.ds(r, bs)],
+                    sem.at[k, i32(1)]))
+
+    @pl.when((b == 0) & (i == 0))
+    def _first():
+        # rows no copy reaches meet p = 0 in the fold: they must be finite
+        k_scr[...] = jnp.zeros_like(k_scr)
+        v_scr[...] = jnp.zeros_like(v_scr)
+
+    @pl.when(i == 0)
+    def _fetch():
+        def start(col, _):
+            for c in copies(col, bt_ref[b, col]):
+                c.start()
+            return _
+        jax.lax.fori_loop(_i0(), n_blk, start, _i0())
+        ready[0] = _i0()
+
+    # wait for the spans this block is the first to read (the last block
+    # of a slot for whatever is left: no copy outlives the slot's programs)
+    need = jnp.where(live, pl.cdiv(limit, i32(span)), _i0())
+    need = jnp.where(i == pl.num_programs(1) - 1,
+                     pl.cdiv(n_blk, i32(G)), need)
+
+    def wait_span(k, _):
+        def wait(g, _):
+            for c in copies(k * i32(G) + g):
+                c.wait()
+            return _
+        return jax.lax.fori_loop(
+            _i0(), jnp.minimum(n_blk - k * i32(G), i32(G)), wait, _)
+    jax.lax.fori_loop(ready[0], need, wait_span, _i0())
+    ready[0] = jnp.maximum(ready[0], need)
+
+    @pl.when(jnp.logical_not(live))
+    def _padding():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    @pl.when(live)
+    def _fold():
+        # rows h*block_q.. of a group's block are head h's queries
+        lane = jax.lax.broadcasted_iota(i32, (block_q, lanes), 1)
+        qpos = jnp.concatenate(
+            [row0 + jax.lax.broadcasted_iota(i32, (block_q, 1), 0)] * heads,
+            axis=0)
+        # spans wholly visible to every row of the block and wholly live
+        n_full = jnp.minimum((row0 + i32(1)) // i32(span), sl // i32(span))
+        hi = pl.cdiv(limit, i32(span))
+
+        def group(g, done):
+            cols = pl.ds(pl.multiple_of(g * i32(lanes), lanes), lanes)
+            qg = q_ref[:, cols].astype(jnp.float32)  # selects on 32 bits
+            if fold_scale:
+                qg = qg * _f32(scale)
+            qbd = jnp.concatenate(
+                [jnp.where((lane >= i32(h * head_dim))
+                           & (lane < i32((h + 1) * head_dim)), qg, _f32(0))
+                 for h in range(heads)], axis=0).astype(k_scr.dtype)
+
+            def fold(k, carry, masked):
+                m, l, acc = carry
+                s = dot_nt(qbd, k_scr[k, :, cols])
+                if not fold_scale:
+                    s = s * _f32(scale)
+                if masked:
+                    kpos = k * i32(span) + jax.lax.broadcasted_iota(
+                        i32, (1, span), 1)
+                    s = jnp.where((kpos <= qpos) & (kpos < sl), s, _NEG_INF)
+                m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
+                p = jnp.exp(s - m_new)
+                corr = jnp.exp(m - m_new)
+                l = l * corr + p.sum(axis=-1, keepdims=True)
+                # p in the pool's dtype, as the gather path rounds its
+                # probabilities before the value matmul
+                acc = acc * corr + dot(p.astype(v_scr.dtype),
+                                       v_scr[k, :, cols])
+                return m_new, l, acc
+
+            carry = (jnp.full((rows, 1), _NEG_INF, jnp.float32),
+                     jnp.zeros((rows, 1), jnp.float32),
+                     jnp.zeros((rows, lanes), jnp.float32))
+            carry = jax.lax.fori_loop(
+                _i0(), n_full, functools.partial(fold, masked=False), carry)
+            _, l, acc = jax.lax.fori_loop(
+                n_full, hi, functools.partial(fold, masked=True), carry)
+            out = acc * (_f32(1) / jnp.maximum(l, _TINY))
+            mine = out[:block_q]  # head h's output: rows h.., its lanes
+            for h in range(1, heads):
+                mine = jnp.where(lane >= i32(h * head_dim),
+                                 out[h * block_q:(h + 1) * block_q], mine)
+            o_ref[:, cols] = mine.astype(o_ref.dtype)
+            return done
+
+        # a loop, not an unroll: one copy of the body whatever the width
+        jax.lax.fori_loop(_i0(), i32(n_groups), group, _i0())
+
+
+# jitted: the layers of a decoder call it with one signature, so a step
+# traces and lowers the body once, not once a layer (PERF.md, PR 36: with
+# the body unrolled over the head groups and traced a layer, 24 layers x
+# the 2-3 traces an engine's warm-up makes of a bucket were 35 s of the
+# long-prefill cell's `setup_s`; the body loops over the groups now)
+@functools.partial(jax.jit, static_argnames=("scale", "interpret", "block_q",
+                                             "span"))
+def _flash_prefill_fused(q, k_pool, v_pool, block_tables, seq_lens,
+                         q_offsets, scale, interpret, block_q=None,
+                         span=None):
+    B, T, H, Dh = q.shape
+    bs = int(k_pool.shape[1])
+    M = int(block_tables.shape[1])
+    HD = H * Dh
+    block_q, G = _prefill_plan(T, bs, M, block_q, span)
+    n_spans = -(-M // G)
+    lanes = _prefill_group_lanes(H, Dh)
+    resident = _prefill_resident_bytes(M, G, bs, HD, k_pool.dtype)
+
+    def q_map(b, i, bt, sl, qo):
+        return (b, i, _i0())
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(B, T // block_q),
+        in_specs=[pl.BlockSpec((None, block_q, HD), q_map),
+                  pl.BlockSpec(memory_space=pl.ANY),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((None, block_q, HD), q_map),
+        scratch_shapes=[
+            pltpu.VMEM((n_spans, G * bs, HD), k_pool.dtype),  # slot's K
+            pltpu.VMEM((n_spans, G * bs, HD), v_pool.dtype),
+            pltpu.SemaphoreType.DMA((n_spans, 2)),  # [span, K | V]
+            pltpu.SMEM((1,), jnp.int32),  # spans waited for
+        ],
+    )
+    out = pl.pallas_call(
+        functools.partial(_flash_prefill_kernel, scale=scale, head_dim=Dh,
+                          lanes=lanes,
+                          precision=_dot_precision(k_pool.dtype)),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, T, HD), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=resident + _PREFILL_WORK_BYTES),
+        interpret=interpret,
+        name=_kernel_name("flash_prefill"),
+    )(block_tables.astype(jnp.int32), seq_lens.astype(jnp.int32),
+      q_offsets.astype(jnp.int32), q.reshape(B, T, HD), k_pool, v_pool)
+    return out.reshape(B, T, H, Dh)
+
+
+def flash_prefill(q, k_pool, v_pool, block_tables, seq_lens, q_offsets,
+                  kernel="xla", scale=None, mesh=None):
+    """The prompt span's attention: ``q`` [B, T, H, Dh], T new rows a slot
+    already written to the pools [num_blocks, block_size, H*Dh], read back
+    through ``block_tables`` [B, M] under the paged family's mask
+    (``seq_lens`` counts the span's own rows, ``q_offsets`` is the position
+    of span row 0). ``kernel`` as :func:`paged_attention`'s, resolved once
+    an engine by :func:`select_prefill_kernel`; "xla" is the gather route
+    (:func:`paged_attention_xla`), the parity oracle. A ``mesh`` whose 'mp'
+    axis is > 1 runs the kernel per head shard through ``shard_map``."""
+    scale = float(scale) if scale is not None else q.shape[-1] ** -0.5
+    if kernel == "xla":
+        return paged_attention_xla(q, k_pool, v_pool, block_tables,
+                                   seq_lens, q_offsets, scale=scale)
+    if kernel not in ("pallas", "interpret"):
+        raise ValueError(
+            f"unknown flash-prefill kernel {kernel!r} "
+            "(expected pallas | interpret | xla)")
+    k_pool, v_pool = _kv_pool.merged(k_pool), _kv_pool.merged(v_pool)
+    body = functools.partial(_flash_prefill_fused, scale=scale,
+                             interpret=(kernel == "interpret"))
+    if _mesh_mp_degree(mesh) > 1:
+        body = _per_head_shard(body, mesh, int(q.shape[2]))
+    return body(q, k_pool, v_pool, block_tables, seq_lens, q_offsets)
+
+
+def prefill_tileable(head_dim, block_size, dtype, num_heads, table_cols):
+    """Will Mosaic compile the flash prefill kernel over this pool geometry
+    (``num_heads``: the heads one shard holds; ``table_cols``: a slot's
+    blocks)? Returns (ok, reason)."""
+    dt = jnp.dtype(dtype)
+    if dt not in (jnp.dtype(jnp.float32), jnp.dtype(jnp.bfloat16)):
+        return False, f"pool dtype {dt.name} not in (float32, bfloat16)"
+    width = num_heads * head_dim
+    if width % math.lcm(head_dim, 128):
+        return False, (
+            f"a merged row of {num_heads} x {head_dim} = {width} lanes "
+            "does not split into whole 128-lane tiles holding whole heads")
+    tile = 32 // dt.itemsize  # rows of one packed sublane tile
+    if block_size % tile:
+        return False, (
+            f"a block of {block_size} {dt.name} rows is not whole "
+            f"{tile}-row tiles: its copy lands mid-tile in the slot's rows")
+    G = _prefill_plan(_PREFILL_MIN_ROWS, block_size, table_cols)[1]
+    resident = _prefill_resident_bytes(table_cols, G, block_size, width, dt)
+    if resident > _PREFILL_MAX_RESIDENT:
+        return False, (
+            f"a slot's K and V rows ({table_cols} blocks of {block_size} x "
+            f"{width} {dt.name}) are {resident / 2 ** 20:.0f} MiB; the "
+            "kernel holds them in VMEM (budget "
+            f"{_PREFILL_MAX_RESIDENT >> 20} MiB)")
+    return True, "tileable"
+
+
+def select_prefill_kernel(paged_kind, *, spans, head_dim, block_size, dtype,
+                          num_heads, table_cols, mesh=None):
+    """Resolve the prompt span's read for one engine build, from what the
+    engine's paged kernel resolved to (``paged_kernel=`` is the one request
+    both follow): "xla" stays the gather path; a fused kind stays itself
+    when every prompt span (``spans``: the buckets) is one the model will
+    route (:func:`prefill_span`, or short enough for the paged kernel) and,
+    compiled, :func:`prefill_tileable`; a refusal is "xla", loudly
+    (``serving.kernel.fallbacks``, a ``kernel_fallback`` event). Returns
+    ``(kind, reason)``; sets no counter of its own kind."""
+    if paged_kind == "xla":
+        return "xla", "the engine's paged kernel resolved to xla"
+    odd = [s for s in spans if s >= _PREFILL_MIN_ROWS and not prefill_span(s)]
+    why = None
+    if odd:
+        why = (f"prompt spans {odd} are not whole {_PREFILL_MIN_ROWS}-row "
+               "tiles")
+    elif paged_kind == "pallas":  # the interpreter tiles anything
+        ok, reason = prefill_tileable(
+            head_dim, block_size, dtype,
+            num_heads // _mesh_mp_degree(mesh), table_cols)
+        why = None if ok else reason
+    if why is None:
+        return paged_kind, "follows the paged kernel: " + (
+            "compiled" if paged_kind == "pallas" else "the interpreter")
+    _note_kernel_fallback("flash_prefill", why, head_dim=head_dim,
+                          block_size=block_size, spans=list(spans))
+    return "xla", why
 
 
 # ====================== latent (MLA) paged attention =========================

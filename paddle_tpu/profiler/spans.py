@@ -120,6 +120,9 @@ KERNELS = {
                               "keys",
     "mla_paged_attention": "absorbed latent (MLA) decode attention over "
                            "the latent block pool, all heads a block",
+    "flash_prefill": "the prompt span's attention over the slot's rows in "
+                     "the block pool (models/gpt.py's prefill): online "
+                     "softmax a block of query rows, all heads",
 }
 
 # Jitted steps: the function's name, so the `XLA Modules` event and the

@@ -113,7 +113,8 @@ _counters = _registry.scoped_counters("serving", {
     "handoff_stale": 0, "chunked_prefills": 0, "prefill_chunks": 0,
     "kv_tokens_read": 0, "kv_window_rows_read": 0,
     "cache_refusals": 0, "moe_layer_steps": 0, "moe_routed_rows": 0,
-    "moe_experts_hit": 0, "sample_topk_steps": 0, "sample_topp_steps": 0})
+    "moe_experts_hit": 0, "sample_topk_steps": 0, "sample_topp_steps": 0,
+    "prefill_flash_calls": 0})
 
 # Decode replay fast path (ISSUE 9, same machinery as lazy.ReplayStep):
 # in the steady window a decode iteration is one fingerprint check (the
@@ -353,8 +354,11 @@ class GenerationEngine:
         # over by the jitted steps, so the replay fast path sees ONE
         # stable executable per (bucket, kernel) and a mid-flight kernel
         # flip is impossible by construction. Decode + spec verify ride
-        # it; prefill stays on the XLA gather path (compute-bound, and
-        # its [1, L] spans amortize the gather anyway).
+        # it; so does the prompt span of a decoder whose cache path reads
+        # the pools for it (`prefill_reads_pools`, ISSUE 36: the gather
+        # path's float32 [H, L, S] scores were 88 of a 147 ms prefill) —
+        # `_prefill_kernel` below, the same kind unless a bucket or the
+        # geometry refuses, and then "xla" loudly.
         from ..ops import pallas_ops as _pallas_ops
 
         if self._cache.kind == "latent":
@@ -379,6 +383,19 @@ class GenerationEngine:
         self._paged_mesh = mesh if (
             mesh is not None
             and self._paged_kernel in ("pallas", "interpret")) else None
+        # the prompt span's read, resolved once like the kernel it follows
+        if self._cache.kind == "heads" and getattr(
+                gpt, "prefill_reads_pools", False):
+            self._prefill_kernel, self._prefill_kernel_reason = \
+                _pallas_ops.select_prefill_kernel(
+                    self._paged_kernel, spans=self.buckets,
+                    head_dim=head_dim, block_size=self.block_size,
+                    dtype=self._dtype, num_heads=heads,
+                    table_cols=self.blocks_per_slot, mesh=mesh)
+        else:
+            self._prefill_kernel, self._prefill_kernel_reason = (
+                "xla", "the decoder's prefill is its own forward's")
+        _registry.gauge_set("serving.prefill_kernel", self._prefill_kernel)
         if mesh is not None:
             # telemetry for the stats_dump "mesh serving" section
             _registry.gauge_set("serving.mesh.mp",
@@ -645,19 +662,24 @@ class GenerationEngine:
         """One request's prompt-SUFFIX pass at bucket shape [1, L]: the
         tokens after the cached prefix are embedded at absolute positions
         prefix_len.., their KV rows scatter through the block table into
-        the pool, attention reads the slot's whole logical view (cached
-        prefix blocks included), and the first token is sampled at the
-        prompt's true last position. A cold prefill is the SAME program
-        with prefix_len == 0 — prefix length is data, never a shape, so
-        hits and misses share one executable per bucket (and stay
-        token-bitwise: same program, same reduction order)."""
+        the pool, attention reads the slot's rows back (cached prefix
+        blocks included) — through the engine's prefill kernel where one
+        resolved (`flash_prefill`: the live keys only, a block of query
+        rows at a time), else the gathered view of the whole table under
+        a mask — and the first token is sampled at the prompt's true last
+        position. A cold prefill is the SAME program with prefix_len == 0
+        — prefix length is data, never a shape, so hits, misses and chunks
+        share one executable per bucket (and stay token-bitwise: same
+        program, same order of keys)."""
         L = ids.shape[1]
         positions = jnp.minimum(
             prefix_len[:, None] + jnp.arange(L, dtype=jnp.int32)[None],
             self.max_seq_len - 1)
         hidden, nk, nv = self._forward_slot(
             state_arrays, ids, positions, ks, vs, prefix_len, prompt_len,
-            block_table)
+            block_table,
+            kernel=None if self._prefill_kernel == "xla"
+            else self._prefill_kernel)
         last_local = prompt_len - 1 - prefix_len
         last = jnp.take_along_axis(
             hidden,
@@ -954,6 +976,7 @@ class GenerationEngine:
             tok, nk, nv = self._prefill_jit(*args)
             tok = int(np.asarray(tok)[0])
         self._k, self._v = list(nk), list(nv)
+        _counters["prefill_flash_calls"] += self._prefill_kernel != "xla"
         return tok
 
     def _reserve_extra(self, slot, prompt, max_new_tokens):
@@ -1466,6 +1489,8 @@ class GenerationEngine:
                "weight_generation": self.prefix_cache.generation,
                "kv_pool_row_major": self._kv_row_major,
                "paged_keys_per_program": self._paged_keys_per_program,
+               "prefill_kernel": self._prefill_kernel,
+               "prefill_kernel_reason": self._prefill_kernel_reason,
                "kv_cache_kind": self._cache.kind,
                "kv_row_width": self._cache.row_width(),
                "kv_cache": self._cache.describe(),

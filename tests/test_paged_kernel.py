@@ -23,7 +23,12 @@ bf16 additionally by where probabilities are rounded). Covers:
     oracle AND a float64 numpy oracle, every head geometry the lowering
     test compiles, one block a program for a large block;
   * grouped queries (Hq/Hkv in {1, 4, 16}) and a window layer's ring that
-    has wrapped (PR 35), against the gather route and a float64 oracle.
+    has wrapped (PR 35), against the gather route and a float64 oracle;
+  * the prompt span's kernel `flash_prefill` (PR 36): cold, behind a cached
+    prefix, a chunk that ends mid-block, a prompt shorter than its bucket,
+    over several query blocks and key spans, against both oracles; and the
+    engine's one-shot, chunked and prefix-hit prefills through it, token
+    for token.
 
 The parity cases run on a 4-D pool passed to the public op (merged on
 entry) and on the engine's merged pool (PR 28). The compiled kernel is
@@ -485,6 +490,148 @@ class TestKernelSelection:
         assert ok
 
 
+def _prefill_both(q, kp, vp, bt, sl, qo, **plan):
+    """`flash_prefill` through the interpreter against the gather route
+    and the float64 oracle on every row of the prompt (rows past
+    ``seq_len`` are the bucket's padding: nobody reads them). Returns the
+    kernel's output."""
+    sl_a, qo_a = jnp.asarray(sl, jnp.int32), jnp.asarray(qo, jnp.int32)
+    if plan:
+        out = pallas_ops._flash_prefill_fused(
+            q, kp, vp, bt, sl_a, qo_a, q.shape[-1] ** -0.5, True, **plan)
+    else:
+        out = pallas_ops.flash_prefill(q, kp, vp, bt, sl_a, qo_a,
+                                       kernel="interpret")
+    ref = pallas_ops.flash_prefill(q, kp, vp, bt, sl_a, qo_a, kernel="xla")
+    want = _oracle64(q, kp, vp, bt, sl, qo)
+    atol, rtol = pallas_ops.PAGED_PARITY_TOL[jnp.dtype(q.dtype).name]
+    assert bool(jnp.isfinite(out).all())
+    for b in range(q.shape[0]):
+        n = max(0, min(sl[b] - qo[b], q.shape[1]))
+        got = np.asarray(out[b, :n], np.float64)
+        np.testing.assert_allclose(got, np.asarray(ref[b, :n], np.float64),
+                                   atol=atol, rtol=rtol)
+        np.testing.assert_allclose(got, want[b, :n], atol=atol, rtol=rtol)
+    return out
+
+
+class TestFlashPrefillParity:
+    """PR 36: `flash_prefill`, the prompt span's read. Program (b, i) is a
+    block of query rows of slot b; the slot's live blocks are copied once
+    into VMEM as they lie, spans at absolute key positions fold in rising
+    order (without the mask where every row sees the whole span), dead
+    spans and query blocks past the prompt are skipped. A plan of 16 query
+    rows and 32 keys (two blocks of 16) puts offsets and lengths on every
+    side of a block's, a span's and a query block's edge."""
+
+    SMALL = dict(block_q=16, span=32)
+    CASES = {
+        # name: (T, cache_offset, seq_len)
+        "cold_full_bucket": (64, 0, 64),
+        "cold_shorter_than_bucket": (64, 0, 37),  # dead query blocks
+        "cold_one_token": (32, 0, 1),
+        "prefix_hit": (32, 48, 80),               # keys 0..47 are cached
+        "prefix_hit_short": (32, 64, 75),
+        "chunk_ends_mid_block": (32, 32, 55),     # rows 32..54, 55 % 16 = 7
+        "chunk_off_a_span_edge": (16, 40, 56),    # offset mid-span
+        "last_span_to_the_table_edge": (64, 64, 128),
+    }
+
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+    @pytest.mark.parametrize("case", sorted(CASES))
+    def test_against_both_oracles(self, case, dtype):
+        T, off, sl = self.CASES[case]
+        q, kp, vp, bt = _case(1, T, 4, 32, 12, 16, 8, dtype=dtype,
+                              form="merged", seed=T + off + sl)
+        out = _prefill_both(q, kp, vp, bt, [sl], [off], **self.SMALL)
+        # a query block wholly past the prompt is skipped: zeros
+        dead = -(-(sl - off) // 16) * 16
+        assert not np.asarray(out[0, dead:], np.float32).any()
+
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+    @pytest.mark.parametrize("H,Dh,lanes", [
+        (2, 64, 128), (4, 64, 128), (8, 16, 128), (2, 128, 128),
+        (3, 64, 192), (2, 24, 48)])
+    def test_head_geometries_under_the_default_plan(self, H, Dh, lanes,
+                                                    dtype):
+        # two heads a lane tile, four tiles' worth, eight heads a tile, a
+        # head that owns its tile, and (the interpreter only) widths that
+        # split into no whole tiles; two slots, one behind a prefix
+        assert pallas_ops._prefill_group_lanes(H, Dh) == lanes
+        q, kp, vp, bt = _case(2, 32, H, Dh, 20, 16, 6, dtype=dtype,
+                              form="merged", seed=H * Dh)
+        _prefill_both(q, kp, vp, bt, [29, 90], [0, 64])
+
+    def test_rows_fold_the_same_keys_in_the_same_order_however_cut(self):
+        # spans sit at absolute positions, so a row's output is the same
+        # bits in a one-shot prefill, as the tail of a prefix hit and as
+        # one chunk of three
+        q, kp, vp, bt = _case(1, 96, 4, 32, 12, 16, 8, form="merged",
+                              seed=5)
+        one = _prefill_both(q, kp, vp, bt, [96], [0], **self.SMALL)
+        hit = _prefill_both(q[:, 32:], kp, vp, bt, [96], [32], block_q=16,
+                            span=32)
+        np.testing.assert_array_equal(np.asarray(one[:, 32:]),
+                                      np.asarray(hit))
+        for start in (0, 32, 64):
+            chunk = _prefill_both(q[:, start:start + 32], kp, vp, bt,
+                                  [start + 32], [start], block_q=32, span=32)
+            np.testing.assert_array_equal(
+                np.asarray(one[:, start:start + 32]), np.asarray(chunk))
+
+    def test_padding_and_dead_keys_cannot_leak(self):
+        # keys past seq_len (a stale tenant's rows in the slot's last live
+        # block, whole dead blocks behind it) never reach a live row
+        q, kp, vp, bt = _case(1, 64, 4, 32, 12, 16, 8, form="merged", seed=8)
+        sl, off = [41], [0]
+        base = _prefill_both(q, kp, vp, bt, sl, off, **self.SMALL)
+        kp2, vp2 = kp, vp
+        for pos in range(41, 128):
+            blk = int(bt[0, pos // 16])
+            kp2 = kp2.at[blk, pos % 16].add(50.0)
+            vp2 = vp2.at[blk, pos % 16].add(-50.0)
+        got = _prefill_both(q, kp2, vp2, bt, sl, off, **self.SMALL)
+        np.testing.assert_array_equal(np.asarray(base[:, :41]),
+                                      np.asarray(got[:, :41]))
+
+    def test_which_spans_are_prompt_spans(self):
+        # decode's row and a verify span stay with the paged kernel
+        assert [t for t in (1, 5, 8, 15, 16, 17, 24, 32, 256, 2048)
+                if pallas_ops.prefill_span(t)] == [16, 32, 256, 2048]
+        # a bucket takes the largest query block that divides it; the span
+        # comes from the pool's geometry alone
+        assert pallas_ops._prefill_plan(2048, 16, 128) == (128, 32)
+        assert pallas_ops._prefill_plan(48, 16, 128) == (16, 32)
+        assert pallas_ops._prefill_plan(1024, 16, 6) == (128, 6)
+
+    def test_selection_follows_the_paged_kernel_and_refuses_loudly(self):
+        geo = dict(head_dim=64, block_size=16, dtype=jnp.bfloat16,
+                   num_heads=32, table_cols=128)
+        c0 = dict(registry.counters("serving"))
+        assert pallas_ops.select_prefill_kernel(
+            "xla", spans=(256, 2048), **geo)[0] == "xla"
+        assert pallas_ops.select_prefill_kernel(
+            "interpret", spans=(8, 256, 2048), **geo)[0] == "interpret"
+        assert pallas_ops.select_prefill_kernel(
+            "pallas", spans=(256, 512, 1024), **geo)[0] == "pallas"
+        assert registry.counters("serving")["kernel.fallbacks"] \
+            == c0["kernel.fallbacks"]
+        # a bucket that is not whole tiles of rows; a merged row that is
+        # not whole lane tiles; a slot too long for the kernel's VMEM
+        for kind, spans, over, word in (
+                ("interpret", (16, 100), {}, "100"),
+                ("pallas", (256,), dict(num_heads=12, head_dim=80),
+                 "128-lane"),
+                ("pallas", (256,), dict(table_cols=1024), "VMEM")):
+            got, why = pallas_ops.select_prefill_kernel(
+                kind, spans=spans, **{**geo, **over})
+            assert got == "xla" and word in why, (got, why)
+        assert registry.counters("serving")["kernel.fallbacks"] \
+            == c0["kernel.fallbacks"] + 3
+        ev = explainer.events(kind="kernel_fallback")[-1]
+        assert ev["op"] == "flash_prefill" and "VMEM" in ev["why"]
+
+
 def _run_one(eng, prompt, n, step=None, **kw):
     out = [eng.prefill(0, prompt, **kw)]
     if step is None:
@@ -539,6 +686,49 @@ class TestEngineTokenParity:
             _run_one(eng, shared + [3, 4], 6, seed=40)   # publish prefix
             outs.append(_run_one(eng, shared + [5, 6], 6, seed=41))
         assert outs[0] == outs[1]
+
+    def test_one_shot_chunked_and_prefix_hit_prefill_through_the_kernel(
+            self):
+        # PR 36: the prompt span reads through `flash_prefill` (buckets of
+        # whole 16-row tiles) — a one-shot prefill at the 64 bucket, the
+        # same prompt in chunks of 16 and its tail behind a cached prefix
+        # of 24 give the gather path's greedy tokens, every prefill call
+        # counted in `serving.prefill_flash_calls`
+        from paddle_tpu.serving import GenerationEngine
+
+        ekw = dict(max_batch_size=2, buckets=(16, 32, 64), rng_seed=9,
+                   block_size=4)
+        e_xla = GenerationEngine(_build_model(78), paged_kernel="xla",
+                                 **ekw)
+        e_pal = GenerationEngine(_build_model(78), paged_kernel="pallas",
+                                 **ekw)
+        st = e_pal.stats()
+        assert st["prefill_kernel"] == "interpret", st
+        assert registry.gauge("serving.prefill_kernel") == "interpret"
+        assert e_xla.stats()["prefill_kernel"] == "xla"
+        rng = np.random.default_rng(12)
+        prompt = list(rng.integers(1, VOCAB, 40))
+        want = _run_one(e_xla, prompt, 8, seed=3)
+        c0 = dict(registry.counters("serving"))
+        e_pal.begin_prefill(0, prompt, seed=3, chunk_tokens=16)
+        tok = None
+        while tok is None:
+            tok = e_pal.prefill_chunk(0)  # 16 + 16 + 8 rows
+        chunked = [tok] + [int(e_pal.decode_step()[0]) for _ in range(7)]
+        e_pal.release(0)
+        e_pal.reset()  # drop the prefix the chunked run published
+        one_shot = _run_one(e_pal, prompt, 8, seed=3)
+        _run_one(e_pal, prompt[:24] + [3, 4], 2, seed=4)  # same prefix
+        h0 = registry.counters("serving")["prefix_hits"]
+        hit = _run_one(e_pal, prompt, 8, seed=3)
+        c1 = registry.counters("serving")
+        assert c1["prefix_hits"] > h0
+        assert chunked == want and one_shot == want and hit == want
+        calls = c1["prefill_n"] - c0["prefill_n"]
+        assert calls == 3 + 1 + 1 + 1
+        assert c1["prefill_flash_calls"] - c0["prefill_flash_calls"] == calls
+        assert c1["kernel.fallbacks"] == c0["kernel.fallbacks"]
+        e_pal.pool.audit()
 
     def test_spec_verify_span_tokens_identical(self):
         from paddle_tpu.serving import (DraftVerifyEngine,

@@ -297,6 +297,30 @@ try:
         1, 64, jnp.bfloat16, one, one, H=32, pool=one, B=32, M=128, nb=3073))
 except Exception as e:
     out["paged_chat_cell_body"] = f"{type(e).__name__}: {e}"[:600]
+# the training cell's forward kernel (gpt2-medium: 8 x 1024 x 16 heads x 64,
+# causal): PR 36 added a second flash forward, for serving's prompt span,
+# and left this one as it was
+try:
+    out["flash_fwd_body_sha256"] = body_sha256(
+        lambda q, k, v: po._flash_attention_tpu(q, k, v, causal=True),
+        a, a, a)
+except Exception as e:
+    out["flash_fwd_body"] = f"{type(e).__name__}: {e}"[:600]
+# ... and that one, `flash_prefill`, at the long-prefill cell's geometry: one
+# slot's bucket of 2048 rows over 32 x 64 heads, block 16, 128 table columns
+prefill_2048 = (
+    lambda *x: po.flash_prefill(*x, kernel="pallas"),
+    sds((1, 2048, 32, 64), jnp.bfloat16, sharding=one),
+    sds((1025, 16, 2048), jnp.bfloat16, sharding=one),
+    sds((1025, 16, 2048), jnp.bfloat16, sharding=one),
+    sds((1, 128), jnp.int32, sharding=one),
+    sds((1,), jnp.int32, sharding=one), sds((1,), jnp.int32, sharding=one))
+compile_("flash_prefill_2048", *prefill_2048)
+try:
+    out["flash_prefill_2048"]["body_sha256"] = body_sha256(*prefill_2048)
+    out["flash_prefill_2048"]["plan"] = list(po._prefill_plan(2048, 16, 128))
+except Exception as e:
+    out["flash_prefill_2048_body"] = f"{type(e).__name__}: {e}"[:600]
 # the xing4 cell's kernels at its sizes: the latent (MLA) decode kernel, 32
 # slots x 32 heads over 640-lane rows, 5,633 blocks of 16, 176 table columns;
 # and XLA:TPU's own grouped matmul for the dropless expert layer's decode
@@ -399,6 +423,48 @@ for T in (1, 5):
     except Exception as e:
         out[f"serve_layer_T{T}"] = f"{type(e).__name__}: {e}"[:600]
 
+# the same layer on a PROMPT span (PR 36): one slot's bucket of rows, the
+# two gpt cells' buckets over their own pools. Through the kernel the layer
+# holds one `flash_prefill` custom call and no float32 array of every
+# head's scores [32, L, 2048]; the gather path (no kernel: the parent's
+# prefill, and today's "xla" route) holds them, so the search is not blind
+def score_arrays(text, L):
+    return sorted(set(re.findall(
+        rf"f32\[(?:1,)?32,{L},2048\]", text)))
+
+def prefill_layer(kernel):
+    def f(x, kp, vp, bt, off, sl):
+        with ag.no_grad(), lazy.lazy_guard(False):
+            y, (nk, nv) = attn(Tensor(x), cache=(Tensor(kp), Tensor(vp)),
+                               cache_offset=Tensor(off), seq_lens=Tensor(sl),
+                               block_tables=Tensor(bt), paged_kernel=kernel)
+        return y._data, nk._data, nv._data
+    return f
+
+for L, blocks, kernel in ((256, NB, "pallas"), (512, NB, "pallas"),
+                          (1024, NB, "pallas"), (2048, 1025, "pallas"),
+                          (2048, 1025, None)):
+    pl_ = sds((blocks, BS, 2048), jnp.bfloat16, sharding=one)
+    name = f"prefill_layer_L{L}" + ("" if kernel else "_gather")
+    try:
+        c = jax.jit(prefill_layer(kernel), donate_argnums=(1, 2)).trace(
+            sds((1, L, 2048), jnp.bfloat16, sharding=one), pl_, pl_,
+            sds((1, COLS), jnp.int32, sharding=one),
+            sds((1,), jnp.int32, sharding=one),
+            sds((1,), jnp.int32, sharding=one)).lower(
+                lowering_platforms=("tpu",)).compile()
+        text = c.as_text()
+        out[name] = {
+            "custom_calls": text.count('"tpu_custom_call"'),
+            "kernels": sorted(set(re.findall(
+                r"%(flash_prefill|paged_attention\w*?)(?:\.\d+)? = ", text))),
+            "score_arrays": score_arrays(text, L),
+            "temp_bytes": int(c.memory_analysis().temp_size_in_bytes),
+            "alias_bytes": int(c.memory_analysis().alias_size_in_bytes),
+            "pool_bytes": blocks * BS * 2048 * 2}
+    except Exception as e:
+        out[name] = f"{type(e).__name__}: {e}"[:600]
+
 # the command-a-plus cell's two kinds of attention layer at its own sizes
 # (PR 35: 128 query heads over 8 key/value heads of 128, 32 slots; a full
 # layer's pools of 1 + 32 x 560 blocks behind 560 table columns, a window
@@ -496,7 +562,11 @@ for fam, (V, build) in toys.items():
                 "vocab_sorts": vocab_sorts(text, V),
                 "conditionals": len(re.findall(r" conditional\(", text)),
                 "whiles": len(re.findall(r" while\(", text)),
-                "kernel": eng.paged_kernel}
+                "kernel": eng.paged_kernel,
+                "prefill_kernel": eng.stats()["prefill_kernel"],
+                "kernels": sorted(re.findall(
+                    r"%(flash_prefill|\w*paged_attention\w*?)(?:\.\d+)? = ",
+                    text))}
     except Exception as e:
         out[f"sampling_{fam}"] = f"{type(e).__name__}: {e}"[:600]
 
@@ -530,6 +600,11 @@ def test_aot_compile_for_v5e():
     assert po._paged_plan(32, 64, 256, jnp.float32)[0] == 1
     ok, why = po.paged_tileable(80, 8, jnp.float32, 12)
     assert not ok and "128-lane" in why
+    # PR 36 added serving's own flash forward and touched no training
+    # kernel: the body of `flash_fwd` at the training cell's shape is the
+    # parent's (commit bbcbf68); the new kernel's is pinned further down
+    assert res.pop("flash_fwd_body_sha256", None) == FLASH_FWD_BODY_SHA256, \
+        res.get("flash_fwd_body")
     bad = {k: v for k, v in res.items() if not isinstance(v, dict)
            and k != "xing4_mla_paged_body"}
     assert not bad, bad
@@ -549,14 +624,23 @@ def test_aot_compile_for_v5e():
     for cell in ("paged_chat_cell", "paged_long_prefill_cell"):
         assert res[cell] == {"custom_calls": 1, "collectives": 0,
                              "keys_per_program": 256}, res[cell]
+    new = res["flash_prefill_2048"]
+    assert new.pop("body_sha256") == FLASH_PREFILL_BODY_SHA256, \
+        res.get("flash_prefill_2048_body", new)
+    assert new == {"custom_calls": 1, "collectives": 0, "plan": [128, 32]}
     _SERVE_LAYERS.update((k, v) for k, v in res.items()
                          if k.startswith(("serve_layer_", "xing4_",
-                                          "sampling_", "commanda_")))
+                                          "sampling_", "commanda_",
+                                          "prefill_layer_")))
 
 
 _SERVE_LAYERS: dict = {}
 PAGED_CHAT_BODY_SHA256 = (
     "272c8a5eeab65e345bf54fd326f0eb7606a7194b06f5a590744a4a01389a830e")
+FLASH_FWD_BODY_SHA256 = (
+    "c580f9859193939218cf0161a111bb338f19d97944b6e07bf801c0e54349e4b8")
+FLASH_PREFILL_BODY_SHA256 = (
+    "ec2d3c3f4aeb5e5403586bb0efbf4613e4f6414dda9fdc23bcadfecb90ddae02")
 LATENT_BODY_SHA256 = (
     "4d8e0df8e25229452bc5f7e3359b5d94b5833ef70664c81897e04bccc6ab27ac")
 
@@ -626,6 +710,52 @@ def test_serving_layer_writes_its_kv_rows_in_place_on_v5e(T):
         + got["pool_sized"].get("dynamic-update-slice", 0) >= 2, got
     assert got["temp_bytes"] < got["pool_bytes"], got  # (c)
     assert got["alias_bytes"] >= 2 * got["pool_bytes"], got  # donated
+
+
+@pytest.mark.parametrize("L", [256, 512, 1024, 2048])
+def test_prompt_span_reads_through_flash_prefill_on_v5e(L):
+    """The two gpt cells' attention layer on a prompt span (gpt3-1.3b: one
+    slot's bucket of L rows, 32 heads x 64, the cell's own pools, donated),
+    compiled for the described v5e (PR 36; the AOT child's result of the
+    test above): ONE custom call, `flash_prefill`, and no float32 array of
+    every head's scores `[32, L, 2048]` — which the gather path at the same
+    shapes does hold (the parent's 88 of a 147 ms prefill), so the search
+    would find them. The pools are still written in place and the layer's
+    temporaries are the bucket's activations, far under one head's scores."""
+    if not _SERVE_LAYERS:
+        pytest.skip("test_aot_compile_for_v5e did not compile here")
+    got = _SERVE_LAYERS[f"prefill_layer_L{L}"]
+    assert isinstance(got, dict), got
+    assert got["custom_calls"] == 1 and got["kernels"] == ["flash_prefill"]
+    assert got["score_arrays"] == [], got
+    assert got["alias_bytes"] >= 2 * got["pool_bytes"], got
+    assert got["temp_bytes"] < 32 * L * 2048 * 4 // 8, got
+    gather = _SERVE_LAYERS["prefill_layer_L2048_gather"]
+    assert isinstance(gather, dict), gather
+    assert gather["custom_calls"] == 0 and gather["score_arrays"], gather
+    assert gather["temp_bytes"] > 32 * 2048 * 2048 * 4, gather
+
+
+def test_serving_prefill_holds_one_flash_prefill_a_layer_on_v5e():
+    """The engine's own executables (the toy gpt engine of the AOT child:
+    one layer, 32 slots, one bucket of 64): the prefill kernel follows the
+    paged kernel to "pallas", `serving_prefill` holds one `flash_prefill`
+    custom call a layer and no paged kernel, `serving_decode` the
+    reverse; the xing4 engine's prefill is its own forward's and resolves
+    to "xla" without a fallback."""
+    if not _SERVE_LAYERS:
+        pytest.skip("test_aot_compile_for_v5e did not compile here")
+    pre, dec = (_SERVE_LAYERS.get(f"sampling_gpt_{step}",
+                                  _SERVE_LAYERS.get("sampling_gpt"))
+                for step in ("prefill", "decode"))
+    assert isinstance(pre, dict) and isinstance(dec, dict), (pre, dec)
+    assert pre["kernel"] == "pallas" and pre["prefill_kernel"] == "pallas"
+    assert pre["kernels"] == ["flash_prefill"], pre
+    assert dec["kernels"] == ["paged_attention"], dec
+    x4 = _SERVE_LAYERS.get("sampling_xing4_prefill",
+                           _SERVE_LAYERS.get("sampling_xing4"))
+    assert isinstance(x4, dict), x4
+    assert x4["prefill_kernel"] == "xla" and x4["kernels"] == [], x4
 
 
 @pytest.mark.parametrize("kind", ["window", "full"])

@@ -222,7 +222,8 @@ def _print_mesh_serving(counters, gauges):
     _print_counters(ms)
 
 
-_KERNEL_PREFIXES = ("serving.kernel.", "kernel.")
+_KERNEL_PREFIXES = ("serving.kernel.", "kernel.", "serving.prefill_kernel",
+                    "serving.prefill_flash_calls")
 
 
 def _print_kernels(counters, gauges):
@@ -231,12 +232,14 @@ def _print_kernels(counters, gauges):
     paged decode/verify family (one bump per engine build), kernel.flash.*
     for the training flash family (one per trace) — plus the fallback
     count; any nonzero serving.kernel.fallbacks means a Pallas-eligible
-    call dropped to the gather path (profiler.explain() names why)."""
+    call dropped to the gather path (profiler.explain() names why). Beside
+    them what the prompt span reads through (gauge serving.prefill_kernel)
+    and how many prefill calls ran `flash_prefill`."""
     kn = {k: counters.pop(k) for k in list(counters)
           if k.startswith(_KERNEL_PREFIXES)}
     kn.update({k: gauges.pop(k) for k in list(gauges)
                if k.startswith(_KERNEL_PREFIXES)})
-    if not any(kn.values()):
+    if not any(v for v in kn.values() if v != "xla"):
         return
     print("kernels:")
     _print_counters(kn)
