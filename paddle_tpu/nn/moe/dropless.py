@@ -36,7 +36,8 @@ from ...profiler import spans as _spans
 from ..initializer import Normal
 from ..layer.layers import Layer
 
-__all__ = ["DroplessMoE", "route_sigmoid_topk", "swiglu"]
+__all__ = ["DroplessMoE", "route_sigmoid_topk", "route_softmax_topk",
+           "swiglu"]
 
 
 def swiglu(x, w_gate, w_up, w_down):
@@ -63,12 +64,27 @@ def route_sigmoid_topk(x, w_router, b_select, top_k, scaling, norm=True):
     return chosen.astype(jnp.int32), picked * jnp.float32(scaling)
 
 
+def route_softmax_topk(x, w_router, top_k, norm=True):
+    """(chosen [N, k] int32, weights [N, k] float32) of a softmax router
+    (the Qwen3-MoE family's, models/sdar_moe.py): float32 probabilities over
+    ALL experts, the k largest, divided by their sum (``norm``); no bias, no
+    scaling."""
+    p = jax.nn.softmax(jnp.dot(x.astype(jnp.float32),
+                               w_router.astype(jnp.float32)), axis=-1)
+    picked, chosen = jax.lax.top_k(p, top_k)
+    if norm:
+        picked = picked / picked.sum(-1, keepdims=True)
+    return chosen.astype(jnp.int32), picked
+
+
 class DroplessMoE(Layer):
     """forward(x [B, T, d], valid=None) -> y [B, T, d] float32: the held
     routed experts' part of the layer plus the shared experts (``n_shared``
     of them side by side in one matmul; their outputs summed, or with
-    ``shared_combine="average"`` their mean; ``select_bias=False``: a
-    router that chooses by its scores alone). The router
+    ``shared_combine="average"`` their mean; ``n_shared=0``: none;
+    ``select_bias=False``: a router that chooses by its scores alone;
+    ``router="softmax"``: probabilities over all experts where the sigmoid
+    scores are, `route_softmax_topk`). The router
     scores ``x`` in the precision it comes in (hand it the float32 normed
     input: a choice between near-tied experts is discontinuous, and a bf16
     rounding of the input flips it); the experts read it in their weights'
@@ -82,7 +98,7 @@ class DroplessMoE(Layer):
                  routed_scaling_factor=1.0, norm_topk_prob=True,
                  experts_held=None, init_std=0.02, bias_std=0.02,
                  dtype=None, select_bias=True, shared_combine="sum",
-                 rows_at_a_time=None):
+                 rows_at_a_time=None, router="sigmoid"):
         super().__init__()
         lo, hi = experts_held or (0, num_experts)
         if not 0 <= lo < hi <= num_experts:
@@ -90,6 +106,12 @@ class DroplessMoE(Layer):
                              f"range of the {num_experts} experts")
         if not 1 <= top_k <= num_experts:
             raise ValueError(f"top_k {top_k} of {num_experts} experts")
+        if router not in ("sigmoid", "softmax") or (
+                router == "softmax" and (select_bias
+                                         or routed_scaling_factor != 1.0)):
+            raise ValueError(f"router {router!r}: 'sigmoid', or 'softmax' "
+                             "with no selection bias and no scaling")
+        self.router_kind = router
         if shared_combine not in ("sum", "average"):
             raise ValueError(f"shared_combine {shared_combine!r} is not "
                              "'sum' or 'average'")
@@ -167,10 +189,15 @@ class DroplessMoE(Layer):
         bias = getattr(self.router, "bias", None)
         with _spans.scope("moe_router"):
             routed_from, x = x, x.astype(self.experts.gate_up._data.dtype)
-            chosen, weight = route_sigmoid_topk(
-                routed_from, self.router.weight._data,
-                None if bias is None else bias._data, k,
-                self.scaling, self.norm_topk_prob)
+            if self.router_kind == "softmax":
+                chosen, weight = route_softmax_topk(
+                    routed_from, self.router.weight._data, k,
+                    self.norm_topk_prob)
+            else:
+                chosen, weight = route_sigmoid_topk(
+                    routed_from, self.router.weight._data,
+                    None if bias is None else bias._data, k,
+                    self.scaling, self.norm_topk_prob)
             mine = (chosen >= lo) & (chosen < hi)
             if valid is not None:
                 mine = mine & valid[:, None]

@@ -512,7 +512,7 @@ PAGED_PARITY_TOL = {"float32": (3e-5, 3e-5), "bfloat16": (0.05, 0.05)}
 def _paged_attn_kernel(bt_ref, sl_ref, qo_ref, q_ref, k_hbm, v_hbm, o_ref,
                        k_buf, v_buf, sem, turn, qbd_scr, m_scr, l_scr,
                        acc_scr, *, scale, head_dim, lanes, precision,
-                       q_per_kv=1, window=None):
+                       q_per_kv=1, window=None, block_span=False):
     """Grid (B, ceil(M / G)): program (b, j) folds the G consecutive
     logical blocks ``j*G .. j*G+G-1`` of slot b (``G * block_size`` keys)
     into the slot's online-softmax state, for all heads at once. Scratch
@@ -538,7 +538,10 @@ def _paged_attn_kernel(bt_ref, sl_ref, qo_ref, q_ref, k_hbm, v_hbm, o_ref,
     the span, and ``p @ V`` holds head h's output on row h under head h's
     lanes (the finalizer keeps those). No head is sliced, rotated or
     addressed. A verify span's T rows are T such row blocks (M of the
-    dots), each masked to its own position.
+    dots), each masked to its own position — or, a ``block_span`` (a
+    block-diffusion decoder's block of T rows, models/sdar_moe.py), all to
+    the span's end: every row admits ``j < q_offsets + T``, its own block
+    whole, in both directions.
 
     Grouped queries (``q_per_kv`` > 1: the pool's row holds Hkv heads and
     ``q_per_kv`` query heads read each). The query arrives ``[T * q_per_kv,
@@ -678,7 +681,9 @@ def _paged_attn_kernel(bt_ref, sl_ref, qo_ref, q_ref, k_hbm, v_hbm, o_ref,
             row = jax.lax.broadcasted_iota(i32, (T * Hp, 1), 0)
             t_of = sum(((row >= i32(t * Hp)).astype(i32)
                         for t in range(1, T)), jnp.zeros_like(row))
-            mask = pos < jnp.minimum(qo_ref[b] + t_of + i32(1), sl_ref[b])
+            # (a block span: every row reads the span whole)
+            mask = pos < (limit if block_span else jnp.minimum(
+                qo_ref[b] + t_of + i32(1), sl_ref[b]))
             if window is not None:
                 mask = mask & (pos > qo_ref[b] + t_of - i32(window))
             for g in range(n_groups):
@@ -810,7 +815,8 @@ def paged_keys_per_program(block_size, num_heads, head_dim, dtype,
 
 
 def _paged_attention_fused(q, k_pool, v_pool, block_tables, seq_lens,
-                           q_offsets, scale, interpret, window=None):
+                           q_offsets, scale, interpret, window=None,
+                           block_span=False):
     B, T, Hq, Dh = q.shape
     bs = int(k_pool.shape[1])
     M = int(block_tables.shape[1])
@@ -847,7 +853,8 @@ def _paged_attention_fused(q, k_pool, v_pool, block_tables, seq_lens,
         functools.partial(_paged_attn_kernel, scale=scale, head_dim=Dh,
                           lanes=lanes,
                           precision=_dot_precision(k_pool.dtype),
-                          q_per_kv=qpk, window=window),
+                          q_per_kv=qpk, window=window,
+                          block_span=block_span),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, T * qpk, H * Dh), q.dtype),
         interpret=interpret,
@@ -894,7 +901,8 @@ def _per_head_shard(body, mesh, num_heads):
 
 
 def paged_attention_xla(q, k_pool, v_pool, block_tables, seq_lens,
-                        q_offsets, scale=None, window=None):
+                        q_offsets, scale=None, window=None,
+                        block_span=False):
     """The gather-path reference: materialize each slot's logical
     [M*bs] view of the pool and run masked attention over it. Same
     semantics as GPTAttention's PR 9 paged branch; serves as the parity
@@ -902,7 +910,8 @@ def paged_attention_xla(q, k_pool, v_pool, block_tables, seq_lens,
     whose row holds fewer heads than ``q`` has is read grouped (query head
     h reads head ``h // (Hq / Hkv)``); with a ``window`` the table is the
     slot's ring and a view row's position comes from
-    ``kv_pool.ring_positions``."""
+    ``kv_pool.ring_positions``; a ``block_span``'s rows all read up to the
+    span's end."""
     B, T, H, Dh = q.shape
     scale = float(scale) if scale is not None else Dh ** -0.5
     k_pool, v_pool = _kv_pool.merged(k_pool), _kv_pool.merged(v_pool)
@@ -914,8 +923,9 @@ def paged_attention_xla(q, k_pool, v_pool, block_tables, seq_lens,
         v_view = jnp.repeat(v_view, H // Hkv, axis=2)
     S = k_view.shape[1]
     jpos = jnp.arange(S, dtype=jnp.int32)[None, None, :]
-    qrow = (q_offsets.astype(jnp.int32)[:, None]
-            + jnp.arange(T, dtype=jnp.int32)[None])
+    qrow = q_offsets.astype(jnp.int32)[:, None] + (
+        jnp.full((1, T), T - 1, jnp.int32) if block_span
+        else jnp.arange(T, dtype=jnp.int32)[None])
     if window is not None:
         jpos = _kv_pool.ring_positions(
             seq_lens, int(block_tables.shape[1]),
@@ -930,7 +940,8 @@ def paged_attention_xla(q, k_pool, v_pool, block_tables, seq_lens,
 
 
 def paged_attention(q, k_pool, v_pool, block_tables, seq_lens, q_offsets,
-                    kernel="xla", scale=None, mesh=None, window=None):
+                    kernel="xla", scale=None, mesh=None, window=None,
+                    block_span=False):
     """Paged-KV attention: ``q`` [B, T, H, Dh] over pools
     [num_blocks, block_size, H*Dh] (ops/kv_pool.py; a 4-D
     [num_blocks, block_size, H, Dh] pool is merged on entry, which is a
@@ -953,8 +964,14 @@ def paged_attention(q, k_pool, v_pool, block_tables, seq_lens, q_offsets,
     slot's ring (``ops/kv_pool.py``); the kernel is then called
     ``paged_attention_window`` in the trace. A ring has no mesh route and
     holds no verify span (its newest rows would overwrite keys its oldest
-    still sees): T must be 1."""
+    still sees): T must be 1. ``block_span``: the T rows are one block of a
+    block-diffusion decoder and each reads key positions ``< q_offsets + T``
+    (the verify span's row t reads ``<= q_offsets + t``); same kernel, same
+    name in a trace, no ring."""
     scale = float(scale) if scale is not None else q.shape[-1] ** -0.5
+    if block_span and window is not None:
+        raise TypeError("paged_attention(block_span=True): a ring holds no "
+                        "span of rows")
     if window is not None and (q.shape[1] != 1
                                or _mesh_mp_degree(mesh) > 1):
         raise TypeError("paged_attention(window=...): a ring takes one "
@@ -962,7 +979,7 @@ def paged_attention(q, k_pool, v_pool, block_tables, seq_lens, q_offsets,
     if kernel == "xla":
         return paged_attention_xla(q, k_pool, v_pool, block_tables,
                                    seq_lens, q_offsets, scale=scale,
-                                   window=window)
+                                   window=window, block_span=block_span)
     if kernel not in ("pallas", "interpret"):
         raise ValueError(
             f"unknown paged-attention kernel {kernel!r} "
@@ -970,7 +987,7 @@ def paged_attention(q, k_pool, v_pool, block_tables, seq_lens, q_offsets,
     k_pool, v_pool = _kv_pool.merged(k_pool), _kv_pool.merged(v_pool)
     body = functools.partial(_paged_attention_fused, scale=scale,
                              interpret=(kernel == "interpret"),
-                             window=window)
+                             window=window, block_span=block_span)
     if _mesh_mp_degree(mesh) > 1:
         body = _per_head_shard(body, mesh, int(q.shape[2]))
     out = body(q, k_pool, v_pool, block_tables, seq_lens, q_offsets)

@@ -57,6 +57,14 @@ SPANS = {
     "serving.decode_sync": "inside serving.decode_step, around "
                            "`np.asarray(toks_d)`: the host waiting for "
                            "the device; the parent's self time is dispatch",
+    "serving.block_denoise": "engine.py `decode_step`, around the call of a "
+                             "forward in which no slot commits (every "
+                             "active slot of a block-diffusion decoder "
+                             "denoises); serving.decode_step lies inside",
+    "serving.block_commit": "engine.py `decode_step`, around the call of a "
+                            "forward in which at least one slot commits "
+                            "its block (the others denoise: the phase is "
+                            "a slot's own)",
     "serving.emit": "scheduler.py, the `_append_token` loop after a "
                     "decode: per-request bookkeeping of one iteration",
     "train.step": "jit `TrainStep.__call__`: lifting the arguments, the "
@@ -88,6 +96,17 @@ COUNTERS = {
                               "decode over a latent cache or a ring; prefix "
                               "sharing switched off for a ring), each with "
                               "a `cache_feature_refused` explainer event",
+    "serving.diffusion.slot_forwards": "active slots x forwards of a "
+                                       "block-diffusion decoder (host, "
+                                       "engine.py `_finish_block`)",
+    "serving.diffusion.tokens_committed": "tokens requests were given by "
+                                          "committed blocks: a block "
+                                          "without a prompt's tail and "
+                                          "without what lies past "
+                                          "max_new_tokens",
+    "serving.diffusion.blocks_committed": "slots x commits",
+    "serving.diffusion.commit_forwards": "forwards in which at least one "
+                                         "slot committed",
     "serving.moe_layer_steps": "expert layers x decode steps (host)",
     "serving.moe_routed_rows": "active slots x experts per token, a "
                                "layer-step (host)",
@@ -148,8 +167,9 @@ SCOPES = {
               "(stream norm, maps, Sinkhorn) and the stream mixing",
     "mla_absorb": "models/xing4.py: decode's absorbed projections, "
                   "q_nope W_uk before and o_lat W_uv after the kernel",
-    "moe_router": "nn/moe/dropless.py: sigmoid scores, biased top-k, the "
-                  "sort of the routed rows by expert",
+    "moe_router": "nn/moe/dropless.py: sigmoid scores and biased top-k (or "
+                  "softmax probabilities and top-k), the sort of the routed "
+                  "rows by expert",
     "moe_experts": "nn/moe/dropless.py: the grouped matmuls of the held "
                    "experts and the weighted sum back to tokens (and the "
                    "shared expert where its output is summed)",
@@ -162,6 +182,13 @@ SCOPES = {
     "attn_full": "models/cohere2_moe.py: a full (no-position) layer's "
                  "attention: QKV, the pool write, the kernel or the prefill "
                  "walk over every earlier key, output projection",
+    "block_attention": "models/sdar_moe.py: a block-causal layer's "
+                       "attention: RMSNorm, QKV, query/key norm, rotary, "
+                       "the pool write, the kernel over a block span or "
+                       "the prefill walk, output projection",
+    "unmask_select": "serving/sampling.py: which masked positions of a "
+                     "block a denoise forward unmasks, from the sampled "
+                     "ids' confidences",
 }
 
 

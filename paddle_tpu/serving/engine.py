@@ -114,7 +114,9 @@ _counters = _registry.scoped_counters("serving", {
     "kv_tokens_read": 0, "kv_window_rows_read": 0,
     "cache_refusals": 0, "moe_layer_steps": 0, "moe_routed_rows": 0,
     "moe_experts_hit": 0, "sample_topk_steps": 0, "sample_topp_steps": 0,
-    "prefill_flash_calls": 0})
+    "prefill_flash_calls": 0, "diffusion.slot_forwards": 0,
+    "diffusion.tokens_committed": 0, "diffusion.blocks_committed": 0,
+    "diffusion.commit_forwards": 0})
 
 # Decode replay fast path (ISSUE 9, same machinery as lazy.ReplayStep):
 # in the steady window a decode iteration is one fingerprint check (the
@@ -252,6 +254,15 @@ class GenerationEngine:
         self._model = model
         self._gpt = gpt
         self._cache = gpt.kv_cache_spec()
+        # how the decoder generates is asked of the decoder, as its cache
+        # is: None (no such answer) is left to right, a token a step; a
+        # block-diffusion decoder (models/sdar_moe.py) answers its block
+        # length, denoise forwards, strategy, threshold and mask id
+        self._gen = getattr(gpt, "generation_spec", lambda: None)()
+        if mesh is not None:
+            self.require_autoregressive(
+                "GenerationEngine(mesh=...)",
+                "a block step's kernel call has no per-shard route")
         if mesh is not None and self._cache.kind != "heads":
             self._refuse(
                 f"GenerationEngine(mesh=...) is not supported for a "
@@ -286,6 +297,16 @@ class GenerationEngine:
         if self.block_size < 1:
             raise ValueError("block_size must be >= 1")
         self.blocks_per_slot = -(-self.max_seq_len // self.block_size)
+        if self._gen is not None:
+            L = self._gen.block_length
+            if self._cache.kind != "heads" or self._cache.window is not None \
+                    or self.block_size % L or self.max_seq_len % L:
+                raise ValueError(
+                    f"a decoder that generates blocks of {L} needs a "
+                    "'heads' cache with no window layers, and a block_size "
+                    f"({self.block_size}) and a max_seq_len "
+                    f"({self.max_seq_len}) that are whole blocks of it (a "
+                    "block's rows never straddle a slot's last pool block)")
         if num_blocks is None:
             num_blocks = 1 + self.max_batch_size * self.blocks_per_slot
         self.pool = BlockPool(num_blocks)
@@ -297,7 +318,13 @@ class GenerationEngine:
         self._ring = self._cache.ring_blocks(self.block_size)
         self._ring_table = _kv_pool.ring_table(self.max_batch_size,
                                                self._ring)
-        self._prefix_sharing = not self._ring
+        self._prefix_sharing = not self._ring and self._gen is None
+        if self._gen is not None:
+            self._note_refusal(
+                "prefix sharing (the radix prefix cache) is off for a "
+                "decoder that generates by diffusion over blocks: its "
+                "prefill attends to the call's own rows only, so a prompt "
+                "is prefilled whole by the call that brings it")
         if self._ring:
             self._note_refusal(
                 "prefix sharing (the radix prefix cache) is off for a "
@@ -440,6 +467,35 @@ class GenerationEngine:
         self._top_ks = np.zeros(B, np.int32)
         self._top_ps = np.ones(B, np.float32)
         self._keys = np.zeros((B, 2), np.uint32)
+        if self._gen is not None:
+            # a block decoder's slot: the block at its cursor — its tokens,
+            # which of them are still masked (STATE, never a comparison
+            # with the mask id: a prompt may hold that id), the denoise
+            # forwards it has had — and, for the host alone, the prompt's
+            # tail that opened the slot's first block and the tokens the
+            # request may still be given
+            L = self._gen.block_length
+            self._blk_tokens = np.full((B, L), self._gen.mask_token_id,
+                                       np.int32)
+            self._blk_masked = np.ones((B, L), bool)
+            self._blk_steps = np.zeros(B, np.int32)
+            self._blk_skip = np.zeros(B, np.int32)
+            self._blk_budget = np.zeros(B, np.int64)
+        # a decode iteration's per-slot arguments after the weights and the
+        # pools, by the name of their host mirror (`_decode_rebuild` puts
+        # them on the device, `_audit_fast` compares), and which of them
+        # the pure step hands back advanced
+        if self._gen is None:
+            self._slot_state = (
+                "_last_tokens", "_cur_lens", "_keys", "_gen_idx", "_temps",
+                "_top_ks", "_top_ps", "_active", "_block_tables")
+            self._slot_stepped = (0, 1, 3)
+        else:
+            self._slot_state = (
+                "_blk_tokens", "_blk_masked", "_blk_steps", "_cur_lens",
+                "_keys", "_temps", "_top_ks", "_top_ps", "_active",
+                "_block_tables")
+            self._slot_stepped = (0, 1, 2, 3)
         # per-slot block tables: row of physical block ids, zero-padded
         # (block 0 = reserved garbage block); _slot_blocks holds the ids
         # each slot has a pool reference on
@@ -534,6 +590,10 @@ class GenerationEngine:
         self._active[slot] = False
         self._cur_lens[slot] = 0
         self._gen_idx[slot] = 0
+        if self._gen is not None:
+            self._blk_tokens[slot] = self._gen.mask_token_id
+            self._blk_masked[slot] = True
+            self._blk_steps[slot] = 0
         self._slot_trace.pop(slot, None)
         self._fast = None  # slot membership changed: rebuild + re-radar
 
@@ -600,7 +660,8 @@ class GenerationEngine:
 
     # ----------------------------------------------------- pure step fns --
     def _jit_decode(self):
-        return jax.jit(_spans.named(self._decode_pure, "serving_decode"),
+        step = self._decode_pure if self._gen is None else self._block_pure
+        return jax.jit(_spans.named(step, "serving_decode"),
                        donate_argnums=self._donate)
 
     def _state_arrays(self):
@@ -680,6 +741,10 @@ class GenerationEngine:
             block_table,
             kernel=None if self._prefill_kernel == "xla"
             else self._prefill_kernel)
+        if self._gen is not None:
+            # a block decoder samples nothing from a prompt: its first
+            # block is generated like every other
+            return jnp.zeros((1,), jnp.int32), nk, nv
         last_local = prompt_len - 1 - prefix_len
         last = jnp.take_along_axis(
             hidden,
@@ -730,6 +795,66 @@ class GenerationEngine:
             ).astype(toks.dtype)])
         return (toks, nk, nv, new_last, cur_lens + adv,
                 gen_idx + adv.astype(gen_idx.dtype))
+
+    def _block_pure(self, state_arrays, ks, vs, blk_tokens, blk_masked,
+                    blk_steps, cur_lens, keys, temps, top_ks, top_ps,
+                    active, block_tables):
+        """One forward of a block-diffusion decoder for EVERY slot at fixed
+        ``[B, L]`` shape (L the block length), denoise and commit alike —
+        the phase is data, a slot's own: each slot's block (the mask id
+        where its state says masked) at positions ``cur .. cur + L - 1``
+        against its rows in the pools and itself, its rows written at the
+        cursor (a denoise forward's are overwritten by the next forward:
+        the cursor moves only on a commit). A slot with a masked position
+        DENOISES: every row samples its own position's token (no shift)
+        with its probability, and `sampling.unmask_select` says which
+        masked positions take theirs. A slot with none COMMITS: the rows
+        just written are the clean block's, the cursor moves on by L and a
+        new block of masks opens. The host reads one array: the blocks
+        (after this forward's unmasking; a committing slot's is the block
+        it committed), their mask bits, who committed, then the step
+        counters. Inactive lanes compute garbage into the garbage block."""
+        g = self._gen
+        L, B = g.block_length, self.max_batch_size
+        i32 = jnp.int32
+        ids = jnp.where(blk_masked, i32(g.mask_token_id), blk_tokens)
+        rows = cur_lens[:, None] + jnp.arange(L, dtype=i32)[None]
+        counts = {}
+        hidden, nk, nv = self._forward_slot(
+            state_arrays, ids, jnp.minimum(rows, self.max_seq_len - 1), ks,
+            vs, cur_lens, cur_lens + L, block_tables,
+            kernel=self._paged_kernel, counts=counts)
+        w = state_arrays[self._head_idx]
+        with _spans.scope("lm_head"):
+            logits = self._head_logits(
+                hidden.reshape(B * L, hidden.shape[-1]), w)
+
+        def a_row(x):  # a slot's knob, once a row of its block
+            return jnp.repeat(x, L, axis=0)
+        # a position's noise is its own: the request's key folded with the
+        # position and the block's denoise forwards so far
+        gum = _sampling.gumbel_rows(
+            a_row(keys), (rows * L + blk_steps[:, None]).reshape(B * L),
+            logits.shape[-1])
+        tok, conf = _sampling.sample_block(
+            logits, a_row(jnp.where(active, temps, 0.0)), a_row(top_ks),
+            a_row(top_ps), gum, g.mask_token_id)
+        commit = active & ~blk_masked.any(-1)
+        unmask = _sampling.unmask_select(
+            conf.reshape(B, L), blk_masked & active[:, None], blk_steps,
+            g.denoising_steps, g.strategy, g.confidence_threshold)
+        tokens = jnp.where(unmask, tok.reshape(B, L), blk_tokens)
+        masked = blk_masked & ~unmask
+        out = jnp.concatenate(
+            [tokens.reshape(-1), masked.reshape(-1).astype(i32),
+             commit.astype(i32)]
+            + [counts[n].astype(i32)[None]
+               for n in self._step_counter_names])
+        return (out, nk, nv,
+                jnp.where(commit[:, None], i32(g.mask_token_id), tokens),
+                masked | commit[:, None],
+                jnp.where(commit, 0, blk_steps + (active & ~commit)),
+                cur_lens + commit.astype(cur_lens.dtype) * L)
 
     # ------------------------------------------------------- weight swap --
     def _resolve_swap_state(self, state, names=None):
@@ -1022,7 +1147,7 @@ class GenerationEngine:
         self._block_tables[slot] = bt_row
         self._active[slot] = True
         self._cur_lens[slot] = len(prompt)
-        self._last_tokens[slot] = tok
+        self._last_tokens[slot] = tok or 0  # (a block decoder has none)
         self._gen_idx[slot] = 1
         self._temps[slot] = temperature
         self._top_ks[slot] = top_k
@@ -1031,7 +1156,19 @@ class GenerationEngine:
         self._fast = None  # admission is a batch-boundary event: rebuild
         self._note_pool()
         _counters["prefills"] += 1
-        _counters["tokens_generated"] += 1
+        if self._gen is None:
+            _counters["tokens_generated"] += 1
+            return
+        # a block decoder: the cursor stands at the prompt's last whole
+        # block, and the prompt's tail opens the first generated block
+        # already unmasked
+        tail = len(prompt) % self._gen.block_length
+        self._cur_lens[slot] = len(prompt) - tail
+        self._blk_skip[slot] = tail
+        self._blk_tokens[slot, :tail] = prompt[len(prompt) - tail:]
+        self._blk_masked[slot, :tail] = False
+        self._blk_budget[slot] = np.iinfo(np.int64).max \
+            if max_new_tokens is None else int(max_new_tokens)
 
     def _request_key(self, seed):
         if seed is None:
@@ -1057,11 +1194,16 @@ class GenerationEngine:
         table_ids, bt_row, P = self._admit_blocks(slot, prompt,
                                                   max_new_tokens)
         key = self._request_key(seed)
+        end = len(prompt)
+        if self._gen is not None:
+            # block-causally over the prompt's whole blocks, no token
+            # sampled: the tail is generated with the first block
+            end -= end % self._gen.block_length
         try:
             with _tracing.span(trace, "prefill"):
-                tok = self._prefill_call(prompt[P:], len(prompt), P,
-                                         bt_row, key, temperature, top_k,
-                                         top_p)
+                tok = self._prefill_call(
+                    prompt[P:end], end, P, bt_row, key, temperature, top_k,
+                    top_p) if end else None
         except Exception:
             self.pool.decref(table_ids)  # failed admission leaks nothing
             self._note_pool()
@@ -1069,7 +1211,7 @@ class GenerationEngine:
         self._install_slot(slot, prompt, table_ids, bt_row, tok, key,
                            temperature, top_k, top_p, P, max_new_tokens)
         self._slot_trace[slot] = trace
-        return tok
+        return tok if self._gen is None else None
 
     # -------------------------------------------------- chunked prefill --
     def begin_prefill(self, slot, prompt_ids, temperature=0.0, top_k=0,
@@ -1085,6 +1227,10 @@ class GenerationEngine:
         interleave between chunks instead of stalling behind one long
         prompt. Returns the number of pending chunks."""
         self.require_full_layers("chunked prefill (begin_prefill)")
+        self.require_autoregressive(
+            "chunked prefill (begin_prefill)",
+            "its prefill attends to the call's own rows only, not to what "
+            "an earlier chunk wrote")
         prompt = self._check_prompt(slot, prompt_ids)
         bs = self.block_size
         chunk = max(bs, (int(chunk_tokens or bs) // bs) * bs)
@@ -1174,7 +1320,20 @@ class GenerationEngine:
                 "and holds neither a verify span nor the rows an earlier "
                 "call wrote beyond the window")
 
+    def require_autoregressive(self, feature, why):
+        """Refuse what is written for a decoder that yields one token a
+        sequence a step, left to right."""
+        if self._gen is not None:
+            self._refuse(
+                f"{feature} is not supported for a decoder that generates "
+                f"by diffusion over blocks of {self._gen.block_length} "
+                f"yet: {why}")
+
     def _heads_cache_only(self, feature):
+        self.require_autoregressive(
+            feature, "a slot's state is a block's tokens, mask bits and "
+            "phase, not a last token; a verify span is causal and its "
+            "acceptance rule is of a token a step")
         if self._cache.kind != "heads":
             self._refuse(
                 f"{feature} is not supported for a {self._cache.kind!r} "
@@ -1333,9 +1492,11 @@ class GenerationEngine:
 
     # ------------------------------------------------------------- decode --
     def decode_step(self):
-        """One continuous-batching iteration over all slots; returns the
-        np.int32[B] token block (junk on inactive lanes). Advances every
-        active slot's cursor and per-request RNG index.
+        """One continuous-batching iteration over all slots. A decoder that
+        generates left to right returns the np.int32[B] token block (junk on
+        inactive lanes) and advances every active slot's cursor and
+        per-request RNG index; a block-diffusion decoder (`generation`) runs
+        one forward of `_block_pure` and returns what `_finish_block` does.
 
         Steady fast path: when nothing mutated the batch since the last
         iteration (no admission, eviction, weight swap or reprime), the
@@ -1358,16 +1519,30 @@ class GenerationEngine:
                 and self._decode_since_audit + 1 >= self._audit_every:
             self._audit_fast(fast)
             fast = self._fast  # a failed audit demoted it
-        if fast is None:
-            return self._decode_rebuild(active, n_active)
+        rebuilt = fast is None
+        if rebuilt:
+            fast = self._decode_rebuild()
         args = (self._state_arrays(), tuple(self._k), tuple(self._v)) + fast
-        toks, nk, nv, nlast, nlens, ngen = self._decode_call(args)
+        if self._gen is None:
+            out, nk, nv, *stepped = self._decode_call(args)
+        else:
+            # a slot commits iff nothing of its block is masked: the
+            # host's mirror knows before the call
+            commits = bool((~self._blk_masked[active].any(-1)).any())
+            with _span("serving.block_commit" if commits
+                       else "serving.block_denoise"):
+                out, nk, nv, *stepped = self._decode_call(args)
         self._k, self._v = list(nk), list(nv)
-        self._fast = (nlast, nlens, fast[2], ngen) + fast[4:]
-        self._finish_decode(active, n_active, toks)
-        self._decode_since_audit += 1
-        _fp_counters["decode_fast_steps"] += 1
-        return toks
+        fast = list(fast)
+        for i, x in zip(self._slot_stepped, stepped):
+            fast[i] = x
+        self._fast = tuple(fast)
+        given = (self._finish_decode if self._gen is None
+                 else self._finish_block)(active, n_active, out)
+        if not rebuilt:
+            self._decode_since_audit += 1
+            _fp_counters["decode_fast_steps"] += 1
+        return given
 
     def _decode_call(self, args):
         """The one timed site of a decode iteration, fast path and rebuild
@@ -1377,33 +1552,27 @@ class GenerationEngine:
             toks_d, *rest = self._decode_jit(*args)
             with _span("serving.decode_sync"):
                 toks = np.asarray(toks_d)
-        B = self.max_batch_size
+        # the step's own part, then its counters
+        B = len(toks) - len(self._step_counter_names)
         for name, n in zip(self._step_counter_names, toks[B:]):
             _counters[name] += int(n)
         return (toks[:B], *rest)
 
-    def _decode_rebuild(self, active, n_active):
+    def _decode_rebuild(self):
         """Off-steady decode: rebuild the device-side slot state from the
         host mirrors (a batch-boundary event — admission, eviction,
-        weight swap, reprime — invalidated it), run the signature radar,
-        then re-arm the fast path for the next iteration."""
-        tail = (self._put(self._last_tokens),
-                self._put(self._cur_lens), self._put(self._keys),
-                self._put(self._gen_idx), self._put(self._temps),
-                self._put(self._top_ks), self._put(self._top_ps),
-                self._put(active), self._put(self._block_tables))
-        args = (self._state_arrays(), tuple(self._k), tuple(self._v)) + tail
+        weight swap, reprime — invalidated it) and run the signature
+        radar; the iteration that follows re-arms the fast path."""
+        tail = tuple(self._put(getattr(self, name))
+                     for name in self._slot_state)
         self._note_signature(
-            "decode", args,
+            "decode",
+            (self._state_arrays(), tuple(self._k), tuple(self._v)) + tail,
             f"max_batch={self.max_batch_size}, "
             f"max_seq_len={self.max_seq_len}")
         _fp_counters["decode_rebuilds"] += 1
-        toks, nk, nv, nlast, nlens, ngen = self._decode_call(args)
-        self._k, self._v = list(nk), list(nv)
-        self._fast = (nlast, nlens, tail[2], ngen) + tail[4:]
         self._decode_since_audit = 0
-        self._finish_decode(active, n_active, toks)
-        return toks
+        return tail
 
     def _finish_decode(self, active, n_active, toks):
         # host mirrors advance in lockstep with the device copies (numpy
@@ -1426,6 +1595,61 @@ class GenerationEngine:
         c["tokens_generated"] += n_active
         _registry.gauge_set("serving.batch_occupancy",
                             n_active / self.max_batch_size)
+        return toks
+
+    # ------------------------------------------- block-diffusion decoding --
+    @property
+    def generation(self):
+        """How the decoder generates: None, left to right a token a step,
+        or its block-diffusion settings (`decode_step` then runs
+        `_block_pure`, and `_finish_block` says what it returns)."""
+        return self._gen
+
+    def _finish_block(self, active, n_active, out):
+        """The host mirrors after a forward of a block-diffusion decoder,
+        the counters, and what `decode_step` returns for one: a slot, None
+        or — where the slot committed a block — ``(tokens, position)``: the
+        tokens the request is given (the block without the prompt's tail
+        that opened a first block and without what lies past the request's
+        ``max_new_tokens``) and the position of the first of them."""
+        g, c = self._gen, _counters
+        B, L = self.max_batch_size, g.block_length
+        tokens = out[:B * L].reshape(B, L)
+        masked = out[B * L:2 * B * L].reshape(B, L).astype(bool)
+        commit = out[2 * B * L:].astype(bool)
+        # the rows this forward's attention read: each active slot's
+        # committed rows and its block
+        c["kv_tokens_read"] += int((self._cur_lens[active] + L).sum())
+        given, n_tok = [None] * B, 0
+        for slot in np.nonzero(commit)[0]:
+            skip = int(self._blk_skip[slot])
+            new = [int(t) for t in
+                   tokens[slot, skip:skip + int(self._blk_budget[slot])]]
+            given[slot] = (new, int(self._cur_lens[slot]) + skip)
+            self._blk_budget[slot] -= len(new)
+            self._blk_skip[slot] = 0
+            n_tok += len(new)
+        denoised = active & ~commit
+        self._blk_tokens[denoised] = tokens[denoised]
+        self._blk_masked[denoised] = masked[denoised]
+        self._blk_steps[denoised] += 1
+        self._blk_tokens[commit] = g.mask_token_id
+        self._blk_masked[commit] = True
+        self._blk_steps[commit] = 0
+        self._cur_lens[commit] += L
+        c["decode_steps"] += 1
+        self._count_filter_steps(denoised)
+        for name, n in self._host_step_counts(n_active).items():
+            c[name] += n
+        c["active_slot_steps"] += n_active
+        c["tokens_generated"] += n_tok
+        c["diffusion.slot_forwards"] += n_active
+        c["diffusion.tokens_committed"] += n_tok
+        c["diffusion.blocks_committed"] += int(commit.sum())
+        c["diffusion.commit_forwards"] += int(commit.any())
+        _registry.gauge_set("serving.batch_occupancy",
+                            n_active / self.max_batch_size)
+        return given
 
     def _count_filter_steps(self, active):
         """Whether this step's sampling ran its top-k / top-p passes:
@@ -1443,11 +1667,12 @@ class GenerationEngine:
         authoritative) with a structured explainer cause."""
         _fp_counters["decode_audit_runs"] += 1
         self._decode_since_audit = 0
-        ok = (np.array_equal(np.asarray(fast[0]), self._last_tokens)
-              and np.array_equal(np.asarray(fast[1]), self._cur_lens)
-              and np.array_equal(np.asarray(fast[3]), self._gen_idx)
-              and np.array_equal(np.asarray(fast[7]), self._active)
-              and np.array_equal(np.asarray(fast[8]), self._block_tables))
+        # what a step advances, then who is active and where their
+        # blocks lie (the last two of the tuple, whatever the decoder)
+        names = self._slot_state
+        ok = all(np.array_equal(np.asarray(fast[i]), getattr(self, names[i]))
+                 for i in self._slot_stepped
+                 + (len(names) - 2, len(names) - 1))
         if not ok:
             _fp_counters["decode_demotions"] += 1
             self._fast = None

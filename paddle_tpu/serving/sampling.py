@@ -179,3 +179,57 @@ def sample_tokens(logits, temperature, top_k, top_p, gumbel):
         filtered = kept(jnp.maximum(kth, nucleus))
         sampled = jnp.argmax(filtered + gumbel, axis=-1)
         return jnp.where(sampling, sampled, greedy).astype(jnp.int32)
+
+
+# ------------------------------------------------- block-diffusion decoding --
+# A block-diffusion decoder (models/sdar_moe.py) reads, in one forward, the
+# logits at each of a block's positions for that position's OWN token, and
+# unmasks some of the positions that are still masked: which ones is decided
+# by how sure the model is of each (the probability of the id it sampled).
+
+STRATEGIES = ("low_confidence_static", "low_confidence_dynamic")
+
+
+def sample_block(logits, temperature, top_k, top_p, gumbel, mask_id):
+    """``(ids [N], confidence [N])`` of N rows (slots x a block's rows, the
+    knobs repeated a row): :func:`sample_tokens` over logits in which the
+    mask id can never win, and beside each id its probability under the
+    row's softmax (float32, temperature 1, the mask id left out)."""
+    with _scope("sampling"):
+        logits = logits.astype(jnp.float32)
+        ids = lax.broadcasted_iota(jnp.int32, logits.shape, 1)
+        logits = jnp.where(ids == jnp.int32(mask_id), -jnp.inf, logits)
+    tok = sample_tokens(logits, temperature, top_k, top_p, gumbel)
+    with _scope("sampling"):
+        picked = jnp.take_along_axis(logits, tok[:, None], axis=-1)[:, 0]
+        conf = jnp.exp(picked - jax.nn.logsumexp(logits, axis=-1))
+    return tok, conf
+
+
+def unmask_select(conf, masked, steps_done, denoising_steps, strategy,
+                  threshold):
+    """Which masked positions of each block to unmask after a denoise
+    forward: bool ``[S, B]``. ``conf`` [S, B] float32 confidences, ``masked``
+    [S, B] bool, ``steps_done`` [S] the block's denoise forwards before this
+    one. ``low_confidence_static``: the ``ceil(masked / steps_left)`` most
+    confident, so that ``denoising_steps`` forwards leave nothing masked;
+    ``low_confidence_dynamic``: every one whose confidence is over
+    ``threshold``, and at least the most confident one. Equal confidences
+    rank by position, the earlier first."""
+    if strategy not in STRATEGIES:
+        raise ValueError(f"unmask strategy {strategy!r} is not one of "
+                         f"{STRATEGIES}")
+    with _scope("unmask_select"):
+        B = conf.shape[-1]
+        c = jnp.where(masked, conf.astype(jnp.float32), -1.0)
+        pos = jnp.arange(B, dtype=jnp.int32)
+        ahead = (c[:, None, :] > c[:, :, None]) | (
+            (c[:, None, :] == c[:, :, None])
+            & (pos[None, None, :] < pos[None, :, None]))
+        rank = ahead.sum(-1).astype(jnp.int32)  # 0: the most confident
+        if strategy == "low_confidence_dynamic":
+            return masked & ((c > jnp.float32(threshold)) | (rank < 1))
+        n = masked.sum(-1).astype(jnp.int32)
+        left = jnp.maximum(jnp.int32(denoising_steps)
+                           - steps_done.astype(jnp.int32), 1)
+        return masked & (rank < ((n + left - 1) // left)[:, None])

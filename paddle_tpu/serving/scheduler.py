@@ -159,6 +159,10 @@ class ContinuousBatchScheduler:
             # wrote beyond the window: refused at build, not per request
             engine.require_full_layers(
                 "chunked prefill (prefill_chunk_tokens)")
+            engine.require_autoregressive(
+                "chunked prefill (prefill_chunk_tokens)",
+                "its prefill attends to the call's own rows only, not to "
+                "what an earlier chunk wrote")
         self._queue: collections.deque = collections.deque()
         self._active: dict = {}  # slot -> request
         self._prefilling: dict = {}  # slot -> request (chunked admission)
@@ -428,9 +432,7 @@ class ContinuousBatchScheduler:
                 self._prefilling.pop(slot, None)
                 self._active[slot] = req
                 now = time.monotonic()
-                req.ttft_s = now - req.submit_ts
-                _registry.timing("ttft", req.ttft_s, scope="serving")
-                _registry.hist_record("ttft", req.ttft_s)
+                self._note_ttft(req, now)
                 self._append_token(req, first, now)
 
         # (3) one decode iteration over every active slot; per-request
@@ -438,21 +440,33 @@ class ContinuousBatchScheduler:
         # batch boundary (one shared timestamp, no per-token clock reads).
         # A speculative engine (decode_step_spec) emits 1..K+1 tokens per
         # slot per iteration — each bitwise-equal to plain decode's — and
-        # stop conditions are applied per token in emission order.
+        # stop conditions are applied per token in emission order. A
+        # block-diffusion decoder (engine.generation) yields nothing for a
+        # slot that denoised and a block's tokens at once, with one
+        # timestamp, for a slot that committed: a request's first token
+        # comes with its first commit (prefill hands none over).
         if self._active:
             # the engine's serving.decode_step span times the iteration;
             # serving.emit the bookkeeping after it (one span, never per
             # slot / per token)
             spec = getattr(self.engine, "decode_step_spec", None)
+            blocks = getattr(self.engine, "generation", None) is not None
             out = self._decode_with_retry(spec or self.engine.decode_step)
             now = time.monotonic()
             with _span("serving.emit"):
                 for slot, req in list(self._active.items()):
-                    if spec is None:
+                    if blocks:
+                        if out[slot] is None:
+                            continue
+                        toks, base = out[slot]  # its first token's position
+                        if req.ttft_s is None:
+                            self._note_ttft(req, now)
+                    elif spec is None:
                         self._append_token(req, int(out[slot]), now)
                         continue
-                    toks = out[slot]
-                    base = self.engine.slot_len(slot) - len(toks)
+                    else:
+                        toks = out[slot]
+                        base = self.engine.slot_len(slot) - len(toks)
                     for i, t in enumerate(toks):
                         self._append_token(req, int(t), now,
                                            slot_len=base + i + 1)
@@ -591,16 +605,22 @@ class ContinuousBatchScheduler:
         self._active[slot] = req
         _note_queue_wait(t_start - req.submit_ts)
         now = time.monotonic()
-        req.ttft_s = now - req.submit_ts
-        _registry.timing("ttft", req.ttft_s, scope="serving")
-        _registry.hist_record("ttft", req.ttft_s)
+        if first is not None:
+            self._note_ttft(req, now)
         _tracing.add_span(req.trace_id, "queue_wait", req.submit_ts, t_start)
         if handoff:  # a local admission is the serving.admit span itself
             _tracing.add_span(req.trace_id, "kv_adopt", t_start, now)
         _tracing.flight("admit", rid=req.rid, trace_id=req.trace_id,
                         slot=slot, handoff=handoff)
-        self._append_token(req, first, now)
+        if first is not None:  # (a block decoder's prefill hands none over)
+            self._append_token(req, first, now)
         return True
+
+    @staticmethod
+    def _note_ttft(req, now):
+        req.ttft_s = now - req.submit_ts
+        _registry.timing("ttft", req.ttft_s, scope="serving")
+        _registry.hist_record("ttft", req.ttft_s)
 
     def _append_token(self, req, token, now, slot_len=None):
         # slot_len: the sequence length AS OF this token (the spec path

@@ -493,6 +493,7 @@ class DraftVerifyEngine(GenerationEngine):
         super()._finish_decode(active, n_active, toks)
         for b in np.nonzero(active)[0]:
             self._slot_tokens[b].append(int(toks[b]))
+        return toks
 
     def release(self, slot):
         if self._draft_blocks[slot]:

@@ -633,6 +633,13 @@ class TestWeightHotSwap:
                          source="unit-test")
         for r in reqs:
             assert r.result(120).status == RequestStatus.DONE
+        # swap_weights STAGES the swap; the scheduler thread applies it at
+        # its next boundary, which on a fast host may come after the last
+        # of these short requests was answered
+        deadline = time.monotonic() + 30
+        while registry.counters("serving")["weight_swaps"] \
+                == c0["weight_swaps"] and time.monotonic() < deadline:
+            time.sleep(0.01)
         c1 = dict(registry.counters("serving"))
         assert c1["weight_swaps"] == c0["weight_swaps"] + 1
         assert c1["swap_failures"] == c0["swap_failures"]

@@ -512,6 +512,48 @@ for kind, sliding, cols, blocks in (("window", True, 257, 1 + 32 * 257),
     except Exception as e:
         out[f"commanda_layer_{kind}"] = f"{type(e).__name__}: {e}"[:600]
 
+# the sdar cell's attention layer at its own sizes (PR 37: 32 query heads
+# over 4 key/value heads of 128, 32 slots x a block of 4 rows, pools of
+# 1 + 32 x 304 blocks behind 304 table columns), donated: the block span is
+# the one custom call, under the paged kernel's name, and the pools are
+# written in place
+from paddle_tpu.models.sdar_moe import SdarMoeAttention, SdarMoeConfig
+
+sdar_attn = SdarMoeAttention(SdarMoeConfig(dtype="bfloat16",
+                                           num_hidden_layers=1))
+sdar_attn.eval()
+
+def sdar_layer(u, kp, vp, bt, off, sl):
+    with ag.no_grad(), lazy.lazy_guard(False):
+        y, (nk, nv) = sdar_attn(
+            u, off[:, None] + jnp.arange(4, dtype=jnp.int32)[None],
+            cache=(kp, vp), cache_offset=off, seq_lens=sl, block_tables=bt,
+            paged_kernel="pallas")
+    return y, nk, nv
+
+try:
+    blocks = 1 + 32 * 304
+    pool = sds((blocks, 16, 512), jnp.bfloat16, sharding=one)
+    c = jax.jit(sdar_layer, donate_argnums=(1, 2)).trace(
+        sds((32, 4, 2048), jnp.bfloat16, sharding=one), pool, pool,
+        sds((32, 304), jnp.int32, sharding=one),
+        sds((32,), jnp.int32, sharding=one),
+        sds((32,), jnp.int32, sharding=one)).lower(
+            lowering_platforms=("tpu",)).compile()
+    text = c.as_text()
+    out["sdar_layer"] = {
+        "pool_bytes": blocks * 16 * 512 * 2,
+        "pool_layout": list(c.input_formats[0][1].layout.major_to_minor),
+        "pool_sized": pool_sized(text),
+        "temp_bytes": int(c.memory_analysis().temp_size_in_bytes),
+        "alias_bytes": int(c.memory_analysis().alias_size_in_bytes),
+        "custom_calls": text.count('"tpu_custom_call"'),
+        "kernels": sorted(set(re.findall(
+            r"%(paged_attention\w*?)(?:\.\d+)? = ", text))),
+        "plan": list(po._paged_plan(16, 4, 128, jnp.bfloat16, 4, 304, 8))}
+except Exception as e:
+    out["sdar_layer"] = f"{type(e).__name__}: {e}"[:600]
+
 # the serving engines' own executables at toy depth and the cells'
 # vocabularies and slots: sampling finds its thresholds by selection (PR 31),
 # so neither `serving_decode` nor `serving_prefill` may hold a sort whose
@@ -630,7 +672,7 @@ def test_aot_compile_for_v5e():
     assert new == {"custom_calls": 1, "collectives": 0, "plan": [128, 32]}
     _SERVE_LAYERS.update((k, v) for k, v in res.items()
                          if k.startswith(("serve_layer_", "xing4_",
-                                          "sampling_", "commanda_",
+                                          "sampling_", "commanda_", "sdar_",
                                           "prefill_layer_")))
 
 
@@ -784,5 +826,29 @@ def test_window_and_full_pools_are_row_major_and_written_in_place_on_v5e(kind):
     assert got["pool_sized"].get("write", 0) \
         + got["pool_sized"].get("scatter", 0) \
         + got["pool_sized"].get("dynamic-update-slice", 0) >= 2, got
+    assert got["temp_bytes"] < got["pool_bytes"], got
+    assert got["alias_bytes"] >= 2 * got["pool_bytes"], got
+
+
+def test_block_span_layer_is_one_paged_kernel_written_in_place_on_v5e():
+    """The sdar cell's attention layer (PR 37), compiled for the described
+    v5e with donated pools at the cell's sizes (the AOT child's result): a
+    block of 4 rows a slot, 8 query heads a key/value head, meets its keys
+    through ONE custom call under the paged kernel's name (the block span is
+    the same kernel, so the accepted roofline reader finds it); two
+    key/value heads' 8 x 4 query rows each (64 rows against 256 lanes) a
+    dot, 512 keys a program; the pools enter row-major and are written in
+    place."""
+    if not _SERVE_LAYERS:
+        pytest.skip("test_aot_compile_for_v5e did not compile here")
+    got = _SERVE_LAYERS["sdar_layer"]
+    assert isinstance(got, dict), got
+    assert got["custom_calls"] == 1 and got["kernels"] == ["paged_attention"]
+    assert got["plan"] == [32, 256, 64], got
+    assert got["pool_layout"] == [0, 1, 2], got
+    extra = {op: n for op, n in got["pool_sized"].items()
+             if op not in ("parameter", "bitcast", "write", "scatter",
+                           "dynamic-update-slice")}
+    assert not extra, got
     assert got["temp_bytes"] < got["pool_bytes"], got
     assert got["alias_bytes"] >= 2 * got["pool_bytes"], got

@@ -530,6 +530,8 @@ class TestProgramSpans:
         assert "serving.loop_idle" in names
 
     def test_counters_at_the_span_boundaries(self, server):
+        # (an earlier request's last step may still be closing its span)
+        time.sleep(3 * server._idle_wait_s)
         c0 = dict(registry.counters("serving"))
         h = server.submit([9, 8, 7, 6, 5], max_new_tokens=6)
         h.result(timeout=60)
