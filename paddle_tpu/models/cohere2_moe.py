@@ -36,6 +36,10 @@ rows a slot. Three attention paths, one set of weights:
   span of its keys in one dot; the window layers' call walks their ring
   (`paged_attention_window` in a trace).
 
+``moe_kernel`` (the engine's `pallas_ops.select_grouped_kernel`) goes to every
+expert layer: each call's shape decides there between the `grouped_matmul`
+kernel (a decode step's rows) and `lax.ragged_dot` (a prompt's chunk).
+
 The chip may hold a share of the experts (`experts_held`) and of the
 vocabulary (a smaller `vocab_size`): what an expert-parallel deployment's
 chip computes before the exchange; nothing here stands in for the others.
@@ -306,7 +310,8 @@ class Cohere2MoeLayer(nn.Layer):
             dtype=cfg.dtype, select_bias=False, shared_combine="average",
             rows_at_a_time=_MOE_ROWS)
 
-    def forward(self, h, positions, valid=None, **cache_args):
+    def forward(self, h, positions, valid=None, moe_kernel=None,
+                **cache_args):
         """h [B, T, d] float32 -> (h + Attn(LN h) + Experts(LN h), cache)."""
         w = self.input_layernorm.weight._data
         u = _layer_norm(h, w, self.cfg.layer_norm_eps)
@@ -314,7 +319,8 @@ class Cohere2MoeLayer(nn.Layer):
             a, cache = self.self_attn(u.astype(w.dtype), positions,
                                       **cache_args)
         # the router reads the float32 input
-        return h + a + self.mlp(u, valid=valid)._data, cache
+        return h + a + self.mlp(u, valid=valid,
+                                kernel=moe_kernel)._data, cache
 
 
 def _tied_logits(scale):
@@ -380,7 +386,7 @@ class Cohere2MoeModel(nn.Layer):
 
     def forward(self, input_ids, position_ids=None, caches=None,
                 cache_offsets=None, seq_lens=None, block_tables=None,
-                paged_kernel=None, paged_mesh=None):
+                paged_kernel=None, paged_mesh=None, moe_kernel=None):
         if paged_mesh is not None:
             raise TypeError("Cohere2MoeModel: a cache with window layers "
                             "has no mesh route")
@@ -405,7 +411,8 @@ class Cohere2MoeModel(nn.Layer):
                 cache=tuple(arr(p) for p in caches[i]), cache_offset=offs,
                 seq_lens=sl, block_tables=arr(block_tables[i]),
                 paged_kernel=paged_kernel)
-            h, nc = layer(h, positions, valid=valid, **cache_args)
+            h, nc = layer(h, positions, valid=valid, moe_kernel=moe_kernel,
+                          **cache_args)
             if nc is not None:
                 new_caches.append(tuple(Tensor(p) for p in nc))
         w = self.norm.weight._data

@@ -39,6 +39,11 @@ Three attention paths, one set of weights:
   `ops.pallas_ops.paged_attention(block_span=True)`, a key/value head's
   ``q_per_kv x B`` query rows against a span of its keys in one dot.
 
+``moe_kernel`` (the engine's `pallas_ops.select_grouped_kernel`) goes to
+every expert layer beside it: there each call's shape decides between the
+`grouped_matmul` kernel (a block step's rows) and `lax.ragged_dot` (a
+prompt's).
+
 The forward is written on the parameters' arrays (`Tensor._data`): the
 autograd tape does not see it.
 """
@@ -226,7 +231,8 @@ class SdarMoeLayer(nn.Layer):
             init_std=cfg.initializer_range, dtype=cfg.dtype,
             select_bias=False, router="softmax")
 
-    def forward(self, h, positions, valid=None, **cache_args):
+    def forward(self, h, positions, valid=None, moe_kernel=None,
+                **cache_args):
         """h [B, T, d] float32 -> (h + Attn(RMS h), then + Experts(RMS .),
         cache)."""
         eps = self.cfg.rms_norm_eps
@@ -237,7 +243,7 @@ class SdarMoeLayer(nn.Layer):
         h = h + a
         # the router reads the float32 normed input
         u = _rms(h, self.post_attention_layernorm.weight._data, eps)
-        return h + self.mlp(u, valid=valid)._data, cache
+        return h + self.mlp(u, valid=valid, kernel=moe_kernel)._data, cache
 
 
 class SdarMoeModel(nn.Layer):
@@ -301,7 +307,7 @@ class SdarMoeModel(nn.Layer):
 
     def forward(self, input_ids, position_ids=None, caches=None,
                 cache_offsets=None, seq_lens=None, block_tables=None,
-                paged_kernel=None, paged_mesh=None):
+                paged_kernel=None, paged_mesh=None, moe_kernel=None):
         if paged_mesh is not None:
             raise TypeError("SdarMoeModel: a block step has no mesh route")
 
@@ -325,7 +331,8 @@ class SdarMoeModel(nn.Layer):
                 cache=tuple(arr(p) for p in caches[i]), cache_offset=offs,
                 seq_lens=sl, block_tables=arr(block_tables),
                 paged_kernel=paged_kernel)
-            h, nc = layer(h, positions, valid=valid, **cache_args)
+            h, nc = layer(h, positions, valid=valid, moe_kernel=moe_kernel,
+                          **cache_args)
             if nc is not None:
                 new_caches.append(tuple(Tensor(p) for p in nc))
         w = self.norm.weight._data

@@ -25,6 +25,10 @@ Three attention paths, one set of weights:
   (`ops.pallas_ops.mla_paged_attention`, every head against a block read
   once) and ``W_uv`` is applied to the latent output.
 
+``moe_kernel`` (the engine's `pallas_ops.select_grouped_kernel`) goes to every
+expert layer: each call's shape decides there between the `grouped_matmul`
+kernel (a decode step's rows) and `lax.ragged_dot` (a prompt's).
+
 The forward is written on the parameters' arrays (`Tensor._data`): the
 autograd tape does not see it. Training through MLA and the dropless layer
 is open (ROADMAP). Multi-token prediction is not part of a served forward
@@ -422,7 +426,8 @@ class Xing4Layer(nn.Layer):
         else:
             self.mlp = Xing4MLP(cfg)
 
-    def forward(self, streams, positions, valid=None, **cache_args):
+    def forward(self, streams, positions, valid=None, moe_kernel=None,
+                **cache_args):
         eps = self.cfg.rms_norm_eps
         new_cache = []
 
@@ -435,7 +440,7 @@ class Xing4Layer(nn.Layer):
 
         def mlp(u):
             if self.is_moe:  # the router reads the float32 input
-                return self.mlp(u, valid=valid)._data
+                return self.mlp(u, valid=valid, kernel=moe_kernel)._data
             return self.mlp(u.astype(dt))
 
         streams = _hc_sublayer(self.attn_hc, self.input_layernorm.weight,
@@ -500,7 +505,7 @@ class Xing4Model(nn.Layer):
 
     def forward(self, input_ids, position_ids=None, caches=None,
                 cache_offsets=None, seq_lens=None, block_tables=None,
-                paged_kernel=None, paged_mesh=None):
+                paged_kernel=None, paged_mesh=None, moe_kernel=None):
         if paged_mesh is not None:
             raise TypeError("Xing4Model: a 'latent' cache has no mesh route")
         ids = input_ids._data if isinstance(input_ids, Tensor) else input_ids
@@ -526,7 +531,7 @@ class Xing4Model(nn.Layer):
                 seq_lens=sl, block_tables=arr(block_tables),
                 paged_kernel=paged_kernel)
             streams, nc = layer(streams, positions, valid=valid,
-                                **cache_args)
+                                moe_kernel=moe_kernel, **cache_args)
             if nc is not None:
                 new_caches.append(tuple(Tensor(p) for p in nc))
         h = _rms(sum(streams), self.norm.weight._data,

@@ -6,11 +6,13 @@ expert, a shared expert every token passes through.
 `MoEMLP` (layer.py) pads every expert to a capacity and drops what does not
 fit — the price of its dense ``[G, S, E, C]`` masks. Here the shapes stay
 fixed another way: the ``N * k`` routed rows are sorted by expert and the
-group sizes are DATA (`lax.ragged_dot`; on a TPU XLA lowers it to a grouped
-matmul that walks the groups, on the CPU to its reference form), so no token
-is dropped however uneven the routing, one executable serves ``[32, 1]``
-decode and ``[1, 2048]`` prefill, and an expert nobody chose costs no weight
-read.
+group sizes are DATA (`ops.pallas_ops.grouped_matmul`: `lax.ragged_dot` —
+on a TPU XLA lowers it to a grouped matmul that walks the groups, on the CPU
+to its reference form — or, where a serving engine hands the layer a fused
+kernel kind and the step brings a few rows a group, the Pallas kernel that
+copies each hit expert's weight tiles once), so no token is dropped however
+uneven the routing, one executable serves ``[32, 1]`` decode and
+``[1, 2048]`` prefill, and an expert nobody chose costs no weight read.
 
 The layer is TOLD which experts it holds: ``experts_held=(lo, hi)`` keeps
 the weights of experts ``lo..hi-1`` only, the router still scores all
@@ -25,13 +27,11 @@ through it is open (ROADMAP).
 """
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
 
 from ...core.tensor import Tensor
-from ...ops.pallas_ops import _dot_precision
+from ...ops.pallas_ops import grouped_matmul
 from ...profiler import spans as _spans
 from ..initializer import Normal
 from ..layer.layers import Layer
@@ -92,7 +92,10 @@ class DroplessMoE(Layer):
     [B, T] bool marks the rows that are tokens (bucket padding is routed
     nowhere and costs no expert row). After a forward
     ``last_experts_hit`` holds the number of held experts that were given
-    at least one row (an int32 scalar of that trace)."""
+    at least one row (an int32 scalar of that trace). ``kernel``: what a
+    serving engine's `select_grouped_kernel` resolved to, handed down by the
+    model's forward at trace time (None, a layer called outside an engine:
+    `lax.ragged_dot`)."""
 
     def __init__(self, d_model, d_ff, num_experts, top_k, n_shared=1,
                  routed_scaling_factor=1.0, norm_topk_prob=True,
@@ -161,7 +164,17 @@ class DroplessMoE(Layer):
                 setattr(self.shared, name, lin)
         self.last_experts_hit = None
 
-    def forward(self, x, valid=None):
+    def grouped_shapes(self, tokens):
+        """``(M, K, N, G)`` of the layer's two grouped matmuls in a forward
+        over ``tokens`` tokens: what `pallas_ops._grouped_plan` reads."""
+        C = self.rows_at_a_time
+        if C and tokens > C and tokens % C == 0:
+            tokens = C
+        held, d, F2 = self.experts.gate_up.shape
+        M = tokens * self.top_k
+        return [(M, d, F2, held), (M, F2 // 2, d, held)]
+
+    def forward(self, x, valid=None, kernel=None):
         xa = x._data if isinstance(x, Tensor) else x
         B, T, d = xa.shape
         x2 = xa.reshape(B * T, d)
@@ -172,16 +185,16 @@ class DroplessMoE(Layer):
         if C and B * T > C and B * T % C == 0:
             if v is None:
                 v = jnp.ones((B * T,), bool)
-            y, hit = jax.lax.map(lambda a: self._compute(*a),
+            y, hit = jax.lax.map(lambda a: self._compute(*a, kernel),
                                  (x2.reshape(-1, C, d), v.reshape(-1, C)))
             # of the chunks' counts the largest: a count of experts, not
             # of rows (only decode, one chunk, reads it)
             self.last_experts_hit = hit.max()
         else:
-            y, self.last_experts_hit = self._compute(x2, v)
+            y, self.last_experts_hit = self._compute(x2, v, kernel)
         return Tensor(y.reshape(B, T, d))
 
-    def _compute(self, x, valid):
+    def _compute(self, x, valid, kernel=None):
         N, d = x.shape
         k, F = self.top_k, self.d_ff
         lo, hi = self.experts_held
@@ -211,18 +224,14 @@ class DroplessMoE(Layer):
                 jnp.arange(N * k, dtype=jnp.int32))
         with _spans.scope("moe_experts"):
             rows = x[order // k]  # [N*k, d], sorted by expert
-            # the precision is named: XLA:TPU's grouped matmul has no
-            # float32-contract form for bf16 operands, which the package's
-            # global "highest" would ask of it
-            grouped = functools.partial(
-                jax.lax.ragged_dot, group_sizes=sizes,
-                precision=_dot_precision(x.dtype),
-                preferred_element_type=jnp.float32)
-            h = grouped(rows, self.experts.gate_up._data)
+            h = grouped_matmul(rows, self.experts.gate_up._data, sizes,
+                               kernel=kernel)
             h = (jax.nn.silu(h[:, :F]) * h[:, F:]).astype(x.dtype)
-            out = grouped(h, self.experts.down._data)
+            out = grouped_matmul(h, self.experts.down._data, sizes,
+                                 kernel=kernel)
             # back to token order; a row no held expert owns adds nothing
-            # (whatever the grouped matmul left in it)
+            # (whatever the grouped matmul left in it: the kernel never
+            # writes such a row)
             out = jnp.where(mine.reshape(N * k, 1), out[back], 0.0)
             y = (out.reshape(N, k, d) * weight[:, :, None]).sum(1)
         if self.shared is not None:

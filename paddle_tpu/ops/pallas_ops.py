@@ -1644,6 +1644,241 @@ def select_mla_paged_kernel(requested=None, *, row_width, block_size,
         num_heads=1, family="mla_paged_attention")
 
 
+# ============================ grouped matmul =================================
+#
+# The served expert layer's matmuls (nn/moe/dropless.py, ISSUE 38): ``rows``
+# [M, K] sorted by expert, ``weights`` [G, K, N] as the layer keeps them,
+# ``group_sizes`` [G] (data) -> float32 [M, N], row r times the weights of the
+# group that owns it — `lax.ragged_dot`'s contract, for the regime a decode
+# step is in: a few rows a group (2-8 in the serving cells), two orders under
+# the chip's flops a byte, so the work is reading each HIT expert's weights
+# once. XLA's grouped matmul took 1.3-2.8 x that time (PERF.md, PR 37).
+#
+# Grid (N / tn, V, K / tk), V = min(G, M) visits: visit v is the v-th expert
+# that has a row (a scalar-prefetched list, the hit experts first), so an
+# expert without a row is never named and its weights never move; the visits
+# past the last hit expert re-name the block the last one had (no copy) and
+# do nothing. The weights are a BlockSpec operand ``[tk, tn]`` cut out of the
+# bank as it lies (``[tk, N]`` in the serving cells: whole rows of an expert,
+# one contiguous piece; measured 1-2 % ahead of ``[K, tn]`` columns on the
+# v5e, PERF.md PR 38): Pallas copies the tile of step i+1 while step i
+# computes, whatever expert either belongs to. The rows stay whole in VMEM
+# (one copy a call) and so does the ``[M, tn]`` column block of the result
+# for a whole walk over the experts, accumulating over k in float32. A visit's rows are a window of the sorted rows at
+# its group's offset: the start aligned down to a packed sublane tile (16),
+# ``tm`` rows a chunk, as many chunks as the group needs, each row of a chunk
+# kept only where the group owns it (the rest of the window belongs to the
+# neighbours and is left as it was). Rows no group owns are never written:
+# the caller zeroes them (`DroplessMoE._compute`).
+
+# what the kernel's buffers may take of a core's 128 MiB of VMEM (the rows
+# and a column block of the result, each twice as BlockSpec operands, and two
+# weight tiles), and one weight tile, at most
+_GROUPED_VMEM_BUDGET = 40 << 20
+_GROUPED_TILE_BYTES = 4 << 20
+_GROUPED_WORK_BYTES = 8 << 20
+# rows of one chunk of a group's window: a whole number of packed sublane
+# tiles; a group of 8 rows that starts mid-tile still fits one chunk
+_GROUPED_ROW_TILE = 32
+# the kernel's regime: at most this many rows a held group on average
+# (M / G). Above it (a prompt's rows: 64-512 a group in the serving cells) a
+# group is several chunks, each a full pass of the weights through the MXU,
+# and XLA's grouped matmul is not bound by the bytes any more
+_GROUPED_MAX_ROWS_A_GROUP = 32
+_GROUPED_ALIGN = 16
+
+
+def _grouped_vmem_bytes(M, K, N, tiles, dtype):
+    """Bytes of VMEM the kernel's operands take at these tiles."""
+    _, tk, tn = tiles
+    item = jnp.dtype(dtype).itemsize
+    return 2 * (M * K * item + tk * tn * item + M * tn * 4)
+
+
+def _lane_tiles(n, most):
+    """The largest divisor of ``n`` that is whole 128-lane tiles and at
+    most ``most`` (0: none)."""
+    return max((t for t in range(128, min(n, most) + 1, 128) if n % t == 0),
+               default=0)
+
+
+def _grouped_plan(M, K, N, G, dtype, compiled=True):
+    """(route, (tm, tk, tn), why) for ``rows [M, K] x weights [G, K, N]``,
+    from the static shapes alone. ``route``: "kernel"; "xla" — not the
+    kernel's regime (more than ``_GROUPED_MAX_ROWS_A_GROUP`` rows a group):
+    `lax.ragged_dot`, by design; "refused" — the regime is the kernel's but
+    Mosaic could not tile the shape (``compiled`` only: the interpreter tiles
+    anything), the caller falls back loudly. A weight tile is ``[tk, N]``,
+    ``tk`` whole rows of an expert as they lie (one contiguous piece of the
+    bank), the most within ``_GROUPED_TILE_BYTES``; N is cut too only where
+    128 rows of it are over that."""
+    dt = jnp.dtype(dtype)
+    tm = min(_GROUPED_ROW_TILE, M)
+    tn = _lane_tiles(N, _GROUPED_TILE_BYTES // (128 * dt.itemsize)) or N
+    tk = _lane_tiles(K, _GROUPED_TILE_BYTES // (tn * dt.itemsize)) or K
+    tiles = (tm, tk, tn)
+    if M > _GROUPED_MAX_ROWS_A_GROUP * G:
+        return "xla", tiles, (
+            f"{M} rows over {G} groups is more than "
+            f"{_GROUPED_MAX_ROWS_A_GROUP} a group: lax.ragged_dot's regime")
+    if not compiled:
+        return "kernel", tiles, "the interpreter tiles anything"
+    why = None
+    need = _grouped_vmem_bytes(M, K, N, tiles, dt)
+    if dt not in (jnp.dtype(jnp.float32), jnp.dtype(jnp.bfloat16)):
+        why = f"operand dtype {dt.name} not in (float32, bfloat16)"
+    elif M % _GROUPED_ALIGN:
+        why = (f"{M} sorted rows are not whole {_GROUPED_ALIGN}-row tiles: "
+               "a group's window could not be aligned")
+    elif K % 128 or N % 128:
+        why = f"weights [{K}, {N}] are not whole 128-lane tiles both ways"
+    elif need > _GROUPED_VMEM_BUDGET:
+        why = (f"rows [{M}, {K}] {dt.name}, a float32 [{M}, {tn}] block of "
+               f"the result and [{tk}, {tn}] weight tiles, each twice, are "
+               f"{need / 2 ** 20:.0f} MiB of VMEM (budget "
+               f"{_GROUPED_VMEM_BUDGET >> 20} MiB)")
+    if why:
+        return "refused", tiles, why
+    return "kernel", tiles, "tileable"
+
+
+def _grouped_matmul_kernel(eid_ref, off_ref, size_ref, hit_ref, x_ref, w_ref,
+                           o_ref, *, tm, align, precision):
+    """Program (n, v, k): the v-th hit expert's rows against tile (k, n) of
+    its weights. See the section's header."""
+    v = pl.program_id(1)
+    k = pl.program_id(2)
+    M = x_ref.shape[0]
+    tk = w_ref.shape[0]
+    whole_k = tk == x_ref.shape[1]  # static: all of K in one tile
+    i32 = jnp.int32
+
+    @pl.when(v < hit_ref[0])
+    def _visit():
+        e = eid_ref[v]
+        start, size = off_ref[e], size_ref[e]
+        first = start // i32(align) * i32(align)
+
+        def chunk(c, _):
+            lo = first + c * i32(tm)  # the chunk's own rows: lo .. lo+tm-1
+            a = jnp.minimum(lo, i32(M - tm))  # the window stays inside M
+            if align > 1:
+                a = pl.multiple_of(a, align)
+            if whole_k:
+                x = x_ref[pl.ds(a, tm), :]
+            else:
+                x = x_ref[pl.ds(a, tm),
+                          pl.ds(pl.multiple_of(k * i32(tk), 128), tk)]
+            acc = jnp.dot(x, w_ref[...], precision=precision,
+                          preferred_element_type=jnp.float32)
+            row = a + jax.lax.broadcasted_iota(i32, (tm, 1), 0)
+            mine = (row >= jnp.maximum(start, lo)) & (row < start + size)
+            old = o_ref[pl.ds(a, tm), :]
+            if not whole_k:  # K in tiles: the block accumulates
+                acc = jnp.where(k == 0, acc, old + acc)
+            o_ref[pl.ds(a, tm), :] = jnp.where(mine, acc, old)
+            return _
+        jax.lax.fori_loop(_i0(), pl.cdiv(start - first + size, i32(tm)),
+                          chunk, _i0())
+
+
+@functools.partial(jax.jit, static_argnames=("interpret", "tiles"))
+def _grouped_matmul_fused(rows, weights, group_sizes, interpret, tiles):
+    M, K = rows.shape
+    G, _, N = weights.shape
+    tm, tk, tn = tiles
+    n_k, V = K // tk, min(G, M)
+    i32 = jnp.int32
+    sizes = group_sizes.astype(i32)
+    hit = sizes > 0
+    n_hit = hit.sum().astype(i32)
+    # the hit experts first, in their order; past them the last one again
+    order = jnp.argsort(jnp.logical_not(hit), stable=True).astype(i32)
+    eid = order[jnp.minimum(jnp.arange(V, dtype=i32),
+                            jnp.maximum(n_hit - 1, 0))]
+
+    def w_map(n, v, k, eid, off, size, hit):
+        # a visit past the last hit expert re-names that one's last tile
+        return (eid[v], jnp.where(v < hit[0], k, i32(n_k - 1)), n)
+
+    align = _GROUPED_ALIGN \
+        if M % _GROUPED_ALIGN == 0 and tm % _GROUPED_ALIGN == 0 else 1
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4,
+        grid=(N // tn, V, n_k),
+        in_specs=[pl.BlockSpec((M, K), lambda n, v, k, *_: (_i0(), _i0())),
+                  pl.BlockSpec((None, tk, tn), w_map)],
+        out_specs=pl.BlockSpec((M, tn), lambda n, v, k, *_: (_i0(), n)),
+    )
+    return pl.pallas_call(
+        functools.partial(_grouped_matmul_kernel, tm=tm, align=align,
+                          precision=_dot_precision(rows.dtype)),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((M, N), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=_grouped_vmem_bytes(M, K, N, tiles, rows.dtype)
+            + _GROUPED_WORK_BYTES),
+        interpret=interpret,
+        name=_kernel_name("grouped_matmul"),
+    )(eid, jnp.cumsum(sizes) - sizes, sizes, n_hit[None], rows, weights)
+
+
+def grouped_matmul(rows, weights, group_sizes, kernel="xla"):
+    """``rows`` [M, K] sorted by group times ``weights`` [G, K, N], group g
+    owning the ``group_sizes[g]`` rows after its predecessors' -> float32
+    [M, N]; rows past the last group hold anything (`lax.ragged_dot` zeroes
+    them, the kernel leaves them unwritten). ``kernel`` is what the engine's
+    :func:`select_grouped_kernel` resolved to ("xla" / None: always
+    `lax.ragged_dot`); under a fused kind the static shapes decide
+    (:func:`_grouped_plan`): a decode step's few rows a group take the
+    kernel, a prompt's rows stay with `lax.ragged_dot`."""
+    if kernel not in (None, "xla", "pallas", "interpret"):
+        raise ValueError(
+            f"unknown grouped-matmul kernel {kernel!r} "
+            "(expected pallas | interpret | xla)")
+    if kernel in ("pallas", "interpret"):
+        (M, K), (G, _, N) = rows.shape, weights.shape
+        route, tiles, _ = _grouped_plan(M, K, N, G, rows.dtype,
+                                        compiled=kernel == "pallas")
+        if route == "kernel":
+            return _grouped_matmul_fused(
+                rows, weights, group_sizes,
+                interpret=kernel == "interpret", tiles=tiles)
+    # the precision is named: XLA:TPU's grouped matmul has no float32-
+    # contract form for bf16 operands, which the package's global "highest"
+    # would ask of it
+    return jax.lax.ragged_dot(
+        rows, weights, group_sizes=group_sizes.astype(jnp.int32),
+        precision=_dot_precision(rows.dtype),
+        preferred_element_type=jnp.float32)
+
+
+def select_grouped_kernel(paged_kind, *, shapes, dtype):
+    """Resolve the expert layers' grouped matmuls for one engine build, from
+    what the engine's paged kernel resolved to (``paged_kernel=`` is the one
+    request all three follow) and the decode step's shapes (``shapes``:
+    ``(M, K, N, G)`` of every grouped matmul of a step). A fused kind stays
+    itself when :func:`_grouped_plan` gives every shape to the kernel; a
+    shape in `lax.ragged_dot`'s regime is "xla" by design; a shape Mosaic
+    could not tile is "xla" loudly (``serving.kernel.fallbacks``, a
+    ``kernel_fallback`` event). Returns ``(kind, reason)``."""
+    if paged_kind == "xla":
+        return "xla", "the engine's paged kernel resolved to xla"
+    if not shapes:
+        return "xla", "the decoder has no dropless expert layer"
+    for M, K, N, G in shapes:
+        route, _, why = _grouped_plan(M, K, N, G, dtype,
+                                      compiled=paged_kind == "pallas")
+        if route == "refused":
+            _note_kernel_fallback("grouped_matmul", why, rows=M,
+                                  weights=[G, K, N])
+        if route != "kernel":
+            return "xla", why
+    return paged_kind, "follows the paged kernel: " + (
+        "compiled" if paged_kind == "pallas" else "the interpreter")
+
+
 # =========================== fused softmax mask ==============================
 
 def fused_softmax_mask(x, mask):

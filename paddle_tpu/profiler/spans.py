@@ -110,6 +110,11 @@ COUNTERS = {
     "serving.moe_layer_steps": "expert layers x decode steps (host)",
     "serving.moe_routed_rows": "active slots x experts per token, a "
                                "layer-step (host)",
+    "serving.moe_kernel_layer_steps": "of serving.moe_layer_steps those "
+                                      "whose grouped matmuls ran through "
+                                      "the Pallas kernel `grouped_matmul` "
+                                      "(host: all of a step's or none, by "
+                                      "the gauge serving.moe_grouped_kernel)",
     "serving.moe_experts_hit": "distinct held experts given >= 1 row, "
                                "summed over the expert layers of each "
                                "decode step: counted in the executable, "
@@ -124,6 +129,12 @@ GAUGES = {
                                 "`window` rows a slot",
     "serving.kv_window_blocks": "blocks of one slot's ring in a window "
                                 "layer: window / block_size + 1 (0: none)",
+    "serving.moe_grouped_kernel": "what the dropless expert layers' grouped "
+                                  "matmuls run through at a decode step: "
+                                  "pallas / interpret / xla "
+                                  "(`pallas_ops.select_grouped_kernel`, "
+                                  "following the paged kernel; the reason "
+                                  "in `engine.stats()`)",
 }
 
 # Mosaic kernels (`pl.pallas_call(name=...)`): the custom call's HLO
@@ -139,6 +150,9 @@ KERNELS = {
                               "keys",
     "mla_paged_attention": "absorbed latent (MLA) decode attention over "
                            "the latent block pool, all heads a block",
+    "grouped_matmul": "the served expert layer's matmuls at a decode step "
+                      "(nn/moe/dropless.py): each hit expert's weight "
+                      "tiles once, its few sorted rows against them",
     "flash_prefill": "the prompt span's attention over the slot's rows in "
                      "the block pool (models/gpt.py's prefill): online "
                      "softmax a block of query rows, all heads",

@@ -113,7 +113,7 @@ _counters = _registry.scoped_counters("serving", {
     "handoff_stale": 0, "chunked_prefills": 0, "prefill_chunks": 0,
     "kv_tokens_read": 0, "kv_window_rows_read": 0,
     "cache_refusals": 0, "moe_layer_steps": 0, "moe_routed_rows": 0,
-    "moe_experts_hit": 0, "sample_topk_steps": 0, "sample_topp_steps": 0,
+    "moe_experts_hit": 0, "moe_kernel_layer_steps": 0, "sample_topk_steps": 0, "sample_topp_steps": 0,
     "prefill_flash_calls": 0, "diffusion.slot_forwards": 0,
     "diffusion.tokens_committed": 0, "diffusion.blocks_committed": 0,
     "diffusion.commit_forwards": 0})
@@ -423,6 +423,22 @@ class GenerationEngine:
             self._prefill_kernel, self._prefill_kernel_reason = (
                 "xla", "the decoder's prefill is its own forward's")
         _registry.gauge_set("serving.prefill_kernel", self._prefill_kernel)
+        # the dropless expert layers' grouped matmuls, resolved the same
+        # way from the decode step's shapes (a block decoder's step brings
+        # a block of rows a slot); the forward hands the kind down to the
+        # layers at trace time, where each call's own shape decides
+        moe_layers = [l for l in gpt.sublayers()
+                      if hasattr(l, "grouped_shapes")]
+        step_tokens = self.max_batch_size * (
+            self._gen.block_length if self._gen is not None else 1)
+        self._moe_kernel, self._moe_kernel_reason = \
+            _pallas_ops.select_grouped_kernel(
+                self._paged_kernel, dtype=self._dtype,
+                shapes=[s for l in moe_layers
+                        for s in l.grouped_shapes(step_tokens)])
+        self._moe_kernel_arg = {"moe_kernel": self._moe_kernel} \
+            if moe_layers else {}
+        _registry.gauge_set("serving.moe_grouped_kernel", self._moe_kernel)
         if mesh is not None:
             # telemetry for the stats_dump "mesh serving" section
             _registry.gauge_set("serving.mesh.mp",
@@ -706,7 +722,8 @@ class GenerationEngine:
                     seq_lens=Tensor(seq_lens),
                     block_tables=[Tensor(t) for t in tables]
                     if isinstance(tables, list) else Tensor(tables),
-                    paged_kernel=kernel, paged_mesh=paged_mesh)
+                    paged_kernel=kernel, paged_mesh=paged_mesh,
+                    **self._moe_kernel_arg)
                 if self._step_counter_names:
                     got = self._gpt.step_counters()
                     if counts is not None:
@@ -1574,6 +1591,15 @@ class GenerationEngine:
         self._decode_since_audit = 0
         return tail
 
+    def _count_model_step(self, n_active):
+        """What the decoder says a step adds to the host's counters, and of
+        its expert layer-steps those whose grouped matmuls ran through the
+        kernel (all, or none: the kind is the engine's)."""
+        for name, n in self._host_step_counts(n_active).items():
+            _counters[name] += n
+            if name == "moe_layer_steps" and self._moe_kernel != "xla":
+                _counters["moe_kernel_layer_steps"] += n
+
     def _finish_decode(self, active, n_active, toks):
         # host mirrors advance in lockstep with the device copies (numpy
         # stores over B elements; the audit cross-checks the two)
@@ -1589,8 +1615,7 @@ class GenerationEngine:
         self._last_tokens[active] = toks[active]
         c["decode_steps"] += 1
         self._count_filter_steps(active)
-        for name, n in self._host_step_counts(n_active).items():
-            c[name] += n
+        self._count_model_step(n_active)
         c["active_slot_steps"] += n_active
         c["tokens_generated"] += n_active
         _registry.gauge_set("serving.batch_occupancy",
@@ -1639,8 +1664,7 @@ class GenerationEngine:
         self._cur_lens[commit] += L
         c["decode_steps"] += 1
         self._count_filter_steps(denoised)
-        for name, n in self._host_step_counts(n_active).items():
-            c[name] += n
+        self._count_model_step(n_active)
         c["active_slot_steps"] += n_active
         c["tokens_generated"] += n_tok
         c["diffusion.slot_forwards"] += n_active
@@ -1716,6 +1740,8 @@ class GenerationEngine:
                "paged_keys_per_program": self._paged_keys_per_program,
                "prefill_kernel": self._prefill_kernel,
                "prefill_kernel_reason": self._prefill_kernel_reason,
+               "moe_grouped_kernel": self._moe_kernel,
+               "moe_grouped_kernel_reason": self._moe_kernel_reason,
                "kv_cache_kind": self._cache.kind,
                "kv_row_width": self._cache.row_width(),
                "kv_cache": self._cache.describe(),

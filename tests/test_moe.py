@@ -373,6 +373,252 @@ class TestEndpointGC:
 _LEG: dict = {}
 
 
+# ---- ISSUE 38: the served expert layer's grouped matmuls through a kernel ----
+
+def _grouped_case(name):
+    """(M, G, sizes) of one routing the kernel must survive."""
+    rng = np.random.default_rng(38)
+    if name == "even_groups_of_8":
+        return 64, 8, [8] * 8
+    if name == "uneven_with_empty_first_last_and_runs":
+        return 96, 12, [0, 0, 5, 0, 0, 0, 11, 1, 0, 55, 24, 0]
+    if name == "every_row_in_one_group":  # three chunks of the row tile
+        return 96, 4, [0, 96, 0, 0]
+    if name == "starts_not_multiples_of_8_or_16":
+        return 64, 8, [3, 7, 5, 13, 9, 1, 15, 11]
+    if name == "trailing_rows_no_group_owns":
+        return 64, 8, [2, 0, 9, 4, 0, 6, 1, 0]
+    if name == "held_16_of_128_experts":  # most rows belong elsewhere
+        chosen = np.stack([rng.permutation(128)[:8] for _ in range(32)])
+        return 256, 16, np.bincount(chosen[chosen < 16], minlength=16)
+    raise KeyError(name)
+
+
+GROUPED_CASES = ["even_groups_of_8", "uneven_with_empty_first_last_and_runs",
+                 "every_row_in_one_group", "starts_not_multiples_of_8_or_16",
+                 "trailing_rows_no_group_owns", "held_16_of_128_experts"]
+
+
+def _grouped_operands(M, K, N, G, sizes, dtype, seed=0):
+    import jax.numpy as jnp
+
+    rng = np.random.default_rng(seed)
+    return (jnp.asarray(rng.standard_normal((M, K)), dtype),
+            jnp.asarray(rng.standard_normal((G, K, N)) * 0.1, dtype),
+            jnp.asarray(np.asarray(sizes), jnp.int32))
+
+
+class TestGroupedMatmulKernel:
+    """`pallas_ops.grouped_matmul` through the Pallas interpreter against
+    `lax.ragged_dot` on the same operands: the rows the groups own, to
+    float32 round-off (both accumulate bf16 products in float32)."""
+
+    @pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+    @pytest.mark.parametrize("case", GROUPED_CASES)
+    def test_kernel_matches_ragged_dot(self, case, dtype, monkeypatch):
+        from paddle_tpu.ops import pallas_ops as po
+
+        # tiles small enough that the toy weights are several tiles both
+        # ways: the accumulation over k and the walk over column blocks run
+        monkeypatch.setattr(po, "_GROUPED_TILE_BYTES",
+                            128 * 128 * np.dtype(
+                                "float32" if dtype == "float32"
+                                else "float16").itemsize)
+        M, G, sizes = _grouped_case(case)
+        K, N = 256, 384
+        route, (tm, tk, tn), _ = po._grouped_plan(M, K, N, G, dtype)
+        assert route == "kernel" and tm == 32 and tk < K and tn < N
+        x, w, g = _grouped_operands(M, K, N, G, sizes, dtype)
+        got = np.asarray(po.grouped_matmul(x, w, g, kernel="interpret"))
+        ref = np.asarray(po.grouped_matmul(x, w, g, kernel="xla"))
+        owned = int(np.sum(sizes))
+        assert got.shape == ref.shape == (M, N) and got.dtype == np.float32
+        np.testing.assert_allclose(got[:owned], ref[:owned], rtol=1e-5,
+                                   atol=1e-5)
+
+    @pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+    def test_shapes_that_are_not_whole_tiles(self, dtype):
+        """K and N that are not whole 128-lane tiles: the interpreter takes
+        them whole, a compiled plan refuses, loudly (the engine's selection
+        counts a fallback and says why)."""
+        from paddle_tpu.ops import pallas_ops as po
+
+        M, K, N, G = 48, 96, 200, 6
+        assert po._grouped_plan(M, K, N, G, dtype, compiled=False)[:2] == (
+            "kernel", (32, K, N))
+        route, _, why = po._grouped_plan(M, K, N, G, dtype)
+        assert route == "refused" and "128-lane" in why
+        assert po._grouped_plan(40, 128, 128, G, dtype)[0] == "refused"
+        sizes = [5, 0, 17, 1, 9, 12]
+        x, w, g = _grouped_operands(M, K, N, G, sizes, dtype)
+        got = np.asarray(po.grouped_matmul(x, w, g, kernel="interpret"))
+        ref = np.asarray(po.grouped_matmul(x, w, g, kernel="xla"))
+        np.testing.assert_allclose(got[:44], ref[:44], rtol=1e-5, atol=1e-5)
+        before = _reg.counters("serving")["kernel.fallbacks"]
+        kind, reason = po.select_grouped_kernel(
+            "pallas", shapes=[(M, K, N, G)], dtype=dtype)
+        assert kind == "xla" and "128-lane" in reason
+        assert _reg.counters("serving")["kernel.fallbacks"] == before + 1
+        ev = _explain.events(kind="kernel_fallback")[-1]
+        assert ev["op"] == "grouped_matmul" and "128-lane" in ev["why"]
+
+    def test_a_prompts_rows_stay_with_ragged_dot(self):
+        """More than 32 rows a group is `lax.ragged_dot`'s regime whatever
+        kind the engine resolved to: by design, so nothing is counted."""
+        import jax
+
+        from paddle_tpu.ops import pallas_ops as po
+
+        x, w, g = _grouped_operands(512, 128, 128, 4, [100, 200, 12, 150],
+                                    "float32")
+        before = _reg.counters("serving")["kernel.fallbacks"]
+        text = str(jax.make_jaxpr(lambda *a: po.grouped_matmul(
+            *a, kernel="interpret"))(x, w, g))
+        assert "ragged_dot" in text and "pallas_call" not in text
+        assert po.select_grouped_kernel(
+            "pallas", shapes=[(512, 128, 128, 4)], dtype="float32")[0] == "xla"
+        assert _reg.counters("serving")["kernel.fallbacks"] == before
+        with pytest.raises(ValueError, match="unknown grouped-matmul"):
+            po.grouped_matmul(x, w, g, kernel="mosaic")
+
+
+# the three expert cells' decode shapes (rows, K, N, held experts) with the
+# tiles PERF.md's PR 38 entry names, and their prompts' shapes
+GROUPED_DECODE = {
+    "sdar_gate_up": ((1024, 2048, 1536, 128), (32, 1024, 1536)),
+    "sdar_down": ((1024, 768, 2048, 128), (32, 768, 2048)),
+    "commanda_gate_up": ((256, 4096, 8192, 16), (32, 256, 8192)),
+    "commanda_down": ((256, 4096, 4096, 16), (32, 512, 4096)),
+    "xing4_gate_up": ((128, 3584, 2048, 64), (32, 896, 2048)),
+    "xing4_down": ((128, 1024, 3584, 64), (32, 512, 3584)),
+}
+GROUPED_PREFILL = {
+    "sdar_bucket_1536": (12288, 2048, 1536, 128),
+    "sdar_bucket_4096": (32768, 768, 2048, 128),
+    "commanda_chunk_of_1024": (8192, 4096, 8192, 16),
+    "xing4_bucket_1024": (4096, 3584, 2048, 64),
+    "xing4_bucket_2048": (8192, 1024, 3584, 64),
+}
+
+
+class TestGroupedPlan:
+    @pytest.mark.parametrize("cell", list(GROUPED_DECODE))
+    def test_decode_shapes_take_the_kernel(self, cell):
+        from paddle_tpu.ops import pallas_ops as po
+
+        shape, tiles = GROUPED_DECODE[cell]
+        route, got, why = po._grouped_plan(*shape, "bfloat16")
+        assert (route, got, why) == ("kernel", tiles, "tileable")
+        M, K, N, _ = shape
+        _, tk, tn = tiles
+        assert K % tk == 0 and N % tn == 0 and tk % 128 == 0
+        assert tk * tn * 2 <= po._GROUPED_TILE_BYTES
+        assert po._grouped_vmem_bytes(M, K, N, tiles, "bfloat16") \
+            <= po._GROUPED_VMEM_BUDGET
+
+    @pytest.mark.parametrize("cell", list(GROUPED_PREFILL))
+    def test_prefill_shapes_keep_ragged_dot(self, cell):
+        from paddle_tpu.ops import pallas_ops as po
+
+        route, _, why = po._grouped_plan(*GROUPED_PREFILL[cell], "bfloat16")
+        assert route == "xla" and "a group" in why
+
+    def test_the_plan_reads_shapes_alone(self):
+        """No argument of the plan is a name, a flag of a model or the
+        environment's: four ints, a dtype and who compiles."""
+        import inspect
+
+        from paddle_tpu.ops import pallas_ops as po
+
+        assert list(inspect.signature(po._grouped_plan).parameters) == [
+            "M", "K", "N", "G", "dtype", "compiled"]
+        src = inspect.getsource(po._grouped_plan)
+        assert "environ" not in src and "getattr" not in src
+        # too large for VMEM: refused with the sizes, not attempted
+        route, _, why = po._grouped_plan(4096, 8192, 8192, 128, "bfloat16")
+        assert route == "refused" and "MiB of VMEM" in why
+
+
+def _dropless(kind):
+    """The three cells' routers at toy size, float32."""
+    from paddle_tpu.nn.moe.dropless import DroplessMoE
+
+    paddle.seed(38)
+    if kind == "xing4":  # sigmoid + selection bias, scaling 2, one shared
+        return DroplessMoE(48, 24, 8, 2, n_shared=1,
+                           routed_scaling_factor=2.0)
+    if kind == "commanda":  # a share of the experts, 4 shared averaged,
+        # a prompt's tokens a chunk at a time
+        return DroplessMoE(48, 24, 16, 4, n_shared=4, select_bias=False,
+                           experts_held=(0, 4), shared_combine="average",
+                           rows_at_a_time=8)
+    return DroplessMoE(48, 24, 16, 2, n_shared=0, select_bias=False,
+                       router="softmax")
+
+
+@pytest.mark.parametrize("kind", ["xing4", "commanda", "sdar"])
+def test_dropless_forward_through_the_kernel(kind):
+    """`DroplessMoE.forward` with the kernel forced to the interpreter
+    against the `lax.ragged_dot` route: float32 round-off, decode rows and
+    a prompt's (with padding routed nowhere)."""
+    layer = _dropless(kind)
+    rng = np.random.default_rng(0)
+    for B, T in ((4, 1), (1, 16)):
+        x = paddle.to_tensor(rng.standard_normal((B, T, 48)).astype(
+            np.float32))
+        valid = paddle.to_tensor(np.arange(B * T).reshape(B, T) < B * T - 3)
+        for v in (None, valid):
+            ref = layer(x, valid=v).numpy()
+            hit = int(layer.last_experts_hit)
+            got = layer(x, valid=v, kernel="interpret").numpy()
+            assert int(layer.last_experts_hit) == hit
+            assert np.isfinite(got).all()
+            np.testing.assert_allclose(got, ref, rtol=2e-5, atol=2e-6)
+    assert layer.grouped_shapes(4) == [
+        (4 * layer.top_k, 48, 48, layer.experts_held[1]),
+        (4 * layer.top_k, 24, 48, layer.experts_held[1])]
+
+
+@pytest.mark.parametrize("family", ["sdar", "xing4"])
+def test_engine_says_which_grouped_matmul_it_ran(family):
+    """The gauge, its reason and `serving.moe_kernel_layer_steps` read what
+    the engine did: on the CPU an engine resolves to `xla` (counter 0,
+    nothing counted as a fallback); asked for pallas it runs the kernel's
+    body through the interpreter in every expert layer of every step, and
+    serves the same tokens."""
+    from paddle_tpu.models import (SdarMoeConfig, SdarMoeModel, Xing4Config,
+                                   Xing4Model)
+    from paddle_tpu.serving.engine import GenerationEngine
+
+    served = {}
+    for request, kind in ((None, "xla"), ("pallas", "interpret")):
+        paddle.seed(0)
+        model = SdarMoeModel(SdarMoeConfig.preset("tiny")) \
+            if family == "sdar" else Xing4Model(Xing4Config.preset("tiny"))
+        model.eval()
+        eng = GenerationEngine(model, max_batch_size=2, buckets=(16,),
+                               max_seq_len=64, rng_seed=0,
+                               paged_kernel=request)
+        c0 = dict(_reg.counters("serving"))
+        st = eng.stats()
+        assert st["moe_grouped_kernel"] == kind, st
+        assert _reg.gauge("serving.moe_grouped_kernel") == kind
+        assert ("interpreter" if request else "resolved to xla") \
+            in st["moe_grouped_kernel_reason"]
+        first = eng.prefill(0, list(range(3, 12)))
+        steps = [eng.decode_step() for _ in range(4)]
+        c1 = _reg.counters("serving")
+        moved = {k: c1[k] - c0.get(k, 0) for k in (
+            "moe_layer_steps", "moe_kernel_layer_steps", "kernel.fallbacks")}
+        assert moved["moe_layer_steps"] > 0 and moved["kernel.fallbacks"] == 0
+        assert moved["moe_kernel_layer_steps"] == (
+            moved["moe_layer_steps"] if request else 0)
+        served[kind] = repr((first, [np.asarray(s).tolist()
+                                     if not isinstance(s, list) else s
+                                     for s in steps]))
+    assert served["xla"] == served["interpret"]
+
+
 def _batch(rng):
     toks = rng.integers(0, V, (B, T)).astype(np.int64)
     return (spmd.shard_batch(paddle.to_tensor(toks)),

@@ -345,6 +345,35 @@ compile_("xing4_grouped_matmul",
          sds((128, 3584), jnp.bfloat16, sharding=one),
          sds((64, 3584, 2048), jnp.bfloat16, sharding=one),
          sds((64,), jnp.int32, sharding=one))
+# ISSUE 38: the expert layers' grouped matmuls at the three expert cells'
+# decode shapes through `grouped_matmul`: one Mosaic call, the bank read as
+# it lies (no copy of its shape), the plan's tiles
+GROUPED = {"sdar_gate_up": (1024, 2048, 1536, 128),
+           "sdar_down": (1024, 768, 2048, 128),
+           "commanda_gate_up": (256, 4096, 8192, 16),
+           "commanda_down": (256, 4096, 4096, 16),
+           "xing4_gate_up": (128, 3584, 2048, 64),
+           "xing4_down": (128, 1024, 3584, 64)}
+import re
+for cell, (M, K, N, G) in GROUPED.items():
+    try:
+        text = jax.jit(
+            lambda x, w, g: po.grouped_matmul(x, w, g, kernel="pallas")
+        ).trace(sds((M, K), jnp.bfloat16, sharding=one),
+                sds((G, K, N), jnp.bfloat16, sharding=one),
+                sds((G,), jnp.int32, sharding=one)).lower(
+                    lowering_platforms=("tpu",)).compile().as_text()
+        out[f"grouped_{cell}"] = {
+            "custom_calls": text.count('"tpu_custom_call"'),
+            "kernels": len(re.findall(r"%grouped_matmul(?:\.\d+)? = ", text)),
+            "ragged": text.count("ragged-dot"),
+            # anything that makes an array of the bank's shape
+            "bank_copies": [ln.strip()[:100] for ln in text.splitlines()
+                            if f" = bf16[{G},{K},{N}]" in ln
+                            and " parameter(" not in ln],
+            "plan": list(po._grouped_plan(M, K, N, G, jnp.bfloat16)[1])}
+    except Exception as e:
+        out[f"grouped_{cell}"] = f"{type(e).__name__}: {e}"[:600]
 # the serving cell's layer: the step's new rows written into donated pools,
 # then the kernel over them (gpt3-1.3b: 3,073 blocks x 16 x 32 heads x 64,
 # 32 slots, 128 table columns). The pools must enter row-major and stay
@@ -612,6 +641,46 @@ for fam, (V, build) in toys.items():
     except Exception as e:
         out[f"sampling_{fam}"] = f"{type(e).__name__}: {e}"[:600]
 
+# ... and the engines' own steps at toy depth with expert widths Mosaic can
+# tile: `serving_decode` (a block decoder's block step too) holds the
+# kernel's calls, two an expert layer, and no `ragged-dot`; a prompt's rows
+# (more than 32 a group) keep `ragged-dot` in `serving_prefill`
+from paddle_tpu.models import SdarMoeConfig, SdarMoeModel
+moe_toys = {
+    "xing4": (lambda: Xing4Model(Xing4Config.preset(
+        "tiny", num_hidden_layers=2, hidden_size=128,
+        moe_intermediate_size=128, dtype="bfloat16")), 32, 256, 512),
+    "sdar": (lambda: SdarMoeModel(SdarMoeConfig.preset(
+        "tiny", num_hidden_layers=2, hidden_size=128, head_dim=64,
+        moe_intermediate_size=128, max_position_embeddings=1024,
+        dtype="bfloat16")), 8, 512, 1024),
+}
+for fam, (build, slots, bucket, max_len) in moe_toys.items():
+    try:
+        m = build()
+        m.eval()
+        eng = GenerationEngine(m, max_batch_size=slots, buckets=(bucket,),
+                               max_seq_len=max_len, rng_seed=0)
+        av = lambda a: sds(np.shape(a), a.dtype, sharding=one)
+        steps = engine_avals(eng, bucket)
+        head = steps["prefill"][1][:3]
+        steps["decode"] = (
+            eng._decode_pure if eng.generation is None else eng._block_pure,
+            head + tuple(av(getattr(eng, n)) for n in eng._slot_state))
+        for step, (fn, avals) in steps.items():
+            text = jax.jit(fn).trace(*avals).lower(
+                lowering_platforms=("tpu",)).compile().as_text()
+            out[f"grouped_engine_{fam}_{step}"] = {
+                "kind": eng.stats()["moe_grouped_kernel"],
+                "expert_layers": len([l for l in m.sublayers()
+                                      if hasattr(l, "grouped_shapes")]),
+                "kernels": len(re.findall(
+                    r"%grouped_matmul[\w.]* = ", text)),
+                "ragged": len(re.findall(
+                    r"%ragged-dot-none[\w.]* = ", text))}
+    except Exception as e:
+        out[f"grouped_engine_{fam}"] = f"{type(e).__name__}: {e}"[:600]
+
 mesh = Mesh(np.array(devs).reshape(2, 2), ("dp", "mp"))
 lazy.set_spmd_mesh(mesh)
 b = sds((8, 1024, 16, 64), jnp.bfloat16,
@@ -673,7 +742,7 @@ def test_aot_compile_for_v5e():
     _SERVE_LAYERS.update((k, v) for k, v in res.items()
                          if k.startswith(("serve_layer_", "xing4_",
                                           "sampling_", "commanda_", "sdar_",
-                                          "prefill_layer_")))
+                                          "prefill_layer_", "grouped_")))
 
 
 _SERVE_LAYERS: dict = {}
@@ -706,6 +775,50 @@ def test_latent_kernel_and_grouped_matmul_compile_for_v5e():
                    "keys_per_program": 128}
     assert _SERVE_LAYERS["xing4_grouped_matmul"] == {"custom_calls": 2,
                                                      "collectives": 0}
+
+
+GROUPED_TILES = {"sdar_gate_up": [32, 1024, 1536],
+                 "sdar_down": [32, 768, 2048],
+                 "commanda_gate_up": [32, 256, 8192],
+                 "commanda_down": [32, 512, 4096],
+                 "xing4_gate_up": [32, 896, 2048],
+                 "xing4_down": [32, 512, 3584]}
+
+
+@pytest.mark.parametrize("cell", list(GROUPED_TILES))
+def test_grouped_matmul_kernel_compiles_for_v5e(cell):
+    """ISSUE 38: the expert layers' decode matmuls of the three expert
+    cells through `grouped_matmul`, compiled for the described v5e (the AOT
+    child's result of the test above): one Mosaic custom call under the
+    kernel's name, no `ragged-dot`, the weights read as they lie (nothing in
+    the program makes an array of the bank's shape) and the tiles PERF.md
+    names."""
+    if not _SERVE_LAYERS:
+        pytest.skip("test_aot_compile_for_v5e did not compile here")
+    assert _SERVE_LAYERS[f"grouped_{cell}"] == {
+        "custom_calls": 1, "kernels": 1, "ragged": 0, "bank_copies": [],
+        "plan": GROUPED_TILES[cell]}
+
+
+@pytest.mark.parametrize("family", ["xing4", "sdar"])
+def test_serving_decode_runs_its_experts_through_the_kernel_on_v5e(family):
+    """A toy engine of each kind of decoder compiled for the described v5e:
+    `serving_decode` (the block step of a block-diffusion decoder) holds two
+    `grouped_matmul` calls an expert layer and no `ragged-dot`; the prompt's
+    rows, more than 32 a group, keep `ragged-dot` in `serving_prefill`."""
+    if not _SERVE_LAYERS:
+        pytest.skip("test_aot_compile_for_v5e did not compile here")
+    assert f"grouped_engine_{family}" not in _SERVE_LAYERS, \
+        _SERVE_LAYERS[f"grouped_engine_{family}"]
+    decode = _SERVE_LAYERS[f"grouped_engine_{family}_decode"]
+    n = decode["expert_layers"]
+    assert n >= 1 and decode == {"kind": "pallas", "expert_layers": n,
+                                 "kernels": 2 * n, "ragged": 0}
+    # (XLA:TPU takes some of a toy's grouped matmuls in another form: at
+    # least one a layer stays its `ragged-dot`)
+    prefill = dict(_SERVE_LAYERS[f"grouped_engine_{family}_prefill"])
+    assert n <= prefill.pop("ragged") <= 2 * n
+    assert prefill == {"kind": "pallas", "expert_layers": n, "kernels": 0}
 
 
 @pytest.mark.parametrize("step", ["decode", "prefill"])
