@@ -21,12 +21,30 @@ A span that is in both the ring and the profiler's trace (same name, same
 order) gives the offset between `time.monotonic` and the trace's clock.
 
 Rule for call sites: step and phase frequency only — never per op, per slot
-or per token; at most six spans per steady decode iteration and two per
-training step. The program's own call sites use `span` and only names from
-`SPANS`; `RecordEvent` (Paddle's name, a user's own event names) runs the
-same code, takes any name and feeds no counter. `tracing.span` /
-`tracing.add_span` stay for what only they can do: spans closed after the
-fact from two timestamps and spans shipped between processes.
+or per token. The spans of the server thread TILE its iteration, so that a
+reader of the device's idle time (`benchmark/step_timeline.py`) finds a named
+phase under every piece of a gap and no catch-all:
+
+    steady iteration: six spans         an admission adds: seven
+    serving.sched_step                  serving.admit
+      serving.decode_prepare              serving.admit_check
+      serving.decode_step                 serving.admit_blocks
+        serving.decode_sync               serving.admit_stage
+      serving.decode_finish               serving.prefill
+      serving.emit                        serving.admit_install (the engine's)
+                                          serving.admit_install (the scheduler's)
+                                        and the step after it rebuilds: the
+                                        same six spans, no more
+
+`serving.sched_step`'s self time is then the scheduler's own checks and
+nothing of the engine's; a wait for work is `serving.loop_idle`, between
+iterations. A training step has two. `host.gc` is no call site: the
+collector's own callback (`watch_gc`), on whichever thread collects. The
+program's own call sites use `span` and only names from `SPANS`;
+`RecordEvent` (Paddle's name, a user's own event names) runs the same code,
+takes any name and feeds no counter. `tracing.span` / `tracing.add_span`
+stay for what only they can do: spans closed after the fact from two
+timestamps and spans shipped between processes.
 
 The device side of the table (`KERNELS`, `EXECUTABLES`, `SCOPES`) holds the
 names the compiled programs carry: metadata only, the programs do not
@@ -34,6 +52,8 @@ change.
 """
 from __future__ import annotations
 
+import gc
+import threading
 import time
 
 import jax
@@ -46,29 +66,52 @@ SPANS = {
                          "server thread had no work",
     "serving.sched_step": "scheduler.py `step`, whole body: one scheduler "
                           "iteration",
-    "serving.admit": "scheduler.py `_admit`: one admission, the prefill "
-                     "inside it",
-    "serving.prefill": "engine.py `_prefill_call`: dispatch + wait of one "
+    "serving.admit": "scheduler.py `_admit`: one admission (or the "
+                     "attempt the pool refused); its self time is the "
+                     "scheduler's own around the engine's phases",
+    "serving.admit_check": "scheduler.py `_admit`, around the engine's "
+                           "budget check of the queue's head (`can_admit` "
+                           "/ `can_import`: the pool's free blocks and a "
+                           "walk of the radix tree for the evictable ones)",
+    "serving.admit_blocks": "engine.py `_admit_prompt`: the prompt checked, "
+                            "the radix match, the blocks allocated "
+                            "(eviction inside)",
+    "serving.admit_stage": "engine.py `prefill` / `prefill_chunk`: from the "
+                           "blocks to the executable's call: the request's "
+                           "key (`_request_key`: a device op and a read of "
+                           "it), padding, the arguments' `_put`s, the "
+                           "signature radar",
+    "serving.prefill": "engine.py `_prefill_run`: dispatch + wait of one "
                        "prefill executable (whole prompts, prefix-hit "
                        "remainders and chunks)",
-    "serving.decode_step": "engine.py `decode_step`, fast and rebuild "
+    "serving.admit_install": "engine.py around `_install_slot` (slot state, "
+                             "the radix insert, a drafter's ingestion), and "
+                             "again in scheduler.py `_admit_into` around "
+                             "the request's own bookkeeping after it "
+                             "(`_note_ttft`, `_append_token`)",
+    "serving.decode_prepare": "engine.py `decode_step`, from its first line "
+                              "to the call: fault hooks, the audit, the "
+                              "rebuild's `_put`s after an admission, the "
+                              "argument tuple",
+    "serving.decode_step": "engine.py `_decode_call`, fast and rebuild "
                            "path: dispatch + wait of one decode (or "
                            "speculative round) executable",
     "serving.decode_sync": "inside serving.decode_step, around "
                            "`np.asarray(toks_d)`: the host waiting for "
                            "the device; the parent's self time is dispatch",
-    "serving.block_denoise": "engine.py `decode_step`, around the call of a "
-                             "forward in which no slot commits (every "
-                             "active slot of a block-diffusion decoder "
-                             "denoises); serving.decode_step lies inside",
-    "serving.block_commit": "engine.py `decode_step`, around the call of a "
-                            "forward in which at least one slot commits "
-                            "its block (the others denoise: the phase is "
-                            "a slot's own)",
+    "serving.decode_finish": "engine.py `decode_step`, from the call's "
+                             "return to its own: the step's counters, the "
+                             "pools and the fast tuple, the host mirrors "
+                             "(`_finish_decode` / `_finish_block`)",
     "serving.emit": "scheduler.py, the `_append_token` loop after a "
                     "decode: per-request bookkeeping of one iteration",
     "train.step": "jit `TrainStep.__call__`: lifting the arguments, the "
                   "executable call, writing state back",
+    "host.gc": "`watch_gc`: one collection of Python's garbage collector, "
+               "from `gc.callbacks`' \"start\" to its \"stop\", on the "
+               "thread that collects (it holds the interpreter lock, so "
+               "every thread waits); on while a `GenerationServer`'s "
+               "worker runs",
 }
 
 # Spans that also feed the `registry.timing` reservoir of the same name.
@@ -76,6 +119,8 @@ _TIMED = frozenset({"serving.prefill", "serving.decode_step"})
 
 # Counters kept at the same boundaries as the spans (scope.name -> what).
 COUNTERS = {
+    "host.gc_gen2_n": "of host.gc_n the collections of the oldest "
+                      "generation (the long ones)",
     "serving.sched_steps": "one a scheduler `step()`",
     "serving.queue_wait_ns": "submit to admission start, summed over "
                              "admitted requests",
@@ -297,3 +342,43 @@ class span(HostSpan):
         d[k_n] += 1
         if timed:
             registry.timing(timed[0], ns / 1e9, scope=timed[1])
+
+
+class _GcWatch:
+    """`host.gc`: Python's collector says when it starts and stops
+    (`gc.callbacks`), on the thread it runs on; collections never nest, so
+    one open span is all there is. Counted by its users, so that two
+    servers of one process are one callback."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self._users = 0
+        self._open = None
+        self._gen2 = registry.scoped_counters("host", {"gc_gen2_n": 0})
+
+    def __call__(self, phase, info):
+        if phase == "start":
+            self._open = span("host.gc")
+            self._open.__enter__()
+        elif self._open is not None:
+            self._open.__exit__(None, None, None)
+            self._open = None
+            self._gen2["gc_gen2_n"] += info["generation"] == 2
+
+    def acquire(self):
+        with self._lock:
+            self._users += 1
+            if self._users == 1:
+                gc.callbacks.append(self)
+
+    def release(self):
+        with self._lock:
+            self._users -= 1
+            if self._users == 0:
+                gc.callbacks.remove(self)
+                self._open = None
+
+
+_gc_watch = _GcWatch()
+watch_gc = _gc_watch.acquire      # pair every call with one of unwatch_gc
+unwatch_gc = _gc_watch.release

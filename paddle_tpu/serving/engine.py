@@ -1092,14 +1092,21 @@ class GenerationEngine:
         table_ids = matched + fresh
         return table_ids, self._table_row(slot, table_ids), P
 
-    def _prefill_call(self, window, end, start, bt_row, key, temperature,
-                      top_k, top_p):
-        """One compiled prefill pass over prompt[start:end] at the
-        window's bucket. ``end`` doubles as the write mask (only the
-        window's rows land) and positions the sample at ``end - 1`` —
-        intermediate chunks discard that sample, the final window's IS
-        the request's first token. Same executable per bucket whether the
-        window is a whole suffix, a prefix-hit remainder or one chunk."""
+    def _admit_prompt(self, slot, prompt_ids, max_new_tokens):
+        """An admission's first phase (`serving.admit_blocks`): the prompt
+        checked, its cached prefix matched and pinned, the rest of its
+        blocks allocated. Returns (prompt, table_ids, bt_row, matched)."""
+        with _span("serving.admit_blocks"):
+            prompt = self._check_prompt(slot, prompt_ids)
+            return (prompt,) + self._admit_blocks(slot, prompt,
+                                                  max_new_tokens)
+
+    def _prefill_stage(self, window, end, start, bt_row, key, temperature,
+                       top_k, top_p):
+        """The arguments of one prefill pass over prompt[start:end] at the
+        window's bucket, on the device. ``end`` doubles as the write mask
+        (only the window's rows land) and positions the sample at
+        ``end - 1``."""
         L = self.bucket_for(len(window))
         ids = np.zeros((1, L), np.int32)
         ids[0, :len(window)] = window
@@ -1114,6 +1121,13 @@ class GenerationEngine:
         self._note_signature(
             "prefill", args,
             f"bucket_len={L}, max_batch={self.max_batch_size}")
+        return args
+
+    def _prefill_run(self, args):
+        """One compiled prefill pass: intermediate chunks discard its
+        sample, the final window's IS the request's first token. Same
+        executable per bucket whether the window is a whole suffix, a
+        prefix-hit remainder or one chunk."""
         with _span("serving.prefill"):
             tok, nk, nv = self._prefill_jit(*args)
             tok = int(np.asarray(tok)[0])
@@ -1205,12 +1219,10 @@ class GenerationEngine:
         the request even after evicting cold prefixes (the scheduler's
         ``can_admit`` pre-check makes that unreachable in normal
         operation)."""
-        prompt = self._check_prompt(slot, prompt_ids)
+        prompt, table_ids, bt_row, P = self._admit_prompt(
+            slot, prompt_ids, max_new_tokens)
         trace = _tracing.trace_id_for_seed(seed) if seed is not None \
             else None
-        table_ids, bt_row, P = self._admit_blocks(slot, prompt,
-                                                  max_new_tokens)
-        key = self._request_key(seed)
         end = len(prompt)
         if self._gen is not None:
             # block-causally over the prompt's whole blocks, no token
@@ -1218,16 +1230,21 @@ class GenerationEngine:
             end -= end % self._gen.block_length
         try:
             with _tracing.span(trace, "prefill"):
-                tok = self._prefill_call(
-                    prompt[P:end], end, P, bt_row, key, temperature, top_k,
-                    top_p) if end else None
+                # everything between the blocks and the executable's call
+                with _span("serving.admit_stage"):
+                    key = self._request_key(seed)
+                    args = self._prefill_stage(
+                        prompt[P:end], end, P, bt_row, key, temperature,
+                        top_k, top_p) if end else None
+                tok = self._prefill_run(args) if end else None
         except Exception:
             self.pool.decref(table_ids)  # failed admission leaks nothing
             self._note_pool()
             raise
-        self._install_slot(slot, prompt, table_ids, bt_row, tok, key,
-                           temperature, top_k, top_p, P, max_new_tokens)
-        self._slot_trace[slot] = trace
+        with _span("serving.admit_install"):
+            self._install_slot(slot, prompt, table_ids, bt_row, tok, key,
+                               temperature, top_k, top_p, P, max_new_tokens)
+            self._slot_trace[slot] = trace
         return tok if self._gen is None else None
 
     # -------------------------------------------------- chunked prefill --
@@ -1248,21 +1265,22 @@ class GenerationEngine:
             "chunked prefill (begin_prefill)",
             "its prefill attends to the call's own rows only, not to what "
             "an earlier chunk wrote")
-        prompt = self._check_prompt(slot, prompt_ids)
+        prompt, table_ids, bt_row, P = self._admit_prompt(
+            slot, prompt_ids, max_new_tokens)
         bs = self.block_size
         chunk = max(bs, (int(chunk_tokens or bs) // bs) * bs)
-        table_ids, bt_row, P = self._admit_blocks(slot, prompt,
-                                                  max_new_tokens)
         try:
             self._reserve_extra(slot, prompt, max_new_tokens)
         except Exception:
             self.pool.decref(table_ids)  # failed admission leaks nothing
             self._note_pool()
             raise
+        with _span("serving.admit_stage"):
+            key = self._request_key(seed)
         self._mid_prefill[slot] = {
             "prompt": prompt, "done": P, "chunk": chunk,
             "table_ids": table_ids, "bt_row": bt_row,
-            "key": self._request_key(seed), "temperature": temperature,
+            "key": key, "temperature": temperature,
             "top_k": top_k, "top_p": top_p, "matched": P,
             "max_new_tokens": max_new_tokens,
             "trace": _tracing.trace_id_for_seed(seed)
@@ -1287,9 +1305,12 @@ class GenerationEngine:
         end = min(start + st["chunk"], len(prompt))
         try:
             with _tracing.span(st.get("trace"), "prefill_chunk"):
-                tok = self._prefill_call(
-                    prompt[start:end], end, start, st["bt_row"], st["key"],
-                    st["temperature"], st["top_k"], st["top_p"])
+                with _span("serving.admit_stage"):
+                    args = self._prefill_stage(
+                        prompt[start:end], end, start, st["bt_row"],
+                        st["key"], st["temperature"], st["top_k"],
+                        st["top_p"])
+                tok = self._prefill_run(args)
                 self._chunk_extra(slot, prompt, start, end)
         except Exception:
             # drop the chunk state; reserved extras (drafter blocks)
@@ -1303,11 +1324,12 @@ class GenerationEngine:
         if end < len(prompt):
             return None
         del self._mid_prefill[slot]
-        self._install_slot(
-            slot, prompt, st["table_ids"], st["bt_row"], tok, st["key"],
-            st["temperature"], st["top_k"], st["top_p"], st["matched"],
-            st["max_new_tokens"])
-        self._slot_trace[slot] = st.get("trace")
+        with _span("serving.admit_install"):
+            self._install_slot(
+                slot, prompt, st["table_ids"], st["bt_row"], tok, st["key"],
+                st["temperature"], st["top_k"], st["top_p"], st["matched"],
+                st["max_new_tokens"])
+            self._slot_trace[slot] = st.get("trace")
         return tok
 
     # --------------------------------------------- prefill→decode handoff --
@@ -1522,58 +1544,55 @@ class GenerationEngine:
         mirrors advanced by cheap numpy stores. Every
         ``PADDLE_TPU_AUDIT_EVERY`` fast steps an audit cross-checks the
         device copies against the host mirrors and demotes on mismatch."""
-        active = self._active
-        n_active = int(active.sum())
-        if n_active == 0:
-            raise RuntimeError("decode_step with no active slots")
-        if _faults.ACTIVE:
-            _faults.fire("slow_decode")
-            _faults.fire("pod_slow")
-            _faults.fire("replica_kill")
-            _faults.fire("decode_error")
-        fast = self._fast
-        if fast is not None \
-                and self._decode_since_audit + 1 >= self._audit_every:
-            self._audit_fast(fast)
-            fast = self._fast  # a failed audit demoted it
-        rebuilt = fast is None
-        if rebuilt:
-            fast = self._decode_rebuild()
-        args = (self._state_arrays(), tuple(self._k), tuple(self._v)) + fast
-        if self._gen is None:
-            out, nk, nv, *stepped = self._decode_call(args)
-        else:
-            # a slot commits iff nothing of its block is masked: the
-            # host's mirror knows before the call
-            commits = bool((~self._blk_masked[active].any(-1)).any())
-            with _span("serving.block_commit" if commits
-                       else "serving.block_denoise"):
-                out, nk, nv, *stepped = self._decode_call(args)
-        self._k, self._v = list(nk), list(nv)
-        fast = list(fast)
-        for i, x in zip(self._slot_stepped, stepped):
-            fast[i] = x
-        self._fast = tuple(fast)
-        given = (self._finish_decode if self._gen is None
-                 else self._finish_block)(active, n_active, out)
-        if not rebuilt:
-            self._decode_since_audit += 1
-            _fp_counters["decode_fast_steps"] += 1
+        with _span("serving.decode_prepare"):
+            active = self._active
+            n_active = int(active.sum())
+            if n_active == 0:
+                raise RuntimeError("decode_step with no active slots")
+            if _faults.ACTIVE:
+                _faults.fire("slow_decode")
+                _faults.fire("pod_slow")
+                _faults.fire("replica_kill")
+                _faults.fire("decode_error")
+            fast = self._fast
+            if fast is not None \
+                    and self._decode_since_audit + 1 >= self._audit_every:
+                self._audit_fast(fast)
+                fast = self._fast  # a failed audit demoted it
+            rebuilt = fast is None
+            if rebuilt:
+                fast = self._decode_rebuild()
+            args = (self._state_arrays(), tuple(self._k),
+                    tuple(self._v)) + fast
+        toks, nk, nv, *stepped = self._decode_call(args)
+        with _span("serving.decode_finish"):
+            # the step's own part, then its counters
+            B = len(toks) - len(self._step_counter_names)
+            for name, n in zip(self._step_counter_names, toks[B:]):
+                _counters[name] += int(n)
+            self._k, self._v = list(nk), list(nv)
+            fast = list(fast)
+            for i, x in zip(self._slot_stepped, stepped):
+                fast[i] = x
+            self._fast = tuple(fast)
+            given = (self._finish_decode if self._gen is None
+                     else self._finish_block)(active, n_active, toks[:B])
+            if not rebuilt:
+                self._decode_since_audit += 1
+                _fp_counters["decode_fast_steps"] += 1
         return given
 
     def _decode_call(self, args):
         """The one timed site of a decode iteration, fast path and rebuild
         path alike: dispatch of the executable, then the wait for its
-        tokens (the span's self time is the dispatch)."""
+        tokens (the span's self time is the dispatch). The call's begin,
+        the dispatch's return and the wait's end are the three instants
+        `benchmark/step_timeline.py` splits the device's idle time at."""
         with _span("serving.decode_step"):
             toks_d, *rest = self._decode_jit(*args)
             with _span("serving.decode_sync"):
                 toks = np.asarray(toks_d)
-        # the step's own part, then its counters
-        B = len(toks) - len(self._step_counter_names)
-        for name, n in zip(self._step_counter_names, toks[B:]):
-            _counters[name] += int(n)
-        return (toks[:B], *rest)
+        return (toks, *rest)
 
     def _decode_rebuild(self):
         """Off-steady decode: rebuild the device-side slot state from the
@@ -1725,8 +1744,28 @@ class GenerationEngine:
         total = hits + _counters["prefix_misses"]
         return hits / total if total else 0.0
 
+    @staticmethod
+    def host_means():
+        """The host's time, in ms, means since the process began: an
+        iteration's outside its decode call and its admissions
+        (``step_host_ms``), and an admission's outside its prefill
+        executable (``admit_host_ms``); from the spans' counters, as the
+        benchmark's `serve.step_host_ms` / `serve.admit_host_ms` read them
+        over a window. Beside them the iterations that took the fast path:
+        what `stats()` and a pod's `stats` reply show an operator."""
+        ns = {k: _counters.get(k + "_ns", 0) for k in
+              ("sched_step", "decode_step", "admit", "prefill")}
+        steps = _counters.get("sched_steps", 0)
+        admitted = _counters.get("admitted", 0)
+        step = ns["sched_step"] - ns["decode_step"] - ns["admit"]
+        admit = ns["admit"] - ns["prefill"]
+        return {"step_host_ms": step / steps / 1e6 if steps else 0.0,
+                "admit_host_ms": admit / admitted / 1e6 if admitted else 0.0,
+                "decode_fast_steps": _fp_counters["decode_fast_steps"]}
+
     def stats(self):
         out = {**_registry.counters("serving"),
+               **self.host_means(),
                "paged_kernel": self._paged_kernel,
                "paged_kernel_reason": self._paged_kernel_reason,
                "mean_occupancy": self.mean_occupancy(),

@@ -585,6 +585,10 @@ class PodWorker:
               "handoff_exports": c["handoff_exports"],
               "handoff_imports": c["handoff_imports"],
               "kv_blocks_in_use": self.engine.pool.in_use(),
+              # the host's part of an iteration and of an admission (ms,
+              # means since start), and the collector's pauses
+              **self.engine.host_means(),
+              "host": self._registry.counters("host"),
               "swap_count": self._swap_owner.scheduler.swap_count,
               "generation": self.generation,
               # data-plane wire counters + per-link byte/retry table:

@@ -368,43 +368,18 @@ class ContinuousBatchScheduler:
             # request simply stays queued — FIFO order is preserved, the
             # queue backs up, and submit() turns the pressure into
             # QueueFullError backpressure at the edge.
-            can_admit = getattr(self.engine, "can_admit", None)
             while True:
                 free = self.engine.free_slots()
                 if not free:
                     break
                 with self._lock:
                     head = self._queue[0] if self._queue else None
-                if head is None:
-                    break
-                can_import = getattr(self.engine, "can_import", None)
-                if head.kv_payload is not None and can_import is not None:
-                    fits = can_import(head.kv_payload)
-                else:
-                    fits = can_admit is None or can_admit(
-                        head.prompt_ids, head.max_new_tokens)
-                if not fits:
-                    _counters["pool_exhausted"] += 1
-                    _explain.record(
-                        "serving_pool_exhausted", op="admission",
-                        why="KV block pool cannot cover the next queued "
-                            "request even after prefix eviction; leaving "
-                            "it queued (admission backpressure) until "
-                            "running requests release blocks",
-                        queued=len(self._queue))
-                    break
-                with self._lock:
-                    # step() is the only consumer and the deadline scan
-                    # above already ran, so the head we budgeted is still
-                    # the head we pop
-                    req = self._queue.popleft() if self._queue else None
-                if req is None:
-                    break
-                if not self._admit(req, free[0]):
-                    # prefill hit pool pressure despite the budget check
-                    # and the request went back to the head: stop
-                    # admitting THIS step (retrying in this loop would
-                    # spin forever) and let decode progress free blocks
+                if head is None or not self._admit(head, free[0]):
+                    # no request, or pool pressure (at the budget check, or
+                    # in the prefill despite it: the request is at the
+                    # queue's head again): stop admitting THIS step
+                    # (retrying in this loop would spin forever) and let
+                    # decode progress free blocks
                     break
 
         # (2b) chunked prefill (ISSUE 12): advance ONE block-aligned
@@ -510,13 +485,38 @@ class ContinuousBatchScheduler:
         return True
 
     # ----------------------------------------------------------- helpers --
-    def _admit(self, req, slot):
-        """Prefill `req` into `slot`. Returns False when admission hit
-        pool pressure and the request was requeued (the caller must stop
-        admitting this step — retrying immediately would spin); True for
-        every terminal outcome (admitted, chunk-admitted or failed)."""
-        with _span("serving.admit", req.trace_id):
-            return self._admit_into(req, slot)
+    def _admit(self, head, slot):
+        """The queue's head into `slot`, if the pool can cover it: the
+        budget check, then the prefill. Returns False when admission hit
+        pool pressure and the request stayed at (or went back to) the
+        queue's head (the caller must stop admitting this step — retrying
+        immediately would spin); True for every terminal outcome
+        (admitted, chunk-admitted or failed)."""
+        with _span("serving.admit", head.trace_id):
+            with _span("serving.admit_check"):
+                can_import = getattr(self.engine, "can_import", None)
+                can_admit = getattr(self.engine, "can_admit", None)
+                if head.kv_payload is not None and can_import is not None:
+                    fits = can_import(head.kv_payload)
+                else:
+                    fits = can_admit is None or can_admit(
+                        head.prompt_ids, head.max_new_tokens)
+            if not fits:
+                _counters["pool_exhausted"] += 1
+                _explain.record(
+                    "serving_pool_exhausted", op="admission",
+                    why="KV block pool cannot cover the next queued "
+                        "request even after prefix eviction; leaving "
+                        "it queued (admission backpressure) until "
+                        "running requests release blocks",
+                    queued=len(self._queue))
+                return False
+            with self._lock:
+                # step() is the only consumer and the deadline scan
+                # already ran, so the head we budgeted is still the head
+                # we pop
+                req = self._queue.popleft() if self._queue else None
+            return req is not None and self._admit_into(req, slot)
 
     def _admit_into(self, req, slot):
         t_start = time.monotonic()
@@ -600,20 +600,24 @@ class ContinuousBatchScheduler:
             if not isinstance(e, (ValueError, TypeError)):
                 raise
             return True
-        req.slot = slot
-        req.status = RequestStatus.RUNNING
-        self._active[slot] = req
-        _note_queue_wait(t_start - req.submit_ts)
-        now = time.monotonic()
-        if first is not None:
-            self._note_ttft(req, now)
-        _tracing.add_span(req.trace_id, "queue_wait", req.submit_ts, t_start)
-        if handoff:  # a local admission is the serving.admit span itself
-            _tracing.add_span(req.trace_id, "kv_adopt", t_start, now)
-        _tracing.flight("admit", rid=req.rid, trace_id=req.trace_id,
-                        slot=slot, handoff=handoff)
-        if first is not None:  # (a block decoder's prefill hands none over)
-            self._append_token(req, first, now)
+        # the scheduler's half of the installation (the engine's is
+        # `_install_slot`, under the same name)
+        with _span("serving.admit_install"):
+            req.slot = slot
+            req.status = RequestStatus.RUNNING
+            self._active[slot] = req
+            _note_queue_wait(t_start - req.submit_ts)
+            now = time.monotonic()
+            if first is not None:
+                self._note_ttft(req, now)
+            _tracing.add_span(req.trace_id, "queue_wait", req.submit_ts,
+                              t_start)
+            if handoff:  # a local admission is the serving.admit span itself
+                _tracing.add_span(req.trace_id, "kv_adopt", t_start, now)
+            _tracing.flight("admit", rid=req.rid, trace_id=req.trace_id,
+                            slot=slot, handoff=handoff)
+            if first is not None:  # (a block decoder's prefill hands none)
+                self._append_token(req, first, now)
         return True
 
     @staticmethod

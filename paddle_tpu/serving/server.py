@@ -31,6 +31,7 @@ import zlib
 
 from ..profiler import explainer as _explain
 from ..profiler import span as _span
+from ..profiler import spans as _spans
 from ..profiler import tracing as _tracing
 from .engine import FatalEngineError, GenerationEngine
 from .scheduler import (ContinuousBatchScheduler, GenerationRequest,
@@ -189,6 +190,15 @@ class GenerationServer:
         return self
 
     def _loop(self):
+        # the collector's pauses hold this thread whichever thread
+        # collects: `host.gc` spans and counters while a worker runs
+        _spans.watch_gc()
+        try:
+            self._serve()
+        finally:
+            _spans.unwatch_gc()
+
+    def _serve(self):
         while not self._stop.is_set():
             if self.scheduler.has_work():
                 try:

@@ -365,9 +365,9 @@ def _tiny_gpt(seed=11):
     return GPTForPretraining(GPTModel(cfg))
 
 
-def _host_lines(trace_dir):
-    """[(names on the line)] for every line of the host plane of the
-    newest .xplane.pb under trace_dir."""
+def _host_events(trace_dir):
+    """[(start, end, name) of every event on the line] for every line of
+    the host plane of the newest .xplane.pb under trace_dir."""
     import glob
 
     from jax.profiler import ProfileData
@@ -375,12 +375,72 @@ def _host_lines(trace_dir):
     pb = sorted(glob.glob(os.path.join(
         trace_dir, "plugins", "profile", "*", "*.xplane.pb")))[-1]
     data = ProfileData.from_file(pb)
-    return [[e.name for e in line.events]
+    return [[(e.start_ns, e.start_ns + e.duration_ns, e.name)
+             for e in line.events]
             for plane in data.planes if plane.name == "/host:CPU"
             for line in plane.lines]
 
 
+def _host_lines(trace_dir):
+    """[(names on the line)] for every line of that host plane."""
+    return [[n for _, _, n in line] for line in _host_events(trace_dir)]
+
+
+def _server_thread_tree(trace_dir):
+    """The program's spans on the line that holds `serving.sched_step`, as
+    nested [name, children] lists in time order (`host.gc` set aside: the
+    collector runs where it likes)."""
+    (line,) = [ln for ln in _host_events(trace_dir)
+               if any(n == "serving.sched_step" for _, _, n in ln)]
+    root, stack = [], []
+    for s, e, name in sorted((sp for sp in line
+                              if sp[2].startswith("serving.")),
+                             key=lambda x: (x[0], -x[1])):
+        while stack and stack[-1][0] <= s:
+            stack.pop()
+        node = [name, []]
+        (stack[-1][1] if stack else root).append(node)
+        stack.append((e, node[1]))
+    return root
+
+
+# what an iteration carries, nested and in order (profiler/spans.py's rule)
+STEADY = [["serving.decode_prepare", []],
+          ["serving.decode_step", [["serving.decode_sync", []]]],
+          ["serving.decode_finish", []],
+          ["serving.emit", []]]
+ADMISSION = ["serving.admit", [["serving.admit_check", []],
+                               ["serving.admit_blocks", []],
+                               ["serving.admit_stage", []],
+                               ["serving.prefill", []],
+                               ["serving.admit_install", []],
+                               ["serving.admit_install", []]]]
+
+
 class TestSpanApi:
+    def test_every_span_name_has_a_call_site_and_every_call_site_a_name(
+            self):
+        """The table and the code, held together: a name nobody opens (as
+        the removed `serving.block_denoise` / `serving.block_commit` would
+        be) and a call site outside the table both fail here."""
+        import re
+
+        from paddle_tpu.profiler import spans
+
+        root = os.path.join(os.path.dirname(TOOLS), "paddle_tpu")
+        opened = set()
+        for d, _, files in os.walk(root):
+            for fn in files:
+                if fn.endswith(".py"):
+                    with open(os.path.join(d, fn)) as f:
+                        # span("x") / _span("x", ...); tracing.span takes a
+                        # trace id first, never a literal
+                        opened.update(re.findall(
+                            r"""(?<![\w.])_?span\(\s*["']([\w.]+)["']""",
+                            f.read()))
+        assert opened == set(spans.SPANS)
+        assert not {"serving.block_denoise", "serving.block_commit"} & opened
+
     def test_span_refuses_a_name_outside_the_table(self):
         from paddle_tpu.profiler import RecordEvent, span, spans
 
@@ -556,6 +616,100 @@ class TestProgramSpans:
         assert d["kv_tokens_read"] == sum(range(6, 6 + n))
         assert d["sched_step_ns"] >= d["decode_step_ns"] + d["prefill_ns"]
         assert d["decode_step_ns"] >= d["decode_sync_ns"] > 0
+
+    def test_iterations_carry_exactly_the_tables_spans(self, server,
+                                                        tmp_path):
+        """A steady iteration has six spans and an admitting one the
+        admission's seven more, nested and ordered as profiler/spans.py says,
+        all on the scheduler's thread: nothing of the engine's work is left
+        to `serving.sched_step`'s self time."""
+        import jax
+
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+        try:
+            server.generate([5, 6, 7, 8], max_new_tokens=6)
+            time.sleep(3 * server._idle_wait_s)
+        finally:
+            jax.profiler.stop_trace()
+        steps = [node for node in _server_thread_tree(str(tmp_path))
+                 if node[0] == "serving.sched_step"]
+        # the first token comes from the prefill: five decode iterations,
+        # the first of them in the admitting step
+        assert [kids for _, kids in steps] \
+            == [[ADMISSION] + STEADY] + [STEADY] * 4
+
+    def test_span_counters_nest(self, server):
+        """`<span>_ns` of a parent is at least its children's sum, so the
+        differences the benchmark's readers take (`serve.step_host_ms`,
+        `serve.admit_host_ms`) and `engine.stats()` gives are self times."""
+        time.sleep(3 * server._idle_wait_s)
+        c0 = dict(registry.counters("serving"))
+        server.generate([9, 8, 7, 6, 5, 4], max_new_tokens=6)
+        deadline = time.monotonic() + 10
+        while time.monotonic() < deadline:
+            c1 = registry.counters("serving")
+            if c1["sched_step_n"] - c0.get("sched_step_n", 0) \
+                    == c1["sched_steps"] - c0["sched_steps"]:
+                break
+            time.sleep(0.01)
+        d = {k: c1[k] - c0.get(k, 0) for k in c1 if isinstance(c1[k], int)}
+        assert d["sched_step_ns"] >= d["admit_ns"] + d["decode_prepare_ns"] \
+            + d["decode_step_ns"] + d["decode_finish_ns"] + d["emit_ns"]
+        assert d["admit_ns"] >= d["admit_check_ns"] + d["admit_blocks_ns"] \
+            + d["admit_stage_ns"] + d["prefill_ns"] + d["admit_install_ns"]
+        assert d["admit_check_n"] == d["admit_blocks_n"] \
+            == d["admit_stage_n"] == d["admit_n"] == 1
+        assert d["admit_install_n"] == 2  # the engine's half, the scheduler's
+        assert d["decode_prepare_n"] == d["decode_finish_n"] \
+            == d["decode_step_n"] == 5
+        assert all(d[k + "_ns"] > 0 for k in (
+            "admit_check", "admit_blocks", "admit_stage", "admit_install",
+            "decode_prepare", "decode_finish"))
+        stats = server.engine.stats()
+        ns = {k: c1[k + "_ns"] for k in ("sched_step", "decode_step",
+                                         "admit", "prefill")}
+        assert stats["step_host_ms"] == pytest.approx(
+            (ns["sched_step"] - ns["decode_step"] - ns["admit"])
+            / c1["sched_steps"] / 1e6, rel=0.05)
+        assert stats["admit_host_ms"] == pytest.approx(
+            (ns["admit"] - ns["prefill"]) / c1["admitted"] / 1e6, rel=0.05)
+        # (means since the PROCESS began: positive in a serving process,
+        # where every decode step runs under the scheduler; other tests of
+        # this one drive engines by hand)
+        assert stats["decode_fast_steps"] \
+            == registry.counters("fastpath")["decode_fast_steps"]
+
+    def test_gc_pauses_are_spans_while_a_server_runs(self):
+        """`host.gc`: counted and annotated while a server's worker runs,
+        on whichever thread collects; the callback goes with the worker."""
+        import gc
+
+        from paddle_tpu.profiler import spans
+        from paddle_tpu.serving import GenerationServer
+
+        watch = spans._gc_watch
+        users = watch._users  # servers other tests of this process left
+        srv = GenerationServer(_tiny_gpt(), max_batch_size=1, buckets=(8,))
+        try:
+            srv.generate([1, 2, 3], max_new_tokens=2)  # the worker runs
+            assert watch._users == users + 1 and watch in gc.callbacks
+            c0 = dict(registry.counters("host"))
+            gc.collect()
+            c1 = registry.counters("host")
+            assert c1["gc_n"] >= c0.get("gc_n", 0) + 1
+            assert c1["gc_gen2_n"] >= c0.get("gc_gen2_n", 0) + 1
+            assert c1["gc_ns"] > c0.get("gc_ns", 0)
+            assert registry.counters()["host.gc_n"] == c1["gc_n"]
+        finally:
+            assert srv.shutdown(timeout=30)
+        assert watch._users == users
+        assert (watch in gc.callbacks) == (users > 0)
+        if not users:
+            c2 = dict(registry.counters("host"))
+            gc.collect()
+            assert registry.counters("host") == c2
 
     def test_tok_ts_one_stamp_a_token(self, server):
         h = server.submit([4, 3, 2, 1], max_new_tokens=7)
