@@ -142,7 +142,7 @@ class GPTAttention(nn.Layer):
             # block, so slots of wildly different lengths (and slots
             # SHARING immutable prefix blocks) live in one buffer with
             # zero copies. The B*T new rows of H*Dh are written into the
-            # pool at (block, row) pairs derived from the table — in
+            # pool through the table (a prompt span as whole blocks) — in
             # place when the step donates the pools; no view of the whole
             # pool is formed, on the chip that would be a relayout.
             # Attention reads each slot's logical view back out under a

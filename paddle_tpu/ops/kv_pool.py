@@ -93,6 +93,14 @@ class CacheSpec:
     def window_layers(self):
         return [i for i, w in enumerate(self.windows) if w is not None]
 
+    def writes_blocks(self, span, block_size):
+        """Does a span of ``span`` rows a slot land in some layer's pools as
+        whole blocks (:func:`writes_blocks`)? Only a full layer of a
+        "heads" cache can: a latent row is written by the decoder's own
+        forward."""
+        return self.kind == "heads" and any(
+            writes_blocks(span, block_size, w) for w in self.windows)
+
     def ring_blocks(self, block_size):
         """Blocks of one slot's ring in a window layer (0: no such
         layer)."""
@@ -264,18 +272,94 @@ def ring_positions(seq_lens, ring, block_size):
             ).reshape(seq_lens.shape[0], ring * block_size)
 
 
+def span_blocks(block_tables, offsets, seq_lens, span, block_size):
+    """Where the ``span`` new rows of every slot go as WHOLE blocks: the
+    ``n`` logical blocks from the one holding ``offsets[b]`` that a span can
+    touch at any offset. Returns (ids ``[B, n]``: a block's physical id
+    through the slot's table row, clamped to the table as in
+    :func:`span_rows`, or the garbage block 0 where the block holds no live
+    row — the only id that may repeat; shift ``[B]``: the span's first row
+    within its first block; live ``[B, n * block_size]``: the rows of the
+    blocks in ``[offsets[b], min(seq_lens[b], offsets[b] + span))``; edges
+    ``[B, 2]``: the ids of the first block and of the last with a live row,
+    the only ones that can hold rows the span must leave as they were)."""
+    bt = block_tables.astype(jnp.int32)
+    bs = int(block_size)
+    n = -(-(span + bs - 1) // bs)
+    off = offsets.astype(jnp.int32)
+    end = jnp.minimum(seq_lens.astype(jnp.int32), off + jnp.int32(span))
+    first = off // jnp.int32(bs)
+    lb = first[:, None] + jnp.arange(n, dtype=jnp.int32)[None]
+    phys = jnp.take_along_axis(
+        bt, jnp.minimum(lb, jnp.int32(bt.shape[1] - 1)), axis=1)
+    touched = (lb * jnp.int32(bs) < end[:, None]) & (end > off)[:, None]
+    ids = jnp.where(touched, phys, jnp.zeros_like(phys))
+    pos = (first[:, None] * jnp.int32(bs)
+           + jnp.arange(n * bs, dtype=jnp.int32)[None])
+    live = (pos >= off[:, None]) & (pos < end[:, None])
+    last = jnp.clip((end - 1) // jnp.int32(bs) - first, 0, n - 1)
+    edges = jnp.stack(
+        [ids[:, 0], jnp.take_along_axis(ids, last[:, None], axis=1)[:, 0]],
+        axis=1)
+    return ids, off % jnp.int32(bs), live, edges
+
+
+def write_blocks(pool, rows, ids, shift, live, edges):
+    """``pool`` with a span's ``rows`` ``[B, T, W]`` written as whole blocks
+    at ``ids`` (:func:`span_blocks`): the rows shifted to where they lie in
+    their blocks, every row of a block outside ``live`` taken from the
+    pool's own first or last touched block, then ONE block scatter whose
+    index count is the span's blocks, not its rows. Bit-equal to
+    :func:`write_rows` on every live row and every row of a live block;
+    dead blocks land whole on block 0. On a donated pool it updates the
+    buffer in place (tests/test_tpu_lowering.py)."""
+    B, T, W = rows.shape
+    n = ids.shape[1]
+    bs = pool.shape[1]
+    padded = jnp.pad(rows.astype(pool.dtype),
+                     ((0, 0), (bs, n * bs - T), (0, 0)))
+    src = jnp.stack([jax.lax.dynamic_slice_in_dim(
+        padded[b], jnp.int32(bs) - shift[b], n * bs) for b in range(B)])
+    old = jnp.take(pool, edges, axis=0)  # [B, 2, bs, W]
+    old = jnp.where((jnp.arange(n) == 0)[None, :, None, None],
+                    old[:, :1], old[:, 1:])
+    blocks = jnp.where(live.reshape(B, n, bs, 1),
+                       src.reshape(B, n, bs, W), old)
+    return pool.at[ids.reshape(-1)].set(blocks.reshape(B * n, bs, W))
+
+
+def writes_blocks(span, block_size, window=None):
+    """Does a span of ``span`` new rows a slot land in a layer's pools as
+    whole blocks (:func:`write_span`'s choice, static: read from the
+    shapes)? A full layer's span of at least a block a slot does. On a v5e
+    (PERF.md §6) the row scatter costs ~0.2 µs a row at any batch (404 µs
+    a pool for 2,048 rows, 55 as blocks), the block write a fixed ~45 µs a
+    pool at 32 slots (each slot's shift and edge blocks): at 32 slots rows
+    win up to 8 a slot, blocks from 16. Fewer rows (decode's one, a block
+    step's or a short verify span's) keep the row scatter, and so does
+    every span of a window layer's ring, whose columns repeat and of a long
+    prompt the head must not land."""
+    return window is None and span >= block_size
+
+
 def write_span(k_pool, v_pool, k, v, block_tables, offsets, seq_lens,
                window=None):
     """Both pools with a step's new rows ``k``, ``v`` ``[B, T, H, Dh]``
-    written through the block tables: :func:`span_rows` (a window layer's
-    ring: :func:`ring_span_rows`), then :func:`write_rows` for each."""
+    written through the block tables: where :func:`writes_blocks` says so as
+    whole blocks, :func:`span_blocks` then :func:`write_blocks`; else
+    :func:`span_rows` (a window layer's ring: :func:`ring_span_rows`) then
+    :func:`write_rows`."""
     B, T = k.shape[0], k.shape[1]
+    bs = k_pool.shape[1]
+    if writes_blocks(T, bs, window):
+        where = span_blocks(block_tables, offsets, seq_lens, T, bs)
+        return (write_blocks(k_pool, k.reshape(B, T, -1), *where),
+                write_blocks(v_pool, v.reshape(B, T, -1), *where))
     if window is None:
-        blk, row = span_rows(block_tables, offsets, seq_lens, T,
-                             k_pool.shape[1])
+        blk, row = span_rows(block_tables, offsets, seq_lens, T, bs)
     else:
-        blk, row = ring_span_rows(block_tables, offsets, seq_lens, T,
-                                  k_pool.shape[1], window)
+        blk, row = ring_span_rows(block_tables, offsets, seq_lens, T, bs,
+                                  window)
     return (write_rows(k_pool, k.reshape(B * T, -1), blk, row),
             write_rows(v_pool, v.reshape(B * T, -1), blk, row))
 

@@ -1110,8 +1110,9 @@ def paged_kv_write(k_pool, v_pool, key, value, block_tables, offsets,
     ``[num_blocks, block_size, H*Dh]`` (``ops/kv_pool.py``) through each
     slot's ``block_tables`` [B, M] row: row ``offsets[b] + t`` of slot b,
     rows outside ``[0, seq_lens[b])`` into the reserved garbage block 0.
-    A row scatter on the pool as it is — in place when the pools are
-    donated to the step. Returns the two pools. Inference-only."""
+    A scatter on the pool as it is — of whole blocks for a prompt span,
+    of rows otherwise (``kv_pool.write_span``) — in place when the pools
+    are donated to the step. Returns the two pools. Inference-only."""
     from . import kv_pool
 
     return forward(kv_pool.write_span,

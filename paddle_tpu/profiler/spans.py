@@ -160,6 +160,10 @@ COUNTERS = {
                                       "the Pallas kernel `grouped_matmul` "
                                       "(host: all of a step's or none, by "
                                       "the gauge serving.moe_grouped_kernel)",
+    "serving.prefill_block_writes": "prefill calls whose full layers wrote "
+                                    "the prompt's K/V rows into the pools "
+                                    "as whole blocks (host, from the "
+                                    "bucket: `CacheSpec.writes_blocks`)",
     "serving.moe_experts_hit": "distinct held experts given >= 1 row, "
                                "summed over the expert layers of each "
                                "decode step: counted in the executable, "

@@ -114,7 +114,8 @@ _counters = _registry.scoped_counters("serving", {
     "kv_tokens_read": 0, "kv_window_rows_read": 0,
     "cache_refusals": 0, "moe_layer_steps": 0, "moe_routed_rows": 0,
     "moe_experts_hit": 0, "moe_kernel_layer_steps": 0, "sample_topk_steps": 0, "sample_topp_steps": 0,
-    "prefill_flash_calls": 0, "diffusion.slot_forwards": 0,
+    "prefill_flash_calls": 0, "prefill_block_writes": 0,
+    "diffusion.slot_forwards": 0,
     "diffusion.tokens_committed": 0, "diffusion.blocks_committed": 0,
     "diffusion.commit_forwards": 0})
 
@@ -1133,6 +1134,8 @@ class GenerationEngine:
             tok = int(np.asarray(tok)[0])
         self._k, self._v = list(nk), list(nv)
         _counters["prefill_flash_calls"] += self._prefill_kernel != "xla"
+        _counters["prefill_block_writes"] += self._cache.writes_blocks(
+            args[3].shape[1], self.block_size)
         return tok
 
     def _reserve_extra(self, slot, prompt, max_new_tokens):

@@ -107,8 +107,8 @@ def test_names_read_are_names_the_program_writes(bench_path):
     # what the xing4 family's readers look for (no recorded stretch yet)
     assert "mla_paged_attention" in spans.KERNELS
     assert {"serving.moe_layer_steps", "serving.moe_routed_rows",
-            "serving.moe_experts_hit", "serving.kv_tokens_read"} \
-        <= set(spans.COUNTERS)
+            "serving.moe_experts_hit", "serving.kv_tokens_read",
+            "serving.prefill_block_writes"} <= set(spans.COUNTERS)
     assert {"hc_mix", "mla_absorb", "moe_router", "moe_experts"} \
         <= set(spans.SCOPES)
 
@@ -386,6 +386,44 @@ def test_every_per_layer_entry_has_its_reader():
         sys.path.remove(BENCH)
 
 
+def test_prompt_block_write_share_reads_a_toy_engines_counters(bench_path):
+    """`kv.prompt_block_write_share.serve` on the window deltas of a toy
+    gpt engine's counters, taken as run.py takes them: 100 where every
+    bucket holds a block or more, and None where none does, on a program
+    without the counter (the parent of the block write) and in a window
+    without a prefill."""
+    import numpy as np
+
+    import run as bench_run
+    from paddle_tpu.models.gpt import GPTConfig, GPTForPretraining, GPTModel
+    from paddle_tpu.profiler import registry
+    from paddle_tpu.serving import GenerationEngine
+
+    share = bench_run.load_module(
+        "layer_metrics", "kv.prompt_block_write_share.serve.py").read
+    rng = np.random.default_rng(2)
+    for buckets, bs, want in (((16, 32), 4, 100.0), ((8, 24), 32, None)):
+        model = GPTForPretraining(GPTModel(GPTConfig(
+            vocab_size=64, n_layer=1, n_head=2, d_model=32, seq_len=64)))
+        eng = GenerationEngine(model, max_batch_size=1, buckets=buckets,
+                               block_size=bs)
+        c0 = registry.counters()
+        for n in (5, 19):
+            eng.prefill(0, [int(x) for x in rng.integers(1, 64, n)],
+                        max_new_tokens=2)
+            eng.release(0)
+        c1 = registry.counters()
+        counters = {k: c1[k] - c0.get(k, 0) for k in c1
+                    if isinstance(c1[k], (int, float))}
+        assert counters["serving.prefill_n"] == 2
+        assert share({"counters": counters}) == want
+    assert counters["serving.prefill_block_writes"] == 0
+    counters.pop("serving.prefill_block_writes")
+    assert share({"counters": counters}) is None
+    assert share({"counters": {"serving.prefill_n": 0,
+                               "serving.prefill_block_writes": 0}}) is None
+
+
 def _rehearse(cell, seed):
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     r = subprocess.run(
@@ -405,6 +443,8 @@ def test_rehearsal_reads_the_counter_metrics_and_no_device_metric():
                  "serve.loop_idle_share", "serve.itl_p99_ms",
                  "serve.step_host_ms", "serve.admit_host_ms"):
         assert got[name]["value"] >= 0, name
+    # every bucket of the toy cell is a prompt span: written as blocks
+    assert got["kv.prompt_block_write_share.serve"]["value"] == 100
     # the iteration's host time without the admissions' is the smaller
     assert got["serve.step_host_ms"]["value"] \
         < got["serve.sched_self_ms"]["value"]
@@ -427,6 +467,8 @@ def test_rehearsal_of_the_xing4_family_reads_its_counter_metric():
     assert 0 < got["moe.experts_hit_share.serve"]["value"] <= 100
     assert got["serve.decode_call_ms"]["value"] >= 0
     for name in ("serve.decode_step_mfu", "serve.decode_step_roofline",
+                 # the latent rows are written in the decoder's own forward
+                 "kv.prompt_block_write_share.serve",
                  "kernel.mla_paged_attn_roofline.serve",
                  "kernel.paged_attn_roofline.serve",
                  "device.idle_attributed_share.serve"):

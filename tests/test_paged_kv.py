@@ -478,16 +478,25 @@ class TestPoolDeviceForm:
         off, sl = np.asarray(off, np.int32), np.asarray(sl, np.int32)
         want = [_element_scatter(p, n, bt, off, sl)
                 for p, n in zip(pools4, news)]
-        got = F.paged_kv_write(
+        # the public op: a span of a block or more goes by whole blocks,
+        # which leave the garbage block garbage of their own
+        public = F.paged_kv_write(
             *(paddle.to_tensor(np.asarray(kv_pool.merged(jnp.asarray(p))))
               for p in pools4),
             *(paddle.to_tensor(n) for n in news),
             paddle.to_tensor(bt), paddle.to_tensor(off),
             paddle.to_tensor(sl))
-        for g, w, before in zip(got, want, pools4):
+        for g, w in zip(public, want):
             g = np.asarray(g.numpy())
             assert g.shape == (Nb, bs, H * Dh)  # no 4-D pool comes back
-            g = g.reshape(Nb, bs, H, Dh)
+            np.testing.assert_array_equal(g.reshape(Nb, bs, H, Dh)[1:],
+                                          w[1:])
+        blk, row = kv_pool.span_rows(bt, off, sl, T, bs)
+        got = [kv_pool.write_rows(kv_pool.merged(jnp.asarray(p)),
+                                  n.reshape(B * T, -1), blk, row)
+               for p, n in zip(pools4, news)]
+        for g, w, before in zip(got, want, pools4):
+            g = np.asarray(g).reshape(Nb, bs, H, Dh)
             np.testing.assert_array_equal(g, w)
             # rows outside [0, seq_len) and dead lanes: garbage row only
             live = sum(int(min(o + T, s) - o) for b, (o, s) in
@@ -596,6 +605,129 @@ class TestPoolDeviceForm:
         with pytest.raises(ValueError, match="block shape"):
             b.import_request_kv(1, payload)
         b.pool.audit()
+
+
+class TestPromptBlockWrite:
+    """A span of at least a block a slot lands in a full layer's pools as
+    whole blocks (``kv_pool.span_blocks`` + ``write_blocks``): bit-equal to
+    the row write on every row outside the garbage block, whatever the
+    offset and however few the rows."""
+
+    # (B, T, offsets, seq_lens): bs = 4, M = 8 table columns (32 rows)
+    CASES = {
+        "block_aligned": (1, 16, [8], [21]),  # 3 rows of bucket padding
+        "unaligned_offset": (1, 16, [5], [21]),
+        # rows 4-5 of the first block are a shared prefix's tail
+        "shared_prefix_last_block": (1, 16, [6], [19]),
+        "bucket_padding": (1, 32, [0], [3]),  # 8 of 9 blocks dead
+        "span_past_the_table": (1, 32, [16], [32]),
+        "batch_of_three": (3, 16, [0, 9, 20], [16, 12, 32]),
+        "nothing_live": (2, 16, [4, 8], [4, 8]),
+        # spans shorter than a block, the row scatter's in write_span
+        "decode_row": (3, 1, [0, 9, 31], [1, 10, 32]),
+        "short_span": (2, 3, [8, 13], [11, 16]),
+        "span_over_a_block": (2, 6, [3, 12], [9, 16]),
+    }
+
+    @pytest.mark.parametrize("case", sorted(CASES))
+    def test_block_write_bit_equal_to_the_row_write(self, case):
+        import jax.numpy as jnp
+
+        from paddle_tpu.ops import kv_pool
+
+        B, T, off, sl = self.CASES[case]
+        Nb, bs, H, Dh, M = 1 + 3 * 8 + 3, 4, 2, 8, 8
+        rng = np.random.default_rng(7)
+        pools = [jnp.asarray(rng.standard_normal((Nb, bs, H * Dh)),
+                             jnp.float32) for _ in range(2)]
+        new = [jnp.asarray(rng.standard_normal((B, T, H, Dh)), jnp.float32)
+               for _ in range(2)]
+        bt = rng.permutation(np.arange(1, Nb))[:B * M].reshape(B, M) \
+            .astype(np.int32)
+        off, sl = np.asarray(off, np.int32), np.asarray(sl, np.int32)
+        if kv_pool.writes_blocks(T, bs):
+            got = kv_pool.write_span(*pools, *new, bt, off, sl)
+        else:
+            where = kv_pool.span_blocks(bt, off, sl, T, bs)
+            got = [kv_pool.write_blocks(p, r.reshape(B, T, -1), *where)
+                   for p, r in zip(pools, new)]
+        blk, row = kv_pool.span_rows(bt, off, sl, T, bs)
+        live = {(int(bt[b, p // bs]), p % bs) for b in range(B)
+                for p in range(off[b], min(off[b] + T, sl[b]))}
+        for g, pool, rows in zip(got, pools, new):
+            want = np.asarray(kv_pool.write_rows(
+                pool, rows.reshape(B * T, -1), blk, row))
+            g, before = np.asarray(g), np.asarray(pool)
+            assert g.shape == before.shape
+            # live rows bit-equal, every other row outside block 0 as it was
+            np.testing.assert_array_equal(g[1:], want[1:])
+            changed = {(int(b), int(r)) for b, r in
+                       np.argwhere((g != before).any(axis=-1)) if b != 0}
+            assert changed == live
+        # the scatter's indices: the span's blocks, dead ones on block 0
+        ids, shift, rows_live, edges = kv_pool.span_blocks(bt, off, sl, T,
+                                                           bs)
+        ids = np.asarray(ids)
+        assert ids.shape == (B, -(-(T + bs - 1) // bs))
+        held = ids[ids != 0]
+        assert len(held) == len(set(held.tolist()))
+        assert set(held.tolist()) == {b for b, _ in live}
+        np.testing.assert_array_equal(np.asarray(shift), off % bs)
+
+    @pytest.mark.parametrize("T,window,blocks", [
+        (1, None, False), (4, None, False), (5, None, False),
+        (15, None, False), (16, None, True), (2048, None, True),
+        (2048, 4096, False)])
+    def test_which_spans_land_as_blocks(self, T, window, blocks):
+        # at block 16: decode's row, a block step's 4, a verify span's K + 1
+        # under a block and every span of a window layer's ring keep the
+        # row scatter
+        from paddle_tpu.ops import kv_pool
+
+        assert kv_pool.writes_blocks(T, 16, window) is blocks
+
+    def test_the_engine_counts_what_the_layers_write(self):
+        # the engine's counter asks the cache the same question: some full
+        # layer of a heads cache (a latent row is the decoder's own write)
+        from paddle_tpu.ops.kv_pool import CacheSpec
+
+        full = CacheSpec("heads", [(2, 8)] * 2)
+        mixed = CacheSpec("heads", [(2, 8)] * 2, windows=[64, None])
+        rings = CacheSpec("heads", [(2, 8)] * 2, windows=[64, 64])
+        latent = CacheSpec("latent", [(512, 64)])
+        assert full.writes_blocks(16, 16) and mixed.writes_blocks(2048, 16)
+        assert not full.writes_blocks(4, 16)
+        assert not rings.writes_blocks(2048, 16)
+        assert not latent.writes_blocks(2048, 16)
+
+    def test_token_streams_are_the_parent_commits(self):
+        """Greedy and sampled streams of a toy engine whose buckets (16,
+        32) are prompt spans, one of them after a prefix hit (the span
+        starts past the matched blocks), recorded on the tree before the
+        block write: the same on the gather path and through the kernel's
+        interpreter, and every prefill counted as a block write."""
+        want = [[74, 74, 51, 51, 74, 51, 0, 27, 58, 88],
+                [24, 85, 51, 85, 74, 5, 52, 51, 52, 52],
+                [48, 52, 58, 1, 74, 60, 82, 82, 74, 74]]
+        for kern in ("xla", "pallas"):
+            eng = GenerationEngine(_build_model(), max_batch_size=2,
+                                   buckets=(16, 32), rng_seed=7,
+                                   block_size=4, paged_kernel=kern)
+            rng = np.random.default_rng(4)
+            a = [int(x) for x in rng.integers(1, VOCAB, 13)]
+            b = [int(x) for x in rng.integers(1, VOCAB, 27)]
+            c0 = dict(registry.counters("serving"))
+            got = [_run_one(eng, a, 10, seed=1),
+                   _run_one(eng, b, 10, seed=2, temperature=0.9, top_k=20),
+                   _run_one(eng, b[:20] + [5, 6, 7], 10, seed=3)]
+            c1 = registry.counters("serving")
+            assert got == want, kern
+            assert c1["prefix_hits"] - c0["prefix_hits"] == 1
+            assert c1["prefill_block_writes"] \
+                - c0["prefill_block_writes"] == 3
+            # (a span's counters exist from its first call in the process)
+            assert c1["prefill_n"] - c0.get("prefill_n", 0) == 3
+            eng.pool.audit()
 
 
 class TestPagedSchedulingEdges:

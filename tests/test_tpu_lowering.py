@@ -470,10 +470,25 @@ def prefill_layer(kernel):
         return y._data, nk._data, nv._data
     return f
 
+def write_indices(text):
+    # the index count of every scatter into an array of a pool's size: the
+    # leading dimension of its updates (rows, or whole blocks)
+    shapes = dict(re.findall(r"%([\w.\-]+) = \w+\[([0-9,]*)\]", text))
+    counts = []
+    for line in text.splitlines():
+        h = HEAD.match(line)
+        if h and h.group(3) == "scatter" and int(np.prod(
+                [int(d) for d in h.group(2).split(",")])) == POOL:
+            upd = re.search(r"scatter\(%[\w.\-]+, %[\w.\-]+, %([\w.\-]+)\)",
+                            line).group(1)
+            counts.append(int(shapes[upd].split(",")[0]))
+    return counts
+
 for L, blocks, kernel in ((256, NB, "pallas"), (512, NB, "pallas"),
                           (1024, NB, "pallas"), (2048, 1025, "pallas"),
                           (2048, 1025, None)):
     pl_ = sds((blocks, BS, 2048), jnp.bfloat16, sharding=one)
+    POOL = blocks * BS * 2048
     name = f"prefill_layer_L{L}" + ("" if kernel else "_gather")
     try:
         c = jax.jit(prefill_layer(kernel), donate_argnums=(1, 2)).trace(
@@ -490,7 +505,10 @@ for L, blocks, kernel in ((256, NB, "pallas"), (512, NB, "pallas"),
             "score_arrays": score_arrays(text, L),
             "temp_bytes": int(c.memory_analysis().temp_size_in_bytes),
             "alias_bytes": int(c.memory_analysis().alias_size_in_bytes),
-            "pool_bytes": blocks * BS * 2048 * 2}
+            "pool_bytes": POOL * 2,
+            "pool_layout": list(c.input_formats[0][1].layout.major_to_minor),
+            "pool_sized": pool_sized(text),
+            "write_indices": write_indices(text)}
     except Exception as e:
         out[name] = f"{type(e).__name__}: {e}"[:600]
 
@@ -865,6 +883,36 @@ def test_serving_layer_writes_its_kv_rows_in_place_on_v5e(T):
         + got["pool_sized"].get("dynamic-update-slice", 0) >= 2, got
     assert got["temp_bytes"] < got["pool_bytes"], got  # (c)
     assert got["alias_bytes"] >= 2 * got["pool_bytes"], got  # donated
+
+
+@pytest.mark.parametrize("L,blocks", [(1024, 3073), (2048, 1025)])
+def test_prompt_span_lands_as_whole_blocks_in_place_on_v5e(L, blocks):
+    """The two gpt cells' prompt spans (the chat cell's 1024 bucket into
+    its 3,073-block pools, the long-prefill cell's 2048 into 1,025 blocks),
+    the layer compiled for the described v5e with donated pools (the AOT
+    child's result of the test above): each pool's write is ONE scatter
+    whose index count is the span's blocks (L / 16 + 1 at any offset), not
+    its L rows — the row scatter walked them one at a time, 0.38 ms a pool
+    at the 2048 bucket on the chip. The pools enter row-major, nothing of a
+    pool's size exists but the pools and the in-place writes, the
+    temporaries stay under one pool, the pools are aliased, and the one
+    custom call is still `flash_prefill`."""
+    if not _SERVE_LAYERS:
+        pytest.skip("test_aot_compile_for_v5e did not compile here")
+    got = _SERVE_LAYERS[f"prefill_layer_L{L}"]
+    assert isinstance(got, dict), got
+    assert got["pool_bytes"] == blocks * 16 * 2048 * 2
+    assert got["write_indices"] == [L // 16 + 1] * 2, got
+    assert got["pool_layout"] == [0, 1, 2], got
+    extra = {op: n for op, n in got["pool_sized"].items()
+             if op not in ("parameter", "bitcast", "write", "scatter",
+                           "dynamic-update-slice")}
+    assert not extra, got  # no pool-sized copy / reshape / transpose
+    assert got["pool_sized"].get("write", 0) \
+        + got["pool_sized"].get("scatter", 0) >= 2, got
+    assert got["temp_bytes"] < got["pool_bytes"], got
+    assert got["alias_bytes"] >= 2 * got["pool_bytes"], got
+    assert got["custom_calls"] == 1 and got["kernels"] == ["flash_prefill"]
 
 
 @pytest.mark.parametrize("L", [256, 512, 1024, 2048])

@@ -223,7 +223,8 @@ def _print_mesh_serving(counters, gauges):
 
 
 _KERNEL_PREFIXES = ("serving.kernel.", "kernel.", "serving.prefill_kernel",
-                    "serving.prefill_flash_calls")
+                    "serving.prefill_flash_calls",
+                    "serving.prefill_block_writes")
 
 
 def _print_kernels(counters, gauges):
@@ -233,8 +234,9 @@ def _print_kernels(counters, gauges):
     for the training flash family (one per trace) — plus the fallback
     count; any nonzero serving.kernel.fallbacks means a Pallas-eligible
     call dropped to the gather path (profiler.explain() names why). Beside
-    them what the prompt span reads through (gauge serving.prefill_kernel)
-    and how many prefill calls ran `flash_prefill`."""
+    them what the prompt span reads through (gauge serving.prefill_kernel),
+    how many prefill calls ran `flash_prefill` and how many wrote their
+    rows into the pools as whole blocks."""
     kn = {k: counters.pop(k) for k in list(counters)
           if k.startswith(_KERNEL_PREFIXES)}
     kn.update({k: gauges.pop(k) for k in list(gauges)
