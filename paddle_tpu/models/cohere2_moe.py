@@ -59,12 +59,9 @@ from ..nn.moe.dropless import DroplessMoE
 from ..ops import kv_pool as _kv_pool
 from ..ops import pallas_ops as _pallas_ops
 from ..profiler.spans import scope as _scope
-from .xing4 import _mm, _Weight
+from ._blocks import _head_logits, _mm, _walk_attention, _WALK_BLOCK, _Weight
 
 _F32 = jnp.float32
-# queries and keys a block of the walk: float32 scores of every head for one
-# pair of blocks are heads x 512 x 512 x 4 B (134 MB at 128 heads)
-_WALK_BLOCK = 512
 # tokens of a prompt the expert layer takes at a time (DroplessMoE
 # `rows_at_a_time`): 1024 x 8 routed rows x 2 x 4096 float32 are 268 MB
 _MOE_ROWS = 1024
@@ -175,76 +172,6 @@ def _rope(x, positions, theta):
                      axis=-1).reshape(x.shape)
 
 
-def _walk_attention(q, k, v, q_pos, k_pos, k_valid, window, block):
-    """Grouped-query attention of q [B, T, Hq, D] over k, v [B, S, Hkv, D]
-    without the ``[Hq, T, S]`` scores: queries a block at a time, for each a
-    walk over the key blocks it may see with a running softmax (float32
-    maximum, sum and accumulator). ``q_pos`` [B, T] and ``k_pos`` [B, S]
-    are positions, ascending alike (row i of q and of k is the same
-    token's); a query sees keys with ``k_pos <= q_pos``, ``k_valid`` [B, S]
-    and, with a ``window``, ``k_pos > q_pos - window`` — and the walk of a
-    window layer starts at the block that holds the oldest of them.
-    Returns [B, T, Hq, D] in q's dtype."""
-    B, T, Hq, D = q.shape
-    S, G = k.shape[1], k.shape[2]
-    R, dt = Hq // G, q.dtype
-    if T % block or S % block or T != S:
-        block_q, block_k = T, S
-    else:
-        block_q = block_k = block
-    n_k = S // block_k
-    scale = _F32(D ** -0.5)
-    q = q.reshape(B, T // block_q, block_q, G, R, D)
-    i32 = jnp.int32
-
-    def kblock(x, j):  # [B, S, ...] -> block j [B, block_k, ...]
-        return jax.lax.dynamic_slice_in_dim(x, j * block_k, block_k, axis=1)
-
-    def rows(args):
-        i, qb, qp = args  # block index, [B, Q, G, R, D], [B, Q]
-        # rows of q and k are the same tokens: query block i may see key
-        # blocks up to its own, from the one `window` rows back
-        hi = jnp.minimum(((i + 1) * block_q + block_k - 1) // block_k, n_k)
-        lo = i32(0) if window is None else jnp.maximum(
-            (i * block_q - i32(window)) // block_k, i32(0))
-
-        def fold(j, carry):
-            m, l, acc = carry
-            kb, vb = kblock(k, j), kblock(v, j)
-            kp, kv = kblock(k_pos, j), kblock(k_valid, j)
-            s = jnp.einsum("bqgrd,bkgd->bgrqk", qb, kb,
-                           preferred_element_type=_F32) * scale
-            keep = (kp[:, None, :] <= qp[:, :, None]) & kv[:, None, :]
-            if window is not None:
-                keep = keep & (kp[:, None, :] > qp[:, :, None] - i32(window))
-            s = jnp.where(keep[:, None, None], s, -jnp.inf)
-            m_new = jnp.maximum(m, s.max(-1))
-            # a row that has seen no key yet keeps exp(-inf - -inf) out
-            safe = jnp.where(jnp.isfinite(m_new), m_new, _F32(0))
-            p = jnp.exp(s - safe[..., None])
-            corr = jnp.exp(jnp.where(jnp.isfinite(m), m - safe, -jnp.inf))
-            l = l * corr + p.sum(-1)
-            acc = acc * corr[..., None] + jnp.einsum(
-                "bgrqk,bkgd->bgrqd", p.astype(dt), vb,
-                preferred_element_type=_F32)
-            return m_new, l, acc
-
-        shape = (B, G, R, block_q)
-        m, l, acc = jax.lax.fori_loop(
-            lo, hi, fold, (jnp.full(shape, -jnp.inf, _F32),
-                           jnp.zeros(shape, _F32),
-                           jnp.zeros(shape + (D,), _F32)))
-        out = acc / jnp.maximum(l, _F32(1e-30))[..., None]
-        return jnp.moveaxis(out, 3, 1).astype(dt)  # [B, Q, G, R, D]
-
-    n_q = T // block_q
-    out = jax.lax.map(rows, (jnp.arange(n_q, dtype=i32),
-                             jnp.moveaxis(q, 1, 0),
-                             jnp.moveaxis(q_pos.reshape(B, n_q, block_q),
-                                          1, 0)))
-    return jnp.moveaxis(out, 0, 1).reshape(B, T, Hq, D)
-
-
 class Cohere2MoeAttention(nn.Layer):
     def __init__(self, cfg, sliding):
         super().__init__()
@@ -323,16 +250,6 @@ class Cohere2MoeLayer(nn.Layer):
                                 kernel=moe_kernel)._data, cache
 
 
-def _tied_logits(scale):
-    """bf16 x bf16 with float32 accumulation against the embedding table as
-    it is stored (no float32 copy, no transpose), times `logit_scale`."""
-    def logits(hidden, w):
-        out = jax.lax.dot_general(hidden, w, (((1,), (1,)), ((), ())),
-                                  preferred_element_type=_F32)
-        return out if scale == 1 else out * _F32(scale)
-    return logits
-
-
 class Cohere2MoeModel(nn.Layer):
     """forward(input_ids [B, T]) -> logits [B, T, V] float32, or with the
     paged-cache arguments (what `serving.GenerationEngine` passes;
@@ -350,7 +267,7 @@ class Cohere2MoeModel(nn.Layer):
         self.layers = nn.LayerList([Cohere2MoeLayer(cfg, i)
                                     for i in range(cfg.num_hidden_layers)])
         self.norm = _Weight((cfg.hidden_size,), Constant(1.0), cfg.dtype)
-        self._logits = _tied_logits(cfg.logit_scale)
+        self._logits = _head_logits(cfg.logit_scale)
 
     # -- what serving.GenerationEngine asks of a decoder -------------------
     @property

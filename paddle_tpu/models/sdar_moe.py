@@ -28,7 +28,7 @@ listed there and in the benchmark's configuration file (`assumed`).
 
 Three attention paths, one set of weights:
 
-* no cache: `cohere2_moe._walk_attention` (a walk over key blocks with a
+* no cache: `_blocks._walk_attention` (a walk over key blocks with a
   running softmax), every query standing at its block's last position —
   the path tests compare with the reference;
 * paged prefill (``paged_kernel=None``): the call's rows are written into
@@ -51,7 +51,6 @@ from __future__ import annotations
 
 import typing
 
-import jax
 import jax.numpy as jnp
 
 from .. import nn
@@ -61,8 +60,8 @@ from ..nn.moe.dropless import DroplessMoE
 from ..ops import kv_pool as _kv_pool
 from ..ops import pallas_ops as _pallas_ops
 from ..profiler.spans import scope as _scope
-from .cohere2_moe import _WALK_BLOCK, _tied_logits, _walk_attention
-from .xing4 import _mm, _rms, _Weight
+from ._blocks import (_head_logits, _mm, _rms, _rope_halves,
+                      _walk_attention, _WALK_BLOCK, _Weight)
 
 _F32 = jnp.float32
 
@@ -148,18 +147,6 @@ class SdarMoeConfig:
 
     def as_dict(self):
         return {k: getattr(self, k) for k in self.PUBLISHED}
-
-
-def _rope_halves(x, positions, theta):
-    """x [B, T, H, D] rotated on the halves (i, i + D/2) at positions
-    [B, T], in float32: inv_freq_i = theta^(-2i/D)."""
-    D = x.shape[-1]
-    inv = 1.0 / (float(theta) ** (jnp.arange(0, D, 2, dtype=_F32) / D))
-    ang = positions.astype(_F32)[:, :, None, None] * inv
-    cos, sin = jnp.cos(ang), jnp.sin(ang)
-    x = x.astype(_F32)
-    a, b = x[..., :D // 2], x[..., D // 2:]
-    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin], axis=-1)
 
 
 class SdarMoeAttention(nn.Layer):
@@ -267,7 +254,7 @@ class SdarMoeModel(nn.Layer):
         # untied, [V, d] as the checkpoints keep it
         self.lm_head = _Weight((cfg.vocab_size, cfg.hidden_size), init,
                                cfg.dtype)
-        self._logits = _tied_logits(1)
+        self._logits = _head_logits()
 
     # -- what serving.GenerationEngine asks of a decoder -------------------
     @property

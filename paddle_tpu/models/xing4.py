@@ -48,6 +48,7 @@ from ..nn.moe.dropless import DroplessMoE, swiglu
 from ..ops import kv_pool as _kv_pool
 from ..ops import pallas_ops as _pallas_ops
 from ..profiler.spans import scope as _scope
+from ._blocks import _head_logits, _mm, _param, _rms, _Weight
 
 _F32 = jnp.float32
 _HI = jax.lax.Precision.HIGHEST
@@ -123,31 +124,6 @@ class Xing4Config:
 
     def as_dict(self):
         return {k: getattr(self, k) for k in self.PUBLISHED}
-
-
-def _param(layer, shape, init, dtype):
-    return layer.create_parameter(list(shape), dtype=dtype,
-                                  default_initializer=init)
-
-
-class _Weight(nn.Layer):
-    """A bare ``weight`` of shape [in, out] (or [n] for a norm's gain), so
-    that names read `q_a_proj.weight` as the published checkpoints' do."""
-
-    def __init__(self, shape, init, dtype):
-        super().__init__()
-        self.weight = _param(self, shape, init, dtype)
-
-
-def _rms(x, w, eps):
-    """RMSNorm with float32 statistics; returns float32."""
-    x = x.astype(_F32)
-    return x * jax.lax.rsqrt((x * x).mean(-1, keepdims=True)
-                             + _F32(eps)) * w.astype(_F32)
-
-
-def _mm(x, w):
-    return jnp.dot(x, w, preferred_element_type=_F32)
 
 
 # ------------------------------------------------------------------ rotary --
@@ -451,12 +427,6 @@ class Xing4Layer(nn.Layer):
         return streams, new_cache[0]
 
 
-def _untied_logits(hidden, w):
-    """bf16 x bf16 with float32 accumulation: no float32 copy of the head."""
-    return jax.lax.dot_general(hidden, w, (((1,), (1,)), ((), ())),
-                               preferred_element_type=_F32)
-
-
 class Xing4Model(nn.Layer):
     """forward(input_ids [B, T]) -> logits [B, T, V] float32, or with the
     paged-cache arguments (what `serving.GenerationEngine` passes) ->
@@ -488,7 +458,7 @@ class Xing4Model(nn.Layer):
             * len(self.layers))
 
     def serving_head(self):
-        return self.lm_head.weight, _untied_logits
+        return self.lm_head.weight, _head_logits()
 
     def step_counters(self):
         """Device-side counts of the last forward, by name (taken once: the
@@ -538,6 +508,6 @@ class Xing4Model(nn.Layer):
                  self.cfg.rms_norm_eps).astype(self.norm.weight._data.dtype)
         if caches is not None:
             return Tensor(h), new_caches
-        return Tensor(_untied_logits(
+        return Tensor(_head_logits()(
             h.reshape(B * T, -1), self.lm_head.weight._data
         ).reshape(B, T, -1))

@@ -81,7 +81,9 @@ class DroplessMoE(Layer):
     """forward(x [B, T, d], valid=None) -> y [B, T, d] float32: the held
     routed experts' part of the layer plus the shared experts (``n_shared``
     of them side by side in one matmul; their outputs summed, or with
-    ``shared_combine="average"`` their mean; ``n_shared=0``: none;
+    ``shared_combine="average"`` their mean, or with
+    ``shared_combine="sigmoid_gate"`` their sum times ``sigmoid(x w)`` a
+    token, ``w`` [d, 1] the layer's `shared_gate`; ``n_shared=0``: none;
     ``select_bias=False``: a router that chooses by its scores alone;
     ``router="softmax"``: probabilities over all experts where the sigmoid
     scores are, `route_softmax_topk`). The router
@@ -115,9 +117,10 @@ class DroplessMoE(Layer):
             raise ValueError(f"router {router!r}: 'sigmoid', or 'softmax' "
                              "with no selection bias and no scaling")
         self.router_kind = router
-        if shared_combine not in ("sum", "average"):
+        if shared_combine not in ("sum", "average", "sigmoid_gate"):
             raise ValueError(f"shared_combine {shared_combine!r} is not "
-                             "'sum' or 'average'")
+                             "'sum', 'average' or 'sigmoid_gate'")
+        self.shared_combine = shared_combine if n_shared else None
         # n experts side by side give their sum: the mean is that over n
         self.shared_scale = 1.0 / n_shared \
             if n_shared and shared_combine == "average" else None
@@ -162,6 +165,11 @@ class DroplessMoE(Layer):
                 lin = Layer()
                 lin.weight = param(shape)
                 setattr(self.shared, name, lin)
+            if shared_combine == "sigmoid_gate":
+                # one score a token, read from the float32 input as the
+                # router's are
+                self.shared_gate = Layer()
+                self.shared_gate.weight = param((d_model, 1))
         self.last_experts_hit = None
 
     def grouped_shapes(self, tokens):
@@ -235,12 +243,17 @@ class DroplessMoE(Layer):
             out = jnp.where(mine.reshape(N * k, 1), out[back], 0.0)
             y = (out.reshape(N, k, d) * weight[:, :, None]).sum(1)
         if self.shared is not None:
-            with _spans.scope("moe_experts" if self.shared_scale is None
+            with _spans.scope("moe_experts" if self.shared_combine == "sum"
                               else "moe_shared"):
                 shared = swiglu(x, self.shared.gate_proj.weight._data,
                                 self.shared.up_proj.weight._data,
                                 self.shared.down_proj.weight._data)
                 if self.shared_scale is not None:
                     shared = shared * jnp.float32(self.shared_scale)
-                y = y + shared
+            if self.shared_combine == "sigmoid_gate":
+                with _spans.scope("moe_shared_gate"):
+                    shared = shared * jax.nn.sigmoid(jnp.dot(
+                        routed_from.astype(jnp.float32),
+                        self.shared_gate.weight._data.astype(jnp.float32)))
+            y = y + shared
         return y, (sizes > 0).sum().astype(jnp.int32)

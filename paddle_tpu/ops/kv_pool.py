@@ -26,6 +26,13 @@ heads share (``rope_dim`` lanes), zero-padded to ``W``, the next multiple
 of 128 lanes, so that the row is whole lane tiles (512 + 64 -> 640: stored
 row-major on a v5e like the merged form, AOT, PERF.md) and the decode kernel
 scores a block with one dot over the row.
+
+A "heads" cache may also hold STATE layers (``CacheSpec(states=...)``): a
+layer of linear attention keeps no row a token but one fixed-size state a
+slot — a recurrent state ``[Hv, dk, dv]`` float32 and the tail of its short
+convolution, the last ``conv_k - 1`` pre-convolution rows ``[conv_k - 1,
+conv_ch]`` — in two pools ``[slots, ...]`` indexed by the slot, not through
+a block table. The block pool budgets only the layers that keep rows.
 """
 from __future__ import annotations
 
@@ -56,30 +63,65 @@ class CacheSpec:
     its pools are sized by the window, not by ``max_seq_len``. A slot's
     ring blocks are its own from engine build: blocks ``1 + slot * ring
     ..``, block 0 the garbage block as in every pool. All window layers of
-    a decoder share one window."""
+    a decoder share one window.
 
-    def __init__(self, kind, layers, windows=None, q_per_kv=1):
+    ``states`` (heads only) says per layer None, or ``(Hv, dk, dv, conv_ch,
+    conv_k)`` for a STATE layer (its ``layers`` entry is None): no rows, a
+    recurrent state of ``Hv`` heads ``[dk, dv]`` float32 and the last
+    ``conv_k - 1`` rows of ``conv_ch`` channels before its short
+    convolution, a slot each (see the module's docstring). The engine's
+    table row then ends in one column that holds the slot, which a state
+    layer's prefill reads (`layer_tables`)."""
+
+    def __init__(self, kind, layers, windows=None, q_per_kv=1, states=None):
         if kind not in ("heads", "latent"):
             raise ValueError(f"unknown cache kind {kind!r}")
         self.kind = kind
         # query heads that read each head of a row (grouped queries): the
         # pools do not care, the paged kernel's plan does
         self.q_per_kv = int(q_per_kv)
-        self.layers = [tuple(int(x) for x in l) for l in layers]
+        self.states = [None if st is None else tuple(int(x) for x in st)
+                       for st in (states or [None] * len(layers))]
+        self.layers = [None if l is None else tuple(int(x) for x in l)
+                       for l in layers]
+        if len(self.states) != len(self.layers) \
+                or any((l is None) != (st is not None) or (
+                    st is not None and (len(st) != 5 or kind != "heads"))
+                       for l, st in zip(self.layers, self.states)) \
+                or all(st is not None for st in self.states):
+            raise ValueError(
+                f"CacheSpec: states {states!r} must give every layer of a "
+                "'heads' cache None (a layer that keeps rows) or (Hv, dk, "
+                "dv, conv_ch, conv_k) where its `layers` entry is None, "
+                "and leave one layer at least that keeps rows")
         self.windows = [None if w is None else int(w) for w in (
             windows or [None] * len(self.layers))]
         distinct = {w for w in self.windows if w is not None}
         if len(self.windows) != len(self.layers) or len(distinct) > 1 \
                 or any(w < 1 for w in distinct) \
-                or (distinct and kind != "heads"):
+                or (distinct and kind != "heads") \
+                or any(w is not None and st is not None
+                       for w, st in zip(self.windows, self.states)):
             raise ValueError(
                 f"CacheSpec: windows {windows!r} must give every layer of "
                 "a 'heads' cache None or the one window (>= 1) the "
-                "decoder's window layers share")
+                "decoder's window layers share (a state layer has none)")
         self.window = distinct.pop() if distinct else None
 
-    def row_width(self, layer=0):
-        """Lanes of one pool's row in this layer."""
+    def rows_layers(self):
+        """The layers that keep rows (every layer but the state layers)."""
+        return [i for i, st in enumerate(self.states) if st is None]
+
+    def state_layers(self):
+        return [i for i, st in enumerate(self.states) if st is not None]
+
+    def row_width(self, layer=None):
+        """Lanes of one pool's row in this layer (by default the first layer
+        that keeps rows; a state layer keeps none: 0)."""
+        if layer is None:
+            layer = self.rows_layers()[0]
+        if self.layers[layer] is None:
+            return 0
         a, b = self.layers[layer]
         if self.kind == "heads":
             return a * b
@@ -87,11 +129,27 @@ class CacheSpec:
 
     def heads(self, layer=0):
         """The heads a pool's row holds (what an 'mp' shard divides): a
-        latent row is shared by every head."""
+        latent row is shared by every head; a state layer's value heads."""
+        if self.states[layer] is not None:
+            return self.states[layer][0]
         return self.layers[layer][0] if self.kind == "heads" else 1
 
     def window_layers(self):
         return [i for i, w in enumerate(self.windows) if w is not None]
+
+    def state_shapes(self, layer, slots):
+        """The two pools of a state layer: ``[slots, Hv, dk, dv]`` (the
+        recurrent state) and ``[slots, conv_k - 1, conv_ch]`` (the
+        convolution's tail)."""
+        Hv, dk, dv, ch, ck = self.states[layer]
+        return (int(slots), Hv, dk, dv), (int(slots), ck - 1, ch)
+
+    def table_cols(self, blocks_per_slot, block_size):
+        """Columns of the engine's table row: the layers' blocks, a window
+        layer's ring behind them, and the slot's own column where there are
+        state layers."""
+        return int(blocks_per_slot) + self.ring_blocks(block_size) \
+            + int(bool(self.state_layers()))
 
     def writes_blocks(self, span, block_size):
         """Does a span of ``span`` rows a slot land in some layer's pools as
@@ -99,7 +157,8 @@ class CacheSpec:
         "heads" cache can: a latent row is written by the decoder's own
         forward."""
         return self.kind == "heads" and any(
-            writes_blocks(span, block_size, w) for w in self.windows)
+            writes_blocks(span, block_size, self.windows[i])
+            for i in self.rows_layers())
 
     def ring_blocks(self, block_size):
         """Blocks of one slot's ring in a window layer (0: no such
@@ -117,9 +176,11 @@ class CacheSpec:
     def allocate(self, num_blocks, block_size, dtype, mesh=None, slots=0):
         """``(first, second)`` pools of every layer: the K and the V pools
         of a "heads" cache (heads over 'mp' on a mesh they divide; a window
-        layer's sized by ``slots`` rings, see :meth:`layer_blocks`); the
-        latent pools and none of a "latent" one (no mesh: the engine
-        refuses one for this kind)."""
+        layer's sized by ``slots`` rings, see :meth:`layer_blocks`; a state
+        layer's state and convolution tail, ``slots`` of each, see
+        :meth:`state_shapes`); the latent pools and none of a "latent" one
+        (no mesh: the engine refuses one for this kind, and one for state
+        layers)."""
         if self.kind == "latent":
             return ([jnp.zeros((num_blocks, block_size, self.row_width(i)),
                                dtype) for i in range(len(self.layers))], [])
@@ -128,38 +189,55 @@ class CacheSpec:
             mp = int(dict(zip(mesh.axis_names,
                               mesh.devices.shape)).get("mp", 1))
             sharding = NamedSharding(mesh, pspec(
-                mp > 1 and all(h % mp == 0 for h, _ in self.layers)))
+                mp > 1 and all(self.layers[i][0] % mp == 0
+                               for i in self.rows_layers())))
 
-        def pools():
-            made = [zeros(self.layer_blocks(i, num_blocks, block_size,
-                                            slots), block_size, h, dh, dtype)
-                    for i, (h, dh) in enumerate(self.layers)]
-            if sharding is not None:
-                made = [jax.device_put(p, sharding) for p in made]
-            return made
+        def pool(i, second):
+            if self.states[i] is not None:
+                state, tail = self.state_shapes(i, slots)
+                return jnp.zeros(tail, dtype) if second \
+                    else jnp.zeros(state, jnp.float32)
+            h, dh = self.layers[i]
+            made = zeros(self.layer_blocks(i, num_blocks, block_size, slots),
+                         block_size, h, dh, dtype)
+            return made if sharding is None \
+                else jax.device_put(made, sharding)
 
-        return pools(), pools()
+        return ([pool(i, False) for i in range(len(self.layers))],
+                [pool(i, True) for i in range(len(self.layers))])
 
     def layer_tables(self, block_tables, block_size):
         """What each layer's forward addresses its pools through. One kind
-        of layer: the engine's table as it is. Full and window layers: the
-        engine's table row is the full layers' columns followed by the
-        slot's ``ring_blocks`` ring columns, and this gives every layer its
-        own part."""
-        if self.window is None:
-            return block_tables
+        of layer: the engine's table as it is. Otherwise the engine's table
+        row is the full layers' columns, the slot's ``ring_blocks`` ring
+        columns behind them (window layers) and last the slot's own column
+        (state layers), and this gives every layer its own part."""
         ring = self.ring_blocks(block_size)
-        full, rings = block_tables[:, :-ring], block_tables[:, -ring:]
-        return [full if w is None else rings for w in self.windows]
+        cols = int(bool(self.state_layers()))
+        if not ring and not cols:
+            return block_tables
+        n = block_tables.shape[1] - ring - cols
+        full, rings = block_tables[:, :n], block_tables[:, n:n + ring]
+        slot = block_tables[:, n + ring:]
+        return [slot if st is not None else full if w is None else rings
+                for w, st in zip(self.windows, self.states)]
 
     def describe(self):
         """Every kind of layer the cache has, with how many of each: "3 x
         heads: a K and a V row of 8 x 128 = 1024 a layer, window 4096 (a
         ring of 257 blocks a slot at block 16); 1 x heads: ..., every
         key" — for stats() and the layout explainer."""
-        kinds = collections.Counter(zip(self.layers, self.windows))
+        kinds = collections.Counter(zip(self.layers, self.windows,
+                                        self.states))
         said = []
-        for ((a, b), w), n in kinds.items():
+        for (l, w, st), n in kinds.items():
+            if st is not None:
+                Hv, dk, dv, ch, ck = st
+                said.append(f"{n} x state: a slot's [{Hv}, {dk}, {dv}] "
+                            f"float32 recurrent state and the last {ck - 1}"
+                            f" of {ch} channels before its convolution")
+                continue
+            a, b = l
             if self.kind == "heads":
                 what = (f"heads: a K and a V row of {a} x {b} = {a * b} a "
                         "layer")

@@ -1879,6 +1879,166 @@ def select_grouped_kernel(paged_kind, *, shapes, dtype):
         "compiled" if paged_kind == "pallas" else "the interpreter")
 
 
+# =========================== gated delta rule ================================
+#
+# A state layer's decode step (models/qwen3_next.py: gated DeltaNet): every
+# value head h of a slot keeps S [dk, dv] float32 and, given its token's
+# normalised key k and query q (of key head h // (Hv / Hk)), value v, decay
+# exp(g) and strength beta,
+#
+#     S <- exp(g) S;  S <- S + k (beta (v - S^T k))^T;  o = S^T q.
+#
+# XLA makes several passes over the states for that (the decay, the read
+# S^T k, the update, the read S^T q). `gated_delta_step` is one program per
+# slot and group of value heads: each head's 64 KiB state is copied into
+# VMEM once, updated there on the VPU (k and q arrive as columns, so every
+# product is a lane broadcast and every read a sublane sum) and written back
+# once, in place (the pool is aliased to the result). The key heads are
+# picked by the index map: no repeat is formed. The decay and beta of each
+# head ride scalar prefetch.
+
+_DELTA_GROUP_HEADS = 8  # value heads a program: 8 x 64 KiB of state
+
+
+def _delta_plan(Hk, Hv, dk, dv, compiled=True):
+    """(route, value heads a program, why) for a step over states
+    ``[.., Hv, dk, dv]`` with ``Hk`` key heads, from the shapes alone:
+    "kernel", or "refused" where Mosaic could not tile it (``compiled``
+    only: the interpreter tiles anything)."""
+    r = Hv // Hk if Hk and Hv % Hk == 0 else 0
+    hg = math.gcd(Hv, _DELTA_GROUP_HEADS)
+    why = None
+    if not r:
+        why = f"{Hv} value heads are not a whole number a key head of {Hk}"
+    elif hg % r:
+        hg = r * max(1, _DELTA_GROUP_HEADS // r)
+        if Hv % hg:
+            why = (f"{Hv} value heads do not divide into groups of whole "
+                   f"key heads ({r} value heads a key head)")
+    if why is None and compiled and (dk % 8 or dv % 128):
+        why = (f"a head's state [{dk}, {dv}] is not whole (8, 128) tiles")
+    return ("refused" if why else "kernel"), hg, why or "tileable"
+
+
+def _gated_delta_kernel(decay_ref, beta_ref, qk_ref, v_ref, s_ref, o_ref,
+                        so_ref, *, hg, r):
+    """Program (b, j): slot b's value heads j * hg .. (j + 1) * hg - 1. The
+    group's key heads come as rows, queries then keys; one transpose makes
+    them columns."""
+    base = (pl.program_id(0) * pl.num_programs(1) + pl.program_id(1)) * hg
+    kh = hg // r
+    cols = qk_ref[0, 0].T                              # [dk, rows]
+    for h in range(hg):  # static: the group's heads one after another
+        S = s_ref[0, h] * decay_ref[base + h]          # [dk, dv]
+        qc = cols[:, h // r:h // r + 1]                # [dk, 1]
+        kc = cols[:, kh + h // r:kh + h // r + 1]
+        mem = jnp.sum(S * kc, axis=0, keepdims=True)   # [1, dv]: S^T k
+        delta = (v_ref[0, h:h + 1, :] - mem) * beta_ref[base + h]
+        S = S + kc * delta
+        so_ref[0, h] = S
+        o_ref[0, h:h + 1, :] = jnp.sum(S * qc, axis=0, keepdims=True)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _gated_delta_fused(state, q, k, v, g, beta, interpret):
+    B, Hv, dk, dv = state.shape
+    Hk = q.shape[1]
+    _, hg, _ = _delta_plan(Hk, Hv, dk, dv, compiled=not interpret)
+    r = Hv // Hk
+    n, kh = Hv // hg, hg // r
+    rows = -(-2 * kh // 8) * 8  # a group's queries then keys, whole sublanes
+    f32 = jnp.float32
+    qk = jnp.concatenate([q.astype(f32).reshape(B, n, kh, dk),
+                          k.astype(f32).reshape(B, n, kh, dk)], axis=2)
+    qk = jnp.pad(qk, ((0, 0), (0, 0), (0, rows - 2 * kh), (0, 0)))
+
+    def at(b, j, *_):
+        return (b, j, _i0(), _i0())
+
+    def heads(b, j, *_):
+        return (b, j, _i0())
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(B, n),
+        in_specs=[pl.BlockSpec((1, 1, rows, dk), at),
+                  pl.BlockSpec((1, hg, dv), heads),
+                  pl.BlockSpec((1, hg, dk, dv), at)],
+        out_specs=[pl.BlockSpec((1, hg, dv), heads),
+                   pl.BlockSpec((1, hg, dk, dv), at)],
+    )
+    return pl.pallas_call(
+        functools.partial(_gated_delta_kernel, hg=hg, r=r),
+        grid_spec=grid_spec,
+        out_shape=(jax.ShapeDtypeStruct((B, Hv, dv), f32),
+                   jax.ShapeDtypeStruct(state.shape, f32)),
+        # the flat inputs are (decay, beta, qk, v, state): the state is
+        # updated where it lies
+        input_output_aliases={4: 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel")),
+        interpret=interpret,
+        name=_kernel_name("gated_delta_step"),
+    )(jnp.exp(g.astype(f32)).reshape(-1), beta.astype(f32).reshape(-1), qk,
+      v.astype(f32), state)
+
+
+def gated_delta_step_xla(state, q, k, v, g, beta):
+    """The same step in plain XLA (what `gated_delta_step` computes): value
+    head j reads key head j // (Hv / Hk), every product and sum float32
+    elementwise."""
+    r = state.shape[1] // q.shape[1]
+    f32 = jnp.float32
+    qh = jnp.repeat(q.astype(f32), r, axis=1)[..., None]   # [B, Hv, dk, 1]
+    kh = jnp.repeat(k.astype(f32), r, axis=1)[..., None]
+    S = state * jnp.exp(g.astype(f32))[..., None, None]
+    mem = jnp.sum(S * kh, axis=-2)                          # [B, Hv, dv]
+    delta = (v.astype(f32) - mem) * beta.astype(f32)[..., None]
+    S = S + kh * delta[..., None, :]
+    return jnp.sum(S * qh, axis=-2), S
+
+
+def gated_delta_step(state, q, k, v, g, beta, kernel="xla"):
+    """One decode step of the gated delta rule for every slot: ``state``
+    [B, Hv, dk, dv] float32 (the layer's state pool, updated in place on
+    the kernel route), ``q``, ``k`` [B, Hk, dk] normalised (q scaled),
+    ``v`` [B, Hv, dv], ``g`` and ``beta`` [B, Hv]. Returns (o [B, Hv, dv]
+    float32, the new state). ``kernel`` is what the engine's
+    :func:`select_gated_delta_kernel` resolved to ("xla" / None: plain
+    XLA)."""
+    if kernel not in (None, "xla", "pallas", "interpret"):
+        raise ValueError(
+            f"unknown gated-delta kernel {kernel!r} "
+            "(expected pallas | interpret | xla)")
+    if kernel in ("pallas", "interpret"):
+        return _gated_delta_fused(state, q, k, v, g, beta,
+                                  interpret=kernel == "interpret")
+    return gated_delta_step_xla(state, q, k, v, g, beta)
+
+
+def select_gated_delta_kernel(paged_kind, *, shapes):
+    """Resolve the state layers' decode update for one engine build, from
+    what the engine's paged kernel resolved to (the platform: "pallas" on
+    the chip) and the decode step's shapes (``shapes``: ``(B, Hk, Hv, dk,
+    dv)`` of every state layer). A fused kind stays itself when
+    :func:`_delta_plan` tiles every shape; a shape Mosaic could not tile is
+    "xla" loudly (``serving.kernel.fallbacks``, a ``kernel_fallback``
+    event). Returns ``(kind, reason)``."""
+    if not shapes:
+        return "xla", "the decoder has no state layer"
+    if paged_kind == "xla":
+        return "xla", "the engine's paged kernel resolved to xla"
+    for B, Hk, Hv, dk, dv in shapes:
+        route, _, why = _delta_plan(Hk, Hv, dk, dv,
+                                    compiled=paged_kind == "pallas")
+        if route == "refused":
+            _note_kernel_fallback("gated_delta_step", why,
+                                  state=[B, Hv, dk, dv], key_heads=Hk)
+            return "xla", why
+    return paged_kind, "follows the paged kernel: " + (
+        "compiled" if paged_kind == "pallas" else "the interpreter")
+
+
 # =========================== fused softmax mask ==============================
 
 def fused_softmax_mask(x, mask):

@@ -164,6 +164,15 @@ COUNTERS = {
                                     "the prompt's K/V rows into the pools "
                                     "as whole blocks (host, from the "
                                     "bucket: `CacheSpec.writes_blocks`)",
+    "serving.linear_layer_steps": "state layers (linear attention: a "
+                                  "recurrent state a slot) x decode steps "
+                                  "(host)",
+    "serving.linear_kernel_layer_steps": "of serving.linear_layer_steps "
+                                         "those whose state update ran "
+                                         "through the Pallas kernel "
+                                         "`gated_delta_step` (host: all of "
+                                         "a step's or none, by the gauge "
+                                         "serving.linear_state_kernel)",
     "serving.moe_experts_hit": "distinct held experts given >= 1 row, "
                                "summed over the expert layers of each "
                                "decode step: counted in the executable, "
@@ -184,6 +193,14 @@ GAUGES = {
                                   "(`pallas_ops.select_grouped_kernel`, "
                                   "following the paged kernel; the reason "
                                   "in `engine.stats()`)",
+    "serving.linear_state_kernel": "what the state layers' decode update "
+                                   "runs through: pallas / interpret / xla "
+                                   "(`pallas_ops.select_gated_delta_kernel`"
+                                   ", following the paged kernel; the "
+                                   "reason in `engine.stats()`)",
+    "serving.kv_state_bytes": "bytes of the state layers' pools: every "
+                              "slot's recurrent state and convolution tail "
+                              "(0: no state layer)",
 }
 
 # Mosaic kernels (`pl.pallas_call(name=...)`): the custom call's HLO
@@ -205,6 +222,10 @@ KERNELS = {
     "flash_prefill": "the prompt span's attention over the slot's rows in "
                      "the block pool (models/gpt.py's prefill): online "
                      "softmax a block of query rows, all heads",
+    "gated_delta_step": "a state layer's decode update (models/qwen3_next.py"
+                        "): each slot's gated delta rule state read once "
+                        "and written once in place, a group of value heads "
+                        "a program",
 }
 
 # Jitted steps: the function's name, so the `XLA Modules` event and the
@@ -252,6 +273,21 @@ SCOPES = {
     "unmask_select": "serving/sampling.py: which masked positions of a "
                      "block a denoise forward unmasks, from the sampled "
                      "ids' confidences",
+    "gated_delta": "models/qwen3_next.py: a linear-attention layer: its "
+                   "projections, the short convolution, the gated delta "
+                   "rule (the kernel at a decode step, the chunks at a "
+                   "prompt), the gated norm, output projection",
+    "gated_delta_chunks": "models/qwen3_next.py: a prompt's gated delta "
+                          "rule by chunks of 64 (the intra-chunk triangular "
+                          "solve, the scan of the state over chunks)",
+    "short_conv": "models/qwen3_next.py: the causal depthwise convolution "
+                  "over q, k, v and its tail's shift",
+    "attn_gate": "models/qwen3_next.py: a gated full-attention layer: "
+                 "projections, q/k norms, partial rotary, the pool write, "
+                 "the kernel or the prefill walk, the sigmoid output gate, "
+                 "output projection",
+    "moe_shared_gate": "nn/moe/dropless.py: the sigmoid gate a token over "
+                       "the shared expert's output",
 }
 
 

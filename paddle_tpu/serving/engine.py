@@ -63,7 +63,9 @@ layers, whose state is a ring of the last ``window`` rows a slot: a
 refused with a ``TypeError`` naming the feature and the kind, counted in
 ``serving.cache_refusals`` and explained (``cache_feature_refused``); prefix
 sharing, which is no call but a default, is switched off at engine build
-and said so the same way.
+and said so the same way. A cache with state layers (linear attention: a
+recurrent state a slot, which a prefix's rows do not give) refuses the
+same features as one with window layers.
 
 Two kinds of cache state side by side (``CacheSpec.windows``): a full
 layer's pools are the above; a window layer's hold ``1 + max_batch *
@@ -71,6 +73,19 @@ ring`` blocks, slot s owning blocks ``1 + s * ring ..`` for good. A slot's
 table row is the full layers' columns followed by its ring columns; each
 layer's forward gets its own part (``CacheSpec.layer_tables``). Admission
 budgets the full layers' blocks; the rings need no budget.
+
+A third kind (``CacheSpec.states``): a state layer's two pools, a recurrent
+state ``[max_batch, Hv, dk, dv]`` float32 and its convolution's tail
+``[max_batch, conv_k - 1, conv_ch]``, are indexed by the slot. They ride
+the step functions where a layer's K and V pools do (donated alike): the
+prefill executable writes the slot's state whole, from the prompt alone,
+through the slot's own column at the end of its table row; the decode step
+updates every lane's state in place, lane b being slot b (a released
+slot's lane updates a state nothing reads, and the next admission writes
+it whole). Preemption is by recompute: a request prefilled again from its
+prompt and the tokens it was given rebuilds the state. Their update at a
+decode step is `ops.pallas_ops.gated_delta_step`, resolved once here
+(``select_gated_delta_kernel``, following the paged kernel).
 
 Slot lifecycle: free → (admission: blocks allocated/shared, suffix
 prefill, first token sampled) → active (each decode step appends one row
@@ -113,6 +128,7 @@ _counters = _registry.scoped_counters("serving", {
     "handoff_stale": 0, "chunked_prefills": 0, "prefill_chunks": 0,
     "kv_tokens_read": 0, "kv_window_rows_read": 0,
     "cache_refusals": 0, "moe_layer_steps": 0, "moe_routed_rows": 0,
+    "linear_layer_steps": 0, "linear_kernel_layer_steps": 0,
     "moe_experts_hit": 0, "moe_kernel_layer_steps": 0, "sample_topk_steps": 0, "sample_topp_steps": 0,
     "prefill_flash_calls": 0, "prefill_block_writes": 0,
     "diffusion.slot_forwards": 0,
@@ -167,14 +183,15 @@ def _note_pool_layout(pools, cache, keys_per_program, ring, pool_bytes):
     of the engine's paged kernel folds (0: the gather path, no kernel)."""
     _registry.gauge_set("serving.paged_keys_per_program", keys_per_program)
     windows = cache.window_layers()
+    rows = cache.rows_layers()
     _registry.gauge_set("serving.kv_layers_window", len(windows))
-    _registry.gauge_set("serving.kv_layers_full",
-                        len(cache.layers) - len(windows))
+    _registry.gauge_set("serving.kv_layers_full", len(rows) - len(windows))
     _registry.gauge_set("serving.kv_window_blocks", ring)
-    # one pool of each kind of layer says it for its kind
+    # one pool of each kind of layer that keeps rows says it for its kind
+    # (a state layer's pools are indexed by slot, not by row)
     kinds = {}
-    for i, pool in enumerate(pools):
-        kinds.setdefault(cache.windows[i], pool)
+    for i in rows:
+        kinds.setdefault(cache.windows[i], pools[i])
     orders = {w: _kv_pool.device_layout(p) for w, p in kinds.items()}
     if any(o is None for o in orders.values()):
         return None
@@ -319,7 +336,11 @@ class GenerationEngine:
         self._ring = self._cache.ring_blocks(self.block_size)
         self._ring_table = _kv_pool.ring_table(self.max_batch_size,
                                                self._ring)
-        self._prefix_sharing = not self._ring and self._gen is None
+        # state layers keep one state a slot, addressed by the slot's own
+        # column at the end of its table row
+        self._states = self._cache.state_layers()
+        self._prefix_sharing = not self._ring and self._gen is None \
+            and not self._states
         if self._gen is not None:
             self._note_refusal(
                 "prefix sharing (the radix prefix cache) is off for a "
@@ -334,6 +355,12 @@ class GenerationEngine:
                 f"{self._cache.window} rows a slot): a shared prefix "
                 "block has no ring rows to go with it, and prefill "
                 "attends to the call's own rows only")
+        if self._states:
+            self._note_refusal(
+                "prefix sharing (the radix prefix cache) is off for a "
+                f"cache with state layers (layers {self._states} keep a "
+                "recurrent state a slot): a shared prefix block gives its "
+                "rows, not the state the prefix left")
 
         # generation is inference: dropout off, or padded lanes would
         # perturb nothing but sampled RNG streams would diverge
@@ -395,7 +422,7 @@ class GenerationEngine:
                     paged_kernel, row_width=self._cache.row_width(),
                     block_size=self.block_size, dtype=self._dtype)
         else:
-            heads, head_dim = self._cache.layers[0]
+            heads, head_dim = self._cache.layers[self._cache.rows_layers()[0]]
             self._paged_kernel, self._paged_kernel_reason = \
                 _pallas_ops.select_paged_kernel(
                     paged_kernel, head_dim=head_dim,
@@ -440,6 +467,19 @@ class GenerationEngine:
         self._moe_kernel_arg = {"moe_kernel": self._moe_kernel} \
             if moe_layers else {}
         _registry.gauge_set("serving.moe_grouped_kernel", self._moe_kernel)
+        # the state layers' decode update, the same way from the decode
+        # step's shapes of every layer that answers `delta_shapes(batch)`
+        delta_layers = [l for l in gpt.sublayers()
+                        if hasattr(l, "delta_shapes")]
+        self._state_kernel, self._state_kernel_reason = \
+            _pallas_ops.select_gated_delta_kernel(
+                self._paged_kernel,
+                shapes=[l.delta_shapes(self.max_batch_size)
+                        for l in delta_layers])
+        self._state_kernel_arg = {"state_kernel": self._state_kernel} \
+            if delta_layers else {}
+        _registry.gauge_set("serving.linear_state_kernel",
+                            self._state_kernel)
         if mesh is not None:
             # telemetry for the stats_dump "mesh serving" section
             _registry.gauge_set("serving.mesh.mp",
@@ -469,7 +509,13 @@ class GenerationEngine:
                 _pallas_ops.paged_keys_per_program(
                     self.block_size, heads // shards, head_dim, self._dtype,
                     self.blocks_per_slot, self._cache.q_per_kv)
-        self._kv_pool_bytes = sum(int(p.nbytes) for p in self._k + self._v)
+        rows = self._cache.rows_layers()
+        self._kv_pool_bytes = sum(int(self._k[i].nbytes) + (
+            int(self._v[i].nbytes) if self._v else 0) for i in rows)
+        self._kv_state_bytes = sum(
+            int(self._k[i].nbytes) + int(self._v[i].nbytes)
+            for i in self._states)
+        _registry.gauge_set("serving.kv_state_bytes", self._kv_state_bytes)
         self._kv_row_major = _note_pool_layout(
             self._k, self._cache, self._paged_keys_per_program, self._ring,
             self._kv_pool_bytes)
@@ -517,8 +563,10 @@ class GenerationEngine:
         # (block 0 = reserved garbage block); _slot_blocks holds the ids
         # each slot has a pool reference on
         # a cache with window layers: the slot's ring columns behind them
-        self._block_tables = np.zeros((B, self.blocks_per_slot + self._ring),
-                                      np.int32)
+        # a cache with state layers: the slot's own column last
+        self._block_tables = np.zeros(
+            (B, self._cache.table_cols(self.blocks_per_slot,
+                                       self.block_size)), np.int32)
         self._slot_blocks = [[] for _ in range(B)]
         # chunked prefill (ISSUE 12): slot -> in-progress admission state.
         # A mid-prefill slot is RESERVED — neither free (its blocks are
@@ -724,7 +772,7 @@ class GenerationEngine:
                     block_tables=[Tensor(t) for t in tables]
                     if isinstance(tables, list) else Tensor(tables),
                     paged_kernel=kernel, paged_mesh=paged_mesh,
-                    **self._moe_kernel_arg)
+                    **self._moe_kernel_arg, **self._state_kernel_arg)
                 if self._step_counter_names:
                     got = self._gpt.step_counters()
                     if counts is not None:
@@ -1064,11 +1112,15 @@ class GenerationEngine:
 
     def _table_row(self, slot, table_ids):
         """A slot's table row: its full-layer blocks in order, zero-padded,
-        then (window layers) the ring blocks the slot owns."""
+        then (window layers) the ring blocks the slot owns, then (state
+        layers) the slot."""
         row = np.zeros(self._block_tables.shape[1], np.int32)
         row[:len(table_ids)] = table_ids
         if self._ring:
-            row[self.blocks_per_slot:] = self._ring_table[slot]
+            row[self.blocks_per_slot:self.blocks_per_slot + self._ring] = \
+                self._ring_table[slot]
+        if self._states:
+            row[-1] = slot
         return row
 
     def _admit_blocks(self, slot, prompt, max_new_tokens):
@@ -1351,8 +1403,9 @@ class GenerationEngine:
         raise TypeError(why)
 
     def require_full_layers(self, feature):
-        """Refuse what a ring cannot hold: window layers keep the last
-        ``window`` rows of a slot in blocks that are the slot's own."""
+        """Refuse what a ring cannot hold (window layers keep the last
+        ``window`` rows of a slot in blocks that are the slot's own) and
+        what a state layer's one state a slot cannot."""
         if self._cache.window is not None:
             self._refuse(
                 f"{feature} is not supported for a cache with window "
@@ -1361,6 +1414,14 @@ class GenerationEngine:
                 "which has no head-sharded placement, no payload form, "
                 "and holds neither a verify span nor the rows an earlier "
                 "call wrote beyond the window")
+        if self._cache.state_layers():
+            self._refuse(
+                f"{feature} is not supported for a cache with state layers "
+                f"yet: layers {self._cache.state_layers()} keep one "
+                "recurrent state a slot, which a prefix's or an earlier "
+                "chunk's rows do not give, a verify span cannot roll back, "
+                "and which has no head-sharded placement and no payload "
+                "form")
 
     def require_autoregressive(self, feature, why):
         """Refuse what is written for a decoder that yields one token a
@@ -1621,6 +1682,8 @@ class GenerationEngine:
             _counters[name] += n
             if name == "moe_layer_steps" and self._moe_kernel != "xla":
                 _counters["moe_kernel_layer_steps"] += n
+            if name == "linear_layer_steps" and self._state_kernel != "xla":
+                _counters["linear_kernel_layer_steps"] += n
 
     def _finish_decode(self, active, n_active, toks):
         # host mirrors advance in lockstep with the device copies (numpy
@@ -1784,6 +1847,9 @@ class GenerationEngine:
                "prefill_kernel_reason": self._prefill_kernel_reason,
                "moe_grouped_kernel": self._moe_kernel,
                "moe_grouped_kernel_reason": self._moe_kernel_reason,
+               "linear_state_kernel": self._state_kernel,
+               "linear_state_kernel_reason": self._state_kernel_reason,
+               "kv_state_bytes": self._kv_state_bytes,
                "kv_cache_kind": self._cache.kind,
                "kv_row_width": self._cache.row_width(),
                "kv_cache": self._cache.describe(),
@@ -1801,14 +1867,21 @@ class GenerationEngine:
 
     def _layer_records(self):
         """Every layer's cache state: the heads (or latent rank) and width
-        of its row, its window, its pools' blocks and bytes."""
-        return [{"layer": i, "heads": self._kv_heads[i],
-                 "row_width": self._cache.row_width(i),
-                 "window": self._cache.windows[i],
-                 "blocks": int(k.shape[0]),
-                 "pool_bytes": int(k.nbytes) + (
-                     int(self._v[i].nbytes) if self._v else 0)}
-                for i, k in enumerate(self._k)]
+        of its row, its window, its pools' blocks and bytes; a state
+        layer's state and convolution tail, with their bytes."""
+        out = []
+        for i, k in enumerate(self._k):
+            rec = {"layer": i, "heads": self._kv_heads[i],
+                   "row_width": self._cache.row_width(i),
+                   "window": self._cache.windows[i],
+                   "blocks": 0 if i in self._states else int(k.shape[0]),
+                   "pool_bytes": int(k.nbytes) + (
+                       int(self._v[i].nbytes) if self._v else 0)}
+            if i in self._states:
+                rec.update(state=[int(d) for d in k.shape[1:]],
+                           conv_tail=[int(d) for d in self._v[i].shape[1:]])
+            out.append(rec)
+        return out
 
     def describe_sharding(self):
         """JSON-able placement description of the engine's hot buffers —

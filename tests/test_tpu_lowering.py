@@ -402,10 +402,12 @@ def serve_layer(x, kp, vp, bt, off, sl):
 HEAD = re.compile(r"^\s*(?:ROOT )?%[\w.\-]+ = ([a-z0-9]+)\[([0-9,]*)\]"
                   r"\S* ([\w\-]+)\(")
 
-def pool_sized(text):
+def pool_sized(text, size=None):
     # opcode -> count over the instructions that yield one array of a
-    # pool's size; a fusion counts as "write" when its body holds the
-    # scatter (or dynamic-update-slice) of that size
+    # pool's size (``size`` elements, by default POOL's); a fusion counts as
+    # "write" when its body holds the scatter (or dynamic-update-slice) of
+    # that size
+    POOL_ = POOL if size is None else size
     writes, body = set(), None
     for line in text.splitlines():
         m = re.match(r"^%?([\w.\-]+) (?:\([^)]*\) -> .* )?\{$", line)
@@ -414,14 +416,14 @@ def pool_sized(text):
         h = HEAD.match(line)
         if h and h.group(3) in ("scatter", "dynamic-update-slice") \
                 and body and int(np.prod([int(d) for d in
-                                          h.group(2).split(",")])) == POOL:
+                                          h.group(2).split(",")])) == POOL_:
             writes.add(body)
     found = {}
     for line in text.splitlines():
         h = HEAD.match(line)
         if not h or not h.group(2):
             continue
-        if int(np.prod([int(d) for d in h.group(2).split(",")])) != POOL:
+        if int(np.prod([int(d) for d in h.group(2).split(",")])) != POOL_:
             continue
         op = h.group(3)
         if op == "fusion":
@@ -601,6 +603,77 @@ try:
 except Exception as e:
     out["sdar_layer"] = f"{type(e).__name__}: {e}"[:600]
 
+# the qwen3next cell's two kinds of layer at its own sizes: a linear
+# layer's decode step (32 slots' [32, 128, 128] float32 states and [3, 8192]
+# convolution tails, donated: the state is updated in place by the one custom
+# call `gated_delta_step`) and its prompt at the 16,384 bucket (the chunks,
+# the slot's state written in place), and a full layer's decode step (16
+# query heads over 2 key/value heads of 256 through `paged_attention`, pools
+# of 1 + 32 x 1072 blocks)
+from paddle_tpu.models.qwen3_next import (GatedAttention, GatedDeltaNet,
+                                          Qwen3NextConfig)
+
+q3 = Qwen3NextConfig(dtype="bfloat16", num_hidden_layers=4)
+q3_delta = GatedDeltaNet(q3)
+q3_delta.eval()
+q3_attn = GatedAttention(q3)
+q3_attn.eval()
+Q3_STATE, Q3_TAIL = (32, 32, 128, 128), (32, 3, 8192)
+q3_avals = (sds(Q3_STATE, jnp.float32, sharding=one),
+            sds(Q3_TAIL, jnp.bfloat16, sharding=one))
+
+def q3_record(c, size):
+    text = c.as_text()
+    ma = c.memory_analysis()
+    return {"state_sized": pool_sized(text, size),
+            "temp_bytes": int(ma.temp_size_in_bytes),
+            "alias_bytes": int(ma.alias_size_in_bytes),
+            "kernels": sorted(re.findall(
+                r"%(\w+?)(?:\.\d+)? = [^\n]*custom_call_target="
+                r"\"tpu_custom_call\"", text))}
+
+def q3_delta_decode(u, S, tl, off):
+    with ag.no_grad(), lazy.lazy_guard(False):
+        y, (S2, t2) = q3_delta(u, off[:, None], cache=(S, tl),
+                               cache_offset=off, seq_lens=off + 1,
+                               block_tables=jnp.zeros((32, 1), jnp.int32),
+                               paged_kernel="pallas", state_kernel="pallas")
+    return y, S2, t2
+
+def q3_delta_prompt(u, S, tl, bt, sl):
+    with ag.no_grad(), lazy.lazy_guard(False):
+        y, (S2, t2) = q3_delta(
+            u, jnp.arange(16384, dtype=jnp.int32)[None], cache=(S, tl),
+            cache_offset=jnp.zeros((1,), jnp.int32), seq_lens=sl,
+            block_tables=bt)
+    return y, S2, t2
+
+def q3_attn_decode(u, kp, vp, bt, off):
+    with ag.no_grad(), lazy.lazy_guard(False):
+        y, (nk, nv) = q3_attn(u, off[:, None], cache=(kp, vp),
+                              cache_offset=off, seq_lens=off + 1,
+                              block_tables=bt, paged_kernel="pallas")
+    return y, nk, nv
+
+i32 = lambda *shape: sds(shape, jnp.int32, sharding=one)
+try:
+    c = jax.jit(q3_delta_decode, donate_argnums=(1, 2)).trace(
+        sds((32, 1, 2048), jnp.bfloat16, sharding=one), *q3_avals,
+        i32(32)).lower(lowering_platforms=("tpu",)).compile()
+    out["qwen3next_delta_decode"] = q3_record(c, int(np.prod(Q3_STATE)))
+    c = jax.jit(q3_delta_prompt, donate_argnums=(1, 2)).trace(
+        sds((1, 16384, 2048), jnp.bfloat16, sharding=one), *q3_avals,
+        i32(1, 1), i32(1)).lower(lowering_platforms=("tpu",)).compile()
+    out["qwen3next_delta_prompt_16384"] = q3_record(
+        c, int(np.prod(Q3_STATE)))
+    q3_pool = sds((1 + 32 * 1072, 16, 512), jnp.bfloat16, sharding=one)
+    c = jax.jit(q3_attn_decode, donate_argnums=(1, 2)).trace(
+        sds((32, 1, 2048), jnp.bfloat16, sharding=one), q3_pool, q3_pool,
+        i32(32, 1072), i32(32)).lower(lowering_platforms=("tpu",)).compile()
+    out["qwen3next_attn_decode"] = q3_record(c, int(np.prod(q3_pool.shape)))
+except Exception as e:
+    out["qwen3next_layers"] = f"{type(e).__name__}: {e}"[:600]
+
 # the serving engines' own executables at toy depth and the cells'
 # vocabularies and slots: sampling finds its thresholds by selection (PR 31),
 # so neither `serving_decode` nor `serving_prefill` may hold a sort whose
@@ -760,7 +833,8 @@ def test_aot_compile_for_v5e():
     _SERVE_LAYERS.update((k, v) for k, v in res.items()
                          if k.startswith(("serve_layer_", "xing4_",
                                           "sampling_", "commanda_", "sdar_",
-                                          "prefill_layer_", "grouped_")))
+                                          "prefill_layer_", "grouped_",
+                                          "qwen3next_")))
 
 
 _SERVE_LAYERS: dict = {}
@@ -1013,3 +1087,59 @@ def test_block_span_layer_is_one_paged_kernel_written_in_place_on_v5e():
     assert not extra, got
     assert got["temp_bytes"] < got["pool_bytes"], got
     assert got["alias_bytes"] >= 2 * got["pool_bytes"], got
+
+
+# the qwen3next cell's resident bytes on the chip (its config file's sizes,
+# families/qwen3_next.py param_counts): 2,929 M bf16 parameters, the full
+# layers' pools (3 layers x K and V x 34,305 blocks x 16 x 512 bf16) and 9
+# linear layers' state pools (32 slots' float32 states and bf16 tails)
+QWEN3NEXT_RESIDENT = (2 * 2_929_374_400 + 3 * 2 * 34305 * 16 * 512 * 2
+                      + 9 * (32 * 32 * 128 * 128 * 4 + 32 * 3 * 8192 * 2))
+
+
+@pytest.mark.parametrize("step", ["decode", "prompt_16384"])
+def test_state_layer_updates_its_pools_in_place_on_v5e(step):
+    """The qwen3next cell's linear layer, compiled for the described v5e at
+    the cell's sizes with its two state pools donated (the AOT child's
+    result of the test above): at a decode step the state is updated by ONE
+    custom call, `gated_delta_step`, in place (the pools are aliased and no
+    other array of a state pool's size exists but the pools themselves and
+    the kernel's own result); at
+    the 16,384 bucket's prompt the chunks write the slot's state in place
+    (one dynamic-update-slice or scatter of the pool), no kernel. The
+    prompt's temporaries (2.7 GB, the chunks' float32 tensors) beside the
+    cell's resident bytes stay under 14.5 GB of the chip's 16."""
+    if not _SERVE_LAYERS:
+        pytest.skip("test_aot_compile_for_v5e did not compile here")
+    assert "qwen3next_layers" not in _SERVE_LAYERS, \
+        _SERVE_LAYERS["qwen3next_layers"]
+    got = _SERVE_LAYERS[f"qwen3next_delta_{step}"]
+    state, tails = 32 * 32 * 128 * 128 * 4, 32 * 3 * 8192 * 2
+    assert got["alias_bytes"] >= state + tails, got
+    extra = {op: n for op, n in got["state_sized"].items()
+             if op not in ("parameter", "bitcast", "write", "scatter",
+                           "dynamic-update-slice", "get-tuple-element")}
+    assert not extra, got
+    if step == "decode":
+        assert got["kernels"] == ["gated_delta_step"], got
+        assert got["temp_bytes"] < 8 << 20, got
+    else:
+        assert got["kernels"] == [], got
+        assert got["state_sized"].get("write", 0) \
+            + got["state_sized"].get("scatter", 0) \
+            + got["state_sized"].get("dynamic-update-slice", 0) >= 1, got
+        assert QWEN3NEXT_RESIDENT + got["temp_bytes"] < 14.5e9, got
+
+
+def test_gated_full_layer_reads_its_pools_through_the_paged_kernel_on_v5e():
+    """The qwen3next cell's full layer (16 query heads over 2 key/value
+    heads of 256: a pool row of 512 lanes), compiled for the described v5e
+    at the cell's sizes: ONE custom call under the paged kernel's name, the
+    pools aliased and written in place."""
+    if not _SERVE_LAYERS:
+        pytest.skip("test_aot_compile_for_v5e did not compile here")
+    got = _SERVE_LAYERS["qwen3next_attn_decode"]
+    assert got["kernels"] == ["paged_attention"], got
+    pool = (1 + 32 * 1072) * 16 * 512 * 2
+    assert got["alias_bytes"] >= 2 * pool, got
+    assert got["temp_bytes"] < pool, got
