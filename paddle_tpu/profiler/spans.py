@@ -160,6 +160,11 @@ COUNTERS = {
                                       "the Pallas kernel `grouped_matmul` "
                                       "(host: all of a step's or none, by "
                                       "the gauge serving.moe_grouped_kernel)",
+    "serving.prefix_evict_visits": "radix tree nodes eviction examined "
+                                   "(entries popped off its LRU heap: "
+                                   "`block_pool.RadixPrefixCache.evict`); "
+                                   "beside serving.prefix_evicted_blocks, "
+                                   "the visits a freed block",
     "serving.prefill_block_writes": "prefill calls whose full layers wrote "
                                     "the prompt's K/V rows into the pools "
                                     "as whole blocks (host, from the "

@@ -31,14 +31,26 @@ nothing here ever touches a device array. Two pieces:
   because KV computed under the old weights is garbage under the new ones
   (the satellite-1 regression in tests/test_paged_kv.py pins this).
   Eviction is leaf-first LRU over a deterministic logical clock (no wall
-  time — replays stay bitwise): under pool pressure the coldest leaves
-  whose blocks nobody but the tree holds are freed, cascading upward.
+  time — replays stay bitwise): under pool pressure the coldest leaf
+  whose block nobody but the tree holds is freed, one at a time, and a
+  parent left a leaf joins the order at its own clock. The tree keeps
+  what eviction needs as it changes instead of walking for it: the
+  evictable count, and the evictable leaves in a heap by clock. The pool
+  tells it when a block it holds goes from refcount 1 to 2 and back
+  (:meth:`BlockPool.watch`), so ``evictable_count()`` is O(1) and
+  ``evict(n)`` is O(n log nodes); ``serving.prefix_evict_visits`` counts
+  the heap entries eviction examines.
 """
 from __future__ import annotations
 
+import heapq
 import itertools
 
 import numpy as np
+
+from ..profiler import registry as _registry
+
+_counters = _registry.scoped_counters("serving", {"prefix_evict_visits": 0})
 
 
 class PagePoolExhausted(RuntimeError):
@@ -72,6 +84,16 @@ class BlockPool:
         # every downstream table/token stream) deterministic
         self._free = list(range(self.num_blocks - 1, 0, -1))
         self._ref = np.zeros(self.num_blocks, np.int32)
+        self._watched = {}
+        self._on_cross = None
+
+    def watch(self, watched, on_cross):
+        """Call ``on_cross(blocks)`` once an ``incref`` / ``decref`` with
+        the blocks keyed in ``watched`` whose refcount crossed between 1
+        and 2, in call order: the radix tree's hook, since a block only
+        the tree holds is evictable and one a slot holds too is not."""
+        self._watched = watched
+        self._on_cross = on_cross
 
     @property
     def usable_blocks(self):
@@ -102,23 +124,33 @@ class BlockPool:
         return out
 
     def incref(self, block_ids):
+        ref, watched, crossed = self._ref, self._watched, []
         for b in block_ids:
-            if self._ref[b] <= 0:
+            if ref[b] <= 0:
                 raise RuntimeError(
                     f"incref on free block {b} — stale block table or "
                     "radix node holding a freed block")
-            self._ref[b] += 1
+            ref[b] += 1
+            if ref[b] == 2 and b in watched:
+                crossed.append(b)
+        if crossed:
+            self._on_cross(crossed)
 
     def decref(self, block_ids):
         """Drop one reference per id; blocks reaching zero return to the
         free list. Double-frees raise instead of corrupting the pool."""
+        ref, watched, crossed = self._ref, self._watched, []
         for b in block_ids:
-            if self._ref[b] <= 0:
+            if ref[b] <= 0:
                 raise RuntimeError(
                     f"decref on free block {b} — double free")
-            self._ref[b] -= 1
-            if self._ref[b] == 0:
+            ref[b] -= 1
+            if ref[b] == 0:
                 self._free.append(b)
+            elif ref[b] == 1 and b in watched:
+                crossed.append(b)
+        if crossed:
+            self._on_cross(crossed)
 
     def refcount(self, block_id):
         return int(self._ref[block_id])
@@ -147,20 +179,33 @@ class BlockPool:
 
 
 class _Node:
-    __slots__ = ("chunk", "block", "children", "parent", "last_used")
+    __slots__ = ("chunk", "block", "children", "parent", "last_used",
+                 "bad", "ok", "queued")
 
     def __init__(self, chunk, block, parent):
         self.chunk = chunk          # tuple of block_size token ids
         self.block = block          # physical block id holding its KV
         self.children = {}          # chunk tuple -> _Node
-        self.parent = parent
+        self.parent = parent        # None once dropped
         self.last_used = 0
+        self.bad = 0                # children that are not evictable
+        self.ok = False             # evictable: refcount 1, bad == 0
+        self.queued = None          # last_used of its live LRU heap entry
 
 
 class RadixPrefixCache:
     """Block-granular prefix tree handing immutable KV blocks to new
     requests by refcount. One tree per engine; single-threaded (the
-    engine's driver thread owns it, like every other slot structure)."""
+    engine's driver thread owns it, like every other slot structure).
+
+    A node is evictable when its block's refcount is 1 (the tree's own)
+    and all its children are evictable; ``evictable_count()`` is their
+    number and ``evict`` frees them leaf first. Both are kept as the tree
+    and the refcounts change: each node counts its children that are not
+    evictable, a change carries up only as far as it flips a node, and
+    the evictable leaves sit in a heap keyed by ``last_used`` (unique:
+    one clock tick a node) whose stale entries are skipped when popped.
+    """
 
     def __init__(self, pool, block_size):
         self.pool = pool
@@ -169,6 +214,10 @@ class RadixPrefixCache:
         self._clock = itertools.count(1)
         self._nodes = 0
         self.generation = 0
+        self._by_block = {}         # block id -> its node
+        self._n_evictable = 0
+        self._lru = []              # (last_used, node), evictable leaves
+        pool.watch(self._by_block, self._cross)
 
     def __len__(self):
         return self._nodes
@@ -179,6 +228,11 @@ class RadixPrefixCache:
         return [tuple(int(t) for t in tokens[i * bs:(i + 1) * bs])
                 for i in range(n)]
 
+    def _touch(self, node):
+        node.last_used = next(self._clock)
+        if node.ok and not node.children:
+            self._offer(node)
+
     def match(self, tokens):
         """Longest cached block-aligned prefix of ``tokens``. Returns the
         matched physical block ids, root-first (prefix length is
@@ -188,7 +242,7 @@ class RadixPrefixCache:
             child = node.children.get(chunk)
             if child is None:
                 break
-            child.last_used = next(self._clock)
+            self._touch(child)
             out.append(child.block)
             node = child
         return out
@@ -204,63 +258,102 @@ class RadixPrefixCache:
         for chunk, block in zip(self._chunks(tokens), block_ids):
             child = node.children.get(chunk)
             if child is None:
-                child = _Node(chunk, block, node)
+                if block in self._by_block:
+                    raise RuntimeError(
+                        f"block {block} is already a radix node's")
+                self.pool.incref([block])  # not yet watched: no hook
+                child = _Node(chunk, block, node)  # the caller holds it too
                 node.children[chunk] = child
-                self.pool.incref([block])
+                self._by_block[block] = child
                 self._nodes += 1
                 created += 1
-            child.last_used = next(self._clock)
+                node.bad += 1
+                self._settle(node)
+            self._touch(child)
             node = child
         return created
 
-    def _evictable(self, node, out):
-        """Depth-first collect of fully-evictable subtrees: a node whose
-        block only the tree holds (refcount 1) and whose children are all
-        evictable too can be freed leaf-first."""
-        ok = self.pool.refcount(node.block) == 1
-        for child in node.children.values():
-            ok = self._evictable(child, out) and ok
-        if ok:
-            out.append(node)
-        return ok
+    def _cross(self, blocks):
+        """The pool's hook: these blocks' refcounts crossed 1 <-> 2. A
+        slot's release hands its whole root-first path in one call; taken
+        leaf first, each node settles against children already settled,
+        so the path costs O(path)."""
+        for b in reversed(blocks):
+            self._settle(self._by_block[b])
+
+    def _settle(self, node):
+        """Recompute whether ``node`` is evictable and carry a flip up
+        through the parents' counts of children that are not."""
+        root, ref = self._root, self.pool.refcount
+        while node is not root:
+            ok = not node.bad and ref(node.block) == 1
+            if ok == node.ok:
+                return
+            node.ok = ok
+            if ok:
+                self._n_evictable += 1
+                if not node.children:
+                    self._offer(node)
+            else:
+                self._n_evictable -= 1
+            node = node.parent
+            node.bad += -1 if ok else 1  # (the root's is never read)
+
+    def _offer(self, node):
+        """An evictable leaf joins the LRU order at its ``last_used``."""
+        if node.queued == node.last_used:
+            return                  # its entry is in the heap already
+        node.queued = node.last_used
+        heapq.heappush(self._lru, (node.last_used, node))
+        if len(self._lru) > 2 * self._nodes + 64:
+            self._compact()
+
+    def _compact(self):
+        """Drop the heap's stale entries (refreshed, pinned, no longer a
+        leaf, dropped); at most half the heap once it outgrows the tree."""
+        live = []
+        for lu, node in self._lru:
+            if node.parent is not None and lu == node.last_used \
+                    and node.ok and not node.children:
+                live.append((lu, node))
+            elif node.queued == lu:
+                node.queued = None
+        self._lru[:] = live
+        heapq.heapify(self._lru)
 
     def evictable_count(self):
-        out = []
-        for child in self._root.children.values():
-            self._evictable(child, out)
-        return len(out)
+        return self._n_evictable
 
     def evict(self, n):
-        """Free up to ``n`` cold blocks, coldest leaves first. Cascades:
-        a parent becomes a leaf once its children are gone. Returns the
-        number of blocks actually freed."""
-        freed = 0
-        while freed < n:
-            leaves = []
-            self._walk_leaves(self._root, leaves)
-            victims = [lf for lf in leaves
-                       if self.pool.refcount(lf.block) == 1]
-            if not victims:
-                break
-            victims.sort(key=lambda nd: nd.last_used)
-            for nd in victims:
-                if freed >= n:
-                    break
-                self._drop(nd)
-                freed += 1
+        """Free up to ``n`` cold blocks, the coldest evictable leaf first.
+        Cascades: a parent left an evictable leaf joins the order at its
+        own ``last_used`` (older than its children's, so an unshared
+        chain empties before the next). Returns the number freed."""
+        freed, lru, root, visits = 0, self._lru, self._root, 0
+        while freed < n and lru:
+            lu, node = heapq.heappop(lru)
+            visits += 1
+            if node.parent is None or lu != node.last_used:
+                continue            # dropped, or refreshed since
+            node.queued = None
+            if not node.ok or node.children:
+                continue            # re-offered when it is a leaf again
+            parent = node.parent
+            self._drop(node)
+            freed += 1
+            if parent is not root and parent.ok and not parent.children:
+                self._offer(parent)
+        _counters["prefix_evict_visits"] += visits
         return freed
 
-    def _walk_leaves(self, node, out):
-        for child in node.children.values():
-            if child.children:
-                self._walk_leaves(child, out)
-            else:
-                out.append(child)
-
     def _drop(self, node):
+        """Free an evictable leaf (its parent's count is unchanged)."""
         del node.parent.children[node.chunk]
-        self.pool.decref([node.block])
+        del self._by_block[node.block]
+        node.parent = None
         self._nodes -= 1
+        self._n_evictable -= 1
+        self.pool.decref([node.block])
 
     def flush(self):
         """Drop every entry (weight swap / reprime: KV from the old
@@ -271,9 +364,14 @@ class RadixPrefixCache:
             for child in list(node.children.values()):
                 _free(child)
             if node is not self._root:
+                node.parent = None
                 self.pool.decref([node.block])
+        self._by_block.clear()      # unwatched first: no hook fires
+        self._lru.clear()
+        self._n_evictable = 0
         _free(self._root)
         self._root.children.clear()
+        self._root.bad = 0
         self._nodes = 0
         return self
 
